@@ -133,8 +133,8 @@ def perf_check(session: nox.Session) -> None:
     """Perf regression gate (ROADMAP item 5, minimal core): run the
     CPU-proxy mini-bench on the ragged serving path and fail on >20%
     tok/s regression or padding-waste growth against the checked-in
-    PERF_BASELINE.json — the instrument the r05 4x drop lacked
-    (BASELINE.md 'Perf regression log').  Also runs the dp
+    PERF_BASELINE.json — the instrument the r05 4x drop lacked.
+    Also runs the dp
     replica-scaling gate (docs/SCALING.md): aggregate tok/s at
     dp=1/2/4 must clear the baseline floors and the dp=2 ≥ 1.6x /
     dp=4 ≥ 2.8x near-linear scaling ratios — plus the lora, kv_tier,

@@ -1,0 +1,443 @@
+"""Compile gates that need no chip: the served path's kernels and one
+whole step, compiled for a DESCRIBED TPU v5e by the TPU compiler that
+ships with the installation.
+
+Interpret-mode parity (test_ragged_attention.py, test_kv_quant.py) checks
+numerics; it cannot see what Mosaic or XLA:TPU refuse — a block shape off
+the tiling, a program that does not fit the chip, a pool the compiler
+re-lays on every step.  These tests see exactly that, in seconds and with
+no chip time.  Nothing runs: a compile that passes is not a chip run
+(``chip_smoke.py`` is).
+
+Rules this file keeps (on-chip-measurement guide, section 2): the
+topology is described inside a module-scoped fixture, never at import and
+never ``autouse``; everything compiles in the test's own process; the
+persistent compile cache is off around the compiles (such an entry cannot
+be read back without a chip); all of it lives in this one file, so one
+xdist worker loads the TPU library and keeps it.
+"""
+
+from __future__ import annotations
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from vllm_tgis_adapter_tpu.ops import attention as attn_ops
+from vllm_tgis_adapter_tpu.ops import kv_quant
+from vllm_tgis_adapter_tpu.ops import pallas_attention as pk
+from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
+
+#: ibm-granite/granite-3.0-2b-instruct, the widths chip_smoke.py serves
+GRANITE_2B = dict(
+    vocab_size=49155, hidden_size=2048, intermediate_size=8192,
+    num_layers=40, num_heads=32, num_kv_heads=8, head_dim=64,
+)
+HBM_BYTES = 16 * 10**9  # one v5e chip
+BLOCK = 16  # CacheConfig.block_size default
+PAGES = 1024
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_chip(monkeypatch):
+    """Steer the code's own device decisions to their chip branch: it
+    asks ``jax.default_backend()``, which here still says cpu."""
+    monkeypatch.setattr(attn_ops, "_use_pallas", lambda: True)
+    monkeypatch.setattr(attn_ops, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(ra, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ra, "_pallas_interpret", lambda: False)
+
+
+def _compile(fn, *args, **kwargs):
+    compiled = jax.jit(fn).lower(*args, **kwargs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _ragged_args(spec, *, h, hkv, dh, t, work_w, cache_dtype, seqs=64):
+    args = [
+        spec((t, h, dh), jnp.bfloat16),
+        spec((hkv, PAGES * BLOCK, dh), cache_dtype),
+        spec((hkv, PAGES * BLOCK, dh), cache_dtype),
+        spec((seqs + 1,), jnp.int32),
+        spec((seqs,), jnp.int32),
+        spec((ra.WORK_FIELDS, work_w), jnp.int32),
+    ]
+    return args
+
+
+@pytest.mark.parametrize(
+    "h,hkv,dh,t,block_q,window,alibi,cache_dtype",
+    [
+        pytest.param(32, 8, 64, 512, 128, 0, False, jnp.bfloat16,
+                     id="granite2b-mixed"),
+        # the pool's rows are lane-padded on the chip (cache_head_dim):
+        # Dh=128 is what granite-2b's served kernel sees, and the width
+        # of Mistral-7B / granite-8b heads
+        pytest.param(32, 8, 128, 512, 128, 0, False, jnp.bfloat16,
+                     id="dh128-mixed"),
+        pytest.param(32, 8, 128, 512, 128, 4096, False, jnp.bfloat16,
+                     id="dh128-window4096"),
+        pytest.param(32, 32, 128, 512, 128, 0, True, jnp.bfloat16,
+                     id="alibi-mha"),
+        pytest.param(32, 8, 128, 512, 128, 0, False, jnp.int8,
+                     id="int8-pages"),
+        pytest.param(32, 8, 128, 512, 128, 0, False, jnp.float8_e4m3fn,
+                     id="fp8-pages"),
+        pytest.param(32, 8, 64, 64, 8, 0, False, jnp.int8,
+                     id="int8-pages-dh64-decode"),
+    ],
+)
+def test_ragged_kernel_compiles(
+    topo, one_chip, h, hkv, dh, t, block_q, window, alibi, cache_dtype
+):
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    quantized = cache_dtype != jnp.bfloat16
+    args = _ragged_args(
+        spec, h=h, hkv=hkv, dh=dh, t=t, work_w=256,
+        cache_dtype=cache_dtype,
+    )
+    if quantized:
+        args += [spec((hkv, PAGES), jnp.float32)] * 2
+    if alibi:
+        args.append(spec((h,), jnp.float32))
+
+    def kernel(q, kc, vc, starts, base, work, *rest):
+        rest = list(rest)
+        scales = (rest.pop(0), rest.pop(0)) if quantized else None
+        return ra._ragged_attention_pallas(
+            q, kc, vc, starts, base, work, BLOCK, dh**-0.5,
+            block_q=block_q, window=window,
+            alibi_slopes=rest[0] if alibi else None,
+            interpret=False, kv_scales=scales,
+        )
+
+    _compile(kernel, *args)
+
+
+def test_ragged_decode_dense_schedule_compiles(topo, one_chip, on_chip):
+    """The fused decode wave's formulation: one-row spans, the schedule
+    built in the trace (``work=None``), ``block_q`` 8."""
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    b, hkv, dh = 64, 8, 128
+    max_blocks = 4096 // BLOCK
+
+    def decode(q, kc, vc, pos, tables):
+        return ra.ragged_paged_attention(
+            q, kc, vc, pos, jnp.arange(b + 1, dtype=jnp.int32), pos,
+            jnp.asarray(b, jnp.int32), tables, BLOCK, dh**-0.5,
+        )
+
+    _compile(
+        decode,
+        spec((b, 32, dh), jnp.bfloat16),
+        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16),
+        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16),
+        spec((b,), jnp.int32),
+        spec((b, max_blocks), jnp.int32),
+    )
+
+
+def test_prefill_kernels_compile(topo, one_chip):
+    """The prompt-logprob path's kernels (ops/pallas_attention.py) at
+    granite-2b widths."""
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    t, h, hkv, dh = 512, 32, 8, 64
+    _compile(
+        lambda q, k, v, n: pk.prefill_attention(q, k, v, dh**-0.5, n),
+        spec((t, h, dh), jnp.bfloat16), spec((t, hkv, dh), jnp.bfloat16),
+        spec((t, hkv, dh), jnp.bfloat16), spec((), jnp.int32),
+    )
+    _compile(
+        lambda q, kc, vc, table, start, n: pk.chunked_prefill_attention(
+            q, kc, vc, table, start, n, BLOCK, dh**-0.5
+        ),
+        spec((t, h, dh), jnp.bfloat16),
+        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16),
+        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16),
+        spec((4096 // BLOCK,), jnp.int32), spec((), jnp.int32),
+        spec((), jnp.int32),
+    )
+
+
+def test_ragged_kernel_under_tp4_shard_map(topo, on_chip):
+    """tp=4 over the described host's four chips: the kernel cannot be
+    partitioned by GSPMD, so it must sit inside shard_map with heads and
+    the pool's kv-head axis split four ways."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 1, 4),
+                ("dp", "sp", "tp"))
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes))
+        )
+
+    t, h, hkv, dh, seqs = 512, 32, 8, 128, 64
+
+    def attend(q, kc, vc, pos, starts, base, tables, work):
+        return ra.ragged_paged_attention(
+            q, kc, vc, pos, starts, base, jnp.asarray(t, jnp.int32),
+            tables, BLOCK, dh**-0.5, work=work, mesh=mesh,
+        )
+
+    _compile(
+        attend,
+        spec((t, h, dh), jnp.bfloat16, None, "tp", None),
+        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16, "tp", None, None),
+        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16, "tp", None, None),
+        spec((t,), jnp.int32), spec((seqs + 1,), jnp.int32),
+        spec((seqs,), jnp.int32), spec((seqs, 4096 // BLOCK), jnp.int32),
+        spec((ra.WORK_FIELDS, 256), jnp.int32),
+    )
+
+
+def test_head_dim_64_pool_is_lane_padded_on_the_chip(topo):
+    """The v5e lays out a pool whose rows are narrower than a lane tile
+    with the slot axis minor; scatter and kernel need the head axis
+    minor, so the pool's rows are padded there — and nowhere else."""
+    chip = topo.devices[0]
+    assert kv_quant.cache_head_dim(64, chip) == 128
+    assert kv_quant.cache_head_dim(128, chip) == 128
+    assert kv_quant.cache_head_dim(64, jax.local_devices()[0]) == 64
+
+
+def test_whole_ragged_step_compiles_and_fits(topo, one_chip, on_chip,
+                                             monkeypatch):
+    """One whole step program of the runner's serving path (mixed
+    ragged step, T=512) at granite-2b's published widths and full depth,
+    from ``eval_shape`` parameters: the kernel is in it, weights + pool +
+    temporaries fit one 16 GB chip, and the pool is updated IN PLACE —
+    no pool-sized temporary (the faults this file exists for: the
+    pre-PR-23 scatter re-laid the whole pool twice a step)."""
+    from vllm_tgis_adapter_tpu.models.llama import LlamaForCausalLM
+
+    chip = topo.devices[0]
+    probe = kv_quant.cache_head_dim
+    monkeypatch.setattr(
+        kv_quant, "cache_head_dim", lambda dh, device=None: probe(dh, chip)
+    )
+    cfg = _granite_2b()
+    model = LlamaForCausalLM(cfg)
+
+    def on_chip_shapes(tree):
+        return jax.tree.map(
+            lambda leaf: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=one_chip
+            ),
+            tree,
+        )
+
+    params = on_chip_shapes(
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    )
+    weight_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(params)
+    )
+    # the pool resolve_num_blocks would size: 90% of HBM less the weights
+    page_bytes = 2 * 40 * 8 * BLOCK * 128 * 2
+    pages = (int(HBM_BYTES * 0.9) - weight_bytes) // page_bytes
+    caches = on_chip_shapes(jax.eval_shape(
+        lambda: model.make_kv_caches(pages * BLOCK, cfg.dtype)
+    ))
+    assert caches[0].shape == (40, 8, pages * BLOCK, 128)
+    pool_bytes = 2 * caches[0].size * 2
+
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    t, seqs = 512, 64
+    step = jax.jit(
+        functools.partial(model.ragged_forward, block_size=BLOCK),
+        donate_argnums=(1,),
+    )
+    compiled = step.lower(
+        params, caches, spec((t,), jnp.int32), spec((t,), jnp.int32),
+        spec((t,), jnp.int32), spec((seqs + 1,), jnp.int32),
+        spec((seqs,), jnp.int32), spec((), jnp.int32),
+        spec((seqs, 4096 // BLOCK), jnp.int32), spec((seqs,), jnp.int32),
+        work=spec((ra.WORK_FIELDS, 256), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes  # donated, in place
+    # per-layer K and V operand slices and activations; a pool re-layout
+    # would show here as gigabytes
+    assert mem.temp_size_in_bytes < pool_bytes // 10
+    total = (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert total < HBM_BYTES
+
+
+def _granite_2b(num_layers: int = 40):
+    from vllm_tgis_adapter_tpu.engine.config import ModelConfig
+
+    return ModelConfig(
+        model="granite-3.0-2b-instruct", model_type="granite",
+        max_model_len=4096, rms_norm_eps=1e-5, tie_word_embeddings=True,
+        dtype=jnp.bfloat16, logits_scaling=8.0, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=0.015625,
+        **{**GRANITE_2B, "num_layers": num_layers},
+    )
+
+
+def test_page_movement_is_in_place(topo, one_chip):
+    """The host KV tier's one-page gather and restore (ops/kv_quant.py)
+    against a pool of the size one chip holds: the first chip run died
+    here, the gather asking for a second copy of the K pool."""
+    pages = 3872  # what resolve_num_blocks gave granite-2b on the chip
+    pool = jax.ShapeDtypeStruct(
+        (40, 8, pages * BLOCK, 128), jnp.bfloat16, sharding=one_chip
+    )
+    page = jax.ShapeDtypeStruct(
+        (40, 8, BLOCK, 128), jnp.bfloat16, sharding=one_chip
+    )
+    idx = jax.ShapeDtypeStruct((BLOCK,), jnp.int32, sharding=one_chip)
+    pool_bytes = 2 * pool.size * 2
+    gather = jax.jit(kv_quant.gather_kv_page).lower(pool, pool, idx).compile()
+    assert gather.memory_analysis().temp_size_in_bytes < pool_bytes // 100
+    restore = jax.jit(kv_quant.restore_kv_page, donate_argnums=(0, 1)).lower(
+        pool, pool, idx, page, page
+    ).compile()
+    mem = restore.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 100
+
+
+def test_tp4_step_keeps_the_pool_on_its_shards(topo, on_chip, monkeypatch):
+    """The ragged step, 2 layers deep at granite-2b widths, over tp=4 of
+    the described host: all-reduces and the kernel are in it, each
+    device updates its quarter of the pool in place, and nothing
+    gathers the pool across devices."""
+    from vllm_tgis_adapter_tpu.models.llama import LlamaForCausalLM
+    from vllm_tgis_adapter_tpu.parallel import (
+        cache_sharding,
+        llama_param_specs,
+    )
+
+    chip = topo.devices[0]
+    probe = kv_quant.cache_head_dim
+    monkeypatch.setattr(
+        kv_quant, "cache_head_dim", lambda dh, device=None: probe(dh, chip)
+    )
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 1, 4),
+                ("dp", "sp", "tp"))
+    model = LlamaForCausalLM(_granite_2b(num_layers=2))
+    model.mesh = mesh
+    shapes = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda leaf, spec: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=NamedSharding(mesh, spec)
+        ),
+        shapes, llama_param_specs(shapes, tp=4),
+    )
+    caches = jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=cache_sharding(mesh)
+        ),
+        jax.eval_shape(
+            lambda: model.make_kv_caches(8192 * BLOCK, jnp.bfloat16)
+        ),
+    )
+    shard_pool_bytes = 2 * caches[0].size * 2 // 4
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.int32, sharding=NamedSharding(mesh, P())
+        )
+
+    t, seqs = 512, 64
+    compiled = jax.jit(
+        functools.partial(model.ragged_forward, block_size=BLOCK),
+        donate_argnums=(1,),
+    ).lower(
+        params, caches, spec(t), spec(t), spec(t), spec(seqs + 1),
+        spec(seqs), spec(), spec(seqs, 4096 // BLOCK), spec(seqs),
+        work=spec(ra.WORK_FIELDS, 256),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-reduce(" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == shard_pool_bytes
+    assert mem.temp_size_in_bytes < shard_pool_bytes
+
+
+def test_lane_padded_pool_serves_the_same_tokens(tiny_model_dir,
+                                                 monkeypatch):
+    """The padded pool is chosen on the chip only, so the CPU suite would
+    never run it: force it for the tiny model (Dh 16 → 128) and require
+    the greedy tokens of the unpadded engine."""
+    from vllm_tgis_adapter_tpu.engine.config import (
+        CacheConfig,
+        EngineConfig,
+        LoRAConfig,
+        ModelConfig,
+        ParallelConfig,
+        SchedulerConfig,
+    )
+    from vllm_tgis_adapter_tpu.engine.core import LLMEngine
+    from vllm_tgis_adapter_tpu.engine.sampling_params import SamplingParams
+
+    def serve() -> tuple[list[int], int]:
+        mcfg = ModelConfig.from_pretrained(tiny_model_dir, dtype="float32")
+        engine = LLMEngine.from_config(EngineConfig(
+            model_config=mcfg,
+            cache_config=CacheConfig(block_size=16, num_blocks=32,
+                                     cache_dtype=mcfg.dtype),
+            scheduler_config=SchedulerConfig(
+                max_num_seqs=4, prefill_buckets=(32, 64)
+            ),
+            parallel_config=ParallelConfig(),
+            lora_config=LoRAConfig(),
+        ))
+        engine.add_request(
+            "r", None,
+            SamplingParams(temperature=0.0, max_tokens=8, ignore_eos=True),
+            prompt_token_ids=list(range(3, 40)),
+        )
+        tokens: list[int] = []
+        for _ in range(100):
+            if not engine.has_unfinished_requests():
+                break
+            for out in engine.step():
+                tokens = list(out.outputs[0].token_ids)
+        return tokens, engine.runner.caches[0].shape[-1]
+
+    plain, width = serve()
+    assert width == 16 and len(plain) == 8
+    monkeypatch.setattr(
+        kv_quant, "cache_head_dim", lambda dh, device=None: 128
+    )
+    padded, width = serve()
+    assert width == 128
+    assert padded == plain

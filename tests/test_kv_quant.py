@@ -597,6 +597,8 @@ def test_calibrated_checkpoint_floors_load_and_apply(tmp_path):
         for out in engine.step():
             pass
     assert done
+    # the steps donated the caches read above: take the live ones
+    k_cache, _ = engine.runner.caches
     scale0 = np.asarray(k_cache.scale[0])
     assert (scale0[scale0 > 0] >= 0.25 - 1e-6).all(), (
         "written pages ignored the calibrated floor"

@@ -131,6 +131,15 @@ def test_statless_backend_falls_back():
     assert resolve_num_blocks(cfg, FakeDevice({})) == _FALLBACK_BLOCKS
 
 
+def test_statless_tpu_is_an_error():
+    """The static pool is for CPU tests: a chip without an HBM limit
+    must not come up on it."""
+    dev = FakeDevice(None)
+    dev.platform = "tpu"
+    with pytest.raises(RuntimeError, match="static KV pool"):
+        resolve_num_blocks(make_config(), dev)
+
+
 def test_engine_resolves_auto_sizing(tiny_model_dir):
     """num_blocks=0 in the config must be resolved by engine boot."""
     import jax

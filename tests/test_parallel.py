@@ -61,6 +61,24 @@ def test_tp_divisibility_check():
     validate_tp_divisibility(tiny_config(), 4)
 
 
+def test_indivisible_vocab_replicates_the_table():
+    """granite's vocabulary (49155) is odd: tp must not refuse it, and
+    the embedding table then stays whole on every device."""
+    from jax.sharding import PartitionSpec as P
+
+    from vllm_tgis_adapter_tpu.parallel import llama_param_specs
+
+    validate_tp_divisibility(tiny_config(vocab_size=63), 4)
+    shapes = {
+        "embed": jax.ShapeDtypeStruct((63, 32), jnp.float32),
+        "lm_head": jax.ShapeDtypeStruct((32, 63), jnp.float32),
+        "layers": [],
+    }
+    specs = llama_param_specs(shapes, tp=4)
+    assert specs["embed"] == specs["lm_head"] == P(None, None)
+    assert llama_param_specs(shapes, tp=3)["embed"] == P("tp", None)
+
+
 @pytest.mark.parametrize("tp", [2, 4])
 def test_tp_matches_single_device(tp):
     """Sharded prefill + decode ≡ unsharded, bit-for-bit shapes, close values."""

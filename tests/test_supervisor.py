@@ -432,6 +432,51 @@ def test_oom_death_recovers_with_cause_label(tiny_model_dir):
     assert engine.supervisor.restart_history[0]["cause"] == "oom"
 
 
+def test_rebuild_can_free_the_dead_engines_pool(tiny_model_dir):
+    """A dispatch that dies with the KV pool among its frame's locals
+    (the host-tier gather, as on the v5e in PR 23) must not pin that pool
+    through its traceback: on a chip the pool fills HBM, and the rebuild
+    allocates its own.  By the time ``_rebuild`` has dropped
+    ``runner.caches`` nothing may still reference the old arrays."""
+    import weakref
+
+    engine = _build_engine(tiny_model_dir, tier_gb=0.01)
+    runner = engine.engine.runner
+    refs: list = []
+
+    def dying_gather(slots):
+        k_cache, v_cache = runner.caches  # the real frame's locals
+        refs[:] = [weakref.ref(k_cache), weakref.ref(v_cache)]
+        raise RuntimeError(
+            "RESOURCE_EXHAUSTED: Error loading program 'jit_gather_kv_page'"
+        )
+
+    runner.gather_kv_block = dying_gather
+    alive_after_release: list = []
+    rebuild = engine.supervisor._rebuild
+
+    def spy(old):
+        # what _rebuild does first; plain reference counting must free
+        # the arrays here, before the new pool is allocated (no
+        # gc.collect: a cycle would hold them across the allocation)
+        old.runner.caches = None
+        alive_after_release[:] = [ref() is not None for ref in refs]
+        return rebuild(old)
+
+    engine.supervisor._rebuild = spy
+
+    async def scenario():
+        result = await _collect(
+            engine, "r", prompt_ids=list(range(3, 60)), max_tokens=5
+        )
+        await engine.stop()
+        return result
+
+    status, _ = asyncio.run(scenario())
+    assert status == "ok"
+    assert alive_after_release == [False, False]
+
+
 def test_watchdog_restart_action_recovers_stuck_dispatch(
     tiny_model_dir, tmp_path
 ):

@@ -19,13 +19,18 @@ import pytest
 from vllm_tgis_adapter_tpu.ops import attention as ref_ops
 from vllm_tgis_adapter_tpu.ops import pallas_attention as pk
 
-pytestmark = [
-    pytest.mark.tpu,
-    pytest.mark.skipif(
-        jax.default_backend() != "tpu",
-        reason="requires a real TPU (RUN_TPU_TESTS=1)",
-    ),
-]
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _needs_tpu():
+    """Asked once a test of THIS file runs, never while it is imported:
+    every xdist worker collects the same tests and none touches a
+    device to do so."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("requires a real TPU (RUN_TPU_TESTS=1)")
+
+
+pytestmark = [pytest.mark.tpu]
 
 
 def _paged_case(seed, b, num_kv, g, head_dim, block_size, max_blocks, dtype):

@@ -136,3 +136,30 @@ def test_spawn_task_explicit_loop():
         assert loop.run_until_complete(task) == 7
     finally:
         loop.close()
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """compile_cache.py: with JAX_COMPILATION_CACHE_DIR set nothing is
+    set in code (JAX reads the variable itself); unset, the cache goes to
+    the one fixed path under the checkout — never /tmp, a temporary
+    name, a pid or the time, because a path that moves never hits."""
+    import jax
+
+    from vllm_tgis_adapter_tpu import compile_cache
+
+    updates = []
+    monkeypatch.setattr(
+        jax.config, "update", lambda *args: updates.append(args)
+    )
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    assert compile_cache.configure_compile_cache() == str(tmp_path)
+    assert updates == []
+
+    monkeypatch.delenv(compile_cache.ENV_VAR)
+    repo_root = compile_cache.Path(__file__).resolve().parent.parent
+    assert compile_cache.configure_compile_cache() == str(
+        repo_root / ".jax_cache"
+    )
+    assert updates == [
+        ("jax_compilation_cache_dir", str(repo_root / ".jax_cache"))
+    ]

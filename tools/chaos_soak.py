@@ -45,7 +45,6 @@ import json
 import os
 import random
 import sys
-import tempfile
 import time
 from pathlib import Path
 
@@ -681,10 +680,9 @@ def _enable_persistent_compile_cache() -> None:
     persistent compilation cache pays, not a cold build."""
     import jax
 
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        tempfile.mkdtemp(prefix="chaos-soak-xla-cache-"),
-    )
+    from vllm_tgis_adapter_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 

@@ -8,8 +8,7 @@ against the checked-in ``PERF_BASELINE.json``:
   20%) regression against the baseline, using the BEST of ``runs``
   short passes per backend to damp scheduler/load jitter (the r05
   lesson: a single 0.5s timed pass swings 3x run-to-run, which is how
-  the 1847 → 466 drop went unattributed for a round — BASELINE.md
-  "Perf regression log");
+  the 1847 → 466 CPU-run drop went unattributed for a round);
 * ``padding_waste_frac`` — fails when the padding fraction grows more
   than ``waste_slack`` absolute over the baseline (the ragged backend's
   whole claim is waste ≈ 0; a silent return of bucket padding is a
@@ -607,8 +606,7 @@ def main(argv: list[str] | None = None) -> int:
             "_comment": (
                 "CPU-proxy perf floors for nox -s perf_check (best of "
                 "`runs` BENCH_TINY passes per backend; see "
-                "tools/perf_check.py and BASELINE.md 'Perf regression "
-                "log').  Update with `python tools/perf_check.py "
+                "tools/perf_check.py).  Update with `python tools/perf_check.py "
                 "--write` after a reviewed perf-relevant change."
             ),
             "runs": runs,

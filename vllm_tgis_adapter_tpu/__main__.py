@@ -235,6 +235,10 @@ def main() -> None:
     except ImportError:
         pass
 
+    from vllm_tgis_adapter_tpu.compile_cache import configure_compile_cache
+
+    logger.info("XLA compile cache at %s", configure_compile_cache())
+
     loop = asyncio.new_event_loop()
     try:
         task = spawn_task(start_servers(args), name="start_servers", loop=loop)

@@ -34,6 +34,7 @@ logger = init_logger(__name__)
 _lock = threading.Lock()
 _seen_shapes: set[tuple[str, str]] = set()
 _total_recompiles = 0
+_total_compile_seconds = 0.0
 
 # dispatches currently executing inside a tracked jit entry point,
 # token -> (fn_name, monotonic entry time).  The stall watchdog
@@ -75,7 +76,7 @@ def inflight_dispatch() -> Optional[tuple[str, float]]:
 def record_compile(fn_name: str, shape: str, seconds: float) -> None:
     """Fold one observed compile into the counters (also the hook tests
     and non-jit compile sites can feed directly)."""
-    global _total_recompiles
+    global _total_recompiles, _total_compile_seconds
     with _lock:
         novel = (fn_name, shape) not in _seen_shapes
         if novel:
@@ -94,6 +95,7 @@ def record_compile(fn_name: str, shape: str, seconds: float) -> None:
                 if fn.startswith("ragged_") == (backend == "ragged")
             ))
         _total_recompiles += 1
+        _total_compile_seconds += seconds
     metrics.xla_recompile_total.labels(fn=fn_name, shape=shape).inc()
     metrics.xla_compile_seconds.observe(seconds)
     if novel:
@@ -122,12 +124,20 @@ def total_recompiles() -> int:
         return _total_recompiles
 
 
+def total_compile_seconds() -> float:
+    """Wall seconds of every compiling dispatch since boot (each
+    includes that dispatch's enqueue, not its device time)."""
+    with _lock:
+        return _total_compile_seconds
+
+
 def reset() -> None:
     """Test hook: forget seen shapes (Prometheus counters keep history)."""
-    global _total_recompiles
+    global _total_recompiles, _total_compile_seconds
     with _lock:
         _seen_shapes.clear()
         _total_recompiles = 0
+        _total_compile_seconds = 0.0
         _inflight.clear()
 
 

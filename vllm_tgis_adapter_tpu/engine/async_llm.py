@@ -1180,6 +1180,9 @@ class AsyncLLMEngine:
         watchdog's dump all call exactly this (flight_recorder.py
         serializers), so the three views can never diverge."""
         from vllm_tgis_adapter_tpu import compile_tracker
+        from vllm_tgis_adapter_tpu.engine.device_report import (
+            device_report,
+        )
         from vllm_tgis_adapter_tpu.flight_recorder import (
             engine_introspection,
         )
@@ -1205,6 +1208,9 @@ class AsyncLLMEngine:
         events.sort(key=lambda e: e["mono_ns"])
         inflight = compile_tracker.inflight_dispatch()
         return {
+            # what device this is and what the engine decided because of
+            # it (engine/device_report.py; the boot log's line)
+            "device": device_report(self.engine),
             "engine": {
                 "running": self.is_running,
                 "errored": self.errored,
@@ -1259,6 +1265,9 @@ class AsyncLLMEngine:
             "compile_tracker": {
                 "compiled_shapes": compile_tracker.num_shapes(),
                 "total_compiles": compile_tracker.total_recompiles(),
+                "compile_seconds": round(
+                    compile_tracker.total_compile_seconds(), 3
+                ),
                 "inflight_dispatch": (
                     {"fn": inflight[0], "age_s": round(inflight[1], 3)}
                     if inflight is not None

@@ -88,13 +88,28 @@ class LLMEngine:
 
     def __init__(self, config: EngineConfig, model, params, tokenizer,
                  mesh=None, memory_device=None, pp_devices=None):
+        # device_report.py: how the pool came to its size, and how long
+        # from_config spent loading weights (None for direct builds)
+        self.kv_pool_sized_from = "config"
+        self.weights_load_s: float | None = None
         if config.cache_config.num_blocks <= 0:
             # auto-size the KV pool from free HBM now that the weights are
             # resident (reference behavior: vLLM's gpu_memory_utilization)
             import dataclasses as _dc
 
+            import jax
+
             from vllm_tgis_adapter_tpu.engine.kv_cache import (
+                device_memory_stats,
                 resolve_num_blocks,
+            )
+
+            if memory_device is None:
+                memory_device = jax.local_devices()[0]
+            self.kv_pool_sized_from = (
+                "memory_stats"
+                if device_memory_stats(memory_device).get("bytes_limit")
+                else "static_fallback"
             )
 
             size_cfg = config
@@ -409,7 +424,13 @@ class LLMEngine:
                 validate_tp_divisibility(mcfg, mesh.shape["tp"])
                 place = make_place_fn(mesh)
         logger.info("loading weights from %s", mcfg.model)
+        load_t0 = time.monotonic()
         params = load_model_params(mcfg, mcfg.model, place=place)
+        import jax
+
+        # tpulint: disable=TPL201(boot, not the step loop: the load is timed to its end for the device report)
+        jax.block_until_ready(params)
+        weights_load_s = round(time.monotonic() - load_t0, 3)
         if config.quantization == "int8":
             # weight-only int8 after (possibly sharded) load; the KV pool
             # auto-sizing below sees the freed HBM.  The draft model (if
@@ -462,8 +483,15 @@ class LLMEngine:
         # a replacement engine must own the SAME device slice — under dp
         # the other slices hold other replicas' weights and pools
         engine._devices = devices
+        engine.weights_load_s = weights_load_s
         if draft_model is not None:
             engine.attach_speculative(draft_model, draft_params)
+        from vllm_tgis_adapter_tpu.engine.device_report import (
+            device_report,
+            format_report,
+        )
+
+        logger.info(format_report(device_report(engine)))
         return engine
 
     def attach_speculative(self, draft_model, draft_params) -> None:  # noqa: ANN001

@@ -73,7 +73,8 @@ def per_block_bytes(config: "EngineConfig") -> int:
         kv_heads_per_dev = max(1, m.num_kv_heads // tp)
         payload = (
             2 * m.num_layers * ccfg.block_size
-            * kv_heads_per_dev * m.head_dim * itemsize
+            * kv_heads_per_dev * kv_quant.cache_head_dim(m.head_dim)
+            * itemsize
         )
         if qdtype is not None:
             payload += kv_quant.scale_bytes_per_page(
@@ -101,6 +102,15 @@ def pages_for_budget(config: "EngineConfig", budget_bytes: int) -> int:
     return max(0, int(budget_bytes) // per_block_bytes(config))
 
 
+def device_memory_stats(device) -> dict:
+    """``device.memory_stats()``, or ``{}`` on backends that keep none
+    (the CPU backend returns None)."""
+    try:
+        return device.memory_stats() or {}
+    except Exception:  # pragma: no cover - backend-dependent API
+        return {}
+
+
 def resolve_num_blocks(
     config: "EngineConfig", device=None
 ) -> int:
@@ -118,7 +128,8 @@ def resolve_num_blocks(
     ``num_kv_heads / tp`` heads of every page — the per-device page cost
     shrinks with the mesh and the pool grows accordingly.
 
-    Backends without memory stats (CPU tests) fall back to a static pool.
+    Backends without memory stats (CPU tests) fall back to a static
+    pool; on a TPU backend that is an error.
     """
     import jax
 
@@ -133,14 +144,18 @@ def resolve_num_blocks(
 
     if device is None:
         device = jax.local_devices()[0]
-    stats: Optional[dict] = None
-    try:
-        stats = device.memory_stats()
-    except Exception:  # pragma: no cover - backend-dependent API
-        stats = None
-    limit = (stats or {}).get("bytes_limit")
-    in_use = (stats or {}).get("bytes_in_use", 0)
+    stats = device_memory_stats(device)
+    limit = stats.get("bytes_limit")
+    in_use = stats.get("bytes_in_use", 0)
     if not limit:
+        if getattr(device, "platform", None) == "tpu":
+            # a chip that reports no HBM limit is a broken runtime, not
+            # a reason to serve from a pool sized for CPU tests
+            raise RuntimeError(
+                f"{device} reports no memory_stats() bytes_limit: "
+                "refusing to serve from the static KV pool on a TPU "
+                "backend"
+            )
         num_blocks = min(full_occupancy, _FALLBACK_BLOCKS)
         logger.info(
             "backend exposes no memory stats; static KV pool of %d pages "

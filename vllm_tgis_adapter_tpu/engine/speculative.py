@@ -325,7 +325,7 @@ class SpeculativeDecoder:
             )
         self.draft_params = draft_params
 
-        donate = (1,) if jax.default_backend() == "tpu" else ()
+        donate = (1,)
         self._draft_prefill_fn = jax.jit(
             draft_model.prefill, donate_argnums=donate
         )
@@ -440,7 +440,7 @@ class SpeculativeDecoder:
             )
             return caches, drafted[:gamma], qprobs[:gamma]  # [γ,B],[γ,B,V]
 
-        donate = (1,) if jax.default_backend() == "tpu" else ()
+        donate = (1,)
         return jax.jit(propose, static_argnums=(12,), donate_argnums=donate)
 
     def propose(self, prep) -> tuple:  # noqa: ANN001

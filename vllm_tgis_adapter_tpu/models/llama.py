@@ -354,7 +354,10 @@ class LlamaForCausalLM:
         # attaches calibrated page-scale floors from checkpoints that
         # ship k_scale/v_scale tensors (engine/weights.py).
         cfg = self.config
-        shape = (cfg.num_layers, cfg.num_kv_heads, num_slots, cfg.head_dim)
+        # rows are cache_head_dim wide: head_dim, or whole lanes where
+        # the device would not keep a narrower head axis minor
+        shape = (cfg.num_layers, cfg.num_kv_heads, num_slots,
+                 kv_quant.cache_head_dim(cfg.head_dim))
         k_floor, v_floor = (
             kv_scale_floors
             if kv_scale_floors is not None
@@ -739,8 +742,12 @@ class LlamaForCausalLM:
 
         def attend(i, q, k, v):
             nonlocal k_cache, v_cache
-            k_cache = kv_quant.scatter_layer(k_cache, i, safe_slots, k)
-            v_cache = kv_quant.scatter_layer(v_cache, i, safe_slots, v)
+            k_cache = kv_quant.scatter_layer(
+                k_cache, i, safe_slots, k, self.mesh
+            )
+            v_cache = kv_quant.scatter_layer(
+                v_cache, i, safe_slots, v, self.mesh
+            )
             # dense attend over the chunk's own (full-precision) K/V:
             # quantization only perturbs later PAGED reads of this cache
             return attn_ops.prefill_attention(
@@ -815,16 +822,21 @@ class LlamaForCausalLM:
 
         def attend(i, q, k, v):
             nonlocal k_cache, v_cache
-            k_cache = kv_quant.scatter_layer(k_cache, i, safe_slots, k)
-            v_cache = kv_quant.scatter_layer(v_cache, i, safe_slots, v)
+            k_cache = kv_quant.scatter_layer(
+                k_cache, i, safe_slots, k, self.mesh
+            )
+            v_cache = kv_quant.scatter_layer(
+                v_cache, i, safe_slots, v, self.mesh
+            )
             return attn_ops.chunked_prefill_attention(
-                q, kv_quant.layer_data(k_cache, i),
+                kv_quant.pad_to_cache_width(q, k_cache),
+                kv_quant.layer_data(k_cache, i),
                 kv_quant.layer_data(v_cache, i), block_table, start,
                 valid_len, block_size, scale, mesh=self.mesh,
                 window=self._window_for_layer(i),
                 alibi_slopes=self.alibi,
                 kv_scales=kv_quant.layer_scales(k_cache, v_cache, i),
-            )
+            )[..., : q.shape[-1]]
 
         x = (
             self._embed(params, token_ids, positions)
@@ -888,21 +900,26 @@ class LlamaForCausalLM:
 
         def attend(i, q, k, v):
             nonlocal k_cache, v_cache
-            k_cache = kv_quant.scatter_layer(k_cache, i, safe_slots, k)
-            v_cache = kv_quant.scatter_layer(v_cache, i, safe_slots, v)
+            k_cache = kv_quant.scatter_layer(
+                k_cache, i, safe_slots, k, self.mesh
+            )
+            v_cache = kv_quant.scatter_layer(
+                v_cache, i, safe_slots, v, self.mesh
+            )
             from vllm_tgis_adapter_tpu.ops.ragged_attention import (
                 ragged_paged_attention,
             )
 
             return ragged_paged_attention(
-                q, kv_quant.layer_data(k_cache, i),
+                kv_quant.pad_to_cache_width(q, k_cache),
+                kv_quant.layer_data(k_cache, i),
                 kv_quant.layer_data(v_cache, i), positions, seq_starts,
                 pos_base, total_tokens, block_tables, block_size, scale,
                 work=work, mesh=self.mesh,
                 window=self._window_for_layer(i),
                 alibi_slopes=self.alibi,
                 kv_scales=kv_quant.layer_scales(k_cache, v_cache, i),
-            )
+            )[..., : q.shape[-1]]
 
         x = self._embed(params, token_ids, positions)
         for i, layer in enumerate(params["layers"]):
@@ -955,8 +972,12 @@ class LlamaForCausalLM:
 
         def attend(i, q, k, v):
             nonlocal k_cache, v_cache
-            k_cache = kv_quant.scatter_layer(k_cache, i, safe_slots, k)
-            v_cache = kv_quant.scatter_layer(v_cache, i, safe_slots, v)
+            k_cache = kv_quant.scatter_layer(
+                k_cache, i, safe_slots, k, self.mesh
+            )
+            v_cache = kv_quant.scatter_layer(
+                v_cache, i, safe_slots, v, self.mesh
+            )
             from vllm_tgis_adapter_tpu.ops.ragged_attention import (
                 ragged_paged_attention,
             )
@@ -967,7 +988,8 @@ class LlamaForCausalLM:
             # and their garbage output is discarded by the sampler
             # mask, same as the padded-batch decode contract)
             return ragged_paged_attention(
-                q, kv_quant.layer_data(k_cache, i),
+                kv_quant.pad_to_cache_width(q, k_cache),
+                kv_quant.layer_data(k_cache, i),
                 kv_quant.layer_data(v_cache, i),
                 jnp.maximum(context_lens, 1) - 1,
                 jnp.arange(b + 1, dtype=jnp.int32),
@@ -977,7 +999,7 @@ class LlamaForCausalLM:
                 window=self._window_for_layer(i),
                 alibi_slopes=self.alibi,
                 kv_scales=kv_quant.layer_scales(k_cache, v_cache, i),
-            )
+            )[..., : q.shape[-1]]
 
         x = (
             self._embed(params, token_ids, positions)

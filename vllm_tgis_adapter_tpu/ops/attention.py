@@ -31,7 +31,7 @@ import os
 
 import jax
 import jax.numpy as jnp
-from vllm_tgis_adapter_tpu.jax_compat import shard_map
+from jax import shard_map
 
 NEG_INF = float("-inf")
 

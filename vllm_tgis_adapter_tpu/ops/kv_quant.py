@@ -52,6 +52,8 @@ quantize/dequantize primitive the rest of the stack composes
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -207,7 +209,128 @@ def _quantize_values(x, qdtype, qmax):
     return jnp.clip(x, -qmax, qmax).astype(qdtype)
 
 
-def scatter_layer(cache, i, safe_slots, vals):
+#: the TPU's minor tile dimension
+LANES = 128
+
+
+@functools.lru_cache(maxsize=None)
+def cache_head_dim(head_dim: int, device=None) -> int:
+    """Row width of a KV pool for heads of ``head_dim``: ``head_dim``
+    itself wherever the device keeps it the minor axis of the
+    ``[L, Hkv, slots, Dh]`` pool, else ``head_dim`` rounded up to whole
+    lanes (rows zero-padded).
+
+    A TPU lays out arrays whose last axis is narrower than a lane tile
+    with ANOTHER axis minor (slots, here).  The scatter that writes the
+    pool and the Pallas kernel that reads it both need Dh minor, so on
+    such a device every step would re-lay the whole pool twice.  The
+    question is put to the device's compiler (what layout does it give
+    an argument of this shape?), not to a platform name; the CPU keeps
+    every shape row-major and pads nothing.
+    """
+    if head_dim % LANES == 0:
+        return head_dim
+    from jax.sharding import SingleDeviceSharding
+
+    device = device or jax.local_devices()[0]
+    probe = jax.ShapeDtypeStruct(
+        (1, 8, 4096, head_dim), jnp.bfloat16,
+        sharding=SingleDeviceSharding(device),
+    )
+    layout = (
+        jax.jit(lambda x: x).lower(probe).compile()
+        .input_formats[0][0].layout
+    )
+    if tuple(layout.major_to_minor) == (0, 1, 2, 3):
+        return head_dim
+    return -(-head_dim // LANES) * LANES
+
+
+def pad_to_cache_width(x: jax.Array, cache) -> jax.Array:
+    """Zero-pad the last (head) axis of ``x`` to ``cache``'s row width
+    (:func:`cache_head_dim`); zeros add nothing to q·k and the padded
+    output columns are cut off by the caller."""
+    pad = cache.shape[-1] - x.shape[-1]
+    if pad == 0:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
+def _per_shard(mesh, fn, num_args):
+    """``fn(pool, idx[, vals])`` over each tp shard's own
+    ``[L, Hkv/tp, slots, Dh]`` block (``pool``, ``vals`` and the result
+    split on the kv-head axis, ``idx`` whole).  The flat view below
+    merges the sharded kv-head axis into the row axis, which the SPMD
+    partitioner could only honour by re-sharding the pool; inside
+    shard_map every device works on the heads it holds."""
+    if mesh is None:
+        return fn
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    pool = P(None, "tp", None, None)
+    return shard_map(
+        fn, mesh=mesh, in_specs=(pool, P(), pool)[:num_args],
+        out_specs=pool, check_vma=False,
+    )
+
+
+def _flat_rows(data, first_layer, num_layers, idx):
+    """Row numbers, in the flat ``[L·Hkv·slots, Dh]`` view of a pool, of
+    slots ``idx`` under every kv head of ``num_layers`` layers from
+    ``first_layer``: ``[num_layers·Hkv·n]``.  Slots past the pool
+    (padding, to be dropped) stay out of bounds of the flat view too —
+    there they would land in the next head's first rows."""
+    _, num_kv, num_slots, head_dim = data.shape
+    lead = first_layer * num_kv + jnp.arange(
+        num_layers * num_kv, dtype=jnp.int32
+    )
+    rows = lead[:, None] * num_slots + idx[None, :]
+    return jnp.where(
+        idx[None, :] >= num_slots, data.size // head_dim, rows
+    ).reshape(-1)
+
+
+def take_slots(data, idx, mesh=None):
+    """``jnp.take(data, idx, axis=2)`` → ``[L, Hkv, n, Dh]``, read
+    through the flat view (see :func:`put_slots`)."""
+
+    def local(data, idx):
+        num_layers, num_kv, _, head_dim = data.shape
+        return jnp.take(
+            data.reshape(-1, head_dim),
+            _flat_rows(data, 0, num_layers, idx), axis=0, mode="clip",
+        ).reshape(num_layers, num_kv, idx.shape[0], head_dim)
+
+    return _per_shard(mesh, local, 2)(data, idx)
+
+
+def put_slots(data, idx, vals, mesh=None, layer=0):
+    """``data.at[layer:layer + n, :, idx].set(vals, mode="drop")`` for
+    ``data`` ``[L, Hkv, slots, Dh]`` and ``vals`` ``[n, Hkv, len(idx),
+    Dh]``, written as a row scatter into the flat ``[L·Hkv·slots, Dh]``
+    view.
+
+    Same result, different program: indexed on the third axis the TPU
+    compiler moves the WHOLE pool to a slots-major layout and back on
+    every step (two pool-sized copies and temporaries: the step program
+    of a real pool does not fit the chip).  The flat view is a bitcast
+    of the pool's own layout and its scatter index is the major axis,
+    so the update happens in place.
+    """
+
+    def local(data, idx, vals):
+        head_dim = data.shape[-1]
+        flat = data.reshape(-1, head_dim)
+        flat = flat.at[_flat_rows(data, layer, vals.shape[0], idx)].set(
+            vals.astype(data.dtype).reshape(-1, head_dim), mode="drop"
+        )
+        return flat.reshape(data.shape)
+
+    return _per_shard(mesh, local, 3)(data, idx, vals)
+
+
+def scatter_layer(cache, i, safe_slots, vals, mesh=None):
     """Scatter this step's K (or V) rows into layer ``i`` of ``cache``.
 
     ``vals`` is ``[T, Hkv, Dh]``; ``safe_slots`` is ``[T]`` with padding
@@ -222,9 +345,10 @@ def scatter_layer(cache, i, safe_slots, vals):
     most once per dispatch (spans are disjoint), so the scatter-max
     candidates never race.
     """
+    vals = pad_to_cache_width(vals, cache)
     if not is_quantized(cache):
-        return cache.at[i, :, safe_slots].set(
-            vals.astype(cache.dtype), mode="drop"
+        return put_slots(
+            cache, safe_slots, jnp.swapaxes(vals, 0, 1)[None], mesh, layer=i
         )
     data, scale = cache.data, cache.scale
     bs = cache.block_size
@@ -266,9 +390,7 @@ def scatter_layer(cache, i, safe_slots, vals):
     q = _quantize_values(
         vt / jnp.maximum(row_scale, _EPS)[..., None], data.dtype, qmax
     )
-    data = data.at[i, :, safe_slots].set(
-        jnp.swapaxes(q, 0, 1), mode="drop"
-    )
+    data = put_slots(data, safe_slots, q[None], mesh, layer=i)
     return QuantizedKVCache(data, scale, bs, floor=cache.floor)
 
 
@@ -282,7 +404,7 @@ def scatter_layer(cache, i, safe_slots, vals):
 # tools/tpulint/config.py JIT_REGISTRY.
 
 
-def gather_kv_page(k_cache, v_cache, idx):
+def gather_kv_page(k_cache, v_cache, idx, mesh=None):
     """Gather one page from both caches for host-tier demotion.
 
     Raw caches return ``(k, v)`` slot gathers — the historical
@@ -292,20 +414,17 @@ def gather_kv_page(k_cache, v_cache, idx):
     role handoffs, which reference the same entries).
     """
     if not is_quantized(k_cache):
-        return (
-            jnp.take(k_cache, idx, axis=2),
-            jnp.take(v_cache, idx, axis=2),
-        )
+        return take_slots(k_cache, idx, mesh), take_slots(v_cache, idx, mesh)
     page = idx[0] // k_cache.block_size
     return (
-        jnp.take(k_cache.data, idx, axis=2),
-        jnp.take(v_cache.data, idx, axis=2),
+        take_slots(k_cache.data, idx, mesh),
+        take_slots(v_cache.data, idx, mesh),
         k_cache.scale[:, :, page],
         v_cache.scale[:, :, page],
     )
 
 
-def restore_kv_page(k_cache, v_cache, idx, *arrays):
+def restore_kv_page(k_cache, v_cache, idx, *arrays, mesh=None):
     """Scatter one promoted/checkpointed page back into both caches.
 
     ``arrays`` is exactly what ``gather_kv_page`` produced (the tier
@@ -316,29 +435,21 @@ def restore_kv_page(k_cache, v_cache, idx, *arrays):
     if not is_quantized(k_cache):
         k_host, v_host = arrays
         return (
-            k_cache.at[:, :, idx, :].set(
-                k_host.astype(k_cache.dtype), mode="drop"
-            ),
-            v_cache.at[:, :, idx, :].set(
-                v_host.astype(v_cache.dtype), mode="drop"
-            ),
+            put_slots(k_cache, idx, k_host, mesh),
+            put_slots(v_cache, idx, v_host, mesh),
         )
     k_host, v_host, k_scale, v_scale = arrays
     page = idx[0] // k_cache.block_size
     bs = k_cache.block_size
     return (
         QuantizedKVCache(
-            k_cache.data.at[:, :, idx, :].set(
-                k_host.astype(k_cache.data.dtype), mode="drop"
-            ),
+            put_slots(k_cache.data, idx, k_host, mesh),
             k_cache.scale.at[:, :, page].set(k_scale),
             bs,
             floor=k_cache.floor,
         ),
         QuantizedKVCache(
-            v_cache.data.at[:, :, idx, :].set(
-                v_host.astype(v_cache.data.dtype), mode="drop"
-            ),
+            put_slots(v_cache.data, idx, v_host, mesh),
             v_cache.scale.at[:, :, page].set(v_scale),
             bs,
             floor=v_cache.floor,

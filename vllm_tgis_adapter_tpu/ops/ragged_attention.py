@@ -56,7 +56,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vllm_tgis_adapter_tpu.jax_compat import shard_map
+from jax import shard_map
 from vllm_tgis_adapter_tpu.ops.attention import (
     NEG_INF,
     _pallas_interpret,
@@ -69,6 +69,10 @@ from vllm_tgis_adapter_tpu.ops.attention import (
 #: the sequence, first-item-of-block flag, last-item-of-block flag,
 #: live flag (0 = padding/masked item: no compute, accumulators only).
 WORK_FIELDS = 7
+
+#: lane width of the scale-sidecar block the quantized kernel DMAs (the
+#: TPU's minor tile dimension)
+SCALE_LANES = 128
 
 
 def _pow2_ceil(n: int) -> int:
@@ -197,7 +201,8 @@ def _ragged_kernel(
     alibi_ref,  # [H] f32 SMEM slopes; unused unless use_alibi
     # blocks: q_ref [1, G*bq, Dh], k_ref/v_ref [1, block_size, Dh] (the
     # page picked by index_map), then — quantized caches only — ks_ref/
-    # vs_ref [1, 1] f32 (the page's dequant scale, same index map), then
+    # vs_ref [Hkv, SCALE_LANES] f32 (the lane tile of the scale sidecar
+    # that holds this page's dequant scale), then
     # o_ref [1, G*bq, Dh] and the three f32 scratch accumulators
     # (m [G*bq, 1], l [G*bq, 1], acc [G*bq, Dh])
     q_ref,
@@ -235,10 +240,19 @@ def _ragged_kernel(
         v = v_ref[0].astype(jnp.float32)
         if quantized:
             # in-register dequant: the whole page tile shares ONE
-            # per-(kv head, page) scale (ops/kv_quant.py sidecar),
-            # DMA'd as a 1x1 block by the same page index map
-            k = k * ks_ref[0, 0]
-            v = v * vs_ref[0, 0]
+            # per-(kv head, page) scale (ops/kv_quant.py sidecar).
+            # Mosaic takes no 1x1 block of the [Hkv, pages] sidecar, so
+            # the block is the page's whole lane tile and the scalar is
+            # picked out of it by a masked reduce (one vreg for Hkv<=8)
+            tile = ks_ref.shape
+            pick = (
+                jax.lax.broadcasted_iota(jnp.int32, tile, 0) == h
+            ) & (
+                jax.lax.broadcasted_iota(jnp.int32, tile, 1)
+                == work_ref[2, w] % SCALE_LANES
+            )
+            k = k * jnp.sum(jnp.where(pick, ks_ref[...], 0.0))
+            v = v * jnp.sum(jnp.where(pick, vs_ref[...], 0.0))
         s_mat = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -349,11 +363,12 @@ def _ragged_attention_pallas(
     ]
     operands = [qh, k_cache, v_cache]
     if quantized:
-        # one (kv head, page) scale scalar per cache, picked by the same
-        # physical-page index the K/V tiles DMA with — the in-register
-        # dequant's only extra traffic is two 4-byte blocks per item
+        # the [Hkv, pages] scale sidecars travel as the tile-legal
+        # (Hkv, SCALE_LANES) block holding the item's physical page;
+        # consecutive items in one lane tile re-use the resident block
         scale_spec = pl.BlockSpec(
-            (1, 1), lambda h, w, wk, st, bs_, al: (h, wk[2, w])
+            (num_kv, SCALE_LANES),
+            lambda h, w, wk, st, bs_, al: (0, wk[2, w] // SCALE_LANES),
         )
         in_specs += [scale_spec, scale_spec]
         operands += [

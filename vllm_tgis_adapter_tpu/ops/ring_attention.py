@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from vllm_tgis_adapter_tpu.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from vllm_tgis_adapter_tpu.parallel.mesh import SP_AXIS, TP_AXIS

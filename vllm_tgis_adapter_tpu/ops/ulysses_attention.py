@@ -23,7 +23,7 @@ virtual CPU mesh in tests/test_ulysses.py.
 from __future__ import annotations
 
 import jax
-from vllm_tgis_adapter_tpu.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from vllm_tgis_adapter_tpu.parallel.mesh import SP_AXIS, TP_AXIS

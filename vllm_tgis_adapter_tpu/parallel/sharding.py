@@ -43,8 +43,9 @@ def validate_tp_divisibility(config: "ModelConfig", tp: int) -> None:
         # MoE models whose expert count divides tp shard the EXPERT axis
         # instead of the ffn dim, so the ffn constraint doesn't apply
         problems.append(f"intermediate_size={config.intermediate_size}")
-    if config.vocab_size % tp:
-        problems.append(f"vocab_size={config.vocab_size}")
+    # an indivisible vocabulary (granite's 49155 is odd) is not a
+    # problem: llama_param_specs then keeps embed / lm_head whole on
+    # every device
     if problems:
         raise ValueError(
             f"tensor_parallel_size={tp} does not divide "
@@ -95,6 +96,17 @@ _EXPERT_FFN_SPECS = {
 }
 
 
+def _whole_if_indivisible(spec: P, shape: tuple, tp: int) -> P:
+    """``spec`` with every axis that tp does not divide kept whole: a
+    vocabulary that does not split tp ways (granite's 49155 is odd)
+    replicates its table (0.2 GB for granite-2b) instead of refusing
+    the model."""
+    return P(*(
+        axis if axis is None or dim % tp == 0 else None
+        for axis, dim in zip(spec, shape)
+    ))
+
+
 def llama_param_specs(params: dict, tp: int = 1) -> dict:
     """PartitionSpec pytree matching models/llama.py's param layout."""
     # emit a spec for exactly the keys present: pipeline stages carry
@@ -113,6 +125,11 @@ def llama_param_specs(params: dict, tp: int = 1) -> dict:
     specs: dict = {
         name: top_specs[name] for name in params if name != "layers"
     }
+    for name in ("embed", "lm_head"):
+        if name in params:
+            specs[name] = _whole_if_indivisible(
+                specs[name], params[name].shape, tp
+            )
 
     def layer_spec(layer: dict) -> dict:
         expert_specs = _EXPERT_FFN_SPECS
@@ -228,8 +245,13 @@ def make_place_fn(mesh: Mesh):
     (70B-class models exceed one chip's HBM — sharding after a full load
     would OOM device 0)."""
 
+    tp = mesh.shape[TP_AXIS]
+
     def place(name: str, x: jax.Array) -> jax.Array:
-        return jax.device_put(x, NamedSharding(mesh, hf_name_spec(name)))
+        # validate_tp_divisibility vouches for every axis but the
+        # vocabulary's
+        spec = _whole_if_indivisible(hf_name_spec(name), x.shape, tp)
+        return jax.device_put(x, NamedSharding(mesh, spec))
 
     return place
 

@@ -44,6 +44,7 @@ from __future__ import annotations
 import asyncio
 import os
 import time
+import traceback
 from typing import TYPE_CHECKING, Callable, Optional
 
 from vllm_tgis_adapter_tpu import metrics
@@ -466,6 +467,7 @@ class EngineSupervisor:
         if backoff > 0:
             await asyncio.sleep(backoff)
         old = rep.engine
+        self._release_frames(err)
         new_engine = await asyncio.to_thread(self._rebuild, old)
         # stamp the replica index AND role BEFORE the precompile
         # re-warm: its warmup dispatches record per-replica/role step
@@ -500,6 +502,21 @@ class EngineSupervisor:
             failed + late_failed + resume_failed,
             resumed + local_resumed,
         )
+
+    @staticmethod
+    def _release_frames(err: Optional[BaseException]) -> None:
+        """Drop the locals of every frame the death's tracebacks hold.
+        The frame that died inside a dispatch has the KV pool among its
+        locals (``k_cache, v_cache = self.caches``), and the death error
+        lives on in the restart history — so the pool would outlive
+        ``runner.caches = None`` and the rebuild's pool could not be
+        allocated beside it (seen on a v5e in PR 23: 1.57 GB free)."""
+        seen: set[int] = set()
+        while err is not None and id(err) not in seen:
+            seen.add(id(err))
+            if err.__traceback__ is not None:
+                traceback.clear_frames(err.__traceback__)
+            err = err.__cause__ or err.__context__
 
     def _rebuild(self, old: "LLMEngine") -> "LLMEngine":
         """Build the replacement engine (worker thread; slow is fine).
