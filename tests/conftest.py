@@ -34,7 +34,92 @@ if not _TPU_RUN:
             _flags + " --xla_force_host_platform_device_count=8"
         ).strip()
 
+import faulthandler  # noqa: E402
+import hashlib  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
+import tempfile  # noqa: E402
+import threading  # noqa: E402
+
 import pytest  # noqa: E402
+
+# ------------------------------------------------------ per-test time limit
+#
+# One limit for every test, its fixtures' set-up and teardown included.
+# The slowest honest test of the suite takes 33 s under the driver's
+# command (six workers on eight cores; CHANGES.md PR 26 has the measured
+# list), so a test past the limit is hung, not slow: it fails alone,
+# with the place it hung in its report, and the worker goes on.
+TEST_TIME_LIMIT_S = 120.0
+#: past the limit by this much the interpreter never came back to run
+#: the handler (a hang inside a C call): the worker dumps and exits, and
+#: xdist reports the item as failed and starts another worker
+HARD_EXIT_AFTER_S = 60.0
+#: set in xdist workers.  ``--dist loadfile`` hands the file of a worker
+#: that died to the next one *from the item that killed it* (xdist 3.8,
+#: ``LoadScopeScheduling.remove_node``), so an item in flight leaves a
+#: file behind and is failed at once when it is met again: one worker
+#: lost to a hard hang, not one for each restart xdist allows
+_XDIST_RUN = os.environ.get("PYTEST_XDIST_TESTRUNUID")
+
+
+class TestTimeLimitExceeded(Exception):
+    __test__ = False  # not a test class, whatever its name starts with
+
+
+def _in_flight_marker(item) -> Path:
+    name = hashlib.sha1(item.nodeid.encode()).hexdigest()  # noqa: S324
+    return Path(tempfile.gettempdir()) / f"in-flight-{_XDIST_RUN}-{name}"
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    if threading.current_thread() is not threading.main_thread():
+        yield  # signals reach the main thread only
+        return
+    limit = TEST_TIME_LIMIT_S
+    # between phases nothing is captured, so this is the real stderr:
+    # what the last-resort dump writes must outlive the worker
+    real_stderr = os.fdopen(os.dup(2), "w")
+    marker = _in_flight_marker(item) if _XDIST_RUN else None
+    if marker is not None:
+        item.killed_a_worker = marker.exists()
+        marker.touch()
+
+    def on_limit(signum, frame):  # noqa: ANN001, ARG001
+        # fd 2 is the captured one here: the dump lands in the item's
+        # "Captured stderr" section, under the traceback of this raise
+        faulthandler.dump_traceback(file=sys.__stderr__, all_threads=True)
+        raise TestTimeLimitExceeded(
+            f"{item.nodeid} ran past its {limit:.0f}s limit "
+            "(every thread's stack is in the captured stderr)"
+        )
+
+    previous = signal.signal(signal.SIGALRM, on_limit)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    faulthandler.dump_traceback_later(
+        limit + HARD_EXIT_AFTER_S, exit=True, file=real_stderr
+    )
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, previous)
+        real_stderr.close()
+        if marker is not None:
+            marker.unlink(missing_ok=True)
+
+
+@pytest.hookimpl(tryfirst=True)
+def pytest_runtest_setup(item):
+    if getattr(item, "killed_a_worker", False):
+        pytest.fail(
+            f"{item.nodeid} hung past {TEST_TIME_LIMIT_S:.0f}s + "
+            f"{HARD_EXIT_AFTER_S:.0f}s in an earlier worker of this run, "
+            "which dumped its threads to stderr and exited",
+            pytrace=False,
+        )
 
 
 @pytest.fixture(scope="session")

@@ -663,11 +663,20 @@ def test_adapter_spill_restore_through_engine(
         names[0], het_lora_dirs[names[0]]
     ))
     want = _run_requests(engine, [("first", names[0])])["first"]
-    # fill the 2-entry host registry: names[0] spills to disk
-    for name in names[1:]:
-        asyncio.run(engine.lora_manager.load_lora_adapter(
-            name, het_lora_dirs[name]
-        ))
+    # fill the 2-entry host registry: names[0] spills to disk.  The
+    # spill write is a task of the running loop (a worker thread does
+    # the write), so the loop has to outlive it, as a server's does:
+    # one asyncio.run() per load closed the loop with the write still
+    # queued, and closing cancels it
+    async def fill_registry():
+        manager = engine.lora_manager
+        for name in names[1:]:
+            await manager.load_lora_adapter(name, het_lora_dirs[name])
+        assert names[0] in manager._spilling  # noqa: SLF001 — gate parks on it
+        await asyncio.gather(*manager._disk_tasks)  # noqa: SLF001
+        assert not manager._spilling  # noqa: SLF001
+
+    asyncio.run(fill_registry())
     assert engine.lora_manager.get_weights(names[0]) is None
     assert disk.has_adapter(names[0])
     # a new request for the spilled adapter: restored, same tokens

@@ -526,48 +526,84 @@ def _episodes(state, regime):
     ]
 
 
-def test_host_bound_run_opens_one_episode(tiny_model_dir):
-    """Acceptance: the deliberately host-bound run — sync dispatch
-    (jax_cpu_enable_async_dispatch off), num_decode_steps=1 (no
-    multi-step fusion) and enable_chained_decode=False (no overlap;
-    bench.py's BENCH_SYNC_DISPATCH=1 BENCH_STEPS=1 BENCH_NO_CHAIN=1) —
-    pays the full host round-trip per token, pushes the anatomy
-    window's host_gap_frac past HOST_BOUND_GAP_FRAC, and opens exactly
-    one host_bound episode."""
-    import asyncio
+def _feed_host_bound_steps(timeline, steps, monkeypatch):
+    """Drive ``steps`` steps through the timeline's real stamp helpers
+    on a scripted clock: 1 ms between stamps (a 6 ms step, 2 ms of it
+    device-busy) and 14 ms before the next step is entered, so the
+    device idles 18 ms (end of one wait to the end of the next
+    dispatch) in every 24 ms of step wall: host_gap_frac is 0.75
+    whatever the machine's load."""
+    import time
+    from types import SimpleNamespace
 
-    import jax
+    from vllm_tgis_adapter_tpu.telemetry import steptime
+
+    clock = SimpleNamespace(t=time.perf_counter())
+
+    def tick(seconds=0.001):
+        clock.t += seconds
+        return clock.t
+
+    with monkeypatch.context() as patched:
+        patched.setattr(steptime, "time", SimpleNamespace(
+            perf_counter=lambda: clock.t, time=time.time,
+        ))
+        for step in range(steps):
+            prepared = SimpleNamespace()
+            t_enter = tick(0.014)
+            timeline.stamp_plan(prepared, t_enter=t_enter, t_sched=tick())
+            tick()
+            timeline.begin_dispatch(prepared)
+            tick()
+            timeline.end_dispatch(prepared)
+            tick()
+            timeline.begin_wait(prepared)
+            tick()
+            timeline.end_wait(prepared)
+            tick()
+            assert timeline.finish(
+                prepared, step=10_000 + step, replica=0, kind="decode",
+                tokens=2, fill_ratio=1.0,
+            ) is not None
+
+
+def test_host_bound_run_opens_one_episode(tiny_model_dir, monkeypatch):
+    """Acceptance: a real engine whose anatomy window shows the device
+    idle on the host 18 ms in every 24 opens exactly one host_bound
+    episode, on every surface.  The engine serves one short request
+    for real (fewer than MIN_WINDOW_STEPS steps: no rule can fire yet);
+    the host-bound window is then fed through its own StepTimeline on
+    a scripted clock and the doctor is evaluated at injected times, so
+    nothing here reads the machine's load."""
+    import asyncio
 
     before = _sample(
         _scrape(), "tgis_tpu_doctor_episodes_total",
         ('regime="host_bound"', 'replica="0"'),
     )
-    jax.config.update("jax_cpu_enable_async_dispatch", False)
-    try:
-        engine = _build_engine(
-            tiny_model_dir, num_decode_steps=1,
-            enable_chained_decode=False,
-        )
+    engine = _build_engine(tiny_model_dir)
 
-        async def scenario():
-            await asyncio.gather(
-                _generate(engine, "hb-w1", max_tokens=40),
-                _generate(engine, "hb-w2", max_tokens=40),
+    async def scenario():
+        await _generate(engine, "hb-live", max_tokens=4)
+        timeline = engine._replicas[0].engine.steptime
+        assert 0 < len(timeline) < MIN_WINDOW_STEPS
+        # one step more than the window: the first scripted step's gap
+        # is measured from the last real step and falls out of it
+        _feed_host_bound_steps(timeline, timeline.window + 1, monkeypatch)
+        frac = timeline.host_gap_frac()
+        now = 1_000.0
+        for _ in range(OPEN_AFTER):
+            engine.doctor.evaluate(
+                engine._doctor_signals(), now=(now := now + 1.0)
             )
-            await asyncio.gather(
-                _generate(engine, "hb-a", max_tokens=220),
-                _generate(engine, "hb-b", max_tokens=220),
-            )
-            frac = engine._replicas[0].engine.steptime.host_gap_frac()
-            state = engine.debug_state(last_events=4096)
-            body = await _doctor_http_body(engine, tiny_model_dir)
-            await engine.stop()
-            return frac, state, body
+        state = engine.debug_state(last_events=4096)
+        body = await _doctor_http_body(engine, tiny_model_dir)
+        await engine.stop()
+        return frac, state, body
 
-        frac, state, body = asyncio.run(scenario())
-    finally:
-        jax.config.update("jax_cpu_enable_async_dispatch", True)
+    frac, state, body = asyncio.run(scenario())
 
+    assert frac == pytest.approx(18 / 24)
     # the run is genuinely host-bound by the doctor's own rule inputs
     assert frac > HOST_BOUND_GAP_FRAC
     (episode,) = _episodes(state, "host_bound")

@@ -438,3 +438,52 @@ def test_debug_request_timeline(http_base_url, server_args):
     with pytest.raises(urllib.error.HTTPError) as excinfo:
         _get(f"{http_base_url}/debug/requests/no-such-request")
     assert excinfo.value.code == 404
+
+
+def test_shutdown_does_not_wait_on_an_idle_keepalive_client():
+    """Cancelling the HTTP tier (start_servers' finally, after the
+    drain) must return with a keep-alive client still connected: from
+    Python 3.12 ``Server.wait_closed()`` waits for every connection, so
+    the server closes them itself before it waits."""
+    import argparse
+    import asyncio
+    import socket
+
+    from vllm_tgis_adapter_tpu.http import App, run_http_server
+
+    async def scenario():
+        sock = socket.socket()
+        sock.bind(("127.0.0.1", 0))
+        port = sock.getsockname()[1]
+        args = argparse.Namespace(
+            ssl_keyfile=None, ssl_certfile=None, ssl_ca_certs=None,
+            ssl_cert_reqs=0, host="127.0.0.1", port=port,
+        )
+        server = asyncio.ensure_future(
+            run_http_server(args, None, App(), sock)
+        )
+        for _ in range(100):
+            try:
+                reader, writer = await asyncio.open_connection(
+                    "127.0.0.1", port
+                )
+                break
+            except OSError:
+                await asyncio.sleep(0.05)
+        try:
+            writer.write(b"GET /nope HTTP/1.1\r\nHost: t\r\n\r\n")
+            await writer.drain()
+            head = await asyncio.wait_for(reader.readuntil(b"\r\n"), 5)
+            assert head.startswith(b"HTTP/1.1 404")
+            server.cancel()
+            done, _ = await asyncio.wait([server], timeout=5)
+            assert done, "shutdown waited on the idle keep-alive client"
+            assert server.cancelled()
+            # the client is told: EOF after what was left of the response
+            await asyncio.wait_for(reader.read(), 5)
+            assert reader.at_eof()
+        finally:
+            writer.close()
+            sock.close()
+
+    asyncio.run(scenario())

@@ -268,6 +268,66 @@ def test_staged_handoffs_adopt_for_peer():
     assert staged.pending() == 1
 
 
+# ------------------------------------------------------ service shutdown
+
+
+class _StubManager:
+    """What ``KvTierService`` needs of its manager for HELLO."""
+
+    node_id = "S"
+
+    def __init__(self) -> None:
+        self.inbound: dict = {}
+
+    def note_inbound(self, node, conn) -> None:
+        self.inbound[node] = conn
+
+    def note_inbound_lost(self, node, conn) -> None:
+        self.inbound.pop(node, None)
+
+
+def test_service_stop_does_not_wait_on_a_silent_peer():
+    """A peer that said HELLO and then stays silent holds its handler in
+    ``read_frame``; ``stop()`` must close that link itself and return
+    (from Python 3.12 ``Server.wait_closed()`` waits for every accepted
+    connection, so listener-first order never returned)."""
+    from vllm_tgis_adapter_tpu.kvnet.service import KvTierService
+
+    async def scenario():
+        manager = _StubManager()
+        service = KvTierService(manager, tier=None, listen="127.0.0.1:0")
+        await service.start()
+        before = asyncio.all_tasks()
+        reader, writer = await asyncio.open_connection(
+            "127.0.0.1", service.port
+        )
+        writer.write(wire.encode_frame(
+            wire.OP_HELLO, {"rid": 1, "node": "silent"}
+        ))
+        await writer.drain()
+        op, _flags, header, _ = await asyncio.wait_for(
+            wire.read_frame(reader), 5
+        )
+        assert op == wire.OP_HELLO_R and header["node"] == "S"
+        assert "silent" in manager.inbound
+        handlers = asyncio.all_tasks() - before
+        assert len(handlers) == 1
+        try:
+            await asyncio.wait_for(service.stop(), 5)
+            done, _ = await asyncio.wait(handlers, timeout=5)
+            assert done == handlers
+            assert "silent" not in manager.inbound
+            # the peer sees EOF, not a hang of its own
+            with pytest.raises(asyncio.IncompleteReadError):
+                await asyncio.wait_for(wire.read_frame(reader), 5)
+            # a second stop (engine.stop() after kvnet.stop()) is a no-op
+            await asyncio.wait_for(service.stop(), 5)
+        finally:
+            writer.close()
+
+    asyncio.run(scenario())
+
+
 # -------------------------------------------------------- config surface
 
 
@@ -529,8 +589,12 @@ def test_cross_process_prefix_hit_and_handoff(tiny_model_dir):
     the single-process baseline; the parent engine (prefill-only)
     serves the same prompt via a cross-PROCESS remote prefix fetch and
     hands its decode checkpoint across — token-identical both ways."""
+    import queue
     import subprocess
     import sys
+    import threading
+    import time
+    from pathlib import Path
 
     from vllm_tgis_adapter_tpu.engine.async_llm import AsyncLLMEngine
     from vllm_tgis_adapter_tpu.engine.config import (
@@ -543,21 +607,39 @@ def test_cross_process_prefix_hit_and_handoff(tiny_model_dir):
     )
 
     port_a = _free_port()
+    # the harness and the package of the tree under test, wherever it
+    # is checked out
+    repo_root = Path(__file__).resolve().parent.parent
     child = subprocess.Popen(
         [sys.executable, "tests/kvnet_harness.py", tiny_model_dir,
          "--listen", "127.0.0.1:0", "--peers", f"127.0.0.1:{port_a}",
          "--node-id", "B"],
         stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True,
-        cwd="/root/repo",
+        cwd=repo_root,
     )
+    # a reader thread, so that every wait on the child has a deadline
+    lines: queue.Queue = queue.Queue()
+
+    def pump_stdout():
+        for line in child.stdout:
+            lines.put(line)
+        lines.put("")  # EOF
+
+    threading.Thread(target=pump_stdout, daemon=True).start()
 
     def rpc(obj):
         child.stdin.write(json.dumps(obj) + "\n")
         child.stdin.flush()
 
-    def read_event(kind, timeout_lines=10000):
-        for _ in range(timeout_lines):
-            line = child.stdout.readline()
+    def read_event(kind, timeout_s=60.0):
+        deadline = time.monotonic() + timeout_s
+        while True:
+            try:
+                line = lines.get(timeout=max(0.0, deadline - time.monotonic()))
+            except queue.Empty:
+                raise AssertionError(
+                    f"no {kind} from the harness in {timeout_s:.0f}s"
+                ) from None
             if not line:
                 raise AssertionError("harness died before " + kind)
             try:
@@ -566,7 +648,6 @@ def test_cross_process_prefix_hit_and_handoff(tiny_model_dir):
                 continue
             if evt.get("event") == kind:
                 return evt
-        raise AssertionError("no " + kind)
 
     try:
         ready = read_event("ready")
@@ -629,4 +710,4 @@ def test_cross_process_prefix_hit_and_handoff(tiny_model_dir):
     finally:
         if child.poll() is None:
             child.kill()
-            child.wait()
+            child.wait(timeout=30)

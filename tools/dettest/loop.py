@@ -213,6 +213,9 @@ class DetLoop(asyncio.AbstractEventLoop):
         #: contexts passed to call_exception_handler during the run
         #: (unretrieved task exceptions, callback failures)
         self.exceptions: list[dict] = []
+        #: every task made on this loop: ``det_run`` asks each, when
+        #: the run is over, whether it died with nobody looking
+        self._tasks: list[asyncio.Task] = []
 
     # ------------------------------------------------------------- clock
 
@@ -267,7 +270,25 @@ class DetLoop(asyncio.AbstractEventLoop):
             # how many tasks earlier runs created
             self._task_counter += 1
             task.set_name(f"dtask-{self._task_counter}")
+        self._tasks.append(task)
         return task
+
+    def note_unretrieved_task_exceptions(self) -> None:
+        """What ``Task.__del__`` would report, without waiting for the
+        collector: a finished task is often held by a cycle through its
+        exception's traceback, so its ``__del__`` runs at some later
+        collection and not before ``det_run`` has looked."""
+        for task in self._tasks:
+            if (
+                task.done()
+                and not task.cancelled()
+                and task._log_traceback  # noqa: SLF001 — set iff never retrieved
+            ):
+                self.exceptions.append({
+                    "message": "Task exception was never retrieved",
+                    "exception": task.exception(),  # marks it retrieved
+                    "task": task,
+                })
 
     def run_in_executor(self, executor, func, *args):  # noqa: ANN001, ANN002
         """Run ``func`` as a loop callback instead of a worker thread:
@@ -476,6 +497,7 @@ def det_run(
             loop.drain_pending()
     finally:
         loop.close()
+    loop.note_unretrieved_task_exceptions()
     fatal = [
         ctx
         for ctx in loop.exceptions

@@ -33,6 +33,7 @@ from vllm_tgis_adapter_tpu.frontdoor.errors import (
 )
 from vllm_tgis_adapter_tpu.logging import init_logger
 from vllm_tgis_adapter_tpu.tgis_utils import logs
+from vllm_tgis_adapter_tpu.utils import close_server
 
 if TYPE_CHECKING:
     import argparse
@@ -1186,10 +1187,16 @@ async def run_http_server(
     # engine step loop are already live when the HTTP tier boots
     ssl_context = await asyncio.to_thread(_build_ssl_context, args)
 
+    writers: set[asyncio.StreamWriter] = set()
+
     async def client_connected(
         reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
-        await _handle_connection(app, reader, writer)
+        writers.add(writer)
+        try:
+            await _handle_connection(app, reader, writer)
+        finally:
+            writers.discard(writer)
 
     if sock is not None:
         server = await asyncio.start_server(
@@ -1204,5 +1211,9 @@ async def run_http_server(
         )
     addr = args.host or "0.0.0.0"  # noqa: S104
     logger.info("HTTP Server started at %s:%s", addr, args.port)
-    async with server:
-        await server.serve_forever()
+    try:
+        # start_server is already accepting; serve until cancelled
+        await asyncio.get_running_loop().create_future()
+    finally:
+        # not serve_forever(): its cancel path waits on idle clients
+        await close_server(server, writers, "HTTP server")

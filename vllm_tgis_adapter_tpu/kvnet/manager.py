@@ -42,7 +42,7 @@ from vllm_tgis_adapter_tpu.kvnet.client import (
 )
 from vllm_tgis_adapter_tpu.kvnet.service import KvTierService
 from vllm_tgis_adapter_tpu.supervisor import failpoints
-from vllm_tgis_adapter_tpu.utils import spawn_task
+from vllm_tgis_adapter_tpu.utils import SERVER_STOP_TIMEOUT_S, spawn_task
 
 logger = logging.getLogger(__name__)
 
@@ -168,17 +168,25 @@ class KvNetManager:
         )
 
     async def stop(self) -> None:
+        """Outbound links first, then the listener (which closes the
+        inbound ones before it waits, ``KvTierService.stop``): two
+        hosts stopping together must not each wait on the other."""
         self._stopping = True
-        task, self._hb_task = self._hb_task, None
-        if task is not None:
-            task.cancel()
-        for rid, pump in list(self._pumps.items()):
-            pump.cancel()
+        cancelled = list(self._pumps.values())
         self._pumps.clear()
-        if self.service is not None:
-            await self.service.stop()
+        if self._hb_task is not None:
+            cancelled.append(self._hb_task)
+            self._hb_task = None
+        for task in cancelled:
+            task.cancel()
         for peer in self.peers:
             await peer.close()
+        if self.service is not None:
+            await self.service.stop()
+        if cancelled:
+            # one loop turn, unless a task swallows its cancellation:
+            # bounded like the rest
+            await asyncio.wait(cancelled, timeout=SERVER_STOP_TIMEOUT_S)
 
     # ------------------------------------------------------------ telemetry
 

@@ -21,6 +21,7 @@ from typing import Optional
 
 from vllm_tgis_adapter_tpu import metrics
 from vllm_tgis_adapter_tpu.kvnet import wire
+from vllm_tgis_adapter_tpu.utils import close_server
 
 logger = logging.getLogger(__name__)
 
@@ -79,17 +80,19 @@ class KvTierService:
         )
 
     async def stop(self) -> None:
+        """The listener, then the peer links, then a bounded wait
+        (``utils.close_server``): a ``_handle`` parked in ``read_frame``
+        on a live peer ends only when its link is closed, so a stop
+        that waited on the listener first hung on every connected peer."""
+        conns = list(self._conns)
+        self._conns.clear()
+        for conn in conns:
+            conn.closed = True
         server, self._server = self._server, None
         if server is not None:
-            server.close()
-            await server.wait_closed()
-        for conn in list(self._conns):
-            conn.closed = True
-            try:
-                conn.writer.close()
-            except Exception:  # noqa: BLE001 — teardown best-effort
-                pass
-        self._conns.clear()
+            await close_server(
+                server, [conn.writer for conn in conns], "kvnet service"
+            )
 
     # --------------------------------------------------------- connection
 

@@ -7,6 +7,7 @@ Kubernetes termination-log writer, and tiny sequence helpers.
 from __future__ import annotations
 
 import asyncio
+import logging
 import os
 import time
 from typing import TYPE_CHECKING, Optional
@@ -45,6 +46,29 @@ def spawn_task(coro, *, name: Optional[str] = None, retain=None, loop=None):  # 
     bucket.add(task)
     task.add_done_callback(bucket.discard)
     return task
+
+
+#: most that :func:`close_server` waits for open connections to go away
+SERVER_STOP_TIMEOUT_S = 5.0
+
+
+async def close_server(server, writers, name: str) -> None:  # noqa: ANN001
+    """Stop an ``asyncio`` stream server without waiting on its peers:
+    the listener, then every connection still open (``writers``), and
+    only then a bounded wait.  From Python 3.12 ``Server.wait_closed()``
+    waits for every accepted connection, so close-the-listener-and-wait
+    (``serve_forever()``'s own cancel path too) holds a shutdown for as
+    long as one idle peer stays connected."""
+    server.close()
+    for writer in list(writers):
+        writer.close()
+    try:
+        await asyncio.wait_for(server.wait_closed(), SERVER_STOP_TIMEOUT_S)
+    except asyncio.TimeoutError:
+        logging.getLogger(__name__).warning(
+            "%s: connections still open %.0fs after close; shutdown "
+            "goes on without them", name, SERVER_STOP_TIMEOUT_S,
+        )
 
 
 def check_for_failed_tasks(tasks: Iterable[asyncio.Task]) -> Optional[asyncio.Task]:
