@@ -306,7 +306,8 @@ def phase_kernel(args: argparse.Namespace) -> None:
     results["mixed"] = (np.asarray(got, np.float32)[:t],
                         np.asarray(ref, np.float32)[:t])
 
-    # pure decode: 64 one-row spans, schedule built in the trace
+    # pure decode: 64 one-row spans, a quarter of them dead, over the
+    # wave's compact schedule with its traced grid extent
     b, max_blocks = 64, 16
     ctx = rng.integers(1, max_blocks * block, b).astype(np.int32)
     tables = rng.permutation(256)[: b * 4].reshape(b, 4).astype(np.int32)
@@ -316,19 +317,25 @@ def phase_kernel(args: argparse.Namespace) -> None:
     q = rows(b, h)
     pos_b = jnp.asarray(ctx - 1)
     starts_b = jnp.arange(b + 1, dtype=jnp.int32)
-    work = ra.dense_work_schedule(
-        pos_b, jnp.asarray(tables), block_size=block, block_q=8, t_pad=b
+    live = np.arange(b) % 4 != 1
+    work, n_items = ra.decode_work_schedule(
+        pos_b, jnp.asarray(live), jnp.asarray(tables), block_size=block
     )
+    if int(n_items) != int(((ctx[live] - 1) // block + 1).sum()):
+        raise RuntimeError(f"decode schedule: {int(n_items)} items")
     got = ra._ragged_attention_pallas(
-        q, kc, vc, starts_b, pos_b, work, block, scale, block_q=8,
-        window=0, alibi_slopes=None, interpret=args.tiny,
+        q, kc, vc, starts_b, pos_b, work, block, scale,
+        block_q=ra.decode_block_q(b), window=0, interpret=args.tiny,
+        n_items=n_items,
     )
     ref = ra.ragged_attention_xla(
         q, kc, vc, pos_b, starts_b, jnp.asarray(b), jnp.asarray(tables),
         block, scale,
     )
-    results["decode"] = (np.asarray(got, np.float32),
-                         np.asarray(ref, np.float32))
+    results["decode"] = (np.asarray(got, np.float32)[live],
+                         np.asarray(ref, np.float32)[live])
+    if not np.isfinite(np.asarray(got, np.float32)).all():
+        raise RuntimeError("ragged kernel (decode): dead rows not finite")
 
     for name, (got, ref) in results.items():
         if not np.isfinite(got).all():

@@ -10,7 +10,10 @@ Pinned here:
   wave's tokens without corrupting batchmates;
 * abort while a chained wave is in flight;
 * the free-quarantine epochs that keep stale projected writes off
-  re-allocated pages.
+  re-allocated pages;
+* the wave's step count is an input of ONE program, not a compile
+  shape: waves of any live length, chained or not, give the tokens
+  one-step waves give.
 """
 
 from __future__ import annotations
@@ -356,3 +359,134 @@ def test_admissible_probe_releases_prefix_refcounts(tiny_model_dir):
     for _ in range(25):
         sched._waiting_head_admissible()
     assert sched.allocator.num_free == free_before
+
+
+# ------------------------------------------------- traced step count
+
+
+def _wave_compiles(fn: str) -> float:
+    """Compiles the tracker has counted for one jitted entry point."""
+    from vllm_tgis_adapter_tpu import metrics
+
+    return sum(
+        sample.value
+        for family in metrics.xla_recompile_total.collect()
+        for sample in family.samples
+        if sample.name.endswith("_total") and sample.labels["fn"] == fn
+    )
+
+
+def _spy_wave_steps(core):
+    """Record the live step count of every plain wave dispatched."""
+    seen = []
+    inner = core.runner.dispatch_decode
+
+    def spy(prep):
+        seen.append(prep.num_steps)
+        return inner(prep)
+
+    core.runner.dispatch_decode = spy
+    return seen
+
+
+@pytest.mark.parametrize("steps", [1, 3, 8])
+def test_wave_of_n_live_steps_matches_one_step_waves(tiny_model_dir, steps):
+    """A fused wave that ends after ``steps`` of its 8 steps (every row
+    out of budget) gives the tokens that ``steps`` one-step waves give:
+    the reference is the program whose loop can only run once."""
+    from vllm_tgis_adapter_tpu.engine.core import LLMEngine
+    from vllm_tgis_adapter_tpu.engine.sampling_params import SamplingParams
+
+    # the prefill samples token 1; one wave of ``steps`` tokens follows
+    requests = [
+        ("a", list(range(3, 10)),
+         dict(temperature=0.0, max_tokens=steps + 1, ignore_eos=True)),
+    ]
+    baseline = _sync_baseline(
+        _config(tiny_model_dir, num_decode_steps=1), requests
+    )
+    core = LLMEngine.from_config(_config(tiny_model_dir, num_decode_steps=8))
+    seen = _spy_wave_steps(core)
+    for rid, ids, kwargs in requests:
+        core.add_request(rid, None, SamplingParams(**kwargs),
+                         prompt_token_ids=ids)
+    outs = {}
+    for _ in range(50):
+        if not core.has_unfinished_requests():
+            break
+        for out in core.step():
+            if out.finished:
+                outs[out.request_id] = out.outputs[0].token_ids
+    assert seen == [steps]
+    assert outs == baseline
+    assert len(outs["a"]) == steps + 1
+
+
+def test_one_wave_program_for_every_step_count(tiny_model_dir):
+    """Waves of 1, 3, 8 and 5 live steps through ONE engine compile
+    ``ragged_decode`` once: the step count is data."""
+    from vllm_tgis_adapter_tpu.engine.core import LLMEngine
+    from vllm_tgis_adapter_tpu.engine.sampling_params import SamplingParams
+
+    core = LLMEngine.from_config(_config(tiny_model_dir, num_decode_steps=8))
+    seen = _spy_wave_steps(core)
+    before = _wave_compiles("ragged_decode")
+    for steps in (1, 3, 8, 5):
+        core.add_request(
+            f"r{steps}", None,
+            SamplingParams(temperature=0.0, max_tokens=steps + 1,
+                           ignore_eos=True),
+            prompt_token_ids=list(range(3, 10)),
+        )
+        for _ in range(50):
+            if not core.has_unfinished_requests():
+                break
+            core.step()
+    assert seen == [1, 3, 8, 5]
+    assert _wave_compiles("ragged_decode") - before == 1
+
+
+def test_chained_wave_after_short_wave(tiny_model_dir):
+    """A chained wave behind a SHORT one (a tight pool halves a row's
+    fused run, scheduler._schedule_decode; here the cap is patched in)
+    reads its input tokens from step ``prev_k - 1`` of the one fixed
+    ``[K, B, ...]`` result: tokens equal the sync engine's, and both
+    wave programs compile once."""
+    from vllm_tgis_adapter_tpu.engine.scheduler import Scheduler
+
+    requests = [
+        ("a", list(range(3, 10)),
+         dict(temperature=0.0, max_tokens=30, ignore_eos=True)),
+        ("b", list(range(11, 20)),
+         dict(temperature=0.0, max_tokens=30, ignore_eos=True)),
+    ]
+    baseline = _sync_baseline(_config(tiny_model_dir), requests)
+
+    inner = Scheduler._allowed_steps
+    short_prev = []
+    plain, chained = (
+        _wave_compiles("ragged_decode"),
+        _wave_compiles("ragged_chained_decode"),
+    )
+    from vllm_tgis_adapter_tpu.engine.runner import ModelRunner
+
+    prepare = ModelRunner.prepare_chained_decode
+
+    def spy(self, plan, prev_prep):
+        short_prev.append((prev_prep.num_steps, plan.num_steps))
+        return prepare(self, plan, prev_prep)
+
+    mp = pytest.MonkeyPatch()
+    try:
+        mp.setattr(Scheduler, "_allowed_steps",
+                   lambda self, seq: min(inner(self, seq), 3))
+        mp.setattr(ModelRunner, "prepare_chained_decode", spy)
+        got = _async_run(_config(tiny_model_dir, num_decode_steps=8),
+                         requests)
+    finally:
+        mp.undo()
+    assert got == baseline
+    # a plain wave of 3 steps, then its successor at the full 8
+    assert (3, 8) in short_prev, short_prev
+    assert _wave_compiles("ragged_decode") - plain == 1
+    assert _wave_compiles("ragged_chained_decode") - chained == 1

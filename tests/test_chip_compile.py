@@ -146,25 +146,42 @@ def test_ragged_kernel_compiles(
     _compile(kernel, *args)
 
 
-def test_ragged_decode_dense_schedule_compiles(topo, one_chip, on_chip):
-    """The fused decode wave's formulation: one-row spans, the schedule
-    built in the trace (``work=None``), ``block_q`` 8."""
-    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+@pytest.mark.parametrize("tp", [1, 4])
+def test_ragged_decode_schedule_compiles(topo, on_chip, tp):
+    """The fused decode wave's formulation: one-row spans over the
+    compact schedule built in the trace, ``block_q`` 8, and a grid whose
+    extent is the TRACED ``n_items`` — on one chip and inside the tp=4
+    ``shard_map``, where the scalar travels replicated like ``work``."""
+    mesh = Mesh(np.asarray(topo.devices[:tp]).reshape(1, 1, tp),
+                ("dp", "sp", "tp"))
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes))
+        )
+
     b, hkv, dh = 64, 8, 128
     max_blocks = 4096 // BLOCK
 
-    def decode(q, kc, vc, pos, tables):
+    def decode(q, kc, vc, pos, active, tables):
+        work, n_items = ra.decode_work_schedule(
+            pos, active, tables, block_size=BLOCK
+        )
+        assert work.shape == (ra.WORK_FIELDS, b * max_blocks)
         return ra.ragged_paged_attention(
             q, kc, vc, pos, jnp.arange(b + 1, dtype=jnp.int32), pos,
             jnp.asarray(b, jnp.int32), tables, BLOCK, dh**-0.5,
+            work=work, n_items=n_items, block_q=ra.decode_block_q(b),
+            mesh=mesh if tp > 1 else None,
         )
 
     _compile(
         decode,
-        spec((b, 32, dh), jnp.bfloat16),
-        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16),
-        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16),
+        spec((b, 32, dh), jnp.bfloat16, None, "tp", None),
+        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16, "tp", None, None),
+        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16, "tp", None, None),
         spec((b,), jnp.int32),
+        spec((b,), jnp.bool_),
         spec((b, max_blocks), jnp.int32),
     )
 
@@ -232,14 +249,12 @@ def test_head_dim_64_pool_is_lane_padded_on_the_chip(topo):
     assert kv_quant.cache_head_dim(64, jax.local_devices()[0]) == 64
 
 
-def test_whole_ragged_step_compiles_and_fits(topo, one_chip, on_chip,
-                                             monkeypatch):
-    """One whole step program of the runner's serving path (mixed
-    ragged step, T=512) at granite-2b's published widths and full depth,
-    from ``eval_shape`` parameters: the kernel is in it, weights + pool +
-    temporaries fit one 16 GB chip, and the pool is updated IN PLACE —
-    no pool-sized temporary (the faults this file exists for: the
-    pre-PR-23 scatter re-laid the whole pool twice a step)."""
+def _granite_2b_on_chip(topo, one_chip, monkeypatch):
+    """granite-2b at published widths and full depth as a described chip
+    would hold it: the model, its parameters' shapes (``eval_shape``),
+    the pool ``resolve_num_blocks`` would size (90% of HBM less the
+    weights) with rows lane-padded as the chip pads them, and the pool's
+    bytes."""
     from vllm_tgis_adapter_tpu.models.llama import LlamaForCausalLM
 
     chip = topo.devices[0]
@@ -264,15 +279,40 @@ def test_whole_ragged_step_compiles_and_fits(topo, one_chip, on_chip,
     weight_bytes = sum(
         leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(params)
     )
-    # the pool resolve_num_blocks would size: 90% of HBM less the weights
     page_bytes = 2 * 40 * 8 * BLOCK * 128 * 2
     pages = (int(HBM_BYTES * 0.9) - weight_bytes) // page_bytes
     caches = on_chip_shapes(jax.eval_shape(
         lambda: model.make_kv_caches(pages * BLOCK, cfg.dtype)
     ))
     assert caches[0].shape == (40, 8, pages * BLOCK, 128)
-    pool_bytes = 2 * caches[0].size * 2
+    return model, params, caches, 2 * caches[0].size * 2
 
+
+def _assert_in_place_and_fits(compiled, pool_bytes):
+    """The kernel is in the program, the donated pool is updated IN
+    PLACE (no pool-sized temporary: per-layer K and V operand slices and
+    activations only; a pool re-layout would show as gigabytes), and
+    weights + pool + temporaries fit one 16 GB chip."""
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // 10
+    total = (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert total < HBM_BYTES
+
+
+def test_whole_ragged_step_compiles_and_fits(topo, one_chip, on_chip,
+                                             monkeypatch):
+    """One whole step program of the runner's serving path (mixed
+    ragged step, T=512) at granite-2b's published widths and full depth,
+    from ``eval_shape`` parameters (the faults this file exists for: the
+    pre-PR-23 scatter re-laid the whole pool twice a step)."""
+    model, params, caches, pool_bytes = _granite_2b_on_chip(
+        topo, one_chip, monkeypatch
+    )
     spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     t, seqs = 512, 64
     step = jax.jit(
@@ -286,17 +326,46 @@ def test_whole_ragged_step_compiles_and_fits(topo, one_chip, on_chip,
         spec((seqs, 4096 // BLOCK), jnp.int32), spec((seqs,), jnp.int32),
         work=spec((ra.WORK_FIELDS, 256), jnp.int32),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == pool_bytes  # donated, in place
-    # per-layer K and V operand slices and activations; a pool re-layout
-    # would show here as gigabytes
-    assert mem.temp_size_in_bytes < pool_bytes // 10
-    total = (
-        mem.argument_size_in_bytes + mem.temp_size_in_bytes
-        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    _assert_in_place_and_fits(compiled, pool_bytes)
+
+
+def test_whole_decode_wave_compiles_and_fits(topo, one_chip, on_chip,
+                                             monkeypatch):
+    """The mixed step's twin for the fused wave: ``model.decode`` with
+    its hoisted schedule inside a loop of TRACED length, at granite-2b's
+    published widths and full depth (40 layers: 16 s of the tier-1 run).
+    One program serves every step count and every number of live rows
+    and pages: the kernel's grid extent and the loop's bound are both
+    operands.  The pool is carried through the loop in place."""
+    model, params, caches, pool_bytes = _granite_2b_on_chip(
+        topo, one_chip, monkeypatch
     )
-    assert total < HBM_BYTES
+    b, max_blocks = 64, 4096 // BLOCK
+
+    def wave(params, caches, tokens, pos0, limits, tables, num_steps):
+        def step(k, carry):
+            caches, tokens = carry
+            pos = pos0 + k
+            blk = jnp.take_along_axis(
+                tables, (pos // BLOCK)[:, None], axis=1
+            )[:, 0]
+            slot = jnp.where(pos <= limits, blk * BLOCK + pos % BLOCK, -1)
+            logits, caches = model.decode(
+                params, caches, tokens, pos, slot, tables, pos + 1, BLOCK
+            )
+            return caches, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        return jax.lax.fori_loop(0, num_steps, step, (caches, tokens))
+
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(wave, donate_argnums=(1,)).lower(
+        params, caches, spec((b,), jnp.int32), spec((b,), jnp.int32),
+        spec((b,), jnp.int32), spec((b, max_blocks), jnp.int32),
+        spec((), jnp.int32),
+    ).compile()
+    # the schedule keeps its one static shape, whatever is live
+    assert f"s32[{ra.WORK_FIELDS},{b * max_blocks}]" in compiled.as_text()
+    _assert_in_place_and_fits(compiled, pool_bytes)
 
 
 def _granite_2b(num_layers: int = 40):

@@ -117,6 +117,54 @@ def test_batched_requests_match_solo_greedy(engine):
         )
 
 
+@pytest.mark.parametrize(
+    "budgets,wave", [((3, 2, 2), 1), ((5, 2, 3), 3), ((12, 4, 14), 8)]
+)
+def test_fused_waves_match_one_step_waves(engine_factory, budgets, wave):
+    """A batch whose rows run out of budget at different steps: waves
+    that end after 1, 3 and 8 live steps (and a second, short wave
+    behind a full one) give, row for row, the tokens and logprobs of the
+    engine whose waves hold one step — the fused loop's traced bound
+    changes how many steps run, not what a step computes."""
+    from vllm_tgis_adapter_tpu.engine.sampling_params import SamplingParams
+
+    def serve(num_decode_steps):
+        eng = engine_factory(
+            scheduler_kwargs=dict(num_decode_steps=num_decode_steps)
+        )
+        waves = []
+        inner = eng.runner.dispatch_decode
+
+        def spy(prep):
+            waves.append(prep.num_steps)
+            return inner(prep)
+
+        eng.runner.dispatch_decode = spy
+        for i, budget in enumerate(budgets):
+            eng.add_request(
+                f"r{i}", None,
+                SamplingParams(temperature=0.0, max_tokens=budget,
+                               ignore_eos=True, logprobs=2),
+                prompt_token_ids=list(range(3 + i, 12 + 2 * i)),
+            )
+        outs = run_to_completion(eng)
+        return waves, {
+            rid: (o.outputs[0].token_ids,
+                  [round(entry[tid].logprob, 4) for tid, entry in zip(
+                      o.outputs[0].token_ids, o.outputs[0].logprobs)])
+            for rid, o in outs.items()
+        }
+
+    fused_waves, fused = serve(8)
+    single_waves, single = serve(1)
+    assert set(single_waves) == {1}
+    # the mixed steps that admit r1 and r2 give r0 a token each first
+    assert fused_waves[0] == wave, fused_waves
+    assert fused == single
+    for i, budget in enumerate(budgets):
+        assert len(fused[f"r{i}"][0]) == budget
+
+
 def test_seeded_sampling_reproducible(engine):
     from vllm_tgis_adapter_tpu.engine.sampling_params import SamplingParams
 

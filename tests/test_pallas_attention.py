@@ -46,16 +46,16 @@ def ragged_decode_pallas(q, k_cache, v_cache, block_tables, context_lens,
     b = int(np.asarray(q).shape[0])
     pos = jnp.maximum(jnp.asarray(context_lens, jnp.int32), 1) - 1
     starts = jnp.arange(b + 1, dtype=jnp.int32)
-    block_q = min(8, R._pow2_ceil(b))
-    work = R.dense_work_schedule(
-        pos, jnp.asarray(block_tables, jnp.int32),
-        block_size=block_size, block_q=block_q,
-        t_pad=-(-b // block_q) * block_q,
+    # the wave's compact schedule; its traced extent is the kernel's grid
+    work, n_items = R.decode_work_schedule(
+        pos, jnp.ones(b, bool), jnp.asarray(block_tables, jnp.int32),
+        block_size=block_size,
     )
     return R._ragged_attention_pallas(
         jnp.asarray(q), jnp.asarray(k_cache), jnp.asarray(v_cache),
-        starts, pos, work, block_size, scale, block_q=block_q,
-        window=window, alibi_slopes=alibi_slopes, interpret=True,
+        starts, pos, work, block_size, scale,
+        block_q=R.decode_block_q(b), window=window,
+        alibi_slopes=alibi_slopes, interpret=True, n_items=n_items,
     )
 
 
@@ -201,10 +201,14 @@ def test_pallas_kernels_under_tp_mesh(monkeypatch):
     from vllm_tgis_adapter_tpu.ops import ragged_attention as R
 
     pos = jnp.maximum(jnp.asarray(cl, jnp.int32), 1) - 1
+    work, n_items = R.decode_work_schedule(
+        pos, jnp.ones(b, bool), jnp.asarray(bt), block_size=block_size
+    )
     got = R.ragged_paged_attention(
         jnp.asarray(q), jnp.asarray(k_cache), jnp.asarray(v_cache),
         pos, jnp.arange(b + 1, dtype=jnp.int32), pos,
         jnp.asarray(b, jnp.int32), jnp.asarray(bt), block_size, scale,
+        work=work, n_items=n_items, block_q=R.decode_block_q(b),
         mesh=mesh,
     )
     # prefill too

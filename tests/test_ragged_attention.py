@@ -123,13 +123,13 @@ def test_ragged_xla_matches_decode_reference(window):
 
 @pytest.mark.parametrize("window", [0, 4])
 @pytest.mark.parametrize("use_alibi", [False, True])
-@pytest.mark.parametrize("schedule", ["sparse", "dense"])
+@pytest.mark.parametrize("schedule", ["sparse", "decode"])
 def test_ragged_pallas_matches_xla(window, use_alibi, schedule):
     """The Pallas kernel (interpret mode) matches the XLA reference for
     both the host-built sparse schedule (mixed engine steps, multi-row
-    spans) and the in-trace dense schedule (the fused decode scan —
-    single-row spans by contract, including a pow2-boundary row count
-    so the pad descriptor slot lands past the last query block)."""
+    spans) and the in-trace compact schedule with its traced grid
+    extent (the fused decode wave — single-row spans by contract, at a
+    row count that block_q=8 divides exactly)."""
     import jax.numpy as jnp
 
     from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
@@ -139,8 +139,7 @@ def test_ragged_pallas_matches_xla(window, use_alibi, schedule):
         rng,
         [(0, 6), (9, 1), (3, 4), (5, 1)]
         if schedule == "sparse"
-        # decode contract: every span one row (seq s IS row s); 16 rows
-        # make block_q=8 divide t exactly, the pad-sequence clamp case
+        # decode contract: every span one row (seq s IS row s)
         else [(i % 11, 1) for i in range(16)],
     )
     slopes = (
@@ -154,22 +153,23 @@ def test_ragged_pallas_matches_xla(window, use_alibi, schedule):
         jnp.asarray(case["t"]), jnp.asarray(case["block_tables"]),
         case["bs"], case["scale"], window=window, alibi_slopes=slopes,
     )
+    n_items = None
     if schedule == "sparse":
         work = jnp.asarray(ra.build_work_schedule(
             case["spans"], case["block_tables"],
             block_size=case["bs"], block_q=8, t_pad=case["t_pad"],
         ))
     else:
-        work = ra.dense_work_schedule(
-            jnp.asarray(case["pos_base"]),
-            jnp.asarray(case["block_tables"]),
-            block_size=case["bs"], block_q=8, t_pad=case["t_pad"],
+        t = case["t"]  # S == T: the pad descriptor slot stays out
+        work, n_items = ra.decode_work_schedule(
+            jnp.asarray(case["pos_base"][:t]), jnp.ones(t, bool),
+            jnp.asarray(case["block_tables"][:t]), block_size=case["bs"],
         )
     out = ra._ragged_attention_pallas(
         jnp.asarray(case["q"]), case["kc"], case["vc"],
         jnp.asarray(case["seq_starts"]), jnp.asarray(case["pos_base"]),
         work, case["bs"], case["scale"], block_q=8, window=window,
-        alibi_slopes=slopes, interpret=True,
+        alibi_slopes=slopes, interpret=True, n_items=n_items,
     )
     np.testing.assert_allclose(
         np.asarray(out[: case["t"]]), np.asarray(ref[: case["t"]]),
@@ -177,13 +177,132 @@ def test_ragged_pallas_matches_xla(window, use_alibi, schedule):
     )
 
 
-def test_ragged_dense_schedule_non_pow2_stream(monkeypatch):
-    """work=None dispatch (the fused decode scan) at a non-power-of-two
-    stream width: the in-trace dense schedule must cover exactly the
-    kernel's cdiv query-block grid.  A wider (pow2) schedule emits
-    block indices past the output grid whose first/last flags re-init
-    and finalise the clamped last real block with zeros — silently
-    zeroing the tail rows of every non-pow2 decode wave."""
+def _decode_case(rng, ctx, *, bs=4, max_blocks=8, hkv=2, g=2, dh=16,
+                 cache_dtype=None):
+    """A decode wave's attention inputs: row i one token at position
+    ``ctx[i] - 1``; ``ctx[i] == 0`` marks a dead row (slot -1: empty
+    table, context 1, as prepare_decode pads a batch)."""
+    import jax.numpy as jnp
+
+    from vllm_tgis_adapter_tpu.ops import kv_quant
+
+    ctx = np.asarray(ctx, np.int32)
+    b = len(ctx)
+    num_pages = b * max_blocks
+    tables = np.zeros((b, max_blocks), np.int32)
+    pages = rng.permutation(num_pages)
+    used = 0
+    for i, c in enumerate(ctx):
+        nb = -(-int(c) // bs)
+        tables[i, :nb] = pages[used: used + nb]
+        used += nb
+    shape = (hkv, num_pages * bs, dh)
+    kc = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    vc = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    scales = None
+    if cache_dtype is not None:
+        qk = qv = kv_quant.make_kv_cache(
+            (1, *shape), jnp.float32, cache_dtype, bs
+        )
+        slots = jnp.arange(num_pages * bs, dtype=jnp.int32)
+        qk = kv_quant.scatter_layer(qk, 0, slots, jnp.swapaxes(kc, 0, 1))
+        qv = kv_quant.scatter_layer(qv, 0, slots, jnp.swapaxes(vc, 0, 1))
+        kc, vc = kv_quant.layer_data(qk, 0), kv_quant.layer_data(qv, 0)
+        scales = kv_quant.layer_scales(qk, qv, 0)
+    return dict(
+        q=jnp.asarray(rng.standard_normal((b, hkv * g, dh)), jnp.float32),
+        kc=kc, vc=vc, scales=scales, tables=jnp.asarray(tables),
+        pos=jnp.asarray(np.maximum(ctx, 1) - 1), live=ctx > 0,
+        starts=jnp.arange(b + 1, dtype=jnp.int32), bs=bs, scale=dh**-0.5,
+        # what the dense cross product visited, and what is live
+        width=b * max_blocks,
+        live_items=int((-(-ctx // bs)).sum()),
+    )
+
+
+@pytest.mark.parametrize(
+    "ctx,cache_dtype",
+    [
+        pytest.param([0] * 16, None, id="all-rows-dead"),
+        pytest.param([0] * 23 + [9], None,
+                     id="one-live-row-in-last-block"),
+        pytest.param([4, 8, 1, 32, 5, 0, 12, 16], None,
+                     id="context-ends-on-page-boundary"),
+        pytest.param([32] * 64, None, id="64-rows-full-context"),
+        pytest.param([(5 * i) % 33 for i in range(21)], None,
+                     id="non-pow2-stream"),
+        pytest.param([7, 0, 19, 32, 1, 0, 0, 3, 0, 0, 0, 0, 0, 0, 0, 0, 11],
+                     "int8", id="int8-pages"),
+    ],
+)
+def test_decode_schedule_visits_only_live_work(ctx, cache_dtype):
+    """The fused wave's attention under its compact schedule and a
+    TRACED ``n_items``: the grid covers the live (row, page) items and
+    one dead item per empty query block, live rows equal the XLA
+    reference, dead rows come out finite (zeros)."""
+    import jax
+    import jax.numpy as jnp
+
+    from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
+
+    case = _decode_case(np.random.default_rng(7), ctx,
+                        cache_dtype=cache_dtype)
+    b, live = len(ctx), case["live"]
+    block_q = ra.decode_block_q(b)
+
+    @jax.jit
+    def attend(pos, active, tables):
+        work, n_items = ra.decode_work_schedule(
+            pos, active, tables, block_size=case["bs"]
+        )
+        out = ra._ragged_attention_pallas(
+            case["q"], case["kc"], case["vc"], case["starts"], pos, work,
+            case["bs"], case["scale"], block_q=block_q, window=0,
+            interpret=True, kv_scales=case["scales"], n_items=n_items,
+        )
+        return out, work, n_items
+
+    out, work, n_items = attend(
+        case["pos"], jnp.asarray(live), case["tables"]
+    )
+    work, n_items = np.asarray(work), int(n_items)
+    assert work.shape == (ra.WORK_FIELDS, case["width"])
+    nq = -(-b // block_q)
+    empty = sum(
+        not live[i * block_q: (i + 1) * block_q].any() for i in range(nq)
+    )
+    assert n_items == case["live_items"] + empty
+    head = work[:, :n_items]
+    assert head[6].sum() == case["live_items"]
+    # every query block is initialised once and finalised once, in order
+    assert head[4].sum() == head[5].sum() == nq
+    assert (np.diff(head[0]) >= 0).all()
+    assert sorted(set(head[0])) == list(range(nq))
+    # a live item names a page of its own row's table
+    tables = np.asarray(case["tables"])
+    on = head[6] == 1
+    assert (head[2][on] == tables[head[1][on], head[3][on]]).all()
+
+    ref = ra.ragged_attention_xla(
+        case["q"], case["kc"], case["vc"], case["pos"], case["starts"],
+        jnp.asarray(b), case["tables"], case["bs"], case["scale"],
+        kv_scales=case["scales"],
+    )
+    out = np.asarray(out)
+    assert np.isfinite(out).all()
+    assert (out[~live] == 0).all()
+    np.testing.assert_allclose(
+        out[live], np.asarray(ref)[live], rtol=1e-5, atol=1e-5
+    )
+
+
+def test_ragged_decode_schedule_non_pow2_stream(monkeypatch):
+    """The decode dispatch at a non-power-of-two stream width: the
+    in-trace schedule must cover exactly the kernel's cdiv query-block
+    grid.  A wider (pow2) schedule emits block indices past the output
+    grid whose first/last flags re-init and finalise the clamped last
+    real block with zeros — silently zeroing the tail rows of every
+    non-pow2 decode wave."""
     import jax.numpy as jnp
 
     from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
@@ -202,12 +321,16 @@ def test_ragged_dense_schedule_non_pow2_stream(monkeypatch):
         jnp.asarray(t), jnp.asarray(case["block_tables"]),
         case["bs"], case["scale"],
     )
+    work, n_items = ra.decode_work_schedule(
+        jnp.asarray(case["pos_base"][:t]), jnp.ones(t, bool),
+        jnp.asarray(case["block_tables"][:t]), block_size=case["bs"],
+    )
     out = ra.ragged_paged_attention(
         jnp.asarray(case["q"][:t]), case["kc"], case["vc"],
         jnp.asarray(case["positions"][:t]), jnp.asarray(case["seq_starts"]),
         jnp.asarray(case["pos_base"]), jnp.asarray(t),
         jnp.asarray(case["block_tables"]), case["bs"], case["scale"],
-        work=None,
+        work=work, n_items=n_items, block_q=ra.decode_block_q(t),
     )
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5

@@ -89,6 +89,8 @@ def test_streaming_generation_feeds_step_metrics(tiny_model_dir):
     before = _scrape()
     ttft_0 = _sample(before, "tgis_tpu_ttft_seconds_count")
     itl_0 = _sample(before, "tgis_tpu_inter_token_seconds_count")
+    waves_0 = _sample(before, "tgis_tpu_decode_live_items_count")
+    items_0 = _sample(before, "tgis_tpu_decode_live_items_sum")
     # label deltas are per-engine: each engine owns fresh jitted fns, so
     # its first flat-bucket-16 ragged dispatch compiles exactly once
     prefill_lbl = ('fn="ragged_step"', 'shape="tokens=16"')
@@ -115,6 +117,14 @@ def test_streaming_generation_feeds_step_metrics(tiny_model_dir):
     ), "same prefill bucket dispatched twice must compile exactly once"
     # per-dispatch shape stats were fed by the plan phase
     assert _sample(after, "tgis_tpu_decode_batch_occupancy") > 0
+    # every wave here holds ONE live row of 18-25 tokens of context:
+    # two 16-token pages, so two live (row, page) items a wave
+    waves = _sample(after, "tgis_tpu_decode_live_items_count") - waves_0
+    assert waves > 0
+    assert (
+        _sample(after, "tgis_tpu_decode_live_items_sum") - items_0
+        == 2 * waves
+    )
     assert _sample(after, "tgis_tpu_packed_prefill_prompts_count") > 0
     assert _sample(after, "tgis_tpu_decode_step_seconds_count") > 0
     assert _sample(after, "tgis_tpu_prefill_step_seconds_count") > 0
@@ -171,6 +181,7 @@ def test_metrics_endpoint_serves_step_metrics(tiny_model_dir):
         "tgis_tpu_decode_step_seconds",
         "tgis_tpu_prefill_step_seconds",
         "tgis_tpu_decode_batch_occupancy",
+        "tgis_tpu_decode_live_items",
         "tgis_tpu_prefill_padding_waste",
         "tgis_tpu_padded_tokens_total",
         "tgis_tpu_packed_prefill_prompts",

@@ -53,9 +53,13 @@ def _ragged_decode(q, kc, vc, bt, cl, block_size, scale, *, window=0,
 
     b = q.shape[0]
     pos = jnp.maximum(jnp.asarray(cl, jnp.int32), 1) - 1
+    work, n_items = R.decode_work_schedule(
+        pos, jnp.ones(b, bool), bt, block_size=block_size
+    )
     return R.ragged_paged_attention(
         q, kc, vc, pos, jnp.arange(b + 1, dtype=jnp.int32), pos,
         jnp.asarray(b, jnp.int32), bt, block_size, scale,
+        work=work, n_items=n_items, block_q=R.decode_block_q(b),
         window=window, alibi_slopes=alibi_slopes,
     )
 

@@ -1447,10 +1447,10 @@ class LLMEngine:
         # "all" also compiles the want_topn=True decode variant (static
         # argnum: flipping it at serving time is a fresh full compile)
         topn_variants = [False, True] if batch_widths == "all" else [False]
-        # two full fused waves: the first compiles the production
-        # num_decode_steps program, the second is dispatched CHAINED so
-        # the async loop's separately-jitted _chained_decode_fn compiles
-        # at the same (width, steps) shape
+        # two full fused waves: the first compiles the wave program
+        # (one for every step count), the second is dispatched CHAINED
+        # so the async loop's separately-jitted _chained_decode_fn
+        # compiles at the same width
         steps = sched.config.num_decode_steps
         total = 0
         # solo-prefill buckets whose program ACTUALLY compiled: recorded
@@ -1544,8 +1544,8 @@ class LLMEngine:
         full-batch decode wave CHAINED (mirroring the async loop's
         plan_chained_step -> dispatch_chained_step -> commit order,
         free-epoch discipline included) so the chained program compiles
-        at the production (width, num_decode_steps) shape rather than
-        on the first live chained wave.
+        at the production width rather than on the first live chained
+        wave.
 
         ``covered`` (when given) collects the SOLO prefill buckets this
         drain actually dispatched — the ground truth precompile() needs
@@ -1862,10 +1862,16 @@ class LLMEngine:
                     num_prompts=1,
                 )
             else:
+                # pages under the live rows' contexts, from the host's
+                # copy of the wave's inputs (no device read-back)
+                ctx = prepared.context_lens[: len(plan.seqs)]
                 metrics.observe_decode_plan(
                     num_seqs=len(plan.seqs),
                     batch_bucket=plan.batch_bucket,
                     num_steps=plan.num_steps,
+                    live_items=int(
+                        ((ctx - 1) // self.runner.block_size + 1).sum()
+                    ),
                     replica=self.replica_index,
                 )
         except Exception:  # pragma: no cover — metrics are best-effort

@@ -501,15 +501,19 @@ class ModelRunner:
 
     def _build_decode_fn(self):
         """Fused K-step decode+sample program (SURVEY.md §7 recompilation
-        discipline: one compiled program per batch-width bucket).
+        discipline: ONE compiled program, whatever the wave holds).
 
-        A ``lax.scan`` over the step axis runs the whole
-        decode → penalties → sample → feed-back loop on device, so the
+        A device loop over the step axis runs the whole
+        decode → penalties → sample → feed-back cycle on device, so the
         host pays one dispatch and one [K, B] result transfer for K
         tokens per sequence instead of K round-trips.  Per-step KV slots
         are computed on device from the block tables; rows finish early
         via the ``limits`` mask (their writes are dropped and their
-        sampled tokens discarded by the host).
+        sampled tokens discarded by the host).  The loop ends at the
+        last step some row can use, read from ``limits`` — a traced
+        bound, not a compile shape — and the results fill the head of
+        one fixed ``[num_decode_steps, B, 3+2W]`` buffer: a step no row
+        can use would be a whole pass over the weights.
 
         Transfer packing: the eleven per-row int32 inputs travel as ONE
         ``[11, B]`` array and the five float32 sampling knobs as one
@@ -520,6 +524,8 @@ class ModelRunner:
         """
         model = self.model
         block_size = self.block_size
+        max_steps = self.config.scheduler_config.num_decode_steps
+        vocab_size = self.config.model_config.vocab_size
         # the fused wave runs the SAME unified ragged kernel as mixed
         # steps (each row a one-token span) — the bucketed decode
         # variant ladder (folded → perhead → xla) is retired; the
@@ -539,7 +545,6 @@ class ModelRunner:
             allowed_mask,  # [B, V] bool or None (FSM-constrained rows)
             lora,  # LoRAStacks or None
             lora_idx,  # [B] adapter slot per row or None
-            num_steps: int,  # static: steps fused into this dispatch
             want_topn: bool = True,  # static: any row wants top-N logprobs
         ):
             tokens0 = ints[0]
@@ -564,9 +569,17 @@ class ModelRunner:
             )
             rows = jnp.clip(row_slots, 0, None)
             max_blocks = block_tables.shape[1]
+            # steps some live row can use (row i is active at step k
+            # while positions0[i] + k <= limits[i])
+            num_steps = jnp.minimum(
+                jnp.max(
+                    jnp.where(row_slots >= 0, limits - positions0 + 1, 0)
+                ),
+                max_steps,
+            )
 
-            def step(carry, k):
-                caches, seen, tokens = carry
+            def step(k, carry):
+                caches, seen, tokens, outs = carry
                 pos = positions0 + k
                 active = (pos <= limits) & (row_slots >= 0)
                 blk = jnp.take_along_axis(
@@ -592,24 +605,32 @@ class ModelRunner:
                 seen = sampler_mod.update_seen(
                     seen, jnp.where(active, row_slots, -1), out.tokens
                 )
-                return (caches, seen, out.tokens), out
+                outs = outs.at[k].set(sampler_mod.pack_output(out))
+                return caches, seen, out.tokens, outs
 
-            (caches, seen, _), outs = jax.lax.scan(
-                step, (caches, seen, tokens0), jnp.arange(num_steps)
-            )
             # ONE packed result buffer per wave (sampler.pack_output):
-            # the whole wave's results come back in a single fetch
-            return caches, seen, sampler_mod.pack_output(outs)
+            # the whole wave's results come back in a single fetch;
+            # steps past num_steps stay zero and are never read
+            outs = jnp.zeros(
+                (
+                    max_steps, tokens0.shape[0],
+                    sampler_mod.packed_width(vocab_size, want_topn),
+                ),
+                jnp.int32,
+            )
+            caches, seen, _, outs = jax.lax.fori_loop(
+                0, num_steps, step, (caches, seen, tokens0, outs)
+            )
+            return caches, seen, outs
 
         donate = (1, 2)
 
         def chained_decode_steps(
             params, caches, seen,
-            prev_ints_out,  # [K_prev, B, 3+2W] the in-flight wave's packed
+            prev_ints_out,  # [K, B, 3+2W] the in-flight wave's packed
             #     outputs (column 0 = sampled tokens; see packed_out)
             chain_idx,  # [B] i32: last live step per row in prev wave
             ints, floats, block_tables, allowed_mask, lora, lora_idx,
-            num_steps: int,
             want_topn: bool = True,
         ):
             # chained wave (async scheduling): the input token of each row
@@ -622,24 +643,22 @@ class ModelRunner:
             ints = ints.at[0].set(tokens0)
             return decode_steps(
                 params, caches, seen, ints, floats, block_tables,
-                allowed_mask, lora, lora_idx, num_steps, want_topn,
+                allowed_mask, lora, lora_idx, want_topn,
             )
 
         self._chained_decode_fn = track_jit(
             "ragged_chained_decode",
-            jax.jit(chained_decode_steps, static_argnums=(11, 12),
+            jax.jit(chained_decode_steps, static_argnums=(11,),
                     donate_argnums=donate),
-            # ints is arg 5 ([11, B]), num_steps is static arg 11
-            label=lambda args, kwargs:
-                f"batch={args[5].shape[1]},steps={args[11]}",
+            # ints is arg 5 ([11, B])
+            label=lambda args, kwargs: f"batch={args[5].shape[1]}",
         )
         return track_jit(
             "ragged_decode",
-            jax.jit(decode_steps, static_argnums=(9, 10),
+            jax.jit(decode_steps, static_argnums=(9,),
                     donate_argnums=donate),
-            # ints is arg 3 ([11, B]), num_steps is static arg 9
-            label=lambda args, kwargs:
-                f"batch={args[3].shape[1]},steps={args[9]}",
+            # ints is arg 3 ([11, B])
+            label=lambda args, kwargs: f"batch={args[3].shape[1]}",
         )
 
     def _put(self, x) -> jax.Array:
@@ -1667,7 +1686,6 @@ class ModelRunner:
                 self._put(prep.lora_idx)
                 if prep.lora_idx is not None
                 else None,
-                prep.num_steps,
                 prep.want_topn,
             )
 
@@ -1714,7 +1732,6 @@ class ModelRunner:
                 self._put(prep.lora_idx)
                 if prep.lora_idx is not None
                 else None,
-                prep.num_steps,
                 prep.want_topn,
             )
 

@@ -318,6 +318,12 @@ def pack_output(out: SamplerOutput) -> jax.Array:
     )
 
 
+def packed_width(vocab_size: int, want_topn: bool) -> int:
+    """Last-axis width of ``pack_output`` for ``sample(..., want_topn)``
+    over ``vocab_size`` logits: 3+2W."""
+    return 3 + 2 * (min(TOPN_WIDTH, vocab_size) if want_topn else 0)
+
+
 @jax.jit
 def pack_prompt_logprob_parts(
     parts: tuple[jax.Array, jax.Array, jax.Array, jax.Array],

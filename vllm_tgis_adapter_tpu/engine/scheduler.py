@@ -636,11 +636,11 @@ class Scheduler:
             # masked on device (slot -1), exactly like bucket padding
             # was, and the occupancy gauge keeps reporting real/width
             batch_bucket=self.config.max_num_seqs,
-            # fuse only as many steps as some row can consume: an
+            # only as many steps as some row can consume run: an
             # all-FSM-constrained batch (every row at 1 step) would
             # otherwise pay num_decode_steps of dead decode+sample work.
-            # num_steps is a static jit arg bounded by num_decode_steps,
-            # so this adds at most a handful of compiles.
+            # The device reads the same bound from the rows' limits
+            # (runner._build_decode_fn): a loop count, not a program.
             num_steps=max(steps_per_seq),
             steps_per_seq=steps_per_seq,
         )

@@ -315,6 +315,15 @@ decode_batch_occupancy = _get_or_create(
     "bucket is mostly pad",
     labelnames=("replica",),
 )
+decode_live_items = _get_or_create(
+    Histogram,
+    f"{_PREFIX}_decode_live_items",
+    "Live (row, KV page) items of a dispatched decode wave's first "
+    "step: the attention grid steps per KV head the wave's kernel call "
+    "visits (a dense rows x pages grid would visit max_num_seqs x "
+    "max_model_len / block_size whatever is live)",
+    buckets=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384),
+)
 prefill_padding_waste = _get_or_create(
     Gauge,
     f"{_PREFIX}_prefill_padding_waste",
@@ -747,9 +756,11 @@ step_snapshot = _StepSnapshot()
 
 
 def observe_decode_plan(*, num_seqs: int, batch_bucket: int,
-                        num_steps: int, replica: int = 0) -> None:
+                        num_steps: int, live_items: int,
+                        replica: int = 0) -> None:
     occupancy = num_seqs / batch_bucket if batch_bucket else 0.0
     decode_batch_occupancy.labels(replica=str(replica)).set(occupancy)
+    decode_live_items.observe(live_items)
     padded = (batch_bucket - num_seqs) * num_steps
     if padded > 0:
         padded_tokens_total.labels(phase="decode").inc(padded)

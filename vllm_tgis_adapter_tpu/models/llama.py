@@ -969,6 +969,24 @@ class LlamaForCausalLM:
         self._moe_valid_mask = slot_mapping >= 0  # see prefill
         # see prefill: negative pad slots must not wrap to the last page
         safe_slots = jnp.where(slot_mapping < 0, k_cache.shape[2], slot_mapping)
+        from vllm_tgis_adapter_tpu.ops import ragged_attention as ragged
+
+        b = token_ids.shape[0]
+        # one-token spans: row i is sequence i at position
+        # context_lens[i] - 1 (dead rows carry context 1/slot -1 and
+        # their output is discarded by the sampler mask, same as the
+        # padded-batch decode contract)
+        pos_base = jnp.maximum(context_lens, 1) - 1
+        seq_starts = jnp.arange(b + 1, dtype=jnp.int32)
+        # the kernel's schedule is the same for every layer: built once
+        # a step, over the live rows' live pages alone (a row with slot
+        # -1 writes nothing this step and nothing reads its output)
+        work = n_items = None
+        if attn_ops._use_pallas():
+            work, n_items = ragged.decode_work_schedule(
+                pos_base, slot_mapping >= 0, block_tables,
+                block_size=block_size,
+            )
 
         def attend(i, q, k, v):
             nonlocal k_cache, v_cache
@@ -978,24 +996,15 @@ class LlamaForCausalLM:
             v_cache = kv_quant.scatter_layer(
                 v_cache, i, safe_slots, v, self.mesh
             )
-            from vllm_tgis_adapter_tpu.ops.ragged_attention import (
-                ragged_paged_attention,
-            )
-
-            b = token_ids.shape[0]
-            # one-token spans: row i is sequence i at position
-            # context_lens[i] - 1 (dead rows carry context 1/slot -1
-            # and their garbage output is discarded by the sampler
-            # mask, same as the padded-batch decode contract)
-            return ragged_paged_attention(
+            return ragged.ragged_paged_attention(
                 kv_quant.pad_to_cache_width(q, k_cache),
                 kv_quant.layer_data(k_cache, i),
                 kv_quant.layer_data(v_cache, i),
-                jnp.maximum(context_lens, 1) - 1,
-                jnp.arange(b + 1, dtype=jnp.int32),
-                jnp.maximum(context_lens, 1) - 1,
+                pos_base, seq_starts, pos_base,
                 jnp.asarray(b, jnp.int32),
-                block_tables, block_size, scale, mesh=self.mesh,
+                block_tables, block_size, scale,
+                work=work, n_items=n_items,
+                block_q=ragged.decode_block_q(b), mesh=self.mesh,
                 window=self._window_for_layer(i),
                 alibi_slopes=self.alibi,
                 kv_scales=kv_quant.layer_scales(k_cache, v_cache, i),

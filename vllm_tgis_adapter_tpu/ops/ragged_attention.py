@@ -38,9 +38,12 @@ actually overlaps, exactly the ragged-friendly formulation the paper's
 kernel uses instead of a dense (batch, page) grid.  The schedule rides
 scalar prefetch; pages DMA straight out of the paged cache via the
 BlockSpec index map (the gather happens in the memory system).  Mixed
-engine steps pass a host-built sparse schedule (``build_work_schedule``);
-in-jit callers (the fused decode scan) build the dense per-row schedule
-in-trace (``dense_work_schedule``).  Numerics: f32 online-softmax
+engine steps pass a host-built sparse schedule (``build_work_schedule``)
+and run the whole of it; the fused decode wave builds its own in-trace,
+once per step (``decode_work_schedule``): the live (row, page) items
+first in an array of fixed shape, with their count ``n_items`` as the
+grid's TRACED second extent, so one program visits only what is live.
+Numerics: f32 online-softmax
 accumulation, masking identical to ``paged_decode_attention_xla`` — the
 XLA path below IS that function, so parity is pinned to the same
 reference the bucketed kernels are.
@@ -143,51 +146,82 @@ def build_work_schedule(
     return work
 
 
-def dense_work_schedule(
-    pos_base: jax.Array,  # [S] i32: context position per row (= seq)
+#: query-block height of the fused decode wave's schedule: every span
+#: is one row, so a taller block only multiplies masked rows per item
+DECODE_BLOCK_Q = 8
+
+
+def decode_block_q(num_rows: int) -> int:
+    """The ``block_q`` a ``decode_work_schedule`` over ``num_rows`` rows
+    is laid out for; the attention call must be given the same."""
+    return min(DECODE_BLOCK_Q, _pow2_ceil(num_rows))
+
+
+def decode_work_schedule(
+    pos_base: jax.Array,  # [S] i32: position of each row's one token
+    active: jax.Array,  # [S] bool: row is live in this step
     block_tables: jax.Array,  # [S, max_blocks] i32
     *,
     block_size: int,
-    block_q: int,
-    t_pad: int,
-) -> jax.Array:
-    """In-trace schedule for the fused decode scan, where every span is
+) -> tuple[jax.Array, jax.Array]:
+    """In-trace schedule for the fused decode wave, where every span is
     exactly ONE row (``seq_starts = arange(S+1)``): sequence *s* IS flat
-    row *s*, so its items live only in query block ``s // block_q`` and
-    the schedule is the plain (sequence, logical page) cross product —
-    W = S · max_blocks grid steps, nq× fewer than the general
-    (q-block, sequence, page) product would need.  Pages past a row's
-    context carry ``live=0`` with their DMA index clamped to a live page
-    so consecutive identical indices elide the transfer (same trick as
-    the decode kernel's ``page_index``).  Descriptor slots past the
-    stream (pad sequences, when the caller's S exceeds the row count)
-    clamp onto the last query block; their rows sit outside every real
-    span, so the kernel masks them and only pad outputs are touched.
+    row *s*, so its items live only in query block
+    ``s // decode_block_q(S)``.
+
+    Returns ``(work, n_items)``.  ``work`` keeps the static shape
+    ``[WORK_FIELDS, S * max_blocks]`` (every row at full context), but
+    only its first ``n_items`` entries mean anything: the live
+    (row, logical page) items in (query block, row, page) order — a row
+    owns ``pos_base // block_size + 1`` pages, a dead row none — plus
+    one dead item for each query block none of whose rows is live, so
+    every output block is still initialised and finalised.  The kernel's
+    grid ends at ``n_items`` (a traced scalar): what is not live costs
+    no grid step.
     """
     s_count, max_blocks = block_tables.shape
-    nq = t_pad // block_q
+    block_q = decode_block_q(s_count)
+    nq = -(-s_count // block_q)
+    rows = jnp.arange(s_count, dtype=jnp.int32)
+    pages = jnp.where(
+        active, jnp.clip(pos_base // block_size + 1, 1, max_blocks), 0
+    ).astype(jnp.int32)
+    block_pages = jnp.sum(
+        jnp.pad(pages, (0, nq * block_q - s_count)).reshape(nq, block_q),
+        axis=1,
+    )
+    # the dead item of an empty block rides on the block's first row
+    empty = (rows % block_q == 0) & (
+        jnp.take(block_pages, rows // block_q) == 0
+    )
+    items = pages + empty.astype(jnp.int32)
+    ends = jnp.cumsum(items)
+    n_items = ends[-1]
     w = jnp.arange(s_count * max_blocks, dtype=jnp.int32)
-    s = w // max_blocks
-    j = w % max_blocks
-    qb = jnp.minimum(s // block_q, nq - 1)
-    max_pos = jnp.take(pos_base, s)
-    live = j * block_size <= max_pos
-    j_eff = jnp.minimum(j, jnp.maximum(max_pos, 0) // block_size)
-    page = jnp.take_along_axis(
-        jnp.take(block_tables, s, axis=0), j_eff[:, None], axis=1
-    )[:, 0]
-    page = jnp.clip(page, 0, None)
-    # first/last flags on the block TRANSITIONS (not modular indexing):
-    # the clamp above can hand the last block a ragged item count, and
-    # every block's accumulators must init exactly once and finalise on
-    # the true final item
+    # entries past n_items clamp onto the last row: never visited
+    s = jnp.minimum(
+        jnp.searchsorted(ends, w, side="right").astype(jnp.int32),
+        s_count - 1,
+    )
+    j = w - (jnp.take(ends, s) - jnp.take(items, s))
+    row_pages = jnp.take(pages, s)
+    live = j < row_pages
+    # a dead item's DMA index clamps to a page of its row's table
+    j_eff = jnp.clip(j, 0, jnp.maximum(row_pages - 1, 0))
+    page = jnp.clip(block_tables[s, j_eff], 0, None)
+    qb = s // block_q
+    # first/last flags on the block TRANSITIONS: blocks hold ragged
+    # item counts, and every block's accumulators must init exactly
+    # once and finalise on its true final item
     step = qb[1:] != qb[:-1]
     edge = jnp.ones(1, bool)
-    first = jnp.concatenate([edge, step]).astype(jnp.int32)
-    last = jnp.concatenate([step, edge]).astype(jnp.int32)
-    return jnp.stack([
-        qb, s, page, j, first, last, live.astype(jnp.int32)
+    first = jnp.concatenate([edge, step])
+    last = jnp.concatenate([step, edge]) | (w == n_items - 1)
+    work = jnp.stack([
+        qb, s, page, j, first.astype(jnp.int32), last.astype(jnp.int32),
+        live.astype(jnp.int32),
     ])
+    return work, n_items
 
 
 # ----------------------------------------------------------------- kernel
@@ -321,9 +355,11 @@ def _ragged_attention_pallas(
     *,
     block_q: int,
     window: int,
-    alibi_slopes: jax.Array | None,
+    alibi_slopes: jax.Array | None = None,
     interpret: bool,
     kv_scales: tuple | None = None,  # ([Hkv, pages] f32 x2) quantized
+    n_items: jax.Array | None = None,  # traced scalar: the grid visits
+    #     only work[:, :n_items] (None = the whole schedule)
 ) -> jax.Array:
     t, num_heads, head_dim = q.shape
     num_kv = k_cache.shape[0]
@@ -345,7 +381,7 @@ def _ragged_attention_pallas(
         if alibi_slopes is None
         else alibi_slopes.astype(jnp.float32)
     )
-    num_work = work.shape[1]
+    num_work = work.shape[1] if n_items is None else n_items
     quantized = kv_scales is not None
     in_specs = [
         pl.BlockSpec(
@@ -457,7 +493,8 @@ def ragged_paged_attention(
     block_size: int,
     scale: float,
     *,
-    work: jax.Array | None = None,  # [WORK_FIELDS, W] or None
+    work: jax.Array | None = None,  # [WORK_FIELDS, W]; the Pallas path's
+    n_items: jax.Array | None = None,  # scalar: live prefix of ``work``
     mesh=None,
     window: int = 0,
     alibi_slopes: jax.Array | None = None,  # [H] f32 (bloom lineage)
@@ -467,11 +504,13 @@ def ragged_paged_attention(
     """One causal paged-attention dispatch over a mixed ragged stream.
 
     The caller must have scattered this step's K/V into the cache first.
-    TPU runs the Pallas work-schedule kernel (``work`` from
-    ``build_work_schedule``; built densely in-trace when None, the fused
-    decode-scan case); elsewhere the XLA reference runs and ``work`` is
-    ignored entirely — it never becomes an operand, so schedule-width
-    shape variety cannot retrace the CPU path.
+    TPU runs the Pallas work-schedule kernel over ``work``: the whole of
+    a host-built ``build_work_schedule`` (mixed steps), or the first
+    ``n_items`` entries of a ``decode_work_schedule`` built once per
+    decode step, with ``block_q = decode_block_q(T)``.  Elsewhere the
+    XLA reference runs and ``work`` is ignored entirely — it never
+    becomes an operand, so schedule-width shape variety cannot retrace
+    the CPU path.
 
     ``kv_scales`` marks the caches as quantized pages (ops/kv_quant.py):
     the Pallas kernel dequantizes each page tile in-register against its
@@ -480,63 +519,56 @@ def ragged_paged_attention(
     Under a TP mesh the kernel runs inside shard_map over the head axis,
     cache head-sharded — same contract as the bucketed kernels.
     """
-    if _use_pallas():
-        if work is None:
-            # dense in-trace schedule (the fused decode scan; requires
-            # single-row spans, seq_starts = arange): small q blocks —
-            # every span is one row, so a wide block would only
-            # multiply masked work items per (block, seq) pair.
-            # t_pad must equal the kernel's cdiv padding: a wider pad
-            # (e.g. pow2) emits query-block indices past the kernel's
-            # output grid, and their first/last flags would re-init and
-            # finalise a clamped real block with zeros
-            block_q = min(block_q, 8, _pow2_ceil(q.shape[0]))
-            work = dense_work_schedule(
-                pos_base, block_tables,
-                block_size=block_size, block_q=block_q,
-                t_pad=-(-q.shape[0] // block_q) * block_q,
-            )
-        kernel = functools.partial(
-            _ragged_attention_pallas,
-            block_size=block_size,
-            scale=scale,
-            block_q=block_q,
-            window=window,
-            interpret=_pallas_interpret(),
+    if not _use_pallas():
+        return ragged_attention_xla(
+            q, k_cache, v_cache, positions, seq_starts, total_tokens,
+            block_tables, block_size, scale,
+            window=window, alibi_slopes=alibi_slopes, kv_scales=kv_scales,
         )
-        if mesh is not None:
-            from jax.sharding import PartitionSpec as P
-
-            heads = P(None, "tp", None)
-            cache = P("tp", None, None)
-            operands = [q, k_cache, v_cache, seq_starts, pos_base, work]
-            specs = [heads, cache, cache, P(), P(), P()]
-            n_scales = 0
-            if kv_scales is not None:
-                # scale sidecars shard with the kv-head axis like the
-                # caches they dequantize
-                operands.extend(kv_scales)
-                specs.extend([P("tp", None), P("tp", None)])
-                n_scales = 2
-            if alibi_slopes is not None:
-                operands.append(alibi_slopes)
-                specs.append(P("tp"))
-
-            def wrapped(q, kc, vc, st, pb, wk, *rest):
-                scales = tuple(rest[:n_scales]) if n_scales else None
-                rest = rest[n_scales:]
-                return kernel(q, kc, vc, st, pb, wk,
-                              alibi_slopes=rest[0] if rest else None,
-                              kv_scales=scales)
-
-            return shard_map(
-                wrapped, mesh=mesh, in_specs=tuple(specs),
-                out_specs=heads, check_vma=False,
-            )(*operands)
-        return kernel(q, k_cache, v_cache, seq_starts, pos_base, work,
-                      alibi_slopes=alibi_slopes, kv_scales=kv_scales)
-    return ragged_attention_xla(
-        q, k_cache, v_cache, positions, seq_starts, total_tokens,
-        block_tables, block_size, scale,
-        window=window, alibi_slopes=alibi_slopes, kv_scales=kv_scales,
+    if work is None:
+        raise ValueError(
+            "the Pallas ragged kernel needs a work schedule: "
+            "build_work_schedule or decode_work_schedule"
+        )
+    kernel = functools.partial(
+        _ragged_attention_pallas,
+        block_size=block_size,
+        scale=scale,
+        block_q=block_q,
+        window=window,
+        interpret=_pallas_interpret(),
     )
+    # what only some calls carry
+    extras = {
+        name: value
+        for name, value in (
+            ("kv_scales", kv_scales),
+            ("alibi_slopes", alibi_slopes),
+            ("n_items", n_items),
+        )
+        if value is not None
+    }
+    if mesh is None:
+        return kernel(q, k_cache, v_cache, seq_starts, pos_base, work,
+                      **extras)
+    from jax.sharding import PartitionSpec as P
+
+    heads = P(None, "tp", None)
+    cache = P("tp", None, None)
+    # scale sidecars shard with the kv-head axis like the caches they
+    # dequantize, slopes with the heads; the grid's extent is
+    # replicated, like the schedule it bounds
+    extra_specs = {
+        "kv_scales": (P("tp", None), P("tp", None)),
+        "alibi_slopes": P("tp"),
+        "n_items": P(),
+    }
+    return shard_map(
+        lambda q, kc, vc, st, pb, wk, extras: kernel(
+            q, kc, vc, st, pb, wk, **extras
+        ),
+        mesh=mesh,
+        in_specs=(heads, cache, cache, P(), P(), P(),
+                  {name: extra_specs[name] for name in extras}),
+        out_specs=heads, check_vma=False,
+    )(q, k_cache, v_cache, seq_starts, pos_base, work, extras)
