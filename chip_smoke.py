@@ -291,13 +291,14 @@ def phase_kernel(args: argparse.Namespace) -> None:
     starts[3] = t
     base = np.zeros(4, np.int32)
     base[:3] = [s[2] for s in spans]
-    work = ra.build_work_schedule(
+    work, n_items = ra.build_work_schedule(
         spans, tables, block_size=block, block_q=block_q, t_pad=t_pad
     )
     got = ra._ragged_attention_pallas(
         q, kc, vc, jnp.asarray(starts), jnp.asarray(base),
         jnp.asarray(work), block, scale, block_q=block_q, window=0,
         alibi_slopes=None, interpret=args.tiny,
+        n_items=jnp.asarray(n_items, jnp.int32),
     )
     ref = ra.ragged_attention_xla(
         q, kc, vc, jnp.asarray(pos), jnp.asarray(starts), jnp.asarray(t),

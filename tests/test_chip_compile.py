@@ -29,6 +29,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
+from vllm_tgis_adapter_tpu.engine.runner import TPU_STEP_COMPILER_OPTIONS
 from vllm_tgis_adapter_tpu.ops import attention as attn_ops
 from vllm_tgis_adapter_tpu.ops import kv_quant
 from vllm_tgis_adapter_tpu.ops import pallas_attention as pk
@@ -42,6 +43,7 @@ GRANITE_2B = dict(
 HBM_BYTES = 16 * 10**9  # one v5e chip
 BLOCK = 16  # CacheConfig.block_size default
 PAGES = 1024
+LAYERS = 4  # of the pools the kernel-only gates address
 
 
 @pytest.fixture(scope="module")
@@ -88,13 +90,27 @@ def _compile(fn, *args, **kwargs):
 def _ragged_args(spec, *, h, hkv, dh, t, work_w, cache_dtype, seqs=64):
     args = [
         spec((t, h, dh), jnp.bfloat16),
-        spec((hkv, PAGES * BLOCK, dh), cache_dtype),
-        spec((hkv, PAGES * BLOCK, dh), cache_dtype),
+        spec((LAYERS, hkv, PAGES * BLOCK, dh), cache_dtype),
+        spec((LAYERS, hkv, PAGES * BLOCK, dh), cache_dtype),
         spec((seqs + 1,), jnp.int32),
         spec((seqs,), jnp.int32),
         spec((ra.WORK_FIELDS, work_w), jnp.int32),
+        spec((), jnp.int32),  # n_items
     ]
     return args
+
+
+def _layer_shaped(text: str, pool_shape) -> list[str]:
+    """Instructions of a compiled program whose result is ONE layer of a
+    ``[L, Hkv, slots, Dh]`` pool: the slice, copy or bitcast fusion that
+    would feed a kernel a ``cache[layer]`` operand (~127 MB a call at
+    granite-2b's pool)."""
+    import re
+
+    _, hkv, slots, dh = pool_shape
+    return re.findall(
+        rf"^\s*(?:ROOT )?(\S+) = \w+\[{hkv},{slots},{dh}\]", text, re.M
+    )
 
 
 @pytest.mark.parametrize(
@@ -129,21 +145,25 @@ def test_ragged_kernel_compiles(
         cache_dtype=cache_dtype,
     )
     if quantized:
-        args += [spec((hkv, PAGES), jnp.float32)] * 2
+        args += [spec((LAYERS, hkv, PAGES), jnp.float32)] * 2
     if alibi:
         args.append(spec((h,), jnp.float32))
 
-    def kernel(q, kc, vc, starts, base, work, *rest):
+    def kernel(q, kc, vc, starts, base, work, n_items, *rest):
         rest = list(rest)
         scales = (rest.pop(0), rest.pop(0)) if quantized else None
         return ra._ragged_attention_pallas(
             q, kc, vc, starts, base, work, BLOCK, dh**-0.5,
             block_q=block_q, window=window,
             alibi_slopes=rest[0] if alibi else None,
-            interpret=False, kv_scales=scales,
+            interpret=False, kv_scales=scales, n_items=n_items,
+            layer=LAYERS - 2,
         )
 
-    _compile(kernel, *args)
+    # the pool is the kernel's operand as it stands: nothing cuts a
+    # layer out of it on the way in
+    text = _compile(kernel, *args).as_text()
+    assert not _layer_shaped(text, (LAYERS, hkv, PAGES * BLOCK, dh))
 
 
 @pytest.mark.parametrize("tp", [1, 4])
@@ -172,18 +192,20 @@ def test_ragged_decode_schedule_compiles(topo, on_chip, tp):
             q, kc, vc, pos, jnp.arange(b + 1, dtype=jnp.int32), pos,
             jnp.asarray(b, jnp.int32), tables, BLOCK, dh**-0.5,
             work=work, n_items=n_items, block_q=ra.decode_block_q(b),
-            mesh=mesh if tp > 1 else None,
+            mesh=mesh if tp > 1 else None, layer=LAYERS - 1,
         )
 
-    _compile(
+    pool = (LAYERS, hkv, PAGES * BLOCK, dh)
+    text = _compile(
         decode,
         spec((b, 32, dh), jnp.bfloat16, None, "tp", None),
-        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16, "tp", None, None),
-        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16, "tp", None, None),
+        spec(pool, jnp.bfloat16, None, "tp", None, None),
+        spec(pool, jnp.bfloat16, None, "tp", None, None),
         spec((b,), jnp.int32),
         spec((b,), jnp.bool_),
         spec((b, max_blocks), jnp.int32),
-    )
+    ).as_text()
+    assert not _layer_shaped(text, (LAYERS, hkv // tp, PAGES * BLOCK, dh))
 
 
 def test_prefill_kernels_compile(topo, one_chip):
@@ -211,7 +233,9 @@ def test_prefill_kernels_compile(topo, one_chip):
 def test_ragged_kernel_under_tp4_shard_map(topo, on_chip):
     """tp=4 over the described host's four chips: the kernel cannot be
     partitioned by GSPMD, so it must sit inside shard_map with heads and
-    the pool's kv-head axis split four ways."""
+    the pool's kv-head axis split four ways — the WHOLE pool, each
+    device addressing the layer in the shard it holds, and the mixed
+    step's traced grid extent replicated beside the schedule."""
     mesh = Mesh(np.asarray(topo.devices).reshape(1, 1, 4),
                 ("dp", "sp", "tp"))
 
@@ -222,21 +246,24 @@ def test_ragged_kernel_under_tp4_shard_map(topo, on_chip):
 
     t, h, hkv, dh, seqs = 512, 32, 8, 128, 64
 
-    def attend(q, kc, vc, pos, starts, base, tables, work):
+    def attend(q, kc, vc, pos, starts, base, tables, work, n_items):
         return ra.ragged_paged_attention(
             q, kc, vc, pos, starts, base, jnp.asarray(t, jnp.int32),
-            tables, BLOCK, dh**-0.5, work=work, mesh=mesh,
+            tables, BLOCK, dh**-0.5, work=work, n_items=n_items,
+            mesh=mesh, layer=1,
         )
 
-    _compile(
+    pool = (LAYERS, hkv, PAGES * BLOCK, dh)
+    text = _compile(
         attend,
         spec((t, h, dh), jnp.bfloat16, None, "tp", None),
-        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16, "tp", None, None),
-        spec((hkv, PAGES * BLOCK, dh), jnp.bfloat16, "tp", None, None),
+        spec(pool, jnp.bfloat16, None, "tp", None, None),
+        spec(pool, jnp.bfloat16, None, "tp", None, None),
         spec((t,), jnp.int32), spec((seqs + 1,), jnp.int32),
         spec((seqs,), jnp.int32), spec((seqs, 4096 // BLOCK), jnp.int32),
-        spec((ra.WORK_FIELDS, 256), jnp.int32),
-    )
+        spec((ra.WORK_FIELDS, 256), jnp.int32), spec((), jnp.int32),
+    ).as_text()
+    assert not _layer_shaped(text, (LAYERS, hkv // 4, PAGES * BLOCK, dh))
 
 
 def test_head_dim_64_pool_is_lane_padded_on_the_chip(topo):
@@ -249,12 +276,12 @@ def test_head_dim_64_pool_is_lane_padded_on_the_chip(topo):
     assert kv_quant.cache_head_dim(64, jax.local_devices()[0]) == 64
 
 
-def _granite_2b_on_chip(topo, one_chip, monkeypatch):
-    """granite-2b at published widths and full depth as a described chip
-    would hold it: the model, its parameters' shapes (``eval_shape``),
-    the pool ``resolve_num_blocks`` would size (90% of HBM less the
-    weights) with rows lane-padded as the chip pads them, and the pool's
-    bytes."""
+def _model_on_chip(topo, one_chip, monkeypatch, cfg):
+    """A model at published widths as a described chip would hold it
+    (granite-2b at full depth; Mistral-7B at the 16 layers that fit one
+    chip): the model, its parameters' shapes (``eval_shape``), the pool
+    ``resolve_num_blocks`` would size (90% of HBM less the weights) with
+    rows lane-padded as the chip pads them, and the pool's bytes."""
     from vllm_tgis_adapter_tpu.models.llama import LlamaForCausalLM
 
     chip = topo.devices[0]
@@ -262,7 +289,6 @@ def _granite_2b_on_chip(topo, one_chip, monkeypatch):
     monkeypatch.setattr(
         kv_quant, "cache_head_dim", lambda dh, device=None: probe(dh, chip)
     )
-    cfg = _granite_2b()
     model = LlamaForCausalLM(cfg)
 
     def on_chip_shapes(tree):
@@ -279,24 +305,30 @@ def _granite_2b_on_chip(topo, one_chip, monkeypatch):
     weight_bytes = sum(
         leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(params)
     )
-    page_bytes = 2 * 40 * 8 * BLOCK * 128 * 2
+    page_bytes = 2 * cfg.num_layers * cfg.num_kv_heads * BLOCK * 128 * 2
     pages = (int(HBM_BYTES * 0.9) - weight_bytes) // page_bytes
     caches = on_chip_shapes(jax.eval_shape(
         lambda: model.make_kv_caches(pages * BLOCK, cfg.dtype)
     ))
-    assert caches[0].shape == (40, 8, pages * BLOCK, 128)
+    assert caches[0].shape == (
+        cfg.num_layers, cfg.num_kv_heads, pages * BLOCK, 128
+    )
     return model, params, caches, 2 * caches[0].size * 2
 
 
-def _assert_in_place_and_fits(compiled, pool_bytes):
-    """The kernel is in the program, the donated pool is updated IN
-    PLACE (no pool-sized temporary: per-layer K and V operand slices and
-    activations only; a pool re-layout would show as gigabytes), and
-    weights + pool + temporaries fit one 16 GB chip."""
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_in_place_and_fits(compiled, caches, pool_bytes):
+    """The kernel is in the program; the donated pool is its operand
+    whole and is updated IN PLACE: nothing produces one layer of it (the
+    ``cache[layer]`` slice a kernel operand used to be), temporaries are
+    activations only (a layer's K and V copies were 250 MB, a pool
+    re-layout would show as gigabytes); and weights + pool + temporaries
+    fit one 16 GB chip."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not _layer_shaped(text, caches[0].shape)
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == pool_bytes
-    assert mem.temp_size_in_bytes < pool_bytes // 10
+    assert mem.temp_size_in_bytes < 128 << 20
     total = (
         mem.argument_size_in_bytes + mem.temp_size_in_bytes
         + mem.output_size_in_bytes - mem.alias_size_in_bytes
@@ -304,14 +336,47 @@ def _assert_in_place_and_fits(compiled, pool_bytes):
     assert total < HBM_BYTES
 
 
+def _assert_no_weight_prefetch(compiled):
+    """With the runner's own options (``TPU_STEP_COMPILER_OPTIONS``) the
+    compiler copies no weight into VMEM ahead of its matmul: each such
+    copy is three events of a device trace, and they were three fifths
+    of a step's (a 6 s trace of the PR 31 server could not be handed
+    over inside the profiler client's 60 s)."""
+    import re
+
+    text = compiled.as_text()
+    prefetches = re.findall(r" (slice-start|copy-start)\(", text)
+    # a handful of copies into the program remain (cross-program
+    # prefetch of the first operands); none inside the layers
+    assert prefetches.count("slice-start") == 0
+    assert prefetches.count("copy-start") <= 4, len(prefetches)
+
+
+#: the two served widths: head dim 64 (pool rows lane-padded to 128) at
+#: full depth, and head dim 128 unpadded at the depth one chip holds
+SERVED = pytest.mark.parametrize(
+    "cfg", ["granite2b", "mistral7b-l16"], indirect=True
+)
+
+
+@pytest.fixture
+def cfg(request):
+    return {"granite2b": _granite_2b, "mistral7b-l16": _mistral_7b_l16}[
+        request.param
+    ]()
+
+
+@SERVED
 def test_whole_ragged_step_compiles_and_fits(topo, one_chip, on_chip,
-                                             monkeypatch):
+                                             monkeypatch, cfg):
     """One whole step program of the runner's serving path (mixed
-    ragged step, T=512) at granite-2b's published widths and full depth,
-    from ``eval_shape`` parameters (the faults this file exists for: the
-    pre-PR-23 scatter re-laid the whole pool twice a step)."""
-    model, params, caches, pool_bytes = _granite_2b_on_chip(
-        topo, one_chip, monkeypatch
+    ragged step, T=512) at published widths, from ``eval_shape``
+    parameters (the faults this file exists for: the pre-PR-23 scatter
+    re-laid the whole pool twice a step; until PR 31 every attention
+    call was fed a copy of its layer's K and V).  The schedule's real
+    item count is an operand beside it: the grid's traced extent."""
+    model, params, caches, pool_bytes = _model_on_chip(
+        topo, one_chip, monkeypatch, cfg
     )
     spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     t, seqs = 512, 64
@@ -324,21 +389,24 @@ def test_whole_ragged_step_compiles_and_fits(topo, one_chip, on_chip,
         spec((t,), jnp.int32), spec((seqs + 1,), jnp.int32),
         spec((seqs,), jnp.int32), spec((), jnp.int32),
         spec((seqs, 4096 // BLOCK), jnp.int32), spec((seqs,), jnp.int32),
-        work=spec((ra.WORK_FIELDS, 256), jnp.int32),
-    ).compile()
-    _assert_in_place_and_fits(compiled, pool_bytes)
+        work=spec((ra.WORK_FIELDS, 2048), jnp.int32),
+        n_items=spec((), jnp.int32),
+    ).compile(compiler_options=TPU_STEP_COMPILER_OPTIONS)
+    _assert_in_place_and_fits(compiled, caches, pool_bytes)
+    _assert_no_weight_prefetch(compiled)
 
 
+@SERVED
 def test_whole_decode_wave_compiles_and_fits(topo, one_chip, on_chip,
-                                             monkeypatch):
+                                             monkeypatch, cfg):
     """The mixed step's twin for the fused wave: ``model.decode`` with
-    its hoisted schedule inside a loop of TRACED length, at granite-2b's
-    published widths and full depth (40 layers: 16 s of the tier-1 run).
+    its hoisted schedule inside a loop of TRACED length, at published
+    widths (granite-2b's 40 layers: 16 s of the tier-1 run).
     One program serves every step count and every number of live rows
     and pages: the kernel's grid extent and the loop's bound are both
     operands.  The pool is carried through the loop in place."""
-    model, params, caches, pool_bytes = _granite_2b_on_chip(
-        topo, one_chip, monkeypatch
+    model, params, caches, pool_bytes = _model_on_chip(
+        topo, one_chip, monkeypatch, cfg
     )
     b, max_blocks = 64, 4096 // BLOCK
 
@@ -362,10 +430,11 @@ def test_whole_decode_wave_compiles_and_fits(topo, one_chip, on_chip,
         params, caches, spec((b,), jnp.int32), spec((b,), jnp.int32),
         spec((b,), jnp.int32), spec((b, max_blocks), jnp.int32),
         spec((), jnp.int32),
-    ).compile()
+    ).compile(compiler_options=TPU_STEP_COMPILER_OPTIONS)
     # the schedule keeps its one static shape, whatever is live
     assert f"s32[{ra.WORK_FIELDS},{b * max_blocks}]" in compiled.as_text()
-    _assert_in_place_and_fits(compiled, pool_bytes)
+    _assert_in_place_and_fits(compiled, caches, pool_bytes)
+    _assert_no_weight_prefetch(compiled)
 
 
 def _granite_2b(num_layers: int = 40):
@@ -377,6 +446,20 @@ def _granite_2b(num_layers: int = 40):
         dtype=jnp.bfloat16, logits_scaling=8.0, embedding_multiplier=12.0,
         residual_multiplier=0.22, attention_multiplier=0.015625,
         **{**GRANITE_2B, "num_layers": num_layers},
+    )
+
+
+def _mistral_7b_l16():
+    """``benchmark/configs/mistral-7b-v0.3-l16.json``: head dim 128, so
+    the pool's rows are not padded."""
+    from vllm_tgis_adapter_tpu.engine.config import ModelConfig
+
+    return ModelConfig(
+        model="mistral-7b-v0.3-l16", model_type="mistral",
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_layers=16, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_model_len=4096, rope_theta=1e6, rms_norm_eps=1e-5,
+        dtype=jnp.bfloat16,
     )
 
 
@@ -452,13 +535,16 @@ def test_tp4_step_keeps_the_pool_on_its_shards(topo, on_chip, monkeypatch):
     ).lower(
         params, caches, spec(t), spec(t), spec(t), spec(seqs + 1),
         spec(seqs), spec(), spec(seqs, 4096 // BLOCK), spec(seqs),
-        work=spec(ra.WORK_FIELDS, 256),
+        work=spec(ra.WORK_FIELDS, 256), n_items=spec(),
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce(" in text
+    # each device's kernel reads its own shard of the whole pool
+    layers, hkv, slots, dh = caches[0].shape
+    assert not _layer_shaped(text, (layers, hkv // 4, slots, dh))
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == shard_pool_bytes
-    assert mem.temp_size_in_bytes < shard_pool_bytes
+    assert mem.temp_size_in_bytes < shard_pool_bytes // 10
 
 
 def test_lane_padded_pool_serves_the_same_tokens(tiny_model_dir,

@@ -200,7 +200,7 @@ def test_ragged_kernel_parity_pallas_interpret(scheme, monkeypatch):
     )
     scales = kv_quant.layer_scales(kc, vc, 0)
     ref = ragged_paged_attention(*args, kv_scales=scales)  # XLA on CPU
-    work = build_work_schedule(
+    work, _ = build_work_schedule(
         [(0, 20, 0), (20, 1, 30)], tables,
         block_size=bs, block_q=8, t_pad=24,
     )

@@ -25,7 +25,8 @@ REPO_ROOT = Path(__file__).resolve().parent.parent
 # ---------------------------------------------------------------- kernel
 
 
-def _build_mixed_case(rng, cases, *, hkv=2, g=2, dh=16, bs=4, max_blocks=8):
+def _build_mixed_case(rng, cases, *, hkv=2, g=2, dh=16, bs=4, max_blocks=8,
+                      t_pad=None):
     """Build a paged cache + flat mixed stream from (ctx_before, n_new)
     span specs; returns everything the ragged kernel consumes plus the
     per-sequence pieces the reference needs."""
@@ -61,7 +62,7 @@ def _build_mixed_case(rng, cases, *, hkv=2, g=2, dh=16, bs=4, max_blocks=8):
         per_seq.append((q_new, ctx_before, n_new))
         row += n_new
     t = row
-    t_pad = 16 if t <= 16 else 32
+    t_pad = t_pad or (16 if t <= 16 else 32)
     q = np.zeros((t_pad, h, dh), np.float32)
     q[:t] = np.concatenate(flat_q)
     positions = np.zeros(t_pad, np.int32)
@@ -158,7 +159,7 @@ def test_ragged_pallas_matches_xla(window, use_alibi, schedule):
         work = jnp.asarray(ra.build_work_schedule(
             case["spans"], case["block_tables"],
             block_size=case["bs"], block_q=8, t_pad=case["t_pad"],
-        ))
+        )[0])
     else:
         t = case["t"]  # S == T: the pad descriptor slot stays out
         work, n_items = ra.decode_work_schedule(
@@ -335,6 +336,227 @@ def test_ragged_decode_schedule_non_pow2_stream(monkeypatch):
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5
     )
+
+
+# ------------------------------------- the pool as one operand, by layer
+
+
+def _pool_case(rng, cache_dtype, *, layers=3, hkv=2, g=2, dh=16, bs=4,
+               max_blocks=8):
+    """A mixed ragged batch (prefill chunk, decode rows, a resumed
+    chunk) over a WHOLE ``[L, Hkv, slots, Dh]`` pool whose layers hold
+    different data, written through ``scatter_layer`` as the step
+    programs write it (so quantized pools carry their sidecars)."""
+    import jax.numpy as jnp
+
+    from vllm_tgis_adapter_tpu.ops import kv_quant
+
+    spans_spec = [(0, 6), (9, 1), (3, 4), (5, 1), (13, 1)]
+    num_pages = 32
+    scheme = cache_dtype if cache_dtype in ("int8", "fp8") else "none"
+    store = jnp.bfloat16 if cache_dtype == "bf16" else jnp.float32
+    k_pool = kv_quant.make_kv_cache(
+        (layers, hkv, num_pages * bs, dh), store, scheme, bs
+    )
+    v_pool = kv_quant.make_kv_cache(
+        (layers, hkv, num_pages * bs, dh), store, scheme, bs
+    )
+    slots = jnp.arange(num_pages * bs, dtype=jnp.int32)
+    for layer in range(layers):
+        k_pool = kv_quant.scatter_layer(
+            k_pool, layer, slots, jnp.asarray(
+                rng.standard_normal((num_pages * bs, hkv, dh)), jnp.float32
+            ),
+        )
+        v_pool = kv_quant.scatter_layer(
+            v_pool, layer, slots, jnp.asarray(
+                rng.standard_normal((num_pages * bs, hkv, dh)), jnp.float32
+            ),
+        )
+    s_pad = len(spans_spec) + 1
+    tables = np.zeros((s_pad, max_blocks), np.int32)
+    pages = rng.permutation(num_pages)
+    spans, positions, used, row = [], [], 0, 0
+    for s, (ctx_before, n_new) in enumerate(spans_spec):
+        nb = -(-(ctx_before + n_new) // bs)
+        tables[s, :nb] = pages[used: used + nb]
+        used += nb
+        spans.append((row, n_new, ctx_before))
+        positions += list(range(ctx_before, ctx_before + n_new))
+        row += n_new
+    t, t_pad = row, 16
+    pos = np.zeros(t_pad, np.int32)
+    pos[:t] = positions
+    starts = np.full(s_pad + 1, t_pad, np.int32)
+    starts[: len(spans)] = [sp[0] for sp in spans]
+    starts[len(spans)] = t
+    base = np.zeros(s_pad, np.int32)
+    base[: len(spans)] = [sp[2] for sp in spans]
+    q = np.zeros((t_pad, hkv * g, dh), np.float32)
+    q[:t] = rng.standard_normal((t, hkv * g, dh))
+    return dict(
+        k_pool=k_pool, v_pool=v_pool, q=jnp.asarray(q), t=t, t_pad=t_pad,
+        positions=jnp.asarray(pos), starts=jnp.asarray(starts),
+        base=jnp.asarray(base), tables=tables, spans=spans, bs=bs,
+        scale=dh**-0.5, h=hkv * g, layers=layers,
+    )
+
+
+@pytest.mark.parametrize("use_alibi", [False, True],
+                         ids=["no-alibi", "alibi"])
+@pytest.mark.parametrize("window", [0, 4], ids=["full", "window4"])
+@pytest.mark.parametrize("cache_dtype", ["bf16", "int8", "fp8"])
+@pytest.mark.parametrize("layer", [0, 1, 2],
+                         ids=["first", "middle", "last"])
+def test_pool_operand_is_addressed_by_layer(
+    layer, cache_dtype, window, use_alibi, monkeypatch
+):
+    """The kernel is handed the whole K and V pools (and, quantized,
+    the whole scale sidecars) and the layer to read: its output is the
+    XLA reference's over that layer's own slice — for the first, a
+    middle and the last layer, so an index map that ignored the layer
+    or addressed a neighbour shows."""
+    import jax.numpy as jnp
+
+    from vllm_tgis_adapter_tpu.ops import kv_quant
+    from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
+
+    rng = np.random.default_rng(31)
+    case = _pool_case(rng, cache_dtype)
+    slopes = (
+        jnp.asarray(rng.standard_normal(case["h"]).astype(np.float32) * 0.1)
+        if use_alibi
+        else None
+    )
+    k_pool, v_pool = case["k_pool"], case["v_pool"]
+    ref = ra.ragged_attention_xla(
+        case["q"], kv_quant.layer_data(k_pool, layer),
+        kv_quant.layer_data(v_pool, layer), case["positions"],
+        case["starts"], jnp.asarray(case["t"]), jnp.asarray(case["tables"]),
+        case["bs"], case["scale"], window=window, alibi_slopes=slopes,
+        kv_scales=kv_quant.layer_scales(k_pool, v_pool, layer),
+    )
+    work, n_items = ra.build_work_schedule(
+        case["spans"], case["tables"], block_size=case["bs"], block_q=8,
+        t_pad=case["t_pad"],
+    )
+    monkeypatch.setattr(ra, "_use_pallas", lambda: True)
+    monkeypatch.setattr(ra, "_pallas_interpret", lambda: True)
+    out = ra.ragged_paged_attention(
+        case["q"], *kv_quant.pool_data(k_pool, v_pool), case["positions"],
+        case["starts"], case["base"], jnp.asarray(case["t"]),
+        jnp.asarray(case["tables"]), case["bs"], case["scale"],
+        layer=layer, work=jnp.asarray(work),
+        n_items=jnp.asarray(n_items, jnp.int32), block_q=8,
+        window=window, alibi_slopes=slopes,
+        kv_scales=kv_quant.pool_scales(k_pool, v_pool),
+    )
+    t = case["t"]
+    np.testing.assert_allclose(
+        np.asarray(out[:t]), np.asarray(ref[:t]), rtol=2e-5, atol=2e-5
+    )
+    if layer:  # the layers do differ: a wrong address could not pass
+        other = ra.ragged_attention_xla(
+            case["q"], kv_quant.layer_data(k_pool, layer - 1),
+            kv_quant.layer_data(v_pool, layer - 1), case["positions"],
+            case["starts"], jnp.asarray(case["t"]),
+            jnp.asarray(case["tables"]), case["bs"], case["scale"],
+            window=window, alibi_slopes=slopes,
+            kv_scales=kv_quant.layer_scales(k_pool, v_pool, layer - 1),
+        )
+        assert not np.allclose(
+            np.asarray(other[:t]), np.asarray(ref[:t]), atol=1e-2
+        )
+
+
+# --------------------------- the mixed step's grid ends at its last item
+
+
+def _expected_items(spans, *, bs, block_q, t_pad):
+    """The schedule's real-item count, reckoned apart from the builder:
+    per query block the pages each of its sequences can see there, and
+    one dead item for a block with none."""
+    total = 0
+    for qb in range(t_pad // block_q):
+        lo, hi = qb * block_q, (qb + 1) * block_q - 1
+        items = 0
+        for start, n_rows, pos0 in spans:
+            first, last = max(start, lo), min(start + n_rows - 1, hi)
+            if first <= last:
+                items += (pos0 + last - start) // bs + 1
+        total += items or 1
+    return total
+
+
+@pytest.mark.parametrize(
+    "cases,t_pad,width",
+    [
+        # 11 real rows of a 32-row bucket: query blocks 2 and 3 are all
+        # padding and own one dead item each, the schedule's last two
+        pytest.param([(0, 6), (9, 1), (3, 4)], 32, 64,
+                     id="all-padding-query-blocks"),
+        # 16 items, two of them dead: the real count IS the (pow2) width
+        pytest.param([(0, 8), (31, 1), (8, 7)], 32, None,
+                     id="real-count-equals-width"),
+        # the served shape of the thing: few items, a width far above
+        pytest.param([(5, 1), (17, 1), (2, 9), (30, 1)], 16, 256,
+                     id="width-far-above"),
+    ],
+)
+def test_mixed_grid_ends_at_its_last_real_item(cases, t_pad, width):
+    """A host-built schedule run to ``n_items`` equals the same
+    schedule run whole, and the steps past ``n_items`` are NOT visited:
+    padding rewritten into items that would wipe the last query block
+    (init + finalise with nothing accumulated) changes nothing when the
+    grid ends at the real count, and does when it runs the width."""
+    import jax.numpy as jnp
+
+    from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
+
+    rng = np.random.default_rng(5)
+    case = _build_mixed_case(rng, cases, t_pad=t_pad)
+    block_q = 8
+    work, n_items = ra.build_work_schedule(
+        case["spans"], case["block_tables"], block_size=case["bs"],
+        block_q=block_q, t_pad=t_pad, w_bucket=width,
+    )
+    assert n_items == _expected_items(
+        case["spans"], bs=case["bs"], block_q=block_q, t_pad=t_pad
+    )
+    assert work[5, n_items - 1] == 1  # the last real item finalises
+    assert not work[4:, n_items:].any()  # the rest is flagless padding
+    if width is None:
+        assert work.shape[1] == n_items
+    else:
+        assert work.shape[1] == width > n_items
+
+    def run(work, n_items):
+        out = ra._ragged_attention_pallas(
+            jnp.asarray(case["q"]), case["kc"], case["vc"],
+            jnp.asarray(case["seq_starts"]), jnp.asarray(case["pos_base"]),
+            jnp.asarray(work), case["bs"], case["scale"], block_q=block_q,
+            window=0, interpret=True,
+            n_items=None if n_items is None
+            else jnp.asarray(n_items, jnp.int32),
+        )
+        return np.asarray(out[: case["t"]])
+
+    ref = np.asarray(ra.ragged_attention_xla(
+        jnp.asarray(case["q"]), case["kc"], case["vc"],
+        jnp.asarray(case["positions"]), jnp.asarray(case["seq_starts"]),
+        jnp.asarray(case["t"]), jnp.asarray(case["block_tables"]),
+        case["bs"], case["scale"],
+    )[: case["t"]])
+    whole, bounded = run(work, None), run(work, n_items)
+    np.testing.assert_array_equal(bounded, whole)
+    np.testing.assert_allclose(bounded, ref, rtol=1e-5, atol=1e-5)
+
+    if width is not None:
+        wiped = work.copy()
+        wiped[0, n_items:] = 0  # query block 0 holds real rows
+        wiped[4:6, n_items:] = 1  # init + finalise, nothing accumulated
+        np.testing.assert_array_equal(run(wiped, n_items), whole)
+        assert not np.allclose(run(wiped, None), whole, atol=1e-3)
 
 
 # ------------------------------------------------------------ engine pair

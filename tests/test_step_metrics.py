@@ -130,6 +130,67 @@ def test_streaming_generation_feeds_step_metrics(tiny_model_dir):
     assert _sample(after, "tgis_tpu_prefill_step_seconds_count") > 0
 
 
+def test_mixed_step_observes_its_real_item_count(tiny_model_dir,
+                                                 monkeypatch):
+    """Every served mixed ragged step observes the real-item count of
+    its work schedule in ``tgis_tpu_ragged_live_items``: what the
+    attention grid visits per KV head, not the schedule's compile width.
+    The schedule exists where the Pallas path is taken, so the runner is
+    steered there; the kernel's own choice is not, and the CPU serves
+    through the XLA reference, which takes neither operand."""
+    from vllm_tgis_adapter_tpu.ops import attention as attn_ops
+
+    monkeypatch.setattr(attn_ops, "_use_pallas", lambda: True)
+    engine = _build_engine(tiny_model_dir)
+    runner = engine._replicas[0].engine.runner
+    prepare = runner.prepare_ragged
+    seen: list[tuple[int, int]] = []
+
+    def spy(plan):
+        prep = prepare(plan)
+        seen.append((prep.work_items, prep.work.shape[1]))
+        return prep
+
+    monkeypatch.setattr(runner, "prepare_ragged", spy)
+    before = _scrape()
+    steps_0 = _sample(before, "tgis_tpu_ragged_live_items_count")
+    items_0 = _sample(before, "tgis_tpu_ragged_live_items_sum")
+
+    alone: list[int] = []
+
+    async def scenario() -> None:
+        await _stream_one(engine, "live-items-0", prompt_len=17)
+        alone.extend(items for items, _ in seen)
+        # a second prompt arrives while the first decodes: its prefill
+        # shares mixed steps with the running row
+        first = asyncio.create_task(
+            _stream_one(engine, "live-items-1", prompt_len=17,
+                        max_tokens=24)
+        )
+        await asyncio.sleep(0.5)
+        await _stream_one(engine, "live-items-2", prompt_len=40)
+        await first
+        await engine.stop()
+
+    asyncio.run(scenario())
+
+    after = _scrape()
+    # the planner cuts a lone 17-token prompt into its binary digits: a
+    # 16-token step over the prompt's first page, then one token that
+    # sees both of its 16-token pages; its decode steps are waves
+    assert alone == [1, 2]
+    assert len(seen) > len(alone)
+    assert all(0 < items < width for items, width in seen)
+    assert (
+        _sample(after, "tgis_tpu_ragged_live_items_count") - steps_0
+        == len(seen)
+    )
+    assert (
+        _sample(after, "tgis_tpu_ragged_live_items_sum") - items_0
+        == sum(items for items, _ in seen)
+    )
+
+
 def test_recompile_tracker_two_batch_shapes(tiny_model_dir):
     """Two distinct prefill bucket shapes each record their own labeled
     compile; re-dispatching either adds none."""
@@ -182,6 +243,7 @@ def test_metrics_endpoint_serves_step_metrics(tiny_model_dir):
         "tgis_tpu_prefill_step_seconds",
         "tgis_tpu_decode_batch_occupancy",
         "tgis_tpu_decode_live_items",
+        "tgis_tpu_ragged_live_items",
         "tgis_tpu_prefill_padding_waste",
         "tgis_tpu_padded_tokens_total",
         "tgis_tpu_packed_prefill_prompts",

@@ -410,7 +410,7 @@ def test_ragged_kernel_compiles_and_matches(
     pb = np.zeros(4, np.int32)
     pb[:3] = pos_base
     scale = head_dim**-0.5
-    work = ra.build_work_schedule(
+    work, _ = ra.build_work_schedule(
         spans, tables, block_size=block_size, block_q=block_q,
         t_pad=t_pad,
     )

@@ -1854,6 +1854,7 @@ class LLMEngine:
                         1 for i in plan.items if not i.is_decode
                     ),
                     num_decode=sum(1 for i in plan.items if i.is_decode),
+                    live_items=prepared.work_items,
                 )
             elif isinstance(plan, PrefillPlan):
                 metrics.observe_prefill_plan(

@@ -324,6 +324,16 @@ decode_live_items = _get_or_create(
     "max_model_len / block_size whatever is live)",
     buckets=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384),
 )
+ragged_live_items = _get_or_create(
+    Histogram,
+    f"{_PREFIX}_ragged_live_items",
+    "Real (query block, sequence, KV page) items of a dispatched mixed "
+    "ragged step's work schedule: the attention grid steps per KV head "
+    "its kernel calls visit (the schedule's width, a compile shape that "
+    "only grows, is padding beyond them); observed where a schedule is "
+    "built, i.e. on the Pallas path",
+    buckets=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384),
+)
 prefill_padding_waste = _get_or_create(
     Gauge,
     f"{_PREFIX}_prefill_padding_waste",
@@ -780,13 +790,17 @@ def observe_prefill_plan(*, real_tokens: int, bucket: int,
 
 
 def observe_ragged_plan(*, real_tokens: int, bucket: int,
-                        num_prefill: int, num_decode: int) -> None:
+                        num_prefill: int, num_decode: int,
+                        live_items: int = 0) -> None:
     """Shape stats for one unified ragged dispatch
     (--attention-backend=ragged).  The padding-waste gauge reads from
     the RAGGED plan here — the bucketed gauges must not report stale
-    bucket math when the ragged path is serving."""
+    bucket math when the ragged path is serving.  ``live_items`` is the
+    real-item count of the step's work schedule (0: none was built)."""
     fill = real_tokens / bucket if bucket else 0.0
     ragged_batch_fill_ratio.set(fill)
+    if live_items:
+        ragged_live_items.observe(live_items)
     prefill_padding_waste.set(1.0 - fill)
     if bucket > real_tokens:
         padded_tokens_total.labels(phase="ragged").inc(bucket - real_tokens)

@@ -880,6 +880,7 @@ class LlamaForCausalLM:
         *,
         block_size: int,
         work: jax.Array | None = None,  # Pallas work schedule (TPU only)
+        n_items: jax.Array | None = None,  # scalar: real items of ``work``
     ) -> tuple[jax.Array, tuple[jax.Array, jax.Array]]:
         """One forward over a mixed ragged prefill+decode token stream.
 
@@ -912,13 +913,13 @@ class LlamaForCausalLM:
 
             return ragged_paged_attention(
                 kv_quant.pad_to_cache_width(q, k_cache),
-                kv_quant.layer_data(k_cache, i),
-                kv_quant.layer_data(v_cache, i), positions, seq_starts,
-                pos_base, total_tokens, block_tables, block_size, scale,
-                work=work, mesh=self.mesh,
+                *kv_quant.pool_data(k_cache, v_cache), positions,
+                seq_starts, pos_base, total_tokens, block_tables,
+                block_size, scale, layer=i,
+                work=work, n_items=n_items, mesh=self.mesh,
                 window=self._window_for_layer(i),
                 alibi_slopes=self.alibi,
-                kv_scales=kv_quant.layer_scales(k_cache, v_cache, i),
+                kv_scales=kv_quant.pool_scales(k_cache, v_cache),
             )[..., : q.shape[-1]]
 
         x = self._embed(params, token_ids, positions)
@@ -998,16 +999,15 @@ class LlamaForCausalLM:
             )
             return ragged.ragged_paged_attention(
                 kv_quant.pad_to_cache_width(q, k_cache),
-                kv_quant.layer_data(k_cache, i),
-                kv_quant.layer_data(v_cache, i),
+                *kv_quant.pool_data(k_cache, v_cache),
                 pos_base, seq_starts, pos_base,
                 jnp.asarray(b, jnp.int32),
-                block_tables, block_size, scale,
+                block_tables, block_size, scale, layer=i,
                 work=work, n_items=n_items,
                 block_q=ragged.decode_block_q(b), mesh=self.mesh,
                 window=self._window_for_layer(i),
                 alibi_slopes=self.alibi,
-                kv_scales=kv_quant.layer_scales(k_cache, v_cache, i),
+                kv_scales=kv_quant.pool_scales(k_cache, v_cache),
             )[..., : q.shape[-1]]
 
         x = (

@@ -190,6 +190,22 @@ def layer_scales(k_cache, v_cache, i):
     return None
 
 
+def pool_data(k_cache, v_cache):
+    """The whole ``[L, Hkv, slots, Dh]`` K and V pools (quantized or
+    not), for kernels that address the layer themselves."""
+    if is_quantized(k_cache):
+        return k_cache.data, v_cache.data
+    return k_cache, v_cache
+
+
+def pool_scales(k_cache, v_cache):
+    """``kv_scales`` of the whole pools: ``(k_scale, v_scale)`` (each
+    ``[L, Hkv, num_pages]`` f32) or None when they are unquantized."""
+    if is_quantized(k_cache):
+        return (k_cache.scale, v_cache.scale)
+    return None
+
+
 def dequantize(x, scale):
     """Dequantize gathered page values: ``x * scale`` in f32.
 

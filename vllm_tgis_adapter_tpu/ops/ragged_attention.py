@@ -15,7 +15,10 @@ padding; the only pad is the tail of the single flat-length bucket.
 
 Layout contract (shared with ops/attention.py):
 * KV cache per layer is head-leading ``[Hkv, num_slots, Dh]`` — a page is
-  a contiguous ``(block_size, Dh)`` Mosaic-legal tile;
+  a contiguous ``(block_size, Dh)`` Mosaic-legal tile; the serving
+  programs hand the kernel the whole ``[L, Hkv, num_slots, Dh]`` pool
+  and the layer, which the page's index map addresses: no call is fed a
+  copy of its layer;
 * the caller scatters this step's K/V into the cache BEFORE attention,
   so prefill rows see their own chunk and decode rows see their token
   through the same paged read path — that unification is what removes
@@ -37,12 +40,13 @@ SCHEDULE — one item per (query block, sequence, logical page) triple that
 actually overlaps, exactly the ragged-friendly formulation the paper's
 kernel uses instead of a dense (batch, page) grid.  The schedule rides
 scalar prefetch; pages DMA straight out of the paged cache via the
-BlockSpec index map (the gather happens in the memory system).  Mixed
-engine steps pass a host-built sparse schedule (``build_work_schedule``)
-and run the whole of it; the fused decode wave builds its own in-trace,
-once per step (``decode_work_schedule``): the live (row, page) items
-first in an array of fixed shape, with their count ``n_items`` as the
-grid's TRACED second extent, so one program visits only what is live.
+BlockSpec index map (the gather happens in the memory system).  A
+schedule is an array of fixed shape with its real items first and their
+count ``n_items`` as the grid's TRACED second extent, so one program
+visits only what some row reads.  Mixed engine steps pass a host-built
+sparse schedule (``build_work_schedule``; its width is a compile shape
+that only grows, its count is this step's own); the fused decode wave
+builds its own in-trace, once per step (``decode_work_schedule``).
 Numerics: f32 online-softmax
 accumulation, masking identical to ``paged_decode_attention_xla`` — the
 XLA path below IS that function, so parity is pinned to the same
@@ -96,7 +100,7 @@ def build_work_schedule(
     block_q: int,
     t_pad: int,
     w_bucket: int | None = None,
-) -> "np.ndarray":
+) -> tuple["np.ndarray", int]:
     """Host-side sparse schedule for a mixed ragged batch.
 
     Emits one work item per (query block, sequence, logical page) triple
@@ -108,8 +112,12 @@ def build_work_schedule(
     one item (a dead one if the block is all padding) so its output
     block is always initialised and finalised.
 
-    Returns ``[WORK_FIELDS, W]`` int32 with W padded to a power of two
-    (``w_bucket`` overrides) — the schedule width is a compile shape.
+    Returns ``(work, n_items)``: ``work`` is ``[WORK_FIELDS, W]`` int32
+    with W padded to a power of two (``w_bucket`` overrides) — the
+    schedule width is a compile shape — and ``n_items`` counts the real
+    items at its head (the dead items of empty query blocks included).
+    The last of them carries its block's ``last`` flag, so a grid that
+    ends at ``n_items`` finalises every output block.
     """
     nq = t_pad // block_q
     per_block: list[list[tuple[int, int, int, int]]] = [[] for _ in range(nq)]
@@ -143,7 +151,7 @@ def build_work_schedule(
         # pads keep the final real block's index so the output pipeline
         # never revisits an earlier block; flags all zero = no-ops
         work[0, w:] = items[-1][0]
-    return work
+    return work, w
 
 
 #: query-block height of the fused decode wave's schedule: every span
@@ -234,9 +242,10 @@ def _ragged_kernel(
     base_ref,  # [S] SMEM global position of each span's first row
     alibi_ref,  # [H] f32 SMEM slopes; unused unless use_alibi
     # blocks: q_ref [1, G*bq, Dh], k_ref/v_ref [1, block_size, Dh] (the
-    # page picked by index_map), then — quantized caches only — ks_ref/
-    # vs_ref [Hkv, SCALE_LANES] f32 (the lane tile of the scale sidecar
-    # that holds this page's dequant scale), then
+    # page of the layer's pool picked by index_map), then — quantized
+    # caches only — ks_ref/vs_ref [Hkv, SCALE_LANES] f32 (the lane tile
+    # of the layer's scale sidecar that holds this page's dequant
+    # scale), then
     # o_ref [1, G*bq, Dh] and the three f32 scratch accumulators
     # (m [G*bq, 1], l [G*bq, 1], acc [G*bq, Dh])
     q_ref,
@@ -343,10 +352,23 @@ def _ragged_kernel(
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
+def _pool_view(k_cache, v_cache, kv_scales, layer):
+    """``(k_pool, v_pool, scales, layer)`` in the one form the kernel
+    reads: the whole ``[L, Hkv, slots, Dh]`` pools (sidecars
+    ``[L, Hkv, pages]``) and the layer to address.  One layer's own
+    arrays (``layer`` None) are the pool of a single layer — a leading
+    axis of 1 is a bitcast, not a copy."""
+    if layer is not None:
+        return k_cache, v_cache, kv_scales, layer
+    if kv_scales is not None:
+        kv_scales = tuple(s[None] for s in kv_scales)
+    return k_cache[None], v_cache[None], kv_scales, 0
+
+
 def _ragged_attention_pallas(
     q: jax.Array,  # [T, H, Dh] flat mixed stream
-    k_cache: jax.Array,  # [Hkv, num_slots, Dh]
-    v_cache: jax.Array,
+    k_cache: jax.Array,  # [L, Hkv, num_slots, Dh] with ``layer``, or
+    v_cache: jax.Array,  # one layer's [Hkv, num_slots, Dh] without
     seq_starts: jax.Array,  # [S+1]
     pos_base: jax.Array,  # [S]
     work: jax.Array,  # [WORK_FIELDS, W]
@@ -357,12 +379,17 @@ def _ragged_attention_pallas(
     window: int,
     alibi_slopes: jax.Array | None = None,
     interpret: bool,
-    kv_scales: tuple | None = None,  # ([Hkv, pages] f32 x2) quantized
+    kv_scales: tuple | None = None,  # quantized only, f32 x2:
+    #     [L, Hkv, pages] with ``layer``, one layer's [Hkv, pages] without
     n_items: jax.Array | None = None,  # traced scalar: the grid visits
     #     only work[:, :n_items] (None = the whole schedule)
+    layer: int | None = None,  # static: which layer of the pool to read
 ) -> jax.Array:
     t, num_heads, head_dim = q.shape
-    num_kv = k_cache.shape[0]
+    k_cache, v_cache, kv_scales, layer = _pool_view(
+        k_cache, v_cache, kv_scales, layer
+    )
+    num_kv = k_cache.shape[1]
     g = num_heads // num_kv
     block_q = min(block_q, _pow2_ceil(t))
     nq = pl.cdiv(t, block_q)
@@ -388,23 +415,26 @@ def _ragged_attention_pallas(
             (1, g * block_q, head_dim),
             lambda h, w, wk, st, bs_, al: (h, wk[0, w], 0),
         ),
-        pl.BlockSpec(
-            (1, block_size, head_dim),
-            lambda h, w, wk, st, bs_, al: (h, wk[2, w], 0),
-        ),
-        pl.BlockSpec(
-            (1, block_size, head_dim),
-            lambda h, w, wk, st, bs_, al: (h, wk[2, w], 0),
-        ),
     ]
+    # the pool is ONE operand, never sliced: the layer is a static index
+    # in the page's index map, so the call moves only the pages its
+    # items name (a ``cache[layer]`` operand is a pool-sized copy a call)
+    page_spec = pl.BlockSpec(
+        (None, 1, block_size, head_dim),
+        lambda h, w, wk, st, bs_, al: (layer, h, wk[2, w], 0),
+    )
+    in_specs += [page_spec, page_spec]
     operands = [qh, k_cache, v_cache]
     if quantized:
-        # the [Hkv, pages] scale sidecars travel as the tile-legal
-        # (Hkv, SCALE_LANES) block holding the item's physical page;
-        # consecutive items in one lane tile re-use the resident block
+        # the layer's [Hkv, pages] scale sidecar travels as the
+        # tile-legal (Hkv, SCALE_LANES) block holding the item's
+        # physical page; consecutive items in one lane tile re-use the
+        # resident block
         scale_spec = pl.BlockSpec(
-            (num_kv, SCALE_LANES),
-            lambda h, w, wk, st, bs_, al: (0, wk[2, w] // SCALE_LANES),
+            (None, num_kv, SCALE_LANES),
+            lambda h, w, wk, st, bs_, al: (
+                layer, 0, wk[2, w] // SCALE_LANES
+            ),
         )
         in_specs += [scale_spec, scale_spec]
         operands += [
@@ -483,8 +513,8 @@ def ragged_attention_xla(
 
 def ragged_paged_attention(
     q: jax.Array,  # [T, H, Dh] flat mixed stream
-    k_cache: jax.Array,  # [Hkv, num_slots, Dh] head-leading
-    v_cache: jax.Array,
+    k_cache: jax.Array,  # the [L, Hkv, num_slots, Dh] pool with
+    v_cache: jax.Array,  # ``layer``; one layer's [Hkv, ...] without
     positions: jax.Array,  # [T]
     seq_starts: jax.Array,  # [S+1]
     pos_base: jax.Array,  # [S]
@@ -499,27 +529,37 @@ def ragged_paged_attention(
     window: int = 0,
     alibi_slopes: jax.Array | None = None,  # [H] f32 (bloom lineage)
     block_q: int = 128,
-    kv_scales: tuple | None = None,  # ([Hkv, pages] f32 x2) quantized KV
+    kv_scales: tuple | None = None,  # f32 x2 quantized-KV sidecars:
+    #     [L, Hkv, pages] with ``layer``, one layer's [Hkv, pages] without
+    layer: int | None = None,  # static: the pool's layer this call reads
 ) -> jax.Array:
     """One causal paged-attention dispatch over a mixed ragged stream.
 
     The caller must have scattered this step's K/V into the cache first.
-    TPU runs the Pallas work-schedule kernel over ``work``: the whole of
-    a host-built ``build_work_schedule`` (mixed steps), or the first
-    ``n_items`` entries of a ``decode_work_schedule`` built once per
-    decode step, with ``block_q = decode_block_q(T)``.  Elsewhere the
-    XLA reference runs and ``work`` is ignored entirely — it never
-    becomes an operand, so schedule-width shape variety cannot retrace
-    the CPU path.
+    TPU runs the Pallas work-schedule kernel over the first ``n_items``
+    entries of ``work`` (all of it when ``n_items`` is None): a
+    host-built ``build_work_schedule`` with its real-item count (mixed
+    steps), or a ``decode_work_schedule`` built once per decode step,
+    with ``block_q = decode_block_q(T)``.  The serving programs hand
+    over the WHOLE pool and the ``layer`` to read: the kernel addresses
+    the layer in its index map, so no per-layer slice of the pool is
+    ever made.  Elsewhere the XLA reference runs on the layer's slice
+    and ``work`` is ignored entirely — it never becomes an operand, so
+    schedule-width shape variety cannot retrace the CPU path.
 
     ``kv_scales`` marks the caches as quantized pages (ops/kv_quant.py):
     the Pallas kernel dequantizes each page tile in-register against its
     one per-(kv head, page) scale, the XLA path right after its gather.
 
     Under a TP mesh the kernel runs inside shard_map over the head axis,
-    cache head-sharded — same contract as the bucketed kernels.
+    the pool sharded on its kv-head axis — each device addresses the
+    layer in its own shard.
     """
     if not _use_pallas():
+        if layer is not None:
+            k_cache, v_cache = k_cache[layer], v_cache[layer]
+            if kv_scales is not None:
+                kv_scales = tuple(s[layer] for s in kv_scales)
         return ragged_attention_xla(
             q, k_cache, v_cache, positions, seq_starts, total_tokens,
             block_tables, block_size, scale,
@@ -530,6 +570,9 @@ def ragged_paged_attention(
             "the Pallas ragged kernel needs a work schedule: "
             "build_work_schedule or decode_work_schedule"
         )
+    k_cache, v_cache, kv_scales, layer = _pool_view(
+        k_cache, v_cache, kv_scales, layer
+    )
     kernel = functools.partial(
         _ragged_attention_pallas,
         block_size=block_size,
@@ -537,6 +580,7 @@ def ragged_paged_attention(
         block_q=block_q,
         window=window,
         interpret=_pallas_interpret(),
+        layer=layer,
     )
     # what only some calls carry
     extras = {
@@ -554,12 +598,12 @@ def ragged_paged_attention(
     from jax.sharding import PartitionSpec as P
 
     heads = P(None, "tp", None)
-    cache = P("tp", None, None)
-    # scale sidecars shard with the kv-head axis like the caches they
+    cache = P(None, "tp", None, None)
+    # scale sidecars shard with the kv-head axis like the pools they
     # dequantize, slopes with the heads; the grid's extent is
     # replicated, like the schedule it bounds
     extra_specs = {
-        "kv_scales": (P("tp", None), P("tp", None)),
+        "kv_scales": (P(None, "tp", None), P(None, "tp", None)),
         "alibi_slopes": P("tp"),
         "n_items": P(),
     }
