@@ -27,6 +27,18 @@ class Record:
     error: str | None = None
 
 
+def frames(records: list[Record], t0: float) -> list[dict]:
+    """The client's frames of every request, seconds from ``t0``: what a
+    run or a sweep keeps when somebody has to look at which gaps make up
+    a tail (``--keep-frames``)."""
+    return [{"due": r.due - t0, "prompt_tokens": r.prompt_tokens,
+             "output_tokens": r.output_tokens, "in_sample": r.in_sample,
+             "ok": r.ok, "sent": None if r.sent is None else r.sent - t0,
+             "done": None if r.done is None else r.done - t0,
+             "token_times": [t - t0 for t in r.token_times]}
+            for r in records]
+
+
 def percentile(values: list[float], q: float) -> float:
     """Linear interpolation between closest ranks (numpy's default)."""
     if not values:

@@ -59,7 +59,8 @@ SCRIPT_SEED = 1  # the warm-up script's prompts: the same in every run
 WARMUP_CAP_S = 600  # a warm-up still compiling after this fails the run
 #: logged with every run, judged or not (the last line holds only what
 #: ``BENCHMARK.json`` lists)
-CLIENT_SEES = ("ttft_p90_ms", "tpot_p50_ms", "gap_p99_ms", "out_tok_s")
+CLIENT_SEES = ("ttft_p90_ms", "tpot_p50_ms", "gap_p99_ms", "out_tok_s",
+               "gen_late_p99_ms")
 
 
 def say(**fields) -> None:
@@ -299,7 +300,7 @@ async def observe(session: Session, start: float, end: float,
     if trace:
         async def profile() -> None:
             await at(start + PROFILE_AFTER_S)
-            begun = time.monotonic()
+            begun, began_wall = time.monotonic(), time.time()
             child = await asyncio.create_subprocess_exec(
                 sys.executable, "-m", "jax.collect_profile",
                 str(server.profiler_port), str(int(trace_s * 1000)),
@@ -314,6 +315,13 @@ async def observe(session: Session, start: float, end: float,
                     f"jax.collect_profile failed:\n{text.decode()[-1500:]}")
             out["trace_span"] = (begun, begun + trace_s)
             out["profile_collect_s"] = time.monotonic() - begun
+            # the profiler client gives request + capture + hand-over 60 s:
+            # how far from it this capture stayed (PERF.md section 4)
+            for file in session.profile_dir.rglob("*.xplane.pb"):
+                stat = file.stat()
+                out["xplane"] = {
+                    "bytes": stat.st_size,
+                    "written_after_s": stat.st_mtime - began_wall}
 
         profiling = asyncio.create_task(profile())
         tick = start + 1.0
@@ -416,6 +424,8 @@ def main() -> None:
     parser.add_argument("--log-dir", type=Path, default=WORK / "logs")
     parser.add_argument("--keep-trace", type=Path, default=None,
                         help="copy the raw trace here before deleting it")
+    parser.add_argument("--keep-frames", type=Path, default=None,
+                        help="write the client's frames to this file")
     args = parser.parse_args()
     trace = bool(args.trace)
 
@@ -431,6 +441,10 @@ def main() -> None:
         server.kill()
 
     records, seen = got["records"], got["seen"]
+    if args.keep_frames:
+        args.keep_frames.parent.mkdir(parents=True, exist_ok=True)
+        args.keep_frames.write_text(
+            json.dumps(e2e.frames(records, got["start"])))
     sample = [r for r in records if r.in_sample]
     malformed = [r.error for r in sample
                  if r.error and r.error.startswith("ValueError")]
@@ -476,6 +490,7 @@ def main() -> None:
         say(phase="trace", lines=summary["lines"],
             planes=summary["all_planes"],
             profile_collect_s=seen.get("profile_collect_s"),
+            xplane=seen.get("xplane"),
             also={n: e2e.compute(n, records, args.seconds)
                   for n in CLIENT_SEES})
     say(phase="detail", reference=got["reference"], warmup=got["warmup"],

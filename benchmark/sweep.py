@@ -4,6 +4,9 @@ rates, ``--seconds`` each.  The knee is the highest swept rate at which
 the number of requests in flight does not grow across the window (mean of
 the last third <= 1.2 x mean of the first third) and nothing failed.  Run
 by hand on the chip; the cell file then gets 0.8 x the knee as a number.
+A row prints ``offered_rps`` beside ``rate_rps``: the requests that were
+due in its window over its length, which a drawn process misses by the
+draw (both seeds of PR 33's sweep ran 7-22 % over).
 
     python3 benchmark/sweep.py --workload <cell> --seed <n> --seconds <s> \\
         --rates 1.5,2,2.5,3,3.5,4
@@ -33,7 +36,8 @@ def in_flight(records, lo: float, hi: float, step: float = 0.25) -> float:
     return sum(counts) / max(1, len(counts))
 
 
-async def sweep(session, rates: list[float], seconds: float) -> list[dict]:
+async def sweep(session, rates: list[float], seconds: float,
+                keep_frames: Path | None = None) -> list[dict]:
     session.client = harness.loadgen.Client(session.server.grpc_port)
     rows = []
     for i, rate in enumerate(rates):
@@ -47,17 +51,22 @@ async def sweep(session, rates: list[float], seconds: float) -> list[dict]:
         last = in_flight(records, t0 + 2 * third, t0 + seconds)
         sample = [r for r in records if r.in_sample]
         row = {
-            "rate_rps": rate, "sample": len(sample),
+            "rate_rps": rate, "offered_rps": len(sample) / seconds,
+            "sample": len(sample),
             "failed": sum(1 for r in sample if not r.ok),
             "in_flight_first_third": first, "in_flight_last_third": last,
             "compiles": await asyncio.to_thread(session.server.compiles) - c0,
             "warmup": got["warmup"],
             **{n: e2e.compute(n, records, seconds)
                for n in ("ttft_p90_ms", "tpot_p50_ms", "gap_p99_ms",
-                         "out_tok_s")},
+                         "out_tok_s", "gen_late_p99_ms")},
         }
         row["sustained"] = row["failed"] == 0 and last <= 1.2 * max(first, 1.0)
         harness.say(phase="sweep", **row)
+        if keep_frames:
+            keep_frames.mkdir(parents=True, exist_ok=True)
+            (keep_frames / f"frames-{i:02d}-{rate}.json").write_text(
+                json.dumps(e2e.frames(records, t0)))
         rows.append(row)
         if not row["sustained"]:
             break
@@ -71,6 +80,8 @@ def main() -> None:
     parser.add_argument("--seed", type=int, default=1)
     parser.add_argument("--seconds", type=float, required=True)
     parser.add_argument("--rates", required=True)
+    parser.add_argument("--keep-frames", type=Path, default=None,
+                        help="write every row's client frames here")
     parser.add_argument("--rehearse-on-cpu", action="store_true")
     parser.add_argument("--log-dir", type=Path,
                         default=harness.WORK / "logs")
@@ -82,7 +93,8 @@ def main() -> None:
     try:
         session.prebuild(args.seconds, rates[0])
         session.ready()
-        rows = asyncio.run(sweep(session, rates, args.seconds))
+        rows = asyncio.run(sweep(session, rates, args.seconds,
+                                 args.keep_frames))
         session.server.drain()
     finally:
         session.server.kill()

@@ -8,6 +8,13 @@ MIXES = ["short-answer", "doc-extract", "rehearsal-open", "rehearsal-closed"]
 BIG_SEED = 2**31 + 11  # the driver's seeds do not fit 32 signed bits
 
 
+def drawn_short_answer() -> dict:
+    """``short-answer`` as a process, a draw a seed: the file without its
+    ``replay`` key (what the cell itself sends: ``test_replay.py``)."""
+    mix = traffic.load_traffic("short-answer")
+    return {k: v for k, v in mix.items() if k != "replay"}
+
+
 @pytest.mark.parametrize("name", MIXES)
 def test_lengths_stay_inside_their_clips(name):
     mix = traffic.load_traffic(name)
@@ -17,7 +24,7 @@ def test_lengths_stay_inside_their_clips(name):
 
 
 def test_same_seed_same_schedule_and_another_seed_another_draw():
-    mix = traffic.load_traffic("short-answer")
+    mix = drawn_short_answer()
     a = traffic.open_schedule(mix, 1.6, 120, seed=BIG_SEED)
     b = traffic.open_schedule(mix, 1.6, 120, seed=BIG_SEED)
     c = traffic.open_schedule(mix, 1.6, 120, seed=11)
@@ -30,7 +37,7 @@ def test_same_seed_same_schedule_and_another_seed_another_draw():
 
 def test_arrivals_are_poisson_at_the_rate():
     """Counts in 45 s windows: mean = variance = rate x 45."""
-    mix = traffic.load_traffic("short-answer")
+    mix = drawn_short_answer()
     plan = traffic.open_schedule(mix, 1.6, 45 * 400, seed=5)
     counts = [0] * 400
     for p in plan:
@@ -42,7 +49,7 @@ def test_arrivals_are_poisson_at_the_rate():
 
 
 def test_a_change_of_rate_keeps_the_sizes():
-    mix = traffic.load_traffic("short-answer")
+    mix = drawn_short_answer()
     slow = traffic.open_schedule(mix, 1.0, 60, seed=9)
     fast = traffic.open_schedule(mix, 2.0, 60, seed=9)
     n = min(len(slow), len(fast))
@@ -51,7 +58,7 @@ def test_a_change_of_rate_keeps_the_sizes():
 
 
 def test_median_lengths_are_what_the_file_says_and_the_clips_are_reached():
-    mix = traffic.load_traffic("short-answer")
+    mix = drawn_short_answer()
     got = traffic.sizes(mix, 4000, seed=0)
     prompts = sorted(p for p, _ in got)
     outs = sorted(o for _, o in got)
@@ -62,6 +69,6 @@ def test_median_lengths_are_what_the_file_says_and_the_clips_are_reached():
 
 
 def test_an_unknown_kind_is_a_missing_file():
-    mix = dict(traffic.load_traffic("short-answer"), arrivals="no-such")
+    mix = dict(drawn_short_answer(), arrivals="no-such")
     with pytest.raises(ImportError):
         traffic.open_schedule(mix, 1.0, 10, seed=1)
