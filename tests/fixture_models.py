@@ -118,9 +118,7 @@ def write_llama_safetensors(path: str, *, vocab_size: int,
                             seed: int = 0) -> None:
     """HF-format llama ``model.safetensors`` with seed-deterministic
     random weights, shaped by the given arch — the single source of the
-    tensor-name layout the loader expects (engine/weights.py).  The
-    tiny test fixture and bench.py's dp-fleet model both write through
-    here so the layout cannot drift between them."""
+    tensor-name layout the loader expects (engine/weights.py)."""
     import numpy as np
     from safetensors.numpy import save_file
 

@@ -370,10 +370,9 @@ def test_parked_head_does_not_block_resident_work(tiny_model_dir, lora_dirs):
 
 
 def test_many_adapters_resident_churn(tiny_model_dir, tmp_path):
-    """Scaled-down CPU demo of the acceptance shape (the full 128-
-    adapter run is the perf_check lora gate): 32 registered host-side,
-    8-slot pool, traffic over 16 adapters → every slot in use, nonzero
-    churn, every request completes."""
+    """Scaled-down CPU demo of the acceptance shape: 32 registered
+    host-side, 8-slot pool, traffic over 16 adapters → every slot in
+    use, nonzero churn, every request completes."""
     from tests.fixture_models import build_tiny_lora_adapter
 
     from vllm_tgis_adapter_tpu.engine.core import LLMEngine

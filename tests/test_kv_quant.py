@@ -257,7 +257,7 @@ def test_tier_entry_carries_and_validates_scale_sidecar():
 
 
 def _build_engine(model_dir, kvq, *, num_blocks=64, tier_gb=0.0,
-                  prefix=False):
+                  prefix=False, lora=False):
     from vllm_tgis_adapter_tpu.engine.config import (
         CacheConfig,
         EngineConfig,
@@ -279,12 +279,15 @@ def _build_engine(model_dir, kvq, *, num_blocks=64, tier_gb=0.0,
             max_num_seqs=4, prefill_buckets=(32, 64)
         ),
         parallel_config=ParallelConfig(),
-        lora_config=LoRAConfig(),
+        lora_config=(
+            LoRAConfig(enabled=True, max_loras=2, max_lora_rank=8)
+            if lora else LoRAConfig()
+        ),
         kv_host_cache_gb=tier_gb,
     ))
 
 
-def _run(eng, rid, ids, n=10, logprobs=None):
+def _run(eng, rid, ids, n=10, logprobs=None, lora_name=None):
     from vllm_tgis_adapter_tpu.engine.sampling_params import (
         SamplingParams,
     )
@@ -294,6 +297,7 @@ def _run(eng, rid, ids, n=10, logprobs=None):
         SamplingParams(temperature=0.0, max_tokens=n, ignore_eos=True,
                        logprobs=logprobs),
         prompt_token_ids=ids,
+        lora_name=lora_name,
     )
     for _ in range(600):
         if not eng.has_unfinished_requests():
@@ -304,18 +308,30 @@ def _run(eng, rid, ids, n=10, logprobs=None):
     raise AssertionError(f"request {rid} did not finish")
 
 
-@pytest.mark.parametrize("scheme", ["int8", "fp8"])
-def test_quantized_engine_token_quality(tiny_model_dir, scheme):
+@pytest.mark.parametrize(
+    ("scheme", "lora"), [("int8", None), ("fp8", None), ("int8", "tiny-lora")]
+)
+def test_quantized_engine_token_quality(
+    tiny_model_dir, adapter_cache_dir, scheme, lora
+):
     """Greedy decode under quantized KV must track the unquantized
     baseline: bounded chosen-token logprob deltas over the matched
-    prefix (the scenario suites' gate, in miniature)."""
+    prefix — for base-model rows and for rows that ride an adapter
+    (whose K/V the quantized pages hold all the same)."""
+    import asyncio
+
     prompt = list(range(3, 40))
-    base = _run(
-        _build_engine(tiny_model_dir, "none"), "r", prompt, 12, 1
-    )
-    got = _run(
-        _build_engine(tiny_model_dir, scheme), "r", prompt, 12, 1
-    )
+
+    def generate(kvq):
+        eng = _build_engine(tiny_model_dir, kvq, lora=lora is not None)
+        if lora is not None:
+            asyncio.run(eng.lora_manager.load_lora_adapter(
+                lora, f"{adapter_cache_dir}/{lora}"
+            ))
+        return _run(eng, "r", prompt, 12, 1, lora_name=lora)
+
+    base = generate("none")
+    got = generate(scheme)
     matched = 0
     deltas = []
     for tb, tq, db, dq in zip(

@@ -116,6 +116,25 @@ def test_tp_shrinks_per_device_page_cost():
     assert resolve_num_blocks(cfg4, dev) == 4 * resolve_num_blocks(cfg1, dev)
 
 
+@pytest.mark.parametrize("scheme", ["int8", "fp8"])
+def test_quantized_pages_nearly_double_the_pool(scheme):
+    # a quantized page stores one byte an element plus an f32 scale per
+    # page per head where bf16 stores two bytes: the same per-device
+    # budget holds at least 1.9x the pages, and never quite 2x
+    cfg = make_config(util=1.0)
+    qcfg = dataclasses.replace(
+        cfg,
+        cache_config=dataclasses.replace(
+            cfg.cache_config, kv_quantization=scheme
+        ),
+    )
+    dev = FakeDevice(
+        {"bytes_limit": 1000 * block_bytes(cfg), "bytes_in_use": 0}
+    )
+    assert resolve_num_blocks(cfg, dev) == 1000
+    assert 1900 <= resolve_num_blocks(qcfg, dev) < 2000
+
+
 def test_too_small_budget_raises():
     cfg = make_config(max_model_len=2048, block_size=16, util=1.0)
     bb = block_bytes(cfg)

@@ -497,9 +497,12 @@ def test_remote_handoff_and_prefix_hit_token_identical(netpair):
             await asyncio.sleep(0.05)
         assert a.kvnet.peers[0].mirror, "INDEX sync never surfaced B's pages"
         hits_before = metrics.kvnet_remote_hits_total._value.get()  # noqa: SLF001
+        remote = metrics.kvnet_handoffs_total.labels(outcome="remote")
+        remote_before = remote._value.get()  # noqa: SLF001
         handed = await _stream(a, "hand-1", PROMPT)
         assert handed == base
         assert metrics.kvnet_remote_hits_total._value.get() > hits_before  # noqa: SLF001
+        assert remote._value.get() == remote_before + 1  # noqa: SLF001
         # the handoff ran to completion and retired its source-side state
         assert not a.kvnet.remote_out
         assert b.kvnet.staged.pending() == 0

@@ -155,6 +155,23 @@ def test_int8_leaves_are_int8(engines):
         assert layer[f"{key}_scale"].dtype == jnp.float32
 
 
+def test_int8_resident_weight_bytes(engines):
+    """What --quantization int8 is for: the resident parameters of the
+    same checkpoint take at most 0.75x the bytes (the matmul weights
+    shrink to a byte an element plus a scale per out-channel; the
+    embeddings and norms stay), which the KV pool then gets."""
+    import jax
+
+    def resident_bytes(engine):
+        return sum(
+            int(x.nbytes)
+            for x in jax.tree_util.tree_leaves(engine.runner.params)
+        )
+
+    full_engine, int8_engine = engines
+    assert resident_bytes(int8_engine) <= 0.75 * resident_bytes(full_engine)
+
+
 def test_unsupported_schemes_rejected_at_config_time(tiny_model_dir):
     from vllm_tgis_adapter_tpu.engine.config import (
         CacheConfig,

@@ -837,9 +837,8 @@ def test_ragged_prompt_logprobs_legacy_fallback(tiny_model_dir):
 
 def test_ragged_compile_lattice_is_smaller(tiny_model_dir):
     """precompile() compiles strictly fewer programs than the retired
-    PR 6 bucketed ladder at the same serving config (the bench JSON
-    carries the same evidence via compiled_shapes / xla_compiles;
-    docs/ATTENTION.md documents the expected counts)."""
+    PR 6 bucketed ladder at the same serving config
+    (docs/ATTENTION.md documents the expected counts)."""
     from vllm_tgis_adapter_tpu import compile_tracker
 
     engine = _make_engine(

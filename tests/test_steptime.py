@@ -144,7 +144,7 @@ def test_host_gap_sync_measures_lead_in_from_wait_entry():
 
 
 def test_host_gap_blocking_dispatch_uses_dispatch_window():
-    """CPU proxy with async dispatch disabled (BENCH_SYNC_DISPATCH=1):
+    """CPU backend with async dispatch disabled:
     the device work runs INSIDE dispatch, so the gap must be measured
     t_disp0 - previous t_disp1 — against the wait stamps it would
     degenerate to ~0 and hide the serialization."""

@@ -9,11 +9,8 @@ prefill+decode fleet and always arm the kill-prefill-replica-
 mid-handoff fault — docs/SCALING.md "Disaggregated roles"; a fixed
 rotation of seeds serves with --kv-quantization int8/fp8, proving
 checkpoint/resume and cross-replica migration token-stable under
-QUANTIZED KV pages — docs/QUANTIZATION.md).  The closed-loop engine
-this harness drives (fixtures, engine build, request driving, seeded
-workloads) lives in tools/scenarios.py — the steady-state suites and
-this soak share one workload engine.  Asserted here are the global
-invariants no single scenario can
+QUANTIZED KV pages — docs/QUANTIZATION.md).  Asserted here are the
+global invariants no single scenario can
 (docs/RECOVERY.md "Randomized chaos soak"):
 
 * every submitted request reaches EXACTLY ONE terminal outcome — a
@@ -29,22 +26,17 @@ invariants no single scenario can
   block shape each — compile-tracker gated).
 
 Each seed is one reproducible schedule: ``python tools/chaos_soak.py
---seed 7`` replays exactly what CI saw.  ``--recovery-bench`` runs the
-perf gate instead (tools/perf_check.py ``recovery`` section): one long
-request killed mid-decode must complete, resumed, within
-``max_ratio`` x its uncrashed wall time — with the JAX persistent
-compilation cache on, so the rebuilt engine's recompiles cost what a
-TPU restart with a warm XLA cache pays, not a cold build.
+--seed 7`` replays exactly what CI saw.
 """
 
 from __future__ import annotations
 
 import argparse
 import asyncio
-import json
 import os
 import random
 import sys
+import tempfile
 import time
 from pathlib import Path
 
@@ -57,13 +49,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # fails AT the corrupting step, not as a downstream token divergence
 os.environ.setdefault("TGIS_TPU_SANITIZE", "1")
 
-from tools.scenarios import (  # noqa: E402 — after sys.path insert
-    build_engine,
-    build_fixtures,
-    make_mixed_workload,
-    run_request,
-)
-
 DEFAULT_SEEDS = 5
 DEFAULT_BASE_SEED = 20260804
 #: nothing — request, recovery, or drain — may outlive this (the soak's
@@ -73,6 +58,9 @@ HARNESS_BOUND_S = 60.0
 BUDGET_S = 120.0
 
 REQUESTS_PER_SEED = 8
+
+#: the shared "system prompt" RAG requests reuse (tiers + prefix paths)
+RAG_PREFIX = list(range(400, 424))
 
 #: deterministic --kv-quantization rotation per seed (seed % 3): does
 #: not perturb the rng draw sequence of pre-existing schedules, and
@@ -108,24 +96,150 @@ FAULTS = (
 )
 
 
-# fixture build, engine construction, seeded workloads and request
-# driving were PROMOTED into tools/scenarios.py (the steady-state suite
-# engine); the soak keeps only the chaos schedule and its invariants
-_build_fixtures = build_fixtures
-_run_request = run_request
-
-
-def _build_engine(model_dir: str, *, dp: int, watchdog: bool,
-                  roles: tuple = (), spec: bool = False,
-                  kv_quantization: str = "none"):
-    return build_engine(
-        model_dir, dp=dp, watchdog=watchdog, roles=roles, spec=spec,
-        kv_quantization=kv_quantization,
+def _build_fixtures() -> tuple[str, str]:
+    """Tiny llama + one live LoRA adapter, built once per process."""
+    from tests.fixture_models import (
+        build_tiny_llama,
+        build_tiny_lora_adapter,
     )
+
+    model_dir = tempfile.mkdtemp(prefix="soak-model-")
+    build_tiny_llama(model_dir)
+    adapter_dir = build_tiny_lora_adapter(
+        os.path.join(model_dir, "ad-soak"), seed=11, rank=2
+    )
+    return model_dir, adapter_dir
+
+
+def _build_engine(
+    model_dir: str,
+    *,
+    dp: int = 1,
+    watchdog: bool = False,
+    roles: tuple = (),
+    spec: bool = False,
+    kv_quantization: str = "none",
+    enable_prefix_caching: bool = True,
+    kvnet_listen: str | None = None,
+    kvnet_peers: tuple = (),
+    kvnet_node_id: str | None = None,
+    kvnet_timeout_s: float = 5.0,
+):
+    """One production-shaped in-process engine: supervised, front door
+    on, host KV tier on, LoRA pool of two."""
+    from vllm_tgis_adapter_tpu.engine.async_llm import AsyncLLMEngine
+    from vllm_tgis_adapter_tpu.engine.config import (
+        CacheConfig,
+        EngineConfig,
+        FrontdoorConfig,
+        LoRAConfig,
+        ModelConfig,
+        ParallelConfig,
+        SchedulerConfig,
+        SpeculativeConfig,
+    )
+
+    mcfg = ModelConfig.from_pretrained(model_dir, dtype="float32")
+    config = EngineConfig(
+        model_config=mcfg,
+        cache_config=CacheConfig(
+            block_size=16,
+            num_blocks=96,
+            cache_dtype=mcfg.dtype,
+            enable_prefix_caching=enable_prefix_caching,
+            kv_quantization=kv_quantization,
+        ),
+        scheduler_config=SchedulerConfig(
+            max_num_seqs=4, prefill_buckets=(32, 64)
+        ),
+        parallel_config=ParallelConfig(dp_replicas=dp),
+        lora_config=LoRAConfig(enabled=True, max_loras=2,
+                               max_lora_rank=2),
+        dp_replica_roles=tuple(roles),
+        kv_host_cache_gb=1.0,
+        kvnet_listen=kvnet_listen,
+        kvnet_peers=tuple(kvnet_peers),
+        kvnet_node_id=kvnet_node_id,
+        kvnet_timeout_s=kvnet_timeout_s,
+        max_engine_restarts=20,
+        engine_restart_window_s=300.0,
+        engine_restart_backoff_s=0.01,
+        watchdog_deadline_s=1.0 if watchdog else 0.0,
+        watchdog_action="restart",
+        frontdoor=FrontdoorConfig(enabled=True),
+        speculative=(
+            SpeculativeConfig(
+                draft_model=model_dir,
+                num_speculative_tokens=3,
+                draft_model_config=mcfg,
+            )
+            if spec
+            else None
+        ),
+    )
+    return AsyncLLMEngine.from_config(config)
 
 
 def _make_workload(rng: random.Random) -> list[dict]:
-    return make_mixed_workload(rng, REQUESTS_PER_SEED)
+    """The seeded mixed workload: chat (unique prompts), RAG (shared
+    prefix + unique tail), LoRA-tagged — greedy and seeded-sampled
+    mixed in."""
+    specs = []
+    for i in range(REQUESTS_PER_SEED):
+        kind = ("chat", "rag", "lora")[i % 3]
+        if kind == "rag":
+            prompt = RAG_PREFIX + [
+                rng.randrange(3, 300)
+                for _ in range(rng.randint(4, 12))
+            ]
+        else:
+            prompt = [
+                rng.randrange(3, 300)
+                for _ in range(rng.randint(6, 20))
+            ]
+        sampled = rng.random() < 0.34
+        specs.append({
+            "kind": kind,
+            "prompt": prompt,
+            "max_tokens": rng.randint(8, 24),
+            "temperature": 0.9 if sampled else 0.0,
+            "seed": rng.randrange(1, 2**31) if sampled else None,
+        })
+    return specs
+
+
+def _params(spec: dict):
+    from vllm_tgis_adapter_tpu.engine.sampling_params import (
+        RequestOutputKind,
+        SamplingParams,
+    )
+
+    return SamplingParams(
+        temperature=spec["temperature"],
+        seed=spec["seed"],
+        max_tokens=spec["max_tokens"],
+        ignore_eos=True,
+        output_kind=RequestOutputKind.DELTA,
+    )
+
+
+async def _run_request(engine, rid: str, spec: dict, lora_req):
+    """One DELTA stream to its terminal outcome.  Returns
+    ``("ok", [every streamed token, in order])`` or ``("err", exc)`` —
+    exactly one of the two, exactly once."""
+    toks: list[int] = []
+    try:
+        async for out in engine.generate(
+            prompt=None,
+            sampling_params=_params(spec),
+            request_id=rid,
+            prompt_token_ids=list(spec["prompt"]),
+            lora_request=lora_req if spec["kind"] == "lora" else None,
+        ):
+            toks.extend(out.outputs[0].token_ids)
+        return ("ok", toks)
+    except BaseException as e:  # noqa: BLE001 — the outcome IS the result
+        return ("err", e)
 
 
 async def _wait_serving(engine, what: str, bound: float) -> None:
@@ -397,12 +511,12 @@ async def _cross_host_soak(model_dir: str) -> dict:
 
     prompt = [5 + (i % 40) for i in range(48)]  # 3 full pages at bs=16
     spec = {"kind": "chat", "prompt": prompt, "temperature": 0.0,
-            "seed": None, "max_tokens": 12, "logprobs": None}
+            "seed": None, "max_tokens": 12}
     long_spec = {**spec, "max_tokens": 48}
 
     def _fleet_engine(**kw):  # noqa: ANN003, ANN202
-        return build_engine(
-            model_dir, kv_host_cache_gb=1.0,
+        return _build_engine(
+            model_dir,
             # prefix registration demotes prompt pages at prefill
             # commit, making them INDEX-visible without LRU pressure
             enable_prefix_caching=False,
@@ -514,9 +628,7 @@ async def _cross_host_soak(model_dir: str) -> dict:
         async def _consume() -> None:
             # stream INCREMENTALLY (a real client banks every DELTA as
             # it arrives): tokens A emitted before dying must count —
-            # run_request's end-of-stream return would discard them
-            from tools.scenarios import _params
-
+            # _run_request's end-of-stream return would discard them
             try:
                 async for out in a.generate(
                     prompt=None,
@@ -580,104 +692,9 @@ async def _cross_host_soak(model_dir: str) -> dict:
                 pass
 
 
-async def _recovery_bench(model_dir: str) -> dict:
-    """perf_check ``recovery`` gate: one long greedy request killed
-    mid-decode must complete RESUMED within ``max_ratio`` x its
-    uncrashed wall time.
-
-    Measurement discipline (CPU-proxy fidelity): on the tiny fixture,
-    decode is ~0.3 ms/token while re-TRACING the fused decode programs
-    on ANY fresh engine costs seconds — a warm-baseline ratio would
-    measure JAX tracing, not recovery.  Both sides therefore run with
-    COLD per-engine programs over one shared persistent XLA cache:
-    the baseline is the request's wall time on a freshly built engine,
-    the resumed side is the same request crashed mid-decode (its
-    rebuilt engine is equally cold).  The ratio then isolates exactly
-    what checkpoint/resume adds: the quiesce gathers, the rebuild, the
-    tier promotion, and the tail recompute."""
-    from vllm_tgis_adapter_tpu.supervisor import failpoints
-
-    spec = {
-        "kind": "chat",
-        "prompt": list(range(3, 21)),
-        "max_tokens": 384,
-        "temperature": 0.0,
-        "seed": None,
-    }
-
-    # populate the shared persistent XLA cache (and the decode-tail
-    # step variants a resume can land on) so neither measured side
-    # pays a first-ever backend compile
-    warm = _build_engine(model_dir, dp=1, watchdog=False)
-    try:
-        for k in range(8):
-            status, _ = await _run_request(
-                warm, f"tailwarm-{k}", {**spec, "max_tokens": 9 + k},
-                None,
-            )
-            assert status == "ok"
-        status, base_toks = await _run_request(warm, "full", spec, None)
-        assert status == "ok"
-    finally:
-        await warm.stop()
-
-    # baseline: cold-program engine, uncrashed
-    base = _build_engine(model_dir, dp=1, watchdog=False)
-    try:
-        t0 = time.perf_counter()
-        status, got = await asyncio.wait_for(
-            _run_request(base, "base", spec, None), HARNESS_BOUND_S
-        )
-        base_s = time.perf_counter() - t0
-        assert status == "ok" and got == base_toks
-    finally:
-        await base.stop()
-
-    # resumed: cold-program engine, killed mid-decode; the rebuilt
-    # engine is cold the same way the baseline engine was
-    engine = _build_engine(model_dir, dp=1, watchdog=False)
-    try:
-        t0 = time.perf_counter()
-        task = asyncio.create_task(
-            _run_request(engine, "crashed", spec, None)
-        )
-        deadline = time.monotonic() + HARNESS_BOUND_S
-        while time.monotonic() < deadline:
-            seq = engine.engine._seqs.get("crashed")  # noqa: SLF001
-            # >= 1 COMMITTED (already-streamed) token = mid-decode; the
-            # soak kills at arbitrary depths — here the kill lands at
-            # the first token so the ratio measures recovery, not how
-            # many decode programs happened to trace twice
-            if seq is not None and seq.num_output_tokens >= 1:
-                break
-            await asyncio.sleep(0.005)
-        failpoints.arm_site("core.plan_step", "raise", 1)
-        status, resumed_toks = await asyncio.wait_for(
-            task, HARNESS_BOUND_S
-        )
-        resumed_s = time.perf_counter() - t0
-        assert status == "ok", f"resumed request failed: {resumed_toks!r}"
-        history = engine.supervisor.restart_history
-        return {
-            "kind": "recovery",
-            "base_s": round(base_s, 3),
-            "resumed_s": round(resumed_s, 3),
-            "ratio": round(resumed_s / max(base_s, 1e-9), 3),
-            "token_identical": resumed_toks == base_toks,
-            "resumed": sum(h.get("resumed", 0) for h in history),
-        }
-    finally:
-        failpoints.disarm()
-        try:
-            await engine.stop()
-        except Exception:  # noqa: BLE001
-            pass
-
-
 def _enable_persistent_compile_cache() -> None:
-    """Warm-XLA-cache fidelity for the recovery bench: a rebuilt
-    engine's recompiles should cost what a TPU restart with the
-    persistent compilation cache pays, not a cold build."""
+    """A rebuilt engine's recompiles should cost what a TPU restart
+    with the persistent compilation cache pays, not a cold build."""
     import jax
 
     from vllm_tgis_adapter_tpu.compile_cache import configure_compile_cache
@@ -695,9 +712,6 @@ def main(argv: list[str] | None = None) -> int:
                         default=DEFAULT_BASE_SEED)
     parser.add_argument("--seed", type=int, default=None,
                         help="run exactly one seed (reproduce a CI run)")
-    parser.add_argument("--recovery-bench", action="store_true",
-                        help="run the perf_check recovery measurement "
-                             "and print one JSON line")
     parser.add_argument("--cross-host", action="store_true",
                         help="run the networked-KV-tier fault family "
                              "(corrupt/slow-peer/partition + "
@@ -707,11 +721,6 @@ def main(argv: list[str] | None = None) -> int:
 
     _enable_persistent_compile_cache()
     model_dir, adapter_dir = _build_fixtures()
-
-    if args.recovery_bench:
-        line = asyncio.run(_recovery_bench(model_dir))
-        print(json.dumps(line))
-        return 0
 
     if args.cross_host:
         try:

@@ -109,8 +109,6 @@ REQUIRED_TELEMETRY_METRICS = (
     "tgis_tpu_slo_attainment",
     "tgis_tpu_slo_burn_rate",
     "tgis_tpu_spec_acceptance_rate_ewma",
-    "tgis_tpu_model_tflops_per_s",
-    "tgis_tpu_mfu",
 )
 
 # step anatomy + bottleneck doctor (docs/OBSERVABILITY.md "Step

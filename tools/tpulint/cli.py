@@ -67,8 +67,8 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument(
         "--write-lattice", action="store_true",
         help="regenerate tools/tpulint/lattice_manifest.json from the "
-             "given paths (after an INTENTIONAL jit-entry change; "
-             "mirrors perf_check's --write convention) and exit 0",
+             "given paths (after an INTENTIONAL jit-entry change) "
+             "and exit 0",
     )
     parser.add_argument(
         "--format", choices=("text", "json"), default="text",

@@ -2,7 +2,7 @@
 
 The cache directory is part of the cache key, so a path that moves (a
 temporary name, a pid, the time) never hits.  Every process of this repo
-that compiles — the server, bench.py, the tools — calls
+that compiles — the server, the tools — calls
 :func:`configure_compile_cache` before its first compile and sets no
 cache path anywhere else.
 """

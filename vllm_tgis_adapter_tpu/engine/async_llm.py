@@ -54,7 +54,6 @@ from vllm_tgis_adapter_tpu.telemetry import (
     CostLedger,
     JsonlSink,
     SloEngine,
-    TokenRateEwma,
 )
 from vllm_tgis_adapter_tpu.telemetry.doctor import Doctor, ReplicaSignals
 from vllm_tgis_adapter_tpu.telemetry.slo import (
@@ -139,9 +138,9 @@ class AsyncLLMEngine:
         # affinity-aware placement over the replica fleet
         # (frontdoor/placement.py): prefix-cache residency > tenant/
         # adapter stickiness > least-loaded.  Built even at dp=1 (it
-        # also carries per-replica committed-token attribution for the
-        # bench), but generate() short-circuits single-replica routing
-        # so dp=1 placement costs nothing and scores nothing.
+        # also carries per-replica committed-token attribution), but
+        # generate() short-circuits single-replica routing so dp=1
+        # placement costs nothing and scores nothing.
         from vllm_tgis_adapter_tpu.frontdoor.placement import (
             PlacementRouter,
         )
@@ -149,7 +148,7 @@ class AsyncLLMEngine:
         self.router = PlacementRouter()
         # prefill/decode disaggregation (docs/SCALING.md): flipped by
         # apply_replica_roles when any replica serves a dedicated role;
-        # lifetime handoff outcomes feed /debug/state and the bench
+        # lifetime handoff outcomes feed /debug/state
         self._roles_active = False
         self.handoff_outcomes = {"completed": 0, "fallback": 0}
         self._owner: dict[str, _Replica] = {}
@@ -206,21 +205,10 @@ class AsyncLLMEngine:
             record=self._doctor_record,
             profiler=self._doctor_profiler,
         )
-        # --capture-trace: admitted-traffic shape (token counts and
-        # arrival offsets, never content) for tools/trace_replay.py;
-        # offsets are relative to boot
-        self._capture_sink = (
-            JsonlSink(cfg.capture_trace) if cfg.capture_trace else None
-        )
-        self._capture_t0 = time.time()
         # request_id -> server Span while the stream is live: resume
         # and handoff spans link back to it (tracing.py resume_span)
         self._spans: dict[str, object] = {}
-        # per-replica committed-token rate EWMAs (the live MFU gauges)
-        # and the clock of each replica's last KV page-seconds sample
-        self._token_rate = {
-            rep.index: TokenRateEwma() for rep in self._replicas
-        }
+        # the clock of each replica's last KV page-seconds sample
         self._kv_sample_t: dict[int, float] = {}
         for rep in self._replicas:
             # engine cores feed SLO latency observations and ledger
@@ -586,7 +574,7 @@ class AsyncLLMEngine:
                 )
             # --dp-replicas shared-device mode: every replica runs on
             # the same device slice with its own KV pool.  Correct, and
-            # what the CPU-proxy bench/chaos tests use; on a real
+            # what the CPU and chaos tests use; on a real
             # accelerator N pools on one HBM is almost never what you
             # want — say so loudly.
             shared_devices = True
@@ -722,11 +710,11 @@ class AsyncLLMEngine:
             # the host pages (restart-survival is the SUPERVISOR's path,
             # which never calls stop())
             tier.close()
-        for sink in (self._ledger_sink, self._capture_sink):
-            # final drain: records closed since the last stats tick
-            # must reach the JSONL files before the process exits
-            if sink is not None and sink.pending:
-                await asyncio.to_thread(sink.flush_sync)
+        # final drain: records closed since the last stats tick must
+        # reach the JSONL file before the process exits
+        sink = self._ledger_sink
+        if sink is not None and sink.pending:
+            await asyncio.to_thread(sink.flush_sync)
         if self._tracer is not None:
             # flush buffered spans before the exporter thread dies with
             # the process
@@ -866,21 +854,6 @@ class AsyncLLMEngine:
                 self.slo_engine.observe_outcome(
                     rec.request_class, rec.outcome
                 )
-            if self._capture_sink is not None:
-                self._capture_sink.append({
-                    "offset_s": round(
-                        max(0.0, rec.arrival_time - self._capture_t0), 3
-                    ),
-                    "request_id": request_id,
-                    "tenant": rec.tenant,
-                    "class": rec.request_class,
-                    "adapter": rec.lora_name,
-                    "prompt_tokens": rec.tokens_in,
-                    "output_tokens": rec.tokens_out,
-                    "max_tokens": sampling_params.max_tokens,
-                    "temperature": sampling_params.temperature,
-                    "outcome": rec.outcome,
-                })
 
         if self.frontdoor is None:
             # --disable-frontdoor restores pre-PR4 semantics entirely:
@@ -1311,13 +1284,12 @@ class AsyncLLMEngine:
             "events": events,
         }
 
-    def _note_step_telemetry(self, rep: _Replica, committed: int) -> None:
+    def _note_step_telemetry(self, rep: _Replica) -> None:
         """Per-commit telemetry feeds (telemetry/): each open request's
         current KV page count accrues page-seconds for the interval
-        since this replica's previous commit, and the committed tokens
-        fold into the replica's rate EWMA (the MFU numerator).  dt is
-        capped so an idle gap before a commit cannot bill a full idle
-        period at the current occupancy."""
+        since this replica's previous commit.  dt is capped so an idle
+        gap before a commit cannot bill a full idle period at the
+        current occupancy."""
         now = time.monotonic()
         last = self._kv_sample_t.get(rep.index)
         self._kv_sample_t[rep.index] = now
@@ -1332,8 +1304,6 @@ class AsyncLLMEngine:
                     logger.debug(
                         "kv page-seconds sample failed", exc_info=True
                     )
-        if committed > 0:
-            self._token_rate[rep.index].update(committed, now)
         # bottleneck doctor: throttled internally (cheap clock check
         # before signals are even built), so this rides every commit
         self.doctor.maybe_evaluate(self._doctor_signals)
@@ -1514,25 +1484,6 @@ class AsyncLLMEngine:
                         metrics.spec_acceptance_rate_ewma.labels(
                             replica=str(rep.index)
                         ).set(spec.acceptance_ewma.value)
-            # live MFU (telemetry/mfu.py): committed-token rate EWMA ×
-            # the analytic FLOPs/token the bench stamps; the mfu RATIO
-            # additionally needs an operator-declared TGIS_PEAK_TFLOPS
-            from vllm_tgis_adapter_tpu.telemetry import mfu as mfu_mod
-
-            peak = mfu_mod.peak_tflops()
-            mcfg = self.engine.config.model_config
-            for rep in self._replicas:
-                rate = self._token_rate[rep.index].rate
-                if rate <= 0:
-                    continue
-                achieved = mfu_mod.achieved_tflops(rate, mcfg)
-                metrics.model_tflops_per_s.labels(
-                    replica=str(rep.index)
-                ).set(achieved)
-                if peak:
-                    metrics.mfu.labels(replica=str(rep.index)).set(
-                        achieved / peak
-                    )
             # SLO attainment/burn gauges refresh with the same cadence
             # (every stats tick and every /metrics scrape)
             self.slo_engine.refresh_gauges()
@@ -1570,11 +1521,11 @@ class AsyncLLMEngine:
             active = any(e.has_unfinished_requests() for e in engines)
             allocators = [e.scheduler.allocator for e in engines]
             used, num_blocks = self.refresh_engine_gauges()
-            # drain the ledger/capture JSONL buffers off the event loop
+            # drain the ledger JSONL buffer off the event loop
             # (JsonlSink.flush runs the write in asyncio.to_thread)
-            for sink in (self._ledger_sink, self._capture_sink):
-                if sink is not None and sink.pending:
-                    await sink.flush()
+            sink = self._ledger_sink
+            if sink is not None and sink.pending:
+                await sink.flush()
             if self.engine.config.disable_log_stats or (
                 not active and not was_active
             ):
@@ -1707,12 +1658,11 @@ class AsyncLLMEngine:
                 await self._drain_handoffs(rep)
             committed = self._plan_tokens(plan)
             # per-replica committed-token attribution: the placement
-            # router's load tiebreak and the bench's per-replica tok/s
+            # router's load tiebreak
             self.router.note_committed(rep.index, committed)
-            # telemetry feeds at the same boundary: KV page-seconds
-            # sampling for the cost ledger and the token-rate EWMA
-            # behind the live MFU gauges
-            self._note_step_telemetry(rep, committed)
+            # telemetry feed at the same boundary: KV page-seconds
+            # sampling for the cost ledger
+            self._note_step_telemetry(rep)
             if self.frontdoor is not None:
                 # finished rows free batch slots/pages and the commit's
                 # tokens feed the admission estimator's PER-REPLICA

@@ -784,7 +784,7 @@ class ParallelConfig:
     # of a host with fewer than N*pp*sp*tp devices — replicas then SHARE
     # the visible device set (each still owns its own scheduler, KV
     # pool, step loop, and flight recorder).  That shared mode is the
-    # CPU-proxy / single-host dev story (bench dp scaling, chaos tests);
+    # single-host dev story (CPU tests, chaos tests);
     # on real multi-chip hosts with enough devices both flags partition
     # identical disjoint slices.  Mutually exclusive with
     # data_parallel_size > 1 (one replica-count knob at a time).
@@ -1029,12 +1029,10 @@ class EngineConfig:
     quantization: str | None = None
     otlp_traces_endpoint: str | None = None
     # telemetry signal layer (telemetry/, docs/OBSERVABILITY.md):
-    # per-class SLO objectives (JSON object or path; None = defaults),
-    # the cost-ledger JSONL sink, and admitted-traffic trace capture
-    # for tools/trace_replay.py — all optional, all zero-cost when off
+    # per-class SLO objectives (JSON object or path; None = defaults)
+    # and the cost-ledger JSONL sink — optional, zero-cost when off
     slo_config: str | None = None
     ledger_log: str | None = None
-    capture_trace: str | None = None
     disable_log_requests: bool = True
     disable_log_stats: bool = False
     # stall watchdog (watchdog.py): a step loop with unfinished work
@@ -1533,7 +1531,6 @@ class EngineConfig:
             otlp_traces_endpoint=args.otlp_traces_endpoint,
             slo_config=getattr(args, "slo_config", None),
             ledger_log=getattr(args, "ledger_log", None),
-            capture_trace=getattr(args, "capture_trace", None),
             disable_log_stats=getattr(args, "disable_log_stats", False),
             disable_log_requests=args.disable_log_requests,
             watchdog_deadline_s=float(

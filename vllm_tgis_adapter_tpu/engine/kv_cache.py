@@ -54,9 +54,8 @@ def per_block_bytes(config: "EngineConfig") -> int:
     the K/V payload shrinks to the storage dtype's itemsize (1 byte for
     int8/fp8) and the per-page-per-head f32 scale sidecar
     (ops/kv_quant.py) is added — ~2x pages per HBM budget at the usual
-    ``block_size * head_dim`` tile sizes.  This is the single pricing
-    formula the allocator, the perf gate's capacity check and the bench
-    stamps all share.
+    ``block_size * head_dim`` tile sizes (held at >= 1.9x by
+    tests/test_kv_sizing.py).
     """
     import jax.numpy as jnp
 
@@ -88,18 +87,6 @@ def per_block_bytes(config: "EngineConfig") -> int:
         # geometry (engine/speculative.py) — its pages share the budget
         block_bytes += one_model(config.speculative.draft_model_config)
     return block_bytes
-
-
-def pages_for_budget(config: "EngineConfig", budget_bytes: int) -> int:
-    """Pages ``budget_bytes`` of per-device HBM buys under ``config``.
-
-    Pure arithmetic over :func:`per_block_bytes` — the same division
-    ``resolve_num_blocks`` performs against measured free HBM, exposed
-    so the quant perf gate (tools/perf_check.py ``quant`` section) can
-    price the capacity ratio at an EQUAL synthetic budget on backends
-    whose pool would otherwise fall back to the static size.
-    """
-    return max(0, int(budget_bytes) // per_block_bytes(config))
 
 
 def device_memory_stats(device) -> dict:

@@ -689,7 +689,7 @@ class HostKVTier:
         self._checkpoints: "OrderedDict[str, DecodeCheckpoint]" = (
             OrderedDict()
         )
-        # lifetime stats (debug_state / bench stamps)
+        # lifetime stats (debug_state)
         self.demoted_pages = 0
         # disk-read pages hopped back UP into host RAM during a
         # promotion walk — kept apart from demoted_pages so operators

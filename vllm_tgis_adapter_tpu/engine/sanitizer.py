@@ -5,9 +5,8 @@ the lock/pairing bug *shapes*; this module catches the bugs that slip
 through anyway, at the moment they corrupt state instead of minutes
 later as a wedged request or a silently shrinking pool.  Gated by
 ``TGIS_TPU_SANITIZE=1`` (off by default — zero cost beyond one env
-read per step) and wired on in ``nox -s chaos_soak``,
-``tools/scenarios.py`` and the tier-1 conftest, so every existing test
-doubles as an invariant test.
+read per step) and wired on in ``nox -s chaos_soak`` and the tier-1
+conftest, so every existing test doubles as an invariant test.
 
 Checked after every ``commit_step`` (the step boundary — all host
 mutators of this state run on the loop/main thread, so the reads here

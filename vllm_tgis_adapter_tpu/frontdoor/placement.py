@@ -55,8 +55,8 @@ ROLE_CAPABLE = {
     "decode": ("decode", "mixed"),
 }
 
-# EWMA weight for the per-replica committed-token rate (load tiebreak +
-# bench attribution); one sample ~= one committed dispatch
+# EWMA weight for the per-replica committed-token rate (load
+# tiebreak); one sample ~= one committed dispatch
 _EWMA_ALPHA = 0.3
 
 # host-tier residency scores below device residency (a promotion still
@@ -124,12 +124,12 @@ class PlacementRouter:
         # LRU because tenant ids are client-controlled
         self._sticky: "OrderedDict[str, int]" = OrderedDict()
         self._max_sticky = max_sticky_tenants
-        #: lifetime placements by policy (debug_state + bench stamps)
+        #: lifetime placements by policy (debug_state)
         self.placed_by_policy: dict[str, int] = {p: 0 for p in POLICIES}
         #: lifetime placements per replica index
         self.placed_by_replica: dict[int, int] = {}
         # per-replica committed-token accounting (commit-phase feed):
-        # lifetime totals for bench attribution, EWMA rate for the load
+        # lifetime totals for debug_state, EWMA rate for the load
         # tiebreak between equally-deep queues
         self._committed_total: dict[int, float] = {}
         self._committed_rate: dict[int, float] = {}
@@ -153,7 +153,7 @@ class PlacementRouter:
         self._committed_rate.pop(replica, None)
 
     def committed_by_replica(self) -> dict[int, float]:
-        """Lifetime committed-token totals per replica (bench stamps)."""
+        """Lifetime committed-token totals per replica."""
         return dict(self._committed_total)
 
     # --------------------------------------------------------- placement

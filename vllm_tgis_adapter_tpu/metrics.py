@@ -200,9 +200,7 @@ arena_blocks = _get_or_create(
 
 # ---- quantized KV pages (--kv-quantization, ops/kv_quant.py,
 # docs/QUANTIZATION.md): capacity is the whole point — the dtype label
-# makes the ~2x page-count lift visible next to the HBM budget — and
-# the logprob delta is the token-quality bound the scenario suites
-# gate (tools/scenarios.py writes the last measured value here).
+# makes the ~2x page-count lift visible next to the HBM budget.
 kv_page_capacity_blocks = _get_or_create(
     Gauge,
     f"{_PREFIX}_kv_page_capacity_blocks",
@@ -210,13 +208,6 @@ kv_page_capacity_blocks = _get_or_create(
     "(bf16/f32 full precision, int8/fp8 quantized) per dp replica — "
     "the capacity the HBM budget buys under --kv-quantization",
     labelnames=("dtype", "replica"),
-)
-quant_logprob_delta = _get_or_create(
-    Gauge,
-    f"{_PREFIX}_quant_logprob_delta",
-    "Mean per-token |logprob delta| of the quantized KV path vs the "
-    "bf16 baseline, as last measured by the steady-state scenario "
-    "suites (tools/scenarios.py; 0 until a suite has run)",
 )
 
 
@@ -277,7 +268,7 @@ ttft_seconds = _get_or_create(
     Histogram,
     f"{_PREFIX}_ttft_seconds",
     "Time to first token: request arrival to the first sampled token "
-    "committing on host (the live counterpart of the bench's ttft_ms)",
+    "committing on host",
     buckets=(0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
              30.0, 60.0),
 )
@@ -692,22 +683,6 @@ spec_acceptance_rate_ewma = _get_or_create(
     "Time-decayed (30s half-life) EWMA of the per-dispatch speculative "
     "acceptance rate, per dp replica — the responsive signal the "
     "gamma auto-tuner consumes (lifetime rate: spec_acceptance_rate)",
-    labelnames=("replica",),
-)
-model_tflops_per_s = _get_or_create(
-    Gauge,
-    f"{_PREFIX}_model_tflops_per_s",
-    "Achieved model TFLOP/s per dp replica from the live committed-"
-    "token rate (telemetry/mfu.py: ~2 FLOPs/weight/token, the "
-    "standard MFU numerator)",
-    labelnames=("replica",),
-)
-mfu = _get_or_create(
-    Gauge,
-    f"{_PREFIX}_mfu",
-    "Model FLOPs utilization per dp replica: achieved model FLOP/s "
-    "over the TGIS_PEAK_TFLOPS-declared per-chip peak; exported only "
-    "when the operator sets the peak (the CPU proxy has none)",
     labelnames=("replica",),
 )
 step_anatomy_seconds = _get_or_create(

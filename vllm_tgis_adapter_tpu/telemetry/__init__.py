@@ -26,15 +26,11 @@ Composable pieces, each consumable on its own:
   episodes + ledger records merged into one chrome-trace JSON
   (``GET /debug/timeline``, the ``GetTimeline`` RPC, and
   ``tools/timeline_export.py`` offline);
-* :mod:`~vllm_tgis_adapter_tpu.telemetry.ewma` /
-  :mod:`~vllm_tgis_adapter_tpu.telemetry.mfu` — the decayed-EWMA and
-  model-FLOPs primitives behind the live ``spec_acceptance_rate_ewma``
-  and ``mfu``/``model_tflops_per_s`` gauges.
+* :mod:`~vllm_tgis_adapter_tpu.telemetry.ewma` — the decayed EWMA
+  behind the live ``spec_acceptance_rate_ewma`` gauge.
 
 ROADMAP item 4 (the fleet reshaping itself under live load) keys its
-placement/role/capacity decisions off exactly these signals; trace
-capture (``--capture-trace``) + ``tools/trace_replay.py`` make every
-decision replayable against recorded or synthesized traffic.
+placement/role/capacity decisions off exactly these signals.
 """
 
 from vllm_tgis_adapter_tpu.telemetry.doctor import (
@@ -43,13 +39,12 @@ from vllm_tgis_adapter_tpu.telemetry.doctor import (
     Episode,
     ReplicaSignals,
 )
-from vllm_tgis_adapter_tpu.telemetry.ewma import DecayedEwma, TokenRateEwma
+from vllm_tgis_adapter_tpu.telemetry.ewma import DecayedEwma
 from vllm_tgis_adapter_tpu.telemetry.ledger import (
     CostLedger,
     CostRecord,
     JsonlSink,
 )
-from vllm_tgis_adapter_tpu.telemetry.mfu import flops_per_token
 from vllm_tgis_adapter_tpu.telemetry.slo import (
     REQUEST_CLASSES,
     SloEngine,
@@ -79,9 +74,7 @@ __all__ = [
     "SloEngine",
     "StepRecord",
     "StepTimeline",
-    "TokenRateEwma",
     "chrome_trace_from_state",
     "chrome_trace_json",
-    "flops_per_token",
     "resolve_request_class",
 ]

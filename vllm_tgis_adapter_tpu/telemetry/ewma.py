@@ -1,9 +1,9 @@
-"""Irregular-interval decayed EWMAs (telemetry primitives).
+"""Irregular-interval decayed EWMA (telemetry primitive).
 
 Engine commits land in bursts (wave commits behind XLA compiles, idle
 gaps between requests), so a fixed-α EWMA over *observations* would
 weight a burst of 50 commits in 10 ms the same as 50 commits spread
-over a minute.  Both classes here weight by **elapsed wall time**
+over a minute.  The class here weights by **elapsed wall time**
 instead: an observation ``dt`` seconds after the previous one replaces
 ``1 - 2^(-dt / half_life)`` of the running value, so the estimate
 always represents "the recent ``half_life``-ish window" regardless of
@@ -57,39 +57,3 @@ class DecayedEwma:
             self._value = w * self._value + (1.0 - w) * float(x)
         self._last_t = t
         return self._value
-
-
-class TokenRateEwma:
-    """Time-decayed tokens/second estimator fed with commit counts.
-
-    Each ``update(n, now)`` treats the ``n`` tokens as spread over the
-    gap since the previous update (``rate = n / dt``) and folds that
-    instantaneous rate into a :class:`DecayedEwma`.  Sub-millisecond
-    gaps (two commits in the same wave) are clamped so one lucky
-    scheduling accident cannot spike the estimate.
-    """
-
-    _MIN_DT_S = 1e-3
-
-    def __init__(
-        self,
-        half_life_s: float = 10.0,
-        timer: Callable[[], float] = time.monotonic,
-    ):
-        self._ewma = DecayedEwma(half_life_s, timer=timer)
-        self._timer = timer
-        self._last_t: Optional[float] = None
-
-    @property
-    def rate(self) -> float:
-        return self._ewma.value
-
-    def update(self, n_tokens: int, now: Optional[float] = None) -> float:
-        t = self._timer() if now is None else now
-        if self._last_t is None:
-            # no interval yet — just anchor the clock
-            self._last_t = t
-            return self._ewma.value
-        dt = max(self._MIN_DT_S, t - self._last_t)
-        self._last_t = t
-        return self._ewma.update(n_tokens / dt, now=t)

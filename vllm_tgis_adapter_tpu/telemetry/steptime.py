@@ -37,8 +37,8 @@ dispatches (:func:`backend_dispatch_blocks`):
 
 * JAX async dispatch (TPU, default CPU): ``dispatch_*`` enqueues and
   returns — device busy ~ ``[t_disp1, t_wait1]``;
-* blocking dispatch (CPU proxy with ``jax_cpu_enable_async_dispatch``
-  off, i.e. ``BENCH_SYNC_DISPATCH=1``): the device work runs INSIDE
+* blocking dispatch (the CPU backend with
+  ``jax_cpu_enable_async_dispatch`` off): the device work runs INSIDE
   ``dispatch_*`` — device busy ``[t_disp0, t_disp1]`` and the paired
   wait returns instantly, so the gap must be measured against the
   dispatch window or it degenerates to ~0 and hides exactly the
@@ -83,9 +83,9 @@ DEFAULT_WINDOW = 32
 def backend_dispatch_blocks() -> bool:
     """True when ``dispatch_*`` executes the device work before
     returning: the JAX CPU backend with async dispatch disabled
-    (``BENCH_SYNC_DISPATCH=1`` flips ``jax_cpu_enable_async_dispatch``
-    off).  The engine core samples this once per StepTimeline so the
-    gap computation reads the right device-busy interval."""
+    (``jax_cpu_enable_async_dispatch`` off).  The engine core samples
+    this once per StepTimeline so the gap computation reads the right
+    device-busy interval."""
     try:
         import jax
 

@@ -469,11 +469,6 @@ def add_engine_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                         "terminal request with wall-time splits, token "
                         "counts, KV page-seconds, tier bytes, and "
                         "recovery counts")
-    g.add_argument("--capture-trace", type=str, default=None,
-                   help="JSONL sink capturing admitted traffic shape "
-                        "(arrival offsets, token counts, tenant/class/"
-                        "adapter, sampling params — never content) for "
-                        "tools/trace_replay.py")
     g.add_argument("--jax-profiler-port", type=int, default=None,
                    help="start a jax.profiler server on this port "
                         "(connect with TensorBoard/XProf to capture "
