@@ -322,7 +322,8 @@ def phase_kernel(args: argparse.Namespace) -> None:
     work, n_items = ra.decode_work_schedule(
         pos_b, jnp.asarray(live), jnp.asarray(tables), block_size=block
     )
-    if int(n_items) != int(((ctx[live] - 1) // block + 1).sum()):
+    run = ra.pages_per_item(block)
+    if int(n_items) != int((-(-((ctx[live] - 1) // block + 1) // run)).sum()):
         raise RuntimeError(f"decode schedule: {int(n_items)} items")
     got = ra._ragged_attention_pallas(
         q, kc, vc, starts_b, pos_b, work, block, scale,
@@ -417,7 +418,7 @@ def phase_collectives(args: argparse.Namespace) -> None:
         params, caches, spec(t), spec(t), spec(t), spec(seqs + 1),
         spec(seqs), spec(), spec(seqs, mcfg.max_model_len // block),
         spec(seqs),
-        work=spec(ra.WORK_FIELDS, 64) if _use_pallas() else None,
+        work=spec(ra.work_rows(block), 64) if _use_pallas() else None,
     ).compile().as_text()
     found = {
         "all_reduce": text.count("all-reduce("),

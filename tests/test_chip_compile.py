@@ -42,6 +42,7 @@ GRANITE_2B = dict(
 )
 HBM_BYTES = 16 * 10**9  # one v5e chip
 BLOCK = 16  # CacheConfig.block_size default
+RUN = ra.pages_per_item(BLOCK)  # pages of one work item: 8
 PAGES = 1024
 LAYERS = 4  # of the pools the kernel-only gates address
 
@@ -94,7 +95,7 @@ def _ragged_args(spec, *, h, hkv, dh, t, work_w, cache_dtype, seqs=64):
         spec((LAYERS, hkv, PAGES * BLOCK, dh), cache_dtype),
         spec((seqs + 1,), jnp.int32),
         spec((seqs,), jnp.int32),
-        spec((ra.WORK_FIELDS, work_w), jnp.int32),
+        spec((ra.work_rows(BLOCK), work_w), jnp.int32),
         spec((), jnp.int32),  # n_items
     ]
     return args
@@ -187,7 +188,7 @@ def test_ragged_decode_schedule_compiles(topo, on_chip, tp):
         work, n_items = ra.decode_work_schedule(
             pos, active, tables, block_size=BLOCK
         )
-        assert work.shape == (ra.WORK_FIELDS, b * max_blocks)
+        assert work.shape == (ra.work_rows(BLOCK), b * max_blocks // RUN)
         return ra.ragged_paged_attention(
             q, kc, vc, pos, jnp.arange(b + 1, dtype=jnp.int32), pos,
             jnp.asarray(b, jnp.int32), tables, BLOCK, dh**-0.5,
@@ -261,7 +262,7 @@ def test_ragged_kernel_under_tp4_shard_map(topo, on_chip):
         spec(pool, jnp.bfloat16, None, "tp", None, None),
         spec((t,), jnp.int32), spec((seqs + 1,), jnp.int32),
         spec((seqs,), jnp.int32), spec((seqs, 4096 // BLOCK), jnp.int32),
-        spec((ra.WORK_FIELDS, 256), jnp.int32), spec((), jnp.int32),
+        spec((ra.work_rows(BLOCK), 256), jnp.int32), spec((), jnp.int32),
     ).as_text()
     assert not _layer_shaped(text, (LAYERS, hkv // 4, PAGES * BLOCK, dh))
 
@@ -389,7 +390,7 @@ def test_whole_ragged_step_compiles_and_fits(topo, one_chip, on_chip,
         spec((t,), jnp.int32), spec((seqs + 1,), jnp.int32),
         spec((seqs,), jnp.int32), spec((), jnp.int32),
         spec((seqs, 4096 // BLOCK), jnp.int32), spec((seqs,), jnp.int32),
-        work=spec((ra.WORK_FIELDS, 2048), jnp.int32),
+        work=spec((ra.work_rows(BLOCK), 2048), jnp.int32),
         n_items=spec((), jnp.int32),
     ).compile(compiler_options=TPU_STEP_COMPILER_OPTIONS)
     _assert_in_place_and_fits(compiled, caches, pool_bytes)
@@ -432,7 +433,11 @@ def test_whole_decode_wave_compiles_and_fits(topo, one_chip, on_chip,
         spec((), jnp.int32),
     ).compile(compiler_options=TPU_STEP_COMPILER_OPTIONS)
     # the schedule keeps its one static shape, whatever is live
-    assert f"s32[{ra.WORK_FIELDS},{b * max_blocks}]" in compiled.as_text()
+    # (a row's pages in runs of RUN: an eighth of the items a page each)
+    assert (
+        f"s32[{ra.work_rows(BLOCK)},{b * max_blocks // RUN}]"
+        in compiled.as_text()
+    )
     _assert_in_place_and_fits(compiled, caches, pool_bytes)
     _assert_no_weight_prefetch(compiled)
 
@@ -535,7 +540,7 @@ def test_tp4_step_keeps_the_pool_on_its_shards(topo, on_chip, monkeypatch):
     ).lower(
         params, caches, spec(t), spec(t), spec(t), spec(seqs + 1),
         spec(seqs), spec(), spec(seqs, 4096 // BLOCK), spec(seqs),
-        work=spec(ra.WORK_FIELDS, 256), n_items=spec(),
+        work=spec(ra.work_rows(BLOCK), 256), n_items=spec(),
     ).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce(" in text

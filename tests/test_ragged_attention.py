@@ -26,25 +26,29 @@ REPO_ROOT = Path(__file__).resolve().parent.parent
 
 
 def _build_mixed_case(rng, cases, *, hkv=2, g=2, dh=16, bs=4, max_blocks=8,
-                      t_pad=None):
+                      t_pad=None, num_blocks=32, shuffle=False, stale=0.0):
     """Build a paged cache + flat mixed stream from (ctx_before, n_new)
     span specs; returns everything the ragged kernel consumes plus the
-    per-sequence pieces the reference needs."""
+    per-sequence pieces the reference needs.  ``shuffle`` hands out the
+    pool's pages in a drawn order (a sequence's physical pages then
+    neither follow one another nor ascend); ``stale`` is what every slot
+    holds that no context wrote (the tail of a sequence's last page,
+    which a short run's repeated places read again)."""
     import jax.numpy as jnp
 
     from vllm_tgis_adapter_tpu.ops.attention import write_kv
 
     h = hkv * g
-    num_blocks = 32
-    kc = jnp.zeros((hkv, num_blocks * bs, dh), jnp.float32)
-    vc = jnp.zeros_like(kc)
+    kc = jnp.full((hkv, num_blocks * bs, dh), stale, jnp.float32)
+    vc = jnp.full_like(kc, stale)
     tables = np.zeros((len(cases), max_blocks), np.int32)
     spans, pos_base, flat_q, flat_pos, per_seq = [], [], [], [], []
+    order = rng.permutation(num_blocks) if shuffle else np.arange(num_blocks)
     next_block, row = 0, 0
     for s, (ctx_before, n_new) in enumerate(cases):
         total = ctx_before + n_new
         nb = -(-total // bs)
-        blocks = list(range(next_block, next_block + nb))
+        blocks = [int(b) for b in order[next_block: next_block + nb]]
         next_block += nb
         tables[s, :nb] = blocks
         k_seq = rng.standard_normal((total, hkv, dh)).astype(np.float32)
@@ -122,27 +126,57 @@ def test_ragged_xla_matches_decode_reference(window):
         row += n_new
 
 
+#: what a run of pages can get wrong, by block size (a work item reads
+#: ``P = 128 // bs`` pages = 128 KV tokens; at block size 128 a run IS a
+#: page).  Contexts of 1, 127, 128 and 129 tokens sit on both sides of a
+#: run's end; 300 tokens are three runs, the last one short.  The pool's
+#: pages are dealt in a drawn order and every slot no context wrote
+#: holds 1e4: the repeated places of a short run read such slots again.
+_RUN_EDGES = {
+    # id: (bs, max_blocks, pool pages)
+    "pages-of-4": (4, 80, 256),
+    "pages-of-16": (16, 20, 64),
+    "pages-of-128": (128, 3, 12),
+}
+
+
 @pytest.mark.parametrize("window", [0, 4])
 @pytest.mark.parametrize("use_alibi", [False, True])
 @pytest.mark.parametrize("schedule", ["sparse", "decode"])
-def test_ragged_pallas_matches_xla(window, use_alibi, schedule):
+@pytest.mark.parametrize("shape", ["one-run", *_RUN_EDGES])
+def test_ragged_pallas_matches_xla(window, use_alibi, schedule, shape):
     """The Pallas kernel (interpret mode) matches the XLA reference for
     both the host-built sparse schedule (mixed engine steps, multi-row
     spans) and the in-trace compact schedule with its traced grid
     extent (the fused decode wave — single-row spans by contract, at a
-    row count that block_q=8 divides exactly)."""
+    row count that block_q=8 divides exactly): for short contexts that
+    one run covers, and on the edges of a run at every block size."""
     import jax.numpy as jnp
 
     from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
 
     rng = np.random.default_rng(1)
-    case = _build_mixed_case(
-        rng,
-        [(0, 6), (9, 1), (3, 4), (5, 1)]
-        if schedule == "sparse"
-        # decode contract: every span one row (seq s IS row s)
-        else [(i % 11, 1) for i in range(16)],
-    )
+    if shape == "one-run":
+        case = _build_mixed_case(
+            rng,
+            [(0, 6), (9, 1), (3, 4), (5, 1)]
+            if schedule == "sparse"
+            # decode contract: every span one row (seq s IS row s)
+            else [(i % 11, 1) for i in range(16)],
+        )
+    else:
+        bs, max_blocks, num_blocks = _RUN_EDGES[shape]
+        case = _build_mixed_case(
+            rng,
+            # a decode row on each edge, a chunk whose rows see 121-132
+            # tokens (its later rows a second run), a fresh prompt
+            [(0, 1), (126, 1), (127, 1), (128, 1), (120, 12), (299, 1),
+             (0, 5)]
+            if schedule == "sparse"
+            else [(c - 1, 1) for c in (1, 127, 128, 129, 300, 2, 256, 17)],
+            bs=bs, max_blocks=max_blocks, num_blocks=num_blocks,
+            shuffle=True, stale=1e4,
+        )
     slopes = (
         jnp.asarray(rng.standard_normal(case["h"]).astype(np.float32) * 0.1)
         if use_alibi
@@ -179,16 +213,18 @@ def test_ragged_pallas_matches_xla(window, use_alibi, schedule):
 
 
 def _decode_case(rng, ctx, *, bs=4, max_blocks=8, hkv=2, g=2, dh=16,
-                 cache_dtype=None):
+                 cache_dtype=None, page_gain=1.0):
     """A decode wave's attention inputs: row i one token at position
     ``ctx[i] - 1``; ``ctx[i] == 0`` marks a dead row (slot -1: empty
     table, context 1, as prepare_decode pads a batch)."""
     import jax.numpy as jnp
 
     from vllm_tgis_adapter_tpu.ops import kv_quant
+    from vllm_tgis_adapter_tpu.ops.ragged_attention import pages_per_item
 
     ctx = np.asarray(ctx, np.int32)
     b = len(ctx)
+    run = pages_per_item(bs)
     num_pages = b * max_blocks
     tables = np.zeros((b, max_blocks), np.int32)
     pages = rng.permutation(num_pages)
@@ -200,6 +236,13 @@ def _decode_case(rng, ctx, *, bs=4, max_blocks=8, hkv=2, g=2, dh=16,
     shape = (hkv, num_pages * bs, dh)
     kc = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     vc = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    if page_gain != 1.0:
+        # neighbouring pages (the places of one run) hold values, and
+        # so dequant scales, ``page_gain`` apart
+        gain = jnp.repeat(
+            jnp.where(jnp.arange(num_pages) % 2 == 1, page_gain, 1.0), bs
+        )[None, :, None]
+        kc, vc = kc * gain, vc * gain
     scales = None
     if cache_dtype is not None:
         qk = qv = kv_quant.make_kv_cache(
@@ -215,39 +258,58 @@ def _decode_case(rng, ctx, *, bs=4, max_blocks=8, hkv=2, g=2, dh=16,
         kc=kc, vc=vc, scales=scales, tables=jnp.asarray(tables),
         pos=jnp.asarray(np.maximum(ctx, 1) - 1), live=ctx > 0,
         starts=jnp.arange(b + 1, dtype=jnp.int32), bs=bs, scale=dh**-0.5,
-        # what the dense cross product visited, and what is live
-        width=b * max_blocks,
-        live_items=int((-(-ctx // bs)).sum()),
+        # a row's pages in runs of ``run``: the schedule's width (every
+        # row at full context), what is live, and the pages under it
+        run=run, width=b * -(-max_blocks // run),
+        live_items=int((-(-(-(-ctx // bs)) // run)).sum()),
+        live_pages=int((-(-ctx // bs)).sum()),
     )
 
 
 @pytest.mark.parametrize(
-    "ctx,cache_dtype",
+    "ctx,cache_dtype,shape",
     [
-        pytest.param([0] * 16, None, id="all-rows-dead"),
-        pytest.param([0] * 23 + [9], None,
+        pytest.param([0] * 16, None, None, id="all-rows-dead"),
+        pytest.param([0] * 23 + [9], None, None,
                      id="one-live-row-in-last-block"),
-        pytest.param([4, 8, 1, 32, 5, 0, 12, 16], None,
+        pytest.param([4, 8, 1, 32, 5, 0, 12, 16], None, None,
                      id="context-ends-on-page-boundary"),
-        pytest.param([32] * 64, None, id="64-rows-full-context"),
-        pytest.param([(5 * i) % 33 for i in range(21)], None,
+        pytest.param([32] * 64, None, None, id="64-rows-full-context"),
+        pytest.param([(5 * i) % 33 for i in range(21)], None, None,
                      id="non-pow2-stream"),
         pytest.param([7, 0, 19, 32, 1, 0, 0, 3, 0, 0, 0, 0, 0, 0, 0, 0, 11],
-                     "int8", id="int8-pages"),
+                     "int8", None, id="int8-pages"),
+        # runs of 8 pages of 16 tokens: contexts on both sides of a
+        # run's end, three runs with a short last one, dead rows among
+        # them and a query block (rows 8-15) with no live row
+        pytest.param([1, 127, 128, 129, 300, 0, 16, 0] + [0] * 8 + [255],
+                     None, {"bs": 16, "max_blocks": 20},
+                     id="runs-of-8-pages"),
+        # block size 128: a run IS a page, through the same code
+        pytest.param([1, 127, 128, 129, 300, 0, 256, 0, 384],
+                     None, {"bs": 128, "max_blocks": 3},
+                     id="block-128-runs-of-1-page"),
+        # pages of one run whose dequant scales lie 100x apart
+        pytest.param([1, 127, 128, 129, 300, 0, 16, 77], "int8",
+                     {"bs": 16, "max_blocks": 20, "page_gain": 100.0},
+                     id="int8-runs-scales-100x-apart"),
+        pytest.param([1, 127, 128, 129, 300, 0, 16, 77], "fp8",
+                     {"bs": 16, "max_blocks": 20, "page_gain": 100.0},
+                     id="fp8-runs-scales-100x-apart"),
     ],
 )
-def test_decode_schedule_visits_only_live_work(ctx, cache_dtype):
+def test_decode_schedule_visits_only_live_work(ctx, cache_dtype, shape):
     """The fused wave's attention under its compact schedule and a
-    TRACED ``n_items``: the grid covers the live (row, page) items and
-    one dead item per empty query block, live rows equal the XLA
-    reference, dead rows come out finite (zeros)."""
+    TRACED ``n_items``: the grid covers the live (row, run of pages)
+    items and one dead item per empty query block, live rows equal the
+    XLA reference, dead rows come out finite (zeros)."""
     import jax
     import jax.numpy as jnp
 
     from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
 
     case = _decode_case(np.random.default_rng(7), ctx,
-                        cache_dtype=cache_dtype)
+                        cache_dtype=cache_dtype, **(shape or {}))
     b, live = len(ctx), case["live"]
     block_q = ra.decode_block_q(b)
 
@@ -267,7 +329,8 @@ def test_decode_schedule_visits_only_live_work(ctx, cache_dtype):
         case["pos"], jnp.asarray(live), case["tables"]
     )
     work, n_items = np.asarray(work), int(n_items)
-    assert work.shape == (ra.WORK_FIELDS, case["width"])
+    run = case["run"]
+    assert work.shape == (ra.WORK_FIELDS + run, case["width"])
     nq = -(-b // block_q)
     empty = sum(
         not live[i * block_q: (i + 1) * block_q].any() for i in range(nq)
@@ -275,14 +338,24 @@ def test_decode_schedule_visits_only_live_work(ctx, cache_dtype):
     assert n_items == case["live_items"] + empty
     head = work[:, :n_items]
     assert head[6].sum() == case["live_items"]
+    assert head[2].sum() == case["live_pages"]
     # every query block is initialised once and finalised once, in order
     assert head[4].sum() == head[5].sum() == nq
     assert (np.diff(head[0]) >= 0).all()
     assert sorted(set(head[0])) == list(range(nq))
-    # a live item names a page of its own row's table
+    # a live item names consecutive logical pages of its own row's
+    # table, from a multiple of the run's length on; a short run
+    # repeats its last real page
     tables = np.asarray(case["tables"])
     on = head[6] == 1
-    assert (head[2][on] == tables[head[1][on], head[3][on]]).all()
+    row, real, page0 = head[1][on], head[2][on], head[3][on]
+    assert (page0 % run == 0).all() and (real >= 1).all()
+    assert (real <= run).all()
+    for i in range(run):
+        assert (
+            head[ra.WORK_FIELDS + i][on]
+            == tables[row, page0 + np.minimum(i, real - 1)]
+        ).all()
 
     ref = ra.ragged_attention_xla(
         case["q"], case["kc"], case["vc"], case["pos"], case["starts"],
@@ -341,18 +414,32 @@ def test_ragged_decode_schedule_non_pow2_stream(monkeypatch):
 # ------------------------------------- the pool as one operand, by layer
 
 
-def _pool_case(rng, cache_dtype, *, layers=3, hkv=2, g=2, dh=16, bs=4,
-               max_blocks=8):
+def _pool_case(rng, cache_dtype, *, layers=3, hkv=2, g=2, dh=16):
     """A mixed ragged batch (prefill chunk, decode rows, a resumed
     chunk) over a WHOLE ``[L, Hkv, slots, Dh]`` pool whose layers hold
     different data, written through ``scatter_layer`` as the step
-    programs write it (so quantized pools carry their sidecars)."""
+    programs write it (so quantized pools carry their sidecars).
+
+    ``<dtype>-runs`` is the served form of the thing: pages of 16
+    tokens read in runs of 8, contexts of 127-300 tokens (so runs end
+    inside, on and past a context, and a 300-token row owns three),
+    and the values of neighbouring pages — the places of one run, each
+    with its own dequant scale — 100x apart."""
     import jax.numpy as jnp
 
     from vllm_tgis_adapter_tpu.ops import kv_quant
 
-    spans_spec = [(0, 6), (9, 1), (3, 4), (5, 1), (13, 1)]
-    num_pages = 32
+    cache_dtype, _, runs = cache_dtype.partition("-")
+    if runs:
+        bs, max_blocks, num_pages = 16, 20, 64
+        spans_spec = [(0, 6), (126, 1), (127, 1), (128, 1), (299, 1)]
+    else:
+        bs, max_blocks, num_pages = 4, 8, 32
+        spans_spec = [(0, 6), (9, 1), (3, 4), (5, 1), (13, 1)]
+    gain = jnp.repeat(
+        jnp.where(jnp.arange(num_pages) % 2 == 1, 100.0, 1.0)
+        if runs else jnp.ones(num_pages), bs,
+    )[:, None, None]
     scheme = cache_dtype if cache_dtype in ("int8", "fp8") else "none"
     store = jnp.bfloat16 if cache_dtype == "bf16" else jnp.float32
     k_pool = kv_quant.make_kv_cache(
@@ -364,12 +451,12 @@ def _pool_case(rng, cache_dtype, *, layers=3, hkv=2, g=2, dh=16, bs=4,
     slots = jnp.arange(num_pages * bs, dtype=jnp.int32)
     for layer in range(layers):
         k_pool = kv_quant.scatter_layer(
-            k_pool, layer, slots, jnp.asarray(
+            k_pool, layer, slots, gain * jnp.asarray(
                 rng.standard_normal((num_pages * bs, hkv, dh)), jnp.float32
             ),
         )
         v_pool = kv_quant.scatter_layer(
-            v_pool, layer, slots, jnp.asarray(
+            v_pool, layer, slots, gain * jnp.asarray(
                 rng.standard_normal((num_pages * bs, hkv, dh)), jnp.float32
             ),
         )
@@ -405,7 +492,10 @@ def _pool_case(rng, cache_dtype, *, layers=3, hkv=2, g=2, dh=16, bs=4,
 @pytest.mark.parametrize("use_alibi", [False, True],
                          ids=["no-alibi", "alibi"])
 @pytest.mark.parametrize("window", [0, 4], ids=["full", "window4"])
-@pytest.mark.parametrize("cache_dtype", ["bf16", "int8", "fp8"])
+@pytest.mark.parametrize(
+    "cache_dtype", ["bf16", "int8", "fp8", "bf16-runs", "int8-runs",
+                    "fp8-runs"],
+)
 @pytest.mark.parametrize("layer", [0, 1, 2],
                          ids=["first", "middle", "last"])
 def test_pool_operand_is_addressed_by_layer(
@@ -473,37 +563,53 @@ def test_pool_operand_is_addressed_by_layer(
 
 
 def _expected_items(spans, *, bs, block_q, t_pad):
-    """The schedule's real-item count, reckoned apart from the builder:
-    per query block the pages each of its sequences can see there, and
-    one dead item for a block with none."""
-    total = 0
+    """The schedule's real-item count and the pages under it, reckoned
+    apart from the builder: per query block the pages each of its
+    sequences can see there, in runs of ``128 // bs``, and one dead
+    item for a block with none."""
+    run = max(1, 128 // bs)
+    total = pages = 0
     for qb in range(t_pad // block_q):
         lo, hi = qb * block_q, (qb + 1) * block_q - 1
         items = 0
         for start, n_rows, pos0 in spans:
             first, last = max(start, lo), min(start + n_rows - 1, hi)
             if first <= last:
-                items += (pos0 + last - start) // bs + 1
+                seen = (pos0 + last - start) // bs + 1
+                pages += seen
+                items += -(-seen // run)
         total += items or 1
-    return total
+    return total, pages
 
 
 @pytest.mark.parametrize(
-    "cases,t_pad,width",
+    "cases,t_pad,width,shape",
     [
         # 11 real rows of a 32-row bucket: query blocks 2 and 3 are all
         # padding and own one dead item each, the schedule's last two
-        pytest.param([(0, 6), (9, 1), (3, 4)], 32, 64,
+        pytest.param([(0, 6), (9, 1), (3, 4)], 32, 64, None,
                      id="all-padding-query-blocks"),
-        # 16 items, two of them dead: the real count IS the (pow2) width
-        pytest.param([(0, 8), (31, 1), (8, 7)], 32, None,
+        # 8 items, one of them dead: the real count IS the (pow2) width
+        pytest.param([(0, 8), (31, 1), (8, 7), (2, 1), (0, 2), (7, 1),
+                      (1, 3)], 32, None, None,
                      id="real-count-equals-width"),
         # the served shape of the thing: few items, a width far above
-        pytest.param([(5, 1), (17, 1), (2, 9), (30, 1)], 16, 256,
+        pytest.param([(5, 1), (17, 1), (2, 9), (30, 1)], 16, 256, None,
                      id="width-far-above"),
+        # pages of 16 tokens in runs of 8: a context that ends a run, a
+        # chunk over two query blocks whose second block sees a second
+        # run, three runs with a short last one; 11 items where a page
+        # each made 52
+        pytest.param([(127, 1), (120, 12), (299, 1), (0, 3)], 32, 64,
+                     {"bs": 16, "max_blocks": 20, "num_blocks": 64},
+                     id="runs-of-8-pages"),
+        # block size 128: a run is a page and an item a page, as before
+        pytest.param([(127, 1), (120, 12), (299, 1), (0, 3)], 32, 64,
+                     {"bs": 128, "max_blocks": 3, "num_blocks": 12},
+                     id="block-128-runs-of-1-page"),
     ],
 )
-def test_mixed_grid_ends_at_its_last_real_item(cases, t_pad, width):
+def test_mixed_grid_ends_at_its_last_real_item(cases, t_pad, width, shape):
     """A host-built schedule run to ``n_items`` equals the same
     schedule run whole, and the steps past ``n_items`` are NOT visited:
     padding rewritten into items that would wipe the last query block
@@ -514,15 +620,18 @@ def test_mixed_grid_ends_at_its_last_real_item(cases, t_pad, width):
     from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
 
     rng = np.random.default_rng(5)
-    case = _build_mixed_case(rng, cases, t_pad=t_pad)
+    case = _build_mixed_case(
+        rng, cases, t_pad=t_pad, shuffle=True, stale=1e4, **(shape or {})
+    )
     block_q = 8
     work, n_items = ra.build_work_schedule(
         case["spans"], case["block_tables"], block_size=case["bs"],
         block_q=block_q, t_pad=t_pad, w_bucket=width,
     )
-    assert n_items == _expected_items(
+    assert (n_items, work[2, :n_items].sum()) == _expected_items(
         case["spans"], bs=case["bs"], block_q=block_q, t_pad=t_pad
     )
+    assert work.shape[0] == ra.work_rows(case["bs"])
     assert work[5, n_items - 1] == 1  # the last real item finalises
     assert not work[4:, n_items:].any()  # the rest is flagless padding
     if width is None:

@@ -91,6 +91,8 @@ def test_streaming_generation_feeds_step_metrics(tiny_model_dir):
     itl_0 = _sample(before, "tgis_tpu_inter_token_seconds_count")
     waves_0 = _sample(before, "tgis_tpu_decode_live_items_count")
     items_0 = _sample(before, "tgis_tpu_decode_live_items_sum")
+    runs_0 = _sample(before, "tgis_tpu_attn_item_pages_count")
+    pages_0 = _sample(before, "tgis_tpu_attn_item_pages_sum")
     # label deltas are per-engine: each engine owns fresh jitted fns, so
     # its first flat-bucket-16 ragged dispatch compiles exactly once
     prefill_lbl = ('fn="ragged_step"', 'shape="tokens=16"')
@@ -118,11 +120,19 @@ def test_streaming_generation_feeds_step_metrics(tiny_model_dir):
     # per-dispatch shape stats were fed by the plan phase
     assert _sample(after, "tgis_tpu_decode_batch_occupancy") > 0
     # every wave here holds ONE live row of 18-25 tokens of context:
-    # two 16-token pages, so two live (row, page) items a wave
+    # two 16-token pages in one run of up to eight, so one live
+    # (row, run of pages) item a wave, of two pages
     waves = _sample(after, "tgis_tpu_decode_live_items_count") - waves_0
     assert waves > 0
     assert (
         _sample(after, "tgis_tpu_decode_live_items_sum") - items_0
+        == waves
+    )
+    # the CPU builds no mixed-step schedule, so only the waves observe
+    # how full their items' runs are
+    assert _sample(after, "tgis_tpu_attn_item_pages_count") - runs_0 == waves
+    assert (
+        _sample(after, "tgis_tpu_attn_item_pages_sum") - pages_0
         == 2 * waves
     )
     assert _sample(after, "tgis_tpu_packed_prefill_prompts_count") > 0
@@ -145,10 +155,12 @@ def test_mixed_step_observes_its_real_item_count(tiny_model_dir,
     runner = engine._replicas[0].engine.runner
     prepare = runner.prepare_ragged
     seen: list[tuple[int, int]] = []
+    pages: list[int] = []
 
     def spy(plan):
         prep = prepare(plan)
         seen.append((prep.work_items, prep.work.shape[1]))
+        pages.append(prep.work_pages)
         return prep
 
     monkeypatch.setattr(runner, "prepare_ragged", spy)
@@ -177,8 +189,10 @@ def test_mixed_step_observes_its_real_item_count(tiny_model_dir,
     after = _scrape()
     # the planner cuts a lone 17-token prompt into its binary digits: a
     # 16-token step over the prompt's first page, then one token that
-    # sees both of its 16-token pages; its decode steps are waves
-    assert alone == [1, 2]
+    # sees both of its 16-token pages in ONE run of up to eight; its
+    # decode steps are waves
+    assert alone == [1, 1]
+    assert pages[:2] == [1, 2]
     assert len(seen) > len(alone)
     assert all(0 < items < width for items, width in seen)
     assert (
@@ -244,6 +258,7 @@ def test_metrics_endpoint_serves_step_metrics(tiny_model_dir):
         "tgis_tpu_decode_batch_occupancy",
         "tgis_tpu_decode_live_items",
         "tgis_tpu_ragged_live_items",
+        "tgis_tpu_attn_item_pages",
         "tgis_tpu_prefill_padding_waste",
         "tgis_tpu_padded_tokens_total",
         "tgis_tpu_packed_prefill_prompts",

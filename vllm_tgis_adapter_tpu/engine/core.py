@@ -1855,6 +1855,7 @@ class LLMEngine:
                     ),
                     num_decode=sum(1 for i in plan.items if i.is_decode),
                     live_items=prepared.work_items,
+                    live_pages=prepared.work_pages,
                 )
             elif isinstance(plan, PrefillPlan):
                 metrics.observe_prefill_plan(
@@ -1863,16 +1864,22 @@ class LLMEngine:
                     num_prompts=1,
                 )
             else:
-                # pages under the live rows' contexts, from the host's
-                # copy of the wave's inputs (no device read-back)
+                # pages under the live rows' contexts and the runs the
+                # kernel reads them in, from the host's copy of the
+                # wave's inputs (no device read-back)
+                from vllm_tgis_adapter_tpu.ops.ragged_attention import (
+                    pages_per_item,
+                )
+
                 ctx = prepared.context_lens[: len(plan.seqs)]
+                pages = (ctx - 1) // self.runner.block_size + 1
+                run = pages_per_item(self.runner.block_size)
                 metrics.observe_decode_plan(
                     num_seqs=len(plan.seqs),
                     batch_bucket=plan.batch_bucket,
                     num_steps=plan.num_steps,
-                    live_items=int(
-                        ((ctx - 1) // self.runner.block_size + 1).sum()
-                    ),
+                    live_items=int((-(-pages // run)).sum()),
+                    live_pages=int(pages.sum()),
                     replica=self.replica_index,
                 )
         except Exception:  # pragma: no cover — metrics are best-effort

@@ -168,6 +168,8 @@ class PreparedRagged:
     # real items at the head of ``work``: the attention grid's extent
     # (the rest of the width is compile-shape padding no step visits)
     work_items: int = 0
+    # real KV pages those items read, all runs together
+    work_pages: int = 0
     want_topn: bool = True
     # ---- speculative verify (docs/ATTENTION.md "Speculative decoding"):
     # set when any item is a verify span (scheduler RaggedItem.spec_width
@@ -1235,6 +1237,7 @@ class ModelRunner:
             samples=samples,
             work=work,
             work_items=work_items,
+            work_pages=0 if work is None else int(work[2].sum()),
             want_topn=any(
                 it.is_final and it.seq.params.logprobs not in (None, 0)
                 for it in items

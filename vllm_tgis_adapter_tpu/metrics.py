@@ -309,21 +309,31 @@ decode_batch_occupancy = _get_or_create(
 decode_live_items = _get_or_create(
     Histogram,
     f"{_PREFIX}_decode_live_items",
-    "Live (row, KV page) items of a dispatched decode wave's first "
-    "step: the attention grid steps per KV head the wave's kernel call "
-    "visits (a dense rows x pages grid would visit max_num_seqs x "
-    "max_model_len / block_size whatever is live)",
+    "Live (row, run of KV pages) items of a dispatched decode wave's "
+    "first step: the attention grid steps per KV head the wave's kernel "
+    "call visits, a run being up to 128 KV tokens of a row (a dense "
+    "rows x pages grid would visit max_num_seqs x max_model_len / "
+    "block_size whatever is live)",
     buckets=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384),
 )
 ragged_live_items = _get_or_create(
     Histogram,
     f"{_PREFIX}_ragged_live_items",
-    "Real (query block, sequence, KV page) items of a dispatched mixed "
-    "ragged step's work schedule: the attention grid steps per KV head "
-    "its kernel calls visit (the schedule's width, a compile shape that "
-    "only grows, is padding beyond them); observed where a schedule is "
-    "built, i.e. on the Pallas path",
+    "Real (query block, sequence, run of KV pages) items of a dispatched "
+    "mixed ragged step's work schedule: the attention grid steps per KV "
+    "head its kernel calls visit (the schedule's width, a compile shape "
+    "that only grows, is padding beyond them); observed where a schedule "
+    "is built, i.e. on the Pallas path",
     buckets=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384),
+)
+attn_item_pages = _get_or_create(
+    Histogram,
+    f"{_PREFIX}_attn_item_pages",
+    "Real KV pages per attention work item of a dispatched plan (a "
+    "decode wave's first step, or a mixed ragged step where a schedule "
+    "is built): how full the runs of pages are that a grid step reads, "
+    "from 1 to 128 / block_size",
+    buckets=(1, 2, 3, 4, 5, 6, 7, 8, 16, 32),
 )
 prefill_padding_waste = _get_or_create(
     Gauge,
@@ -742,10 +752,12 @@ step_snapshot = _StepSnapshot()
 
 def observe_decode_plan(*, num_seqs: int, batch_bucket: int,
                         num_steps: int, live_items: int,
-                        replica: int = 0) -> None:
+                        live_pages: int, replica: int = 0) -> None:
     occupancy = num_seqs / batch_bucket if batch_bucket else 0.0
     decode_batch_occupancy.labels(replica=str(replica)).set(occupancy)
     decode_live_items.observe(live_items)
+    if live_items:
+        attn_item_pages.observe(live_pages / live_items)
     padded = (batch_bucket - num_seqs) * num_steps
     if padded > 0:
         padded_tokens_total.labels(phase="decode").inc(padded)
@@ -766,16 +778,19 @@ def observe_prefill_plan(*, real_tokens: int, bucket: int,
 
 def observe_ragged_plan(*, real_tokens: int, bucket: int,
                         num_prefill: int, num_decode: int,
-                        live_items: int = 0) -> None:
+                        live_items: int = 0,
+                        live_pages: int = 0) -> None:
     """Shape stats for one unified ragged dispatch
     (--attention-backend=ragged).  The padding-waste gauge reads from
     the RAGGED plan here — the bucketed gauges must not report stale
     bucket math when the ragged path is serving.  ``live_items`` is the
-    real-item count of the step's work schedule (0: none was built)."""
+    real-item count of the step's work schedule (0: none was built),
+    ``live_pages`` the KV pages those items read."""
     fill = real_tokens / bucket if bucket else 0.0
     ragged_batch_fill_ratio.set(fill)
     if live_items:
         ragged_live_items.observe(live_items)
+        attn_item_pages.observe(live_pages / live_items)
     prefill_padding_waste.set(1.0 - fill)
     if bucket > real_tokens:
         padded_tokens_total.labels(phase="ragged").inc(bucket - real_tokens)

@@ -36,11 +36,17 @@ Descriptors (all device arrays; S = padded sequence-descriptor width):
   re-derives it from SMEM scalars to avoid vector gathers).
 
 Pallas kernel: grid ``(kv_head, work_item)`` over a precomputed WORK
-SCHEDULE — one item per (query block, sequence, logical page) triple that
-actually overlaps, exactly the ragged-friendly formulation the paper's
-kernel uses instead of a dense (batch, page) grid.  The schedule rides
-scalar prefetch; pages DMA straight out of the paged cache via the
-BlockSpec index map (the gather happens in the memory system).  A
+SCHEDULE — one item per (query block, sequence, RUN OF PAGES) triple
+that actually overlaps, exactly the ragged-friendly formulation the
+paper's kernel uses instead of a dense (batch, page) grid.  A run is up
+to ``pages_per_item(block_size)`` consecutive LOGICAL pages of one
+sequence — 128 KV tokens, one lane width of the score matrix — so a
+grid step's fixed cost (the query block's load, the masks, the
+online-softmax rescale) is paid once per 128 tokens, not once per page;
+the run's physical pages are wherever the page table put them.  The
+schedule rides scalar prefetch; each page of the run DMAs straight out
+of the paged cache via its own BlockSpec index map over the one pool
+operand (the gather happens in the memory system).  A
 schedule is an array of fixed shape with its real items first and their
 count ``n_items`` as the grid's TRACED second extent, so one program
 visits only what some row reads.  Mixed engine steps pass a host-built
@@ -71,11 +77,30 @@ from vllm_tgis_adapter_tpu.ops.attention import (
     paged_decode_attention_xla,
 )
 
-#: work-schedule row layout ([WORK_FIELDS, W] i32): query-block index,
-#: sequence id, physical page id (DMA target), logical page index within
-#: the sequence, first-item-of-block flag, last-item-of-block flag,
-#: live flag (0 = padding/masked item: no compute, accumulators only).
+#: work-schedule row layout ([WORK_FIELDS + P, W] i32, P =
+#: ``pages_per_item(block_size)``): query-block index, sequence id, real
+#: pages in the item's run (1..P; 0 for a dead item), logical index of
+#: the run's first page within the sequence, first-item-of-block flag,
+#: last-item-of-block flag, live flag (0 = padding/masked item: no
+#: compute, accumulators only); then the run's P physical page ids (the
+#: DMA targets) — a short run repeats its last real page, so every
+#: target is a page of the same sequence.
 WORK_FIELDS = 7
+
+#: KV tokens of one work item: one lane width of the score matrix
+KV_TILE = 128
+
+
+def pages_per_item(block_size: int) -> int:
+    """Pages in one work item's run: as many as make a ``KV_TILE``-token
+    KV tile (8 at block size 16, 1 from block size 128 up)."""
+    return max(1, KV_TILE // block_size)
+
+
+def work_rows(block_size: int) -> int:
+    """Rows of a work schedule: the fields, then a run's page ids."""
+    return WORK_FIELDS + pages_per_item(block_size)
+
 
 #: lane width of the scale-sidecar block the quantized kernel DMAs (the
 #: TPU's minor tile dimension)
@@ -103,24 +128,28 @@ def build_work_schedule(
 ) -> tuple["np.ndarray", int]:
     """Host-side sparse schedule for a mixed ragged batch.
 
-    Emits one work item per (query block, sequence, logical page) triple
-    whose page could be causally visible to some row of that sequence in
-    that block — the exact page set, so the kernel never DMAs a page no
-    row reads.  Windowed layers mask inside the kernel (the schedule is
-    shared across layers and some layers may be full-attention, so it
-    must cover the full causal span).  Every query block gets at least
-    one item (a dead one if the block is all padding) so its output
-    block is always initialised and finalised.
+    Emits one work item per (query block, sequence, run of pages)
+    triple: the logical pages that could be causally visible to some
+    row of that sequence in that block — the exact page set, so the
+    kernel never DMAs a page no row reads — cut into runs of
+    ``pages_per_item(block_size)``.  Windowed layers mask inside the
+    kernel (the schedule is shared across layers and some layers may be
+    full-attention, so it must cover the full causal span).  Every query
+    block gets at least one item (a dead one if the block is all
+    padding) so its output block is always initialised and finalised.
 
-    Returns ``(work, n_items)``: ``work`` is ``[WORK_FIELDS, W]`` int32
-    with W padded to a power of two (``w_bucket`` overrides) — the
+    Returns ``(work, n_items)``: ``work`` is ``[WORK_FIELDS + P, W]``
+    int32 with W padded to a power of two (``w_bucket`` overrides) — the
     schedule width is a compile shape — and ``n_items`` counts the real
     items at its head (the dead items of empty query blocks included).
     The last of them carries its block's ``last`` flag, so a grid that
     ends at ``n_items`` finalises every output block.
     """
+    run = pages_per_item(block_size)
     nq = t_pad // block_q
-    per_block: list[list[tuple[int, int, int, int]]] = [[] for _ in range(nq)]
+    # per item: (seq, real pages, first logical page, live, page ids)
+    dead = (0, 0, 0, 0, [0] * run)
+    per_block: list[list[tuple]] = [[] for _ in range(nq)]
     for s, (start, n_rows, pos0) in enumerate(spans):
         if n_rows <= 0:
             continue
@@ -131,21 +160,27 @@ def build_work_schedule(
             # can see: its last row's own position
             row_hi = min(start + n_rows - 1, (qb + 1) * block_q - 1)
             max_pos = pos0 + (row_hi - start)
-            for j in range(max_pos // block_size + 1):
-                per_block[qb].append((s, int(block_tables[s, j]), j, 1))
-    items: list[tuple[int, ...]] = []
+            pages = block_tables[s, : max_pos // block_size + 1].tolist()
+            for j in range(0, len(pages), run):
+                ids = pages[j: j + run]
+                # a short run repeats its last real page: the causal
+                # mask already excludes those columns
+                per_block[qb].append((
+                    s, len(ids), j, 1, ids + ids[-1:] * (run - len(ids)),
+                ))
+    items: list[list[int]] = []
     for qb in range(nq):
-        blk = per_block[qb] or [(0, 0, 0, 0)]  # dead item: zeros the block
-        for i, (s, page, j, live) in enumerate(blk):
-            items.append((
-                qb, s, page, j,
+        blk = per_block[qb] or [dead]  # dead item: zeros the block
+        for i, (s, n_pages, j, live, ids) in enumerate(blk):
+            items.append([
+                qb, s, n_pages, j,
                 1 if i == 0 else 0,
                 1 if i == len(blk) - 1 else 0,
-                live,
-            ))
+                live, *ids,
+            ])
     w = len(items)
     width = w_bucket or _pow2_ceil(w)
-    work = np.zeros((WORK_FIELDS, width), np.int32)
+    work = np.zeros((work_rows(block_size), width), np.int32)
     work[:, :w] = np.asarray(items, np.int32).T
     if width > w:
         # pads keep the final real block's index so the output pipeline
@@ -178,45 +213,56 @@ def decode_work_schedule(
     ``s // decode_block_q(S)``.
 
     Returns ``(work, n_items)``.  ``work`` keeps the static shape
-    ``[WORK_FIELDS, S * max_blocks]`` (every row at full context), but
-    only its first ``n_items`` entries mean anything: the live
-    (row, logical page) items in (query block, row, page) order — a row
-    owns ``pos_base // block_size + 1`` pages, a dead row none — plus
-    one dead item for each query block none of whose rows is live, so
-    every output block is still initialised and finalised.  The kernel's
-    grid ends at ``n_items`` (a traced scalar): what is not live costs
-    no grid step.
+    ``[WORK_FIELDS + P, S * cdiv(max_blocks, P)]`` (every row at full
+    context, ``P = pages_per_item(block_size)``), but only its first
+    ``n_items`` entries mean anything: the live (row, run of pages)
+    items in (query block, row, run) order — a row owns
+    ``pos_base // block_size + 1`` pages cut into runs of P, a dead row
+    none — plus one dead item for each query block none of whose rows is
+    live, so every output block is still initialised and finalised.  The
+    kernel's grid ends at ``n_items`` (a traced scalar): what is not
+    live costs no grid step.
     """
     s_count, max_blocks = block_tables.shape
+    run = pages_per_item(block_size)
+    max_runs = -(-max_blocks // run)
     block_q = decode_block_q(s_count)
     nq = -(-s_count // block_q)
     rows = jnp.arange(s_count, dtype=jnp.int32)
     pages = jnp.where(
         active, jnp.clip(pos_base // block_size + 1, 1, max_blocks), 0
     ).astype(jnp.int32)
-    block_pages = jnp.sum(
-        jnp.pad(pages, (0, nq * block_q - s_count)).reshape(nq, block_q),
+    runs = -(-pages // run)
+    block_runs = jnp.sum(
+        jnp.pad(runs, (0, nq * block_q - s_count)).reshape(nq, block_q),
         axis=1,
     )
     # the dead item of an empty block rides on the block's first row
     empty = (rows % block_q == 0) & (
-        jnp.take(block_pages, rows // block_q) == 0
+        jnp.take(block_runs, rows // block_q) == 0
     )
-    items = pages + empty.astype(jnp.int32)
+    items = runs + empty.astype(jnp.int32)
     ends = jnp.cumsum(items)
     n_items = ends[-1]
-    w = jnp.arange(s_count * max_blocks, dtype=jnp.int32)
+    w = jnp.arange(s_count * max_runs, dtype=jnp.int32)
     # entries past n_items clamp onto the last row: never visited
     s = jnp.minimum(
         jnp.searchsorted(ends, w, side="right").astype(jnp.int32),
         s_count - 1,
     )
-    j = w - (jnp.take(ends, s) - jnp.take(items, s))
+    r = w - (jnp.take(ends, s) - jnp.take(items, s))
     row_pages = jnp.take(pages, s)
-    live = j < row_pages
-    # a dead item's DMA index clamps to a page of its row's table
-    j_eff = jnp.clip(j, 0, jnp.maximum(row_pages - 1, 0))
-    page = jnp.clip(block_tables[s, j_eff], 0, None)
+    live = r < jnp.take(runs, s)
+    j = r * run  # the run's first logical page
+    n_pages = jnp.where(live, jnp.minimum(row_pages - j, run), 0)
+    # a short run repeats its last real page, and a dead item's DMA
+    # indices clamp to a page of its row's table: the causal mask
+    # already excludes those columns
+    j_eff = jnp.clip(
+        j[None, :] + jnp.arange(run, dtype=jnp.int32)[:, None],
+        0, jnp.maximum(row_pages - 1, 0)[None, :],
+    )
+    page_ids = jnp.clip(block_tables[s[None, :], j_eff], 0, None)
     qb = s // block_q
     # first/last flags on the block TRANSITIONS: blocks hold ragged
     # item counts, and every block's accumulators must init exactly
@@ -225,9 +271,12 @@ def decode_work_schedule(
     edge = jnp.ones(1, bool)
     first = jnp.concatenate([edge, step])
     last = jnp.concatenate([step, edge]) | (w == n_items - 1)
-    work = jnp.stack([
-        qb, s, page, j, first.astype(jnp.int32), last.astype(jnp.int32),
-        live.astype(jnp.int32),
+    work = jnp.concatenate([
+        jnp.stack([
+            qb, s, n_pages, j, first.astype(jnp.int32),
+            last.astype(jnp.int32), live.astype(jnp.int32),
+        ]),
+        page_ids,
     ])
     return work, n_items
 
@@ -237,20 +286,18 @@ def decode_work_schedule(
 
 def _ragged_kernel(
     # scalar prefetch
-    work_ref,  # [WORK_FIELDS, W] SMEM work schedule
+    work_ref,  # [WORK_FIELDS + P, W] SMEM work schedule
     starts_ref,  # [S+1] SMEM flat span starts (pads = padded length)
     base_ref,  # [S] SMEM global position of each span's first row
     alibi_ref,  # [H] f32 SMEM slopes; unused unless use_alibi
-    # blocks: q_ref [1, G*bq, Dh], k_ref/v_ref [1, block_size, Dh] (the
-    # page of the layer's pool picked by index_map), then — quantized
-    # caches only — ks_ref/vs_ref [Hkv, SCALE_LANES] f32 (the lane tile
-    # of the layer's scale sidecar that holds this page's dequant
-    # scale), then
-    # o_ref [1, G*bq, Dh] and the three f32 scratch accumulators
-    # (m [G*bq, 1], l [G*bq, 1], acc [G*bq, Dh])
+    # blocks: q_ref [1, G*bq, Dh]; P k refs then P v refs, each
+    # [1, block_size, Dh] (one page of the run, picked out of the
+    # layer's pool by its own index_map); then — quantized caches only —
+    # P ks refs and P vs refs, each [Hkv, SCALE_LANES] f32 (the lane
+    # tile of the layer's scale sidecar that holds that page's dequant
+    # scale); then o_ref [1, G*bq, Dh] and the three f32 scratch
+    # accumulators (m [G*bq, 1], l [G*bq, 1], acc [G*bq, Dh])
     q_ref,
-    k_ref,
-    v_ref,
     *refs,
     scale: float,
     block_size: int,
@@ -259,16 +306,18 @@ def _ragged_kernel(
     window: int,
     use_alibi: bool,
     quantized: bool,
+    run: int,
 ):
+    k_refs, v_refs, refs = refs[:run], refs[run: 2 * run], refs[2 * run:]
+    ks_refs = vs_refs = None
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = refs
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = refs
+        ks_refs, vs_refs = refs[:run], refs[run: 2 * run]
+        refs = refs[2 * run:]
+    o_ref, m_ref, l_ref, acc_ref = refs
     h = pl.program_id(0)
     w = pl.program_id(1)
     seq = work_ref[1, w]
-    page_pos = work_ref[3, w]
+    first_page = work_ref[3, w]
 
     @pl.when(work_ref[4, w] == 1)
     def _init():
@@ -276,30 +325,40 @@ def _ragged_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    def _tile(page_refs, scale_refs):
+        """The run's pages as ONE ``[P * bs, Dh]`` f32 tile."""
+        pages = []
+        for i, page_ref in enumerate(page_refs):
+            page = page_ref[0].astype(jnp.float32)  # [bs, Dh]
+            if scale_refs is not None:
+                # in-register dequant: each page segment of the tile
+                # has ONE per-(kv head, page) scale (ops/kv_quant.py
+                # sidecar).  Mosaic takes no 1x1 block of the
+                # [Hkv, pages] sidecar, so the block is the page's
+                # whole lane tile and the scalar is picked out of it by
+                # a masked reduce (one vreg for Hkv<=8)
+                tile = scale_refs[i].shape
+                pick = (
+                    jax.lax.broadcasted_iota(jnp.int32, tile, 0) == h
+                ) & (
+                    jax.lax.broadcasted_iota(jnp.int32, tile, 1)
+                    == work_ref[WORK_FIELDS + i, w] % SCALE_LANES
+                )
+                page = page * jnp.sum(
+                    jnp.where(pick, scale_refs[i][...], 0.0)
+                )
+            pages.append(page)
+        return pages[0] if run == 1 else jnp.concatenate(pages, axis=0)
+
     @pl.when(work_ref[6, w] == 1)
     def _item():
         q = q_ref[0].astype(jnp.float32)  # [G*bq, Dh]
-        k = k_ref[0].astype(jnp.float32)  # [bs, Dh]
-        v = v_ref[0].astype(jnp.float32)
-        if quantized:
-            # in-register dequant: the whole page tile shares ONE
-            # per-(kv head, page) scale (ops/kv_quant.py sidecar).
-            # Mosaic takes no 1x1 block of the [Hkv, pages] sidecar, so
-            # the block is the page's whole lane tile and the scalar is
-            # picked out of it by a masked reduce (one vreg for Hkv<=8)
-            tile = ks_ref.shape
-            pick = (
-                jax.lax.broadcasted_iota(jnp.int32, tile, 0) == h
-            ) & (
-                jax.lax.broadcasted_iota(jnp.int32, tile, 1)
-                == work_ref[2, w] % SCALE_LANES
-            )
-            k = k * jnp.sum(jnp.where(pick, ks_ref[...], 0.0))
-            v = v * jnp.sum(jnp.where(pick, vs_ref[...], 0.0))
+        k = _tile(k_refs, ks_refs)  # [P*bs, Dh]
+        v = _tile(v_refs, vs_refs)
         s_mat = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [G*bq, bs]
+        ) * scale  # [G*bq, P*bs]
         # rows are (g, i) flattened row-major (chunked-kernel layout):
         # flat token index = qb*bq + row % bq
         row = jax.lax.broadcasted_iota(jnp.int32, s_mat.shape, dimension=0)
@@ -312,7 +371,10 @@ def _ragged_kernel(
         start = starts_ref[seq]
         pos_row = base_ref[seq] + tok - start
         col = jax.lax.broadcasted_iota(jnp.int32, s_mat.shape, dimension=1)
-        k_pos = page_pos * block_size + col
+        # the run's pages are consecutive LOGICAL pages, so a column's
+        # position is its offset from the run's first page; the places
+        # of a short run lie past every row's own position
+        k_pos = first_page * block_size + col
         keep = (
             (tok >= start)
             & (tok < starts_ref[seq + 1])
@@ -371,7 +433,7 @@ def _ragged_attention_pallas(
     v_cache: jax.Array,  # one layer's [Hkv, num_slots, Dh] without
     seq_starts: jax.Array,  # [S+1]
     pos_base: jax.Array,  # [S]
-    work: jax.Array,  # [WORK_FIELDS, W]
+    work: jax.Array,  # [WORK_FIELDS + P, W]
     block_size: int,
     scale: float,
     *,
@@ -391,6 +453,12 @@ def _ragged_attention_pallas(
     )
     num_kv = k_cache.shape[1]
     g = num_heads // num_kv
+    run = pages_per_item(block_size)
+    if work.shape[0] != work_rows(block_size):
+        raise ValueError(
+            f"work schedule of {work.shape[0]} rows: block size "
+            f"{block_size} takes {WORK_FIELDS} fields and {run} page ids"
+        )
     block_q = min(block_q, _pow2_ceil(t))
     nq = pl.cdiv(t, block_q)
     t_pad = nq * block_q
@@ -416,31 +484,39 @@ def _ragged_attention_pallas(
             lambda h, w, wk, st, bs_, al: (h, wk[0, w], 0),
         ),
     ]
-    # the pool is ONE operand, never sliced: the layer is a static index
-    # in the page's index map, so the call moves only the pages its
-    # items name (a ``cache[layer]`` operand is a pool-sized copy a call)
-    page_spec = pl.BlockSpec(
-        (None, 1, block_size, head_dim),
-        lambda h, w, wk, st, bs_, al: (layer, h, wk[2, w], 0),
-    )
-    in_specs += [page_spec, page_spec]
-    operands = [qh, k_cache, v_cache]
-    if quantized:
-        # the layer's [Hkv, pages] scale sidecar travels as the
-        # tile-legal (Hkv, SCALE_LANES) block holding the item's
-        # physical page; consecutive items in one lane tile re-use the
-        # resident block
-        scale_spec = pl.BlockSpec(
-            (None, num_kv, SCALE_LANES),
-            lambda h, w, wk, st, bs_, al: (
-                layer, 0, wk[2, w] // SCALE_LANES
+    # the pool is ONE array, never sliced: the layer is a static index
+    # in a page's index map, so the call moves only the pages its items
+    # name (a ``cache[layer]`` operand is a pool-sized copy a call).  It
+    # is passed once per place of the run — by reference, no copy — and
+    # each place reads its own row of the schedule
+    page_specs = [
+        pl.BlockSpec(
+            (None, 1, block_size, head_dim),
+            lambda h, w, wk, st, bs_, al, i=i: (
+                layer, h, wk[WORK_FIELDS + i, w], 0
             ),
         )
-        in_specs += [scale_spec, scale_spec]
-        operands += [
-            kv_scales[0].astype(jnp.float32),
-            kv_scales[1].astype(jnp.float32),
+        for i in range(run)
+    ]
+    in_specs += page_specs * 2
+    operands = [qh, *[k_cache] * run, *[v_cache] * run]
+    if quantized:
+        # the layer's [Hkv, pages] scale sidecar travels as the
+        # tile-legal (Hkv, SCALE_LANES) block holding each place's
+        # physical page; consecutive items in one lane tile re-use the
+        # resident block
+        scale_specs = [
+            pl.BlockSpec(
+                (None, num_kv, SCALE_LANES),
+                lambda h, w, wk, st, bs_, al, i=i: (
+                    layer, 0, wk[WORK_FIELDS + i, w] // SCALE_LANES
+                ),
+            )
+            for i in range(run)
         ]
+        in_specs += scale_specs * 2
+        operands += [kv_scales[0].astype(jnp.float32)] * run
+        operands += [kv_scales[1].astype(jnp.float32)] * run
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(num_kv, num_work),
@@ -460,6 +536,7 @@ def _ragged_attention_pallas(
             _ragged_kernel, scale=scale, block_size=block_size,
             block_q=block_q, g=g, window=window,
             use_alibi=alibi_slopes is not None, quantized=quantized,
+            run=run,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
@@ -523,7 +600,7 @@ def ragged_paged_attention(
     block_size: int,
     scale: float,
     *,
-    work: jax.Array | None = None,  # [WORK_FIELDS, W]; the Pallas path's
+    work: jax.Array | None = None,  # [WORK_FIELDS + P, W]; the Pallas path's
     n_items: jax.Array | None = None,  # scalar: live prefix of ``work``
     mesh=None,
     window: int = 0,
@@ -548,8 +625,9 @@ def ragged_paged_attention(
     schedule-width shape variety cannot retrace the CPU path.
 
     ``kv_scales`` marks the caches as quantized pages (ops/kv_quant.py):
-    the Pallas kernel dequantizes each page tile in-register against its
-    one per-(kv head, page) scale, the XLA path right after its gather.
+    the Pallas kernel dequantizes each page of an item's run in-register
+    against its one per-(kv head, page) scale, the XLA path right after
+    its gather.
 
     Under a TP mesh the kernel runs inside shard_map over the head axis,
     the pool sharded on its kv-head axis — each device addresses the
