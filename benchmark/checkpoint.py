@@ -6,15 +6,24 @@ Copied in substance from ``chip_smoke.py`` (``write_granite_checkpoint``,
 PR cannot change what the benchmark serves.  Nothing in this file
 imports JAX.
 
-The weights are N(0, 0.02**2) in bf16.  One pool of 2**26 such values is
-drawn from the seed and every tensor is a slice of it at an offset drawn
-per tensor, so writing 5-7.5 GB is bound by the disk and not by the
-random generator (chip_smoke drew every value: 36.8 s for granite-2b).
+Which tensors a checkpoint holds, and how each is filled, is its
+family's to say (``families/<family>.py`` ``tensors``): name -> (shape,
+fill), where a fill is one of
+
+- ``("ones",)``;
+- ``("pool", std)``: N(0, std**2).  One pool of 2**26 standard normal
+  values is drawn from the seed and every such tensor, of any rank, is a
+  slice of it at an offset drawn per tensor, so writing 5-7.5 GB is bound
+  by the disk and not by the random generator (chip_smoke drew every
+  value: 36.8 s for granite-2b);
+- ``("values", function)``: ``function(shape, generator)`` returns the
+  values, for the few small tensors that are neither (a decay, a bias).
 """
 
 from __future__ import annotations
 
 import json
+import math
 import os
 import random
 import shutil
@@ -32,7 +41,6 @@ CORPUS = [
     "pack my box with five dozen liquor jugs",
 ]
 MARKER = "COMPLETE"
-WEIGHT_STD = 0.02
 POOL = 1 << 26
 LAYERS_PER_SHARD = 4
 
@@ -63,35 +71,10 @@ def build_tokenizer(path: Path, vocab_size: int) -> None:
     fast.save_pretrained(str(path))
 
 
-def tensor_shapes(cfg: dict) -> tuple[dict, list[dict]]:
-    """(shapes outside the layers, shapes of each layer) by HF llama
-    tensor name; granite and mistral use the same names."""
-    d, ffn = cfg["hidden_size"], cfg["intermediate_size"]
-    heads, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    dh = cfg.get("head_dim") or d // heads
-    top = {"model.embed_tokens.weight": (cfg["vocab_size"], d),
-           "model.norm.weight": (d,)}
-    if not cfg.get("tie_word_embeddings", False):
-        top["lm_head.weight"] = (cfg["vocab_size"], d)
-    layers = []
-    for i in range(cfg["num_hidden_layers"]):
-        p = f"model.layers.{i}"
-        layers.append({
-            f"{p}.input_layernorm.weight": (d,),
-            f"{p}.post_attention_layernorm.weight": (d,),
-            f"{p}.self_attn.q_proj.weight": (heads * dh, d),
-            f"{p}.self_attn.k_proj.weight": (kv * dh, d),
-            f"{p}.self_attn.v_proj.weight": (kv * dh, d),
-            f"{p}.self_attn.o_proj.weight": (d, heads * dh),
-            f"{p}.mlp.gate_proj.weight": (ffn, d),
-            f"{p}.mlp.up_proj.weight": (ffn, d),
-            f"{p}.mlp.down_proj.weight": (d, ffn),
-        })
-    return top, layers
-
-
-def write_checkpoint(path: Path, cfg: dict, seed: int, dtype: str) -> None:
-    """config.json + tokenizer + safetensors shards of seeded weights."""
+def write_checkpoint(path: Path, cfg: dict, seed: int, dtype: str,
+                     tensors) -> None:
+    """config.json + tokenizer + safetensors shards of seeded weights;
+    ``tensors(cfg)`` is the family's table."""
     import ml_dtypes
     import numpy as np
     from safetensors.numpy import save_file
@@ -101,25 +84,33 @@ def write_checkpoint(path: Path, cfg: dict, seed: int, dtype: str) -> None:
     path.mkdir(parents=True)
     (path / "config.json").write_text(json.dumps(cfg, indent=2))
 
+    top, layers = tensors(cfg)
     rng = np.random.default_rng([seed, 0])
-    pool = rng.standard_normal(POOL, dtype=np.float32)
-    pool *= WEIGHT_STD
-    pool = pool.astype(np_dtype)
-    pool = np.concatenate([pool, pool])  # a slice never wraps
+    unit = rng.standard_normal(POOL, dtype=np.float32)
+    pools = {}
+    for std in {fill[1] for table in (top, *layers)
+                for _, fill in table.values() if fill[0] == "pool"}:
+        pool = (unit * std).astype(np_dtype)
+        pools[std] = np.concatenate([pool, pool])  # a slice never wraps
+    del unit
 
-    def tensor(shape: tuple, trng) -> "np.ndarray":
-        if len(shape) == 1:
-            return np.ones(shape, np_dtype)  # norm weights
-        rows, cols = shape
+    def tensor(shape: tuple, fill: tuple, trng) -> "np.ndarray":
+        if fill[0] == "ones":
+            return np.ones(shape, np_dtype)
+        if fill[0] == "values":
+            return np.asarray(fill[1](shape, trng)).astype(np_dtype)
+        if fill[0] != "pool":
+            raise ValueError(f"unknown fill {fill!r}")
+        rows, cols = shape[0], math.prod(shape[1:])
         step = max(1, POOL // cols)
         parts = []
         for lo in range(0, rows, step):
             n = (min(rows, lo + step) - lo) * cols
             off = int(trng.integers(POOL))
-            parts.append(pool[off:off + n].reshape(-1, cols))
-        return parts[0] if len(parts) == 1 else np.concatenate(parts)
+            parts.append(pools[fill[1]][off:off + n])
+        flat = parts[0] if len(parts) == 1 else np.concatenate(parts)
+        return flat.reshape(shape)
 
-    top, layers = tensor_shapes(cfg)
     groups = [layers[lo:lo + LAYERS_PER_SHARD]
               for lo in range(0, len(layers), LAYERS_PER_SHARD)]
     shards = [{k: v for layer in g for k, v in layer.items()} for g in groups]
@@ -129,10 +120,10 @@ def write_checkpoint(path: Path, cfg: dict, seed: int, dtype: str) -> None:
 
     def write_shard(index: int) -> dict[str, str]:
         trng = np.random.default_rng([seed, 1, index])
-        tensors = {name: tensor(shape, trng)
-                   for name, shape in shards[index].items()}
-        save_file(tensors, str(path / names[index]))
-        return {name: names[index] for name in tensors}
+        made = {name: tensor(shape, fill, trng)
+                for name, (shape, fill) in shards[index].items()}
+        save_file(made, str(path / names[index]))
+        return {name: names[index] for name in made}
 
     weight_map: dict[str, str] = {}
     with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as workers:
@@ -147,10 +138,11 @@ def write_checkpoint(path: Path, cfg: dict, seed: int, dtype: str) -> None:
 
 
 def ensure_checkpoint(work: Path, name: str, cfg: dict, seed: int,
-                      dtype: str) -> tuple[Path, bool]:
-    """The checkpoint of ``name`` under ``seed``, written unless it is
-    there whole; checkpoints of the same configuration under other seeds
-    go first, so one is on disk at most.  Returns (path, reused)."""
+                      dtype: str, tensors) -> tuple[Path, bool]:
+    """The checkpoint of ``name`` under ``seed``, written from its
+    family's ``tensors`` unless it is there whole; checkpoints of the same
+    configuration under other seeds go first, so one is on disk at most.
+    Returns (path, reused)."""
     path = work / f"{name}-seed{seed}"
     marker = path / MARKER
     want = {"seed": seed, "config": cfg}
@@ -159,7 +151,7 @@ def ensure_checkpoint(work: Path, name: str, cfg: dict, seed: int,
     work.mkdir(parents=True, exist_ok=True)
     for other in work.glob(f"{name}-seed*"):
         shutil.rmtree(other, ignore_errors=True)
-    write_checkpoint(path, cfg, seed, dtype)
+    write_checkpoint(path, cfg, seed, dtype, tensors)
     return path, False
 
 

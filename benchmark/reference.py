@@ -1,13 +1,16 @@
-"""The plain reference: a float32 numpy forward pass of the dense decoder
-block (RMSNorm, RoPE, grouped-query softmax attention, SwiGLU, and
-granite's four multipliers), independent of the package: no cache, no
-batching, no kernel.  It streams one layer at a time from the
-checkpoint's safetensors, so it holds one layer in float32 at most.
+"""The plain reference's command line and what its families share.
 
-``score`` takes whole sequences (prompt + the tokens the SERVER chose)
-and returns the reference's logprob of each generated token: the first
-comes from the server's prefill step, the rest from decode through its
-paged cache, so both paths are held to the full forward pass.
+A family's reference is ``families/<family>.py`` ``score``: a float32
+numpy forward pass of that family's block, independent of the package:
+no cache, no batching, no kernel.  It takes whole sequences (prompt + the
+tokens the SERVER chose) and returns the reference's logprob of each
+generated token: the first comes from the server's prefill step, the
+rest from decode through its paged cache, so both paths are held to the
+full forward pass.
+
+Here: the checkpoint's tensors by name (``Weights``), RMSNorm, RoPE and
+the softmax tail, which are the same mathematics in every family so far.
+The tolerance is the family's.
 
 Nothing here imports JAX.
 """
@@ -19,16 +22,6 @@ from pathlib import Path
 
 import ml_dtypes  # noqa: F401 - registers bfloat16 with numpy for safetensors
 import numpy as np
-
-#: Largest |server logprob - reference logprob| allowed for a generated
-#: token.  The server multiplies in bfloat16 (8 bits of mantissa,
-#: relative rounding 2**-9 per product, summed in float32) and the
-#: reference in float32 on the same bf16 weights.  Measured on the chip
-#: at published widths (my chip runs, PR 25): see PERF.md section 6; the
-#: bound is about three times the largest difference seen.  A float8
-#: (e4m3: 2**-4 per product) or int8 path errs some sixteen times more
-#: per product than bf16 and lands well outside it.
-LOGPROB_TOLERANCE = 0.03
 
 
 class Weights:
@@ -56,6 +49,11 @@ class Weights:
             self._files[shard].get_tensor(name)
         ).astype(np.float32)
 
+    def close(self) -> None:
+        """Forget the shards opened so far (a family calls this between
+        layers: one layer's shard open at a time is enough)."""
+        self._files.clear()
+
 
 def rms_norm(x: np.ndarray, w: np.ndarray, eps: float) -> np.ndarray:
     return x / np.sqrt(np.mean(x * x, axis=-1, keepdims=True) + eps) * w
@@ -74,81 +72,27 @@ def rope(x: np.ndarray, theta: float) -> np.ndarray:
     return x * cos + rotated * sin
 
 
-LAYER_TENSORS = (
-    "input_layernorm", "post_attention_layernorm", "self_attn.q_proj",
-    "self_attn.k_proj", "self_attn.v_proj", "self_attn.o_proj",
-    "mlp.gate_proj", "mlp.up_proj", "mlp.down_proj",
-)
-
-
-def layer(x: np.ndarray, lw: dict, cfg: dict) -> np.ndarray:
-    """One decoder block on one sequence; ``lw`` holds its tensors."""
-    heads, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    dh = cfg.get("head_dim") or cfg["hidden_size"] // heads
-    eps = cfg["rms_norm_eps"]
-    res = cfg.get("residual_multiplier", 1.0)
-    scale = cfg.get("attention_multiplier", dh ** -0.5)
-    t = x.shape[0]
-
-    h = rms_norm(x, lw["input_layernorm"], eps)
-    q = (h @ lw["self_attn.q_proj"].T).reshape(t, heads, dh)
-    k = (h @ lw["self_attn.k_proj"].T).reshape(t, kv, dh)
-    v = (h @ lw["self_attn.v_proj"].T).reshape(t, kv, dh)
-    q, k = rope(q, cfg["rope_theta"]), rope(k, cfg["rope_theta"])
-    k = np.repeat(k, heads // kv, axis=1)
-    v = np.repeat(v, heads // kv, axis=1)
-    scores = np.einsum("thd,shd->hts", q, k) * scale
-    scores = np.where(np.tril(np.ones((t, t), bool))[None], scores, -np.inf)
-    scores -= scores.max(-1, keepdims=True)
-    probs = np.exp(scores)
-    probs /= probs.sum(-1, keepdims=True)
-    attn = np.einsum("hts,shd->thd", probs, v).reshape(t, heads * dh)
-    x = x + res * (attn @ lw["self_attn.o_proj"].T)
-
-    h = rms_norm(x, lw["post_attention_layernorm"], eps)
-    gate = h @ lw["mlp.gate_proj"].T
-    up = h @ lw["mlp.up_proj"].T
-    act = gate / (1.0 + np.exp(-gate)) * up  # SiLU(gate) * up
-    return x + res * (act @ lw["mlp.down_proj"].T)
-
-
-def score(model_dir: Path, cfg: dict, sequences: list[list[int]],
-          prompt_lens: list[int]) -> list[list[float]]:
-    """For each sequence (prompt ids + generated ids), the reference's
-    logprob of every generated token given all tokens before it."""
-    w = Weights(model_dir)
-    embed = w.get("model.embed_tokens.weight")
-    xs = [
-        embed[np.asarray(ids)] * cfg.get("embedding_multiplier", 1.0)
-        for ids in sequences
-    ]
-    for i in range(cfg["num_hidden_layers"]):
-        lw = {n: w.get(f"model.layers.{i}.{n}.weight") for n in LAYER_TENSORS}
-        xs = [layer(x, lw, cfg) for x in xs]
-        w._files.clear()  # one layer's shard open at a time is enough
-    head = embed if not w.has("lm_head.weight") else w.get("lm_head.weight")
-    norm = w.get("model.norm.weight")
-    out = []
-    for x, ids, n_prompt in zip(xs, sequences, prompt_lens):
-        rows = rms_norm(x[n_prompt - 1:-1], norm, cfg["rms_norm_eps"])
-        logits = rows @ head.T / cfg.get("logits_scaling", 1.0)
-        logits -= logits.max(-1, keepdims=True)
-        logprobs = logits - np.log(np.exp(logits).sum(-1, keepdims=True))
-        chosen = ids[n_prompt:]
-        out.append([float(logprobs[j, tok]) for j, tok in enumerate(chosen)])
-    return out
+def chosen_logprobs(logits: np.ndarray, chosen: list[int]) -> list[float]:
+    """Row j of ``logits`` is the model's output before token ``chosen[j]``:
+    the log-softmax of each row at its chosen token."""
+    logits -= logits.max(-1, keepdims=True)
+    logprobs = logits - np.log(np.exp(logits).sum(-1, keepdims=True))
+    return [float(logprobs[j, tok]) for j, tok in enumerate(chosen)]
 
 
 def main() -> None:
     """``python3 benchmark/reference.py <job.json>``: a job holds
-    ``model_dir``, ``config``, ``sequences`` and ``prompt_lens``; prints
-    the scores as one JSON line.  ``run.py`` starts this as a child with a
-    few threads while the server warms up."""
+    ``family``, ``model_dir``, ``config``, ``sequences`` and
+    ``prompt_lens``; prints the scores of ``families/<family>.py`` as one
+    JSON line.  ``run.py`` starts this as a child with a few threads
+    while the server warms up."""
+    import importlib
     import sys
 
     job = json.loads(Path(sys.argv[1]).read_text())
-    print(json.dumps(score(Path(job["model_dir"]), job["config"],
-                           job["sequences"], job["prompt_lens"])))
+    family = importlib.import_module(f"families.{job['family']}")
+    print(json.dumps(family.score(Path(job["model_dir"]), job["config"],
+                                  job["sequences"], job["prompt_lens"])))
 
 
 if __name__ == "__main__":

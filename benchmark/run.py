@@ -12,7 +12,9 @@ The last line of standard output is the result.
 
 A cell is ``cells/<name>.json``, a configuration ``configs/<name>.json``,
 a traffic mix ``traffic/<name>.json``, a per-layer metric
-``layer_metrics/<name>.json`` read by ``readers/<kind>.py``: all found by
+``layer_metrics/<name>.json`` read by ``readers/<kind>.py``, and a model
+family (tensor table, fills, plain reference, tolerance)
+``families/<family>.py``, named by its configurations: all found by
 name, so a new one is a new file.  Which metrics a cell prints is what
 ``BENCHMARK.json`` lists for it; a cell it does not list (the rehearsal)
 prints every metric there is.  A server that is not on the TPU with
@@ -26,6 +28,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import importlib
+import importlib.util
 import json
 import os
 import random
@@ -51,7 +54,7 @@ from server import Server, check_device  # noqa: E402
 WORK = HERE / ".work"
 #: keys of a configuration file that are not the model's config.json
 NOT_HF = {"source", "assumed", "assumed_why", "reduced", "reduced_why",
-          "deployment", "dtype", "serve_args", "chips", "why"}
+          "deployment", "dtype", "serve_args", "chips", "why", "family"}
 REFERENCE_PROMPTS = (48, 145)  # one inside a page run, one across pages
 REFERENCE_TOKENS = 17  # 1 from prefill + 16 from decode through the cache
 PROFILE_AFTER_S = 3.0
@@ -74,6 +77,20 @@ def load(kind: str, name: str) -> dict:
     return json.loads(path.read_text())
 
 
+def served_config(config: dict) -> dict:
+    """The model's ``config.json`` of a configuration file."""
+    return {k: v for k, v in config.items() if k not in NOT_HF}
+
+
+def load_family(name: str):
+    """``families/<name>.py``, wherever on the path a ``families``
+    directory holds it."""
+    if importlib.util.find_spec(f"families.{name}") is None:
+        raise SystemExit(f"no family {name!r}: "
+                         f"{HERE / 'families' / f'{name}.py'} is missing")
+    return importlib.import_module(f"families.{name}")
+
+
 def listed(cell_name: str, kind: str) -> dict[str, str]:
     """name -> unit of the ``end_to_end`` or ``per_layer`` metrics that
     ``BENCHMARK.json`` lists for this cell.  A cell it does not list (the
@@ -90,6 +107,40 @@ def listed(cell_name: str, kind: str) -> dict[str, str]:
     return {m["name"]: m["unit"] for m in files}
 
 
+async def start_reference(job: Path, family: str, model_dir: Path,
+                          hf_config: dict, sequences: list[list[int]],
+                          prompt_lens: list[int]):
+    """``reference.py`` on ``job`` as a child with a few threads: the
+    family's ``score`` of the sequences, one JSON line on its output."""
+    job.write_text(json.dumps({
+        "family": family, "model_dir": str(model_dir), "config": hf_config,
+        "sequences": sequences, "prompt_lens": prompt_lens}))
+    threads = {k: "4" for k in ("OMP_NUM_THREADS", "OPENBLAS_NUM_THREADS",
+                                "MKL_NUM_THREADS")}
+    return await asyncio.create_subprocess_exec(
+        sys.executable, str(HERE / "reference.py"), str(job),
+        env=dict(os.environ, **threads),
+        stdout=asyncio.subprocess.PIPE, stderr=asyncio.subprocess.PIPE)
+
+
+def judge(served: list[list[float]], scored: list[list[float]],
+          tolerance: float) -> dict:
+    """The served logprobs of the reference requests' tokens against the
+    reference's.  ``reference_logprob_spread`` is there to be looked at:
+    fills that leave the logits flat make every logprob -ln(vocabulary)
+    and the comparison passes whatever the server computed."""
+    diffs = [abs(a - b) for got, want in zip(served, scored)
+             for a, b in zip(got, want)]
+    flat = [b for want in scored for b in want]
+    return {"ok": max(diffs) <= tolerance,
+            "max_abs_logprob_diff": max(diffs),
+            "tolerance": tolerance,
+            "tokens_scored": len(diffs),
+            "reference_logprob_spread": max(flat) - min(flat),
+            "first_token_diffs": [abs(g[0] - w[0]) for g, w in
+                                  zip(served, scored)]}
+
+
 class Session:
     """A booted, checked server with its client, prompts and traffic."""
 
@@ -99,16 +150,15 @@ class Session:
         self.cell = load("cells", cell_name)
         self.config = load("configs", self.cell["config"])
         self.traffic = traffic_mod.load_traffic(self.cell["traffic"])
-        self.hf_config = {
-            k: v for k, v in self.config.items() if k not in NOT_HF
-        }
+        self.family = load_family(self.config["family"])
+        self.hf_config = served_config(self.config)
         # protoc runs HERE, once, and never in the child at the same time
         import vllm_tgis_adapter_tpu.grpc.pb  # noqa: F401
 
         t = time.monotonic()
         self.model_dir, reused = checkpoint.ensure_checkpoint(
             WORK, self.cell["config"], self.hf_config, seed,
-            self.config["dtype"],
+            self.config["dtype"], self.family.tensors,
         )
         say(phase="checkpoint", reused=reused,
             seconds=round(time.monotonic() - t, 2))
@@ -248,32 +298,17 @@ class Session:
                                    "the reference cannot score it")
             sequences.append(self.maker.ids(prompt) + ids)
             self.ref_served.append([lp for _, lp in record.tokens])
-        job = WORK / "reference_job.json"
-        job.write_text(json.dumps({
-            "model_dir": str(self.model_dir), "config": self.hf_config,
-            "sequences": sequences, "prompt_lens": list(REFERENCE_PROMPTS)}))
-        threads = {k: "4" for k in ("OMP_NUM_THREADS", "OPENBLAS_NUM_THREADS",
-                                    "MKL_NUM_THREADS")}
-        self.ref_child = await asyncio.create_subprocess_exec(
-            sys.executable, str(HERE / "reference.py"), str(job),
-            env=dict(os.environ, **threads),
-            stdout=asyncio.subprocess.PIPE, stderr=asyncio.subprocess.PIPE)
+        self.ref_child = await start_reference(
+            WORK / "reference_job.json", self.config["family"],
+            self.model_dir, self.hf_config, sequences,
+            list(REFERENCE_PROMPTS))
 
     async def reference_verdict(self) -> dict:
-        import reference
-
         out, err = await self.ref_child.communicate()
         if self.ref_child.returncode != 0:
             return {"ok": False, "error": err.decode()[-500:]}
         scored = json.loads(out.decode().strip().splitlines()[-1])
-        diffs = [abs(a - b) for got, want in zip(self.ref_served, scored)
-                 for a, b in zip(got, want)]
-        return {"ok": max(diffs) <= reference.LOGPROB_TOLERANCE,
-                "max_abs_logprob_diff": max(diffs),
-                "tolerance": reference.LOGPROB_TOLERANCE,
-                "tokens_scored": len(diffs),
-                "first_token_diffs": [abs(g[0] - w[0]) for g, w in
-                                      zip(self.ref_served, scored)]}
+        return judge(self.ref_served, scored, self.family.LOGPROB_TOLERANCE)
 
     async def close(self) -> None:
         if self.client:
@@ -502,8 +537,17 @@ def main() -> None:
             seen["scrape0"], seen["scrape1"], "tgis_tpu_xla_recompile_total"))
     if "jax" in sys.modules:
         raise RuntimeError("the parent imported jax: it could hold the chip")
-    print(json.dumps({**result, "metrics": metrics, "device": out_device}),
-          flush=True)
+    # what ``correct`` compared, beside its limit: last on standard error
+    # and last in the line
+    verdict = got["reference"]
+    compared = {"max_abs_logprob_diff": {
+        "value": verdict["max_abs_logprob_diff"],
+        "limit": verdict["tolerance"]}} if "tolerance" in verdict else {}
+    for name, pair in compared.items():
+        print(f"compared {name} {pair['value']} limit {pair['limit']}",
+              file=sys.stderr, flush=True)
+    print(json.dumps({**result, "metrics": metrics, "device": out_device,
+                      "compared": compared}), flush=True)
 
 
 if __name__ == "__main__":
