@@ -867,3 +867,115 @@ def quantize_checkpoint_int4(src_dir, dst_dir, *, method="awq",
         }
     cfg_path.write_text(json.dumps(cfg, indent=2))
     return str(dst)
+
+
+# every muP multiplier away from 1, so that a forward pass that drops
+# one of them disagrees with the reference
+TINY_FALCON_H1_CONFIG = {
+    "architectures": ["FalconH1ForCausalLM"],
+    "model_type": "falcon_h1",
+    "vocab_size": 512,
+    "hidden_size": 64,
+    "intermediate_size": 128,
+    "num_hidden_layers": 3,
+    "num_attention_heads": 4,
+    "num_key_value_heads": 2,
+    "head_dim": 16,
+    "mamba_d_ssm": 64,
+    "mamba_n_heads": 4,
+    "mamba_d_head": 16,
+    "mamba_d_state": 16,
+    "mamba_n_groups": 2,
+    "mamba_d_conv": 4,
+    "mamba_chunk_size": 8,
+    "mamba_expand": 1,
+    "mamba_conv_bias": True,
+    "mamba_proj_bias": False,
+    "mamba_rms_norm": True,
+    "mamba_norm_before_gate": False,
+    "mamba_use_mlp": True,
+    "attention_bias": False,
+    "mlp_bias": False,
+    "projectors_bias": False,
+    "hidden_act": "silu",
+    "attention_in_multiplier": 0.9,
+    "attention_out_multiplier": 0.7,
+    "key_multiplier": 0.6,
+    "embedding_multiplier": 3.0,
+    "lm_head_multiplier": 0.25,
+    "ssm_in_multiplier": 0.8,
+    "ssm_out_multiplier": 0.5,
+    "ssm_multipliers": [0.7, 1.3, 0.6, 1.4, 0.8],
+    "mlp_multipliers": [1.2, 0.45],
+    "max_position_embeddings": 512,
+    "rope_theta": 10000.0,
+    "rope_scaling": None,
+    "rms_norm_eps": 1e-5,
+    "tie_word_embeddings": False,
+    "bos_token_id": 1,
+    "eos_token_id": 2,
+    "torch_dtype": "float32",
+}
+
+
+def build_tiny_falcon_h1(path: str, seed: int = 0, **overrides) -> str:
+    """Tiny Falcon-H1 checkpoint in Hugging Face's ``falcon_h1`` tensor
+    names: attention and a Mamba-2 mixer side by side in each of three
+    layers.  ``A_log``, ``dt_bias`` and ``D`` are filled as Mamba-2
+    publishes them; the matrices at 1/sqrt(fan in), so that all three
+    branches of a layer are of comparable size."""
+    import numpy as np
+    from safetensors.numpy import save_file
+
+    out = Path(path)
+    out.mkdir(parents=True, exist_ok=True)
+    build_tokenizer(path)
+    cfg = dict(TINY_FALCON_H1_CONFIG, **overrides)
+    with open(out / "config.json", "w") as f:
+        json.dump(cfg, f, indent=2)
+
+    rng = np.random.default_rng(seed)
+    d, ffn, vocab = (cfg["hidden_size"], cfg["intermediate_size"],
+                     cfg["vocab_size"])
+    h, kv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                 cfg["head_dim"])
+    mh, d_ssm = cfg["mamba_n_heads"], cfg["mamba_d_ssm"]
+    conv_dim = d_ssm + 2 * cfg["mamba_n_groups"] * cfg["mamba_d_state"]
+
+    def w(shape, fan_in=None):
+        std = (fan_in or shape[-1]) ** -0.5
+        return (rng.standard_normal(shape) * std).astype(np.float32)
+
+    tensors = {
+        "model.embed_tokens.weight": w((vocab, d), 1.0),
+        "model.final_layernorm.weight": np.ones(d, np.float32),
+        "lm_head.weight": w((vocab, d)),
+    }
+    for i in range(cfg["num_hidden_layers"]):
+        p = f"model.layers.{i}"
+        dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), mh))
+        tensors |= {
+            f"{p}.input_layernorm.weight": np.ones(d, np.float32),
+            f"{p}.pre_ff_layernorm.weight": np.ones(d, np.float32),
+            f"{p}.self_attn.q_proj.weight": w((h * dh, d)),
+            f"{p}.self_attn.k_proj.weight": w((kv * dh, d)),
+            f"{p}.self_attn.v_proj.weight": w((kv * dh, d)),
+            f"{p}.self_attn.o_proj.weight": w((d, h * dh)),
+            f"{p}.mamba.in_proj.weight": w((d_ssm + conv_dim + mh, d)),
+            f"{p}.mamba.conv1d.weight": w(
+                (conv_dim, 1, cfg["mamba_d_conv"]), 4.0),
+            f"{p}.mamba.conv1d.bias": w((conv_dim,), 16.0),
+            f"{p}.mamba.dt_bias": (dt + np.log(-np.expm1(-dt))).astype(
+                np.float32),
+            f"{p}.mamba.A_log": np.log(rng.uniform(1.0, 16.0, mh)).astype(
+                np.float32),
+            f"{p}.mamba.D": np.ones(mh, np.float32),
+            f"{p}.mamba.norm.weight": (
+                1.0 + 0.1 * rng.standard_normal(d_ssm)).astype(np.float32),
+            f"{p}.mamba.out_proj.weight": w((d, d_ssm)),
+            f"{p}.feed_forward.gate_proj.weight": w((ffn, d)),
+            f"{p}.feed_forward.up_proj.weight": w((ffn, d)),
+            f"{p}.feed_forward.down_proj.weight": w((d, ffn)),
+        }
+    save_file(tensors, out / "model.safetensors")
+    return str(out)

@@ -601,3 +601,144 @@ def test_lane_padded_pool_serves_the_same_tokens(tiny_model_dir,
     padded, width = serve()
     assert width == 128
     assert padded == plain
+
+
+# ---------------------------------------------------------------------------
+# Falcon-H1: a Mamba-2 mixer beside attention, state beside the pool
+# ---------------------------------------------------------------------------
+
+
+def _falcon_h1_34b_l6():
+    """``benchmark/configs/falcon-h1-34b-instruct-l6.json``: the
+    published widths of Falcon-H1-34B-Instruct at six layers."""
+    import json
+    from pathlib import Path
+
+    from vllm_tgis_adapter_tpu.engine.config import ModelConfig
+
+    path = (Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+            / "falcon-h1-34b-instruct-l6.json")
+    return ModelConfig.from_hf_config(
+        "falcon-h1-34b-instruct-l6", json.loads(path.read_text()),
+        max_model_len=4096, dtype="bfloat16",
+    )
+
+
+def _falcon_on_chip(one_chip, cfg, rows=64):
+    """The model, its parameters' shapes, and its caches as the runner
+    builds them: the page pool ``resolve_num_blocks`` would size after
+    the weights AND the state slab, then the slab."""
+    from vllm_tgis_adapter_tpu.models.falcon_h1 import FalconH1ForCausalLM
+
+    model = FalconH1ForCausalLM(cfg)
+
+    def on_chip_shapes(tree):
+        return jax.tree.map(
+            lambda leaf: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = on_chip_shapes(
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    weight_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(params))
+    state_bytes = rows * cfg.mixer.state_bytes_per_row(cfg.num_layers, 2)
+    page_bytes = 2 * cfg.num_layers * cfg.num_kv_heads * BLOCK * 128 * 2
+    pages = (int(HBM_BYTES * 0.9) - weight_bytes - state_bytes) // page_bytes
+    # beside weights and slab the pool is budget-bound: at this file's
+    # 16e9-byte chip 11.5k pages (185k tokens), at the 15.75 GiB a v5e
+    # reports ~15.6k, under the 16,384 (64 rows x 4,096 tokens) it would
+    # be capped at (PERF.md section 4); every row can hold half of
+    # max_model_len at once even here
+    assert pages * BLOCK >= 64 * 2048, pages
+    caches = on_chip_shapes(jax.eval_shape(
+        lambda: (*model.make_kv_caches(pages * BLOCK, cfg.dtype),
+                 model.make_state(rows))))
+    held = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(caches))
+    assert held == 2 * caches[0].size * 2 + state_bytes
+    return model, params, caches, held
+
+
+def _assert_state_in_place_and_fits(compiled, held):
+    """Pool and slab are both donated and updated in place, the update
+    kernel is in the program under its own name, and weights + pool +
+    slab + temporaries fit one 16 GB chip."""
+    from vllm_tgis_adapter_tpu.ops.ssm_scan import DECODE_KERNEL_NAME
+
+    import re
+
+    text = compiled.as_text()
+    # the kernel itself, by the name the device trace will show (a
+    # named_scope of the same name is in every op's metadata)
+    assert re.search(
+        rf"%{DECODE_KERNEL_NAME}[.\w]* = [^\n]*custom-call\([^\n]*"
+        r'custom_call_target="tpu_custom_call"', text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == held
+    total = (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert total < HBM_BYTES, (total, mem.temp_size_in_bytes)
+    return mem
+
+
+@pytest.mark.parametrize("t", [512, 4096])
+def test_falcon_h1_ragged_step_compiles_and_fits(topo, one_chip, on_chip, t):
+    """The mixed step of Falcon-H1-34B at its published widths and six
+    layers: attention at head dim 128 over the unpadded pool, the
+    one-token update kernel over the slab, and the chunked scan's loop
+    over pieces, in one program that fits beside pool and slab."""
+    cfg = _falcon_h1_34b_l6()
+    model, params, caches, held = _falcon_on_chip(one_chip, cfg)
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    seqs = 64
+    step = jax.jit(
+        functools.partial(model.ragged_forward, block_size=BLOCK),
+        donate_argnums=(1,),
+    )
+    compiled = step.lower(
+        params, caches, spec((t,), jnp.int32), spec((t,), jnp.int32),
+        spec((t,), jnp.int32), spec((seqs + 1,), jnp.int32),
+        spec((seqs,), jnp.int32), spec((), jnp.int32),
+        spec((seqs, 4096 // BLOCK), jnp.int32), spec((seqs,), jnp.int32),
+        work=spec((ra.work_rows(BLOCK), 512), jnp.int32),
+        n_items=spec((), jnp.int32),
+        state_slots=spec((seqs,), jnp.int32),
+    ).compile(compiler_options=TPU_STEP_COMPILER_OPTIONS)
+    _assert_state_in_place_and_fits(compiled, held)
+
+
+def test_falcon_h1_decode_wave_compiles_and_fits(topo, one_chip, on_chip):
+    """The fused wave of Falcon-H1-34B at six layers: the loop of traced
+    length carries pool and slab in place; each step one attention call
+    and one ``ssm_decode_update`` a layer."""
+    cfg = _falcon_h1_34b_l6()
+    model, params, caches, held = _falcon_on_chip(one_chip, cfg)
+    b, max_blocks = 64, 4096 // BLOCK
+
+    def wave(params, caches, tokens, pos0, limits, tables, rows, num_steps):
+        def step(k, carry):
+            caches, tokens = carry
+            pos = pos0 + k
+            blk = jnp.take_along_axis(
+                tables, (pos // BLOCK)[:, None], axis=1)[:, 0]
+            slot = jnp.where(pos <= limits, blk * BLOCK + pos % BLOCK, -1)
+            logits, caches = model.decode(
+                params, caches, tokens, pos, slot, tables, pos + 1, BLOCK,
+                state_slots=rows,
+            )
+            return caches, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        return jax.lax.fori_loop(0, num_steps, step, (caches, tokens))
+
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(wave, donate_argnums=(1,)).lower(
+        params, caches, spec((b,), jnp.int32), spec((b,), jnp.int32),
+        spec((b,), jnp.int32), spec((b, max_blocks), jnp.int32),
+        spec((b,), jnp.int32), spec((), jnp.int32),
+    ).compile(compiler_options=TPU_STEP_COMPILER_OPTIONS)
+    mem = _assert_state_in_place_and_fits(compiled, held)
+    assert mem.temp_size_in_bytes < 512 << 20

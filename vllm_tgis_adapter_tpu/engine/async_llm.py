@@ -1405,6 +1405,7 @@ class AsyncLLMEngine:
         allocators = [e.scheduler.allocator for e in engines]
         num_blocks = sum(a.num_blocks for a in allocators)
         used = num_blocks - sum(a.num_free for a in allocators)
+        state = [e.state_slot_usage() for e in engines]
         # requests parked in the front-door fair queue are "waiting" in
         # every operational sense (they count against the bound and the
         # autoscaler should see them), they just haven't reached a
@@ -1422,6 +1423,8 @@ class AsyncLLMEngine:
                 kv_used=used,
                 kv_total=num_blocks,
                 prefix_hits=sum(a.prefix_hits for a in allocators),
+                state_used=sum(u for u, _ in state),
+                state_total=sum(t for _, t in state),
             )
             # per-tier prefix hit rates (docs/KV_TIERING.md): tokens
             # served from each tier over prompt tokens that consulted

@@ -38,6 +38,55 @@ def resolve_dtype(name: str, default: str = "bfloat16") -> Any:
 
 
 @dataclasses.dataclass(frozen=True)
+class MixerConfig:
+    """A Mamba-2 mixer beside the attention of every layer (``falcon_h1``;
+    models/falcon_h1.py), with the family's muP multipliers: what the
+    llama lineage has no field for.  Its presence on a ModelConfig is
+    what "a model with recurrent state" means to the engine: a state
+    slab beside the KV pool (engine/kv_cache.py, docs/STATE.md)."""
+
+    d_ssm: int  # the mixer's inner width = num_heads * head_dim
+    num_heads: int
+    head_dim: int
+    d_state: int
+    n_groups: int  # B and C are shared by num_heads / n_groups heads
+    d_conv: int  # taps of the causal depthwise convolution
+    chunk_size: int
+    rms_norm: bool  # gated RMSNorm (after the gate) on the mixer's output
+    # muP scalars, all read from config.json
+    attention_in_multiplier: float
+    attention_out_multiplier: float
+    key_multiplier: float
+    ssm_in_multiplier: float
+    ssm_out_multiplier: float
+    ssm_multipliers: tuple[float, ...]  # over z, x, B, C, dt of in_proj
+    mlp_multipliers: tuple[float, float]  # gate, down
+    lm_head_multiplier: float
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels through the convolution: x, then B and C."""
+        return self.d_ssm + 2 * self.n_groups * self.d_state
+
+    @property
+    def in_proj_dim(self) -> int:
+        """Columns of ``in_proj``: gate z, the conv channels, dt."""
+        return self.d_ssm + self.conv_dim + self.num_heads
+
+    @property
+    def scan_state_bytes(self) -> int:
+        """The float32 scan state of one sequence in one layer."""
+        return self.num_heads * self.d_state * self.head_dim * 4
+
+    def state_bytes_per_row(self, num_layers: int, act_itemsize: int) -> int:
+        """Bytes one slot of the state slab holds over all layers: the
+        scan state and the convolution's tail in the activations'
+        dtype."""
+        conv = (self.d_conv - 1) * self.conv_dim * act_itemsize
+        return num_layers * (self.scan_state_bytes + conv)
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters, read from a HF-style ``config.json``."""
 
@@ -125,10 +174,17 @@ class ModelConfig:
     # qwen2 semantics: the first ``max_window_layers`` layers use FULL
     # attention, the band applies from that layer on (0 = all layers)
     max_window_layers: int = 0
+    # falcon_h1: a Mamba-2 mixer beside attention in every layer, and
+    # with it per-sequence state that is not KV pages
+    mixer: Optional[MixerConfig] = None
 
     @property
     def q_per_kv(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        return self.mixer is not None
 
     @staticmethod
     def _rope_scaling_factors(
@@ -339,6 +395,10 @@ class ModelConfig:
             return ModelConfig._from_gpt2_config(
                 model, hf, max_model_len=max_model_len, dtype=dtype
             )
+        if model_type == "falcon_h1":
+            return ModelConfig._from_falcon_h1_config(
+                model, hf, max_model_len=max_model_len, dtype=dtype
+            )
         hidden = hf["hidden_size"]
         heads = hf["num_attention_heads"]
         derived_len = hf.get("max_position_embeddings", 2048)
@@ -419,6 +479,109 @@ class ModelConfig:
             mlp_bias=hf.get("mlp_bias", False),
             sliding_window=sliding_window,
             max_window_layers=max_window_layers,
+        )
+
+    @staticmethod
+    def _from_falcon_h1_config(
+        model: str,
+        hf: dict,
+        *,
+        max_model_len: int | None = None,
+        dtype: str = "auto",
+    ) -> "ModelConfig":
+        """Falcon-H1: attention and a Mamba-2 mixer side by side in
+        every layer, then a SwiGLU MLP (models/falcon_h1.py)."""
+        unsupported = {
+            "mamba_norm_before_gate": "the gated norm is applied after "
+            "the gate only",
+            "mamba_proj_bias": "in_proj and out_proj are loaded without "
+            "biases",
+            "attention_bias": "the attention projections are loaded "
+            "without biases",
+            "mlp_bias": "the MLP is loaded without biases",
+            "projectors_bias": "the MLP is loaded without biases",
+            "rope_scaling": "the rotary tables are unscaled",
+        }
+        for key, why in unsupported.items():
+            if hf.get(key):
+                raise ValueError(
+                    f"falcon_h1 config sets {key}={hf[key]!r}, which is "
+                    f"not supported yet: {why}"
+                )
+        if not hf.get("mamba_use_mlp", True):
+            raise ValueError(
+                "falcon_h1 config sets mamba_use_mlp=false, which is not "
+                "supported yet: every layer runs its MLP"
+            )
+        if hf.get("mamba_conv_bias") is False:
+            raise ValueError(
+                "falcon_h1 config sets mamba_conv_bias=false, which is "
+                "not supported yet: the convolution is loaded with its "
+                "bias"
+            )
+        hidden = hf["hidden_size"]
+        heads = hf["num_attention_heads"]
+        m_heads = hf["mamba_n_heads"]
+        m_head_dim = hf["mamba_d_head"]
+        d_ssm = hf.get("mamba_d_ssm") or hf.get("mamba_expand", 2) * hidden
+        if m_heads * m_head_dim != d_ssm:
+            raise ValueError(
+                f"falcon_h1 config: mamba_n_heads {m_heads} x mamba_d_head "
+                f"{m_head_dim} is not mamba_d_ssm {d_ssm}"
+            )
+        groups = hf.get("mamba_n_groups", 1)
+        if m_heads % groups:
+            raise ValueError(
+                f"falcon_h1 config: mamba_n_groups {groups} does not "
+                f"divide mamba_n_heads {m_heads}"
+            )
+        eos = hf.get("eos_token_id", 2)
+        if isinstance(eos, list):
+            eos = eos[0]
+        return ModelConfig(
+            model=model,
+            model_type="falcon_h1",
+            vocab_size=hf["vocab_size"],
+            hidden_size=hidden,
+            intermediate_size=hf["intermediate_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=heads,
+            num_kv_heads=hf.get("num_key_value_heads", heads),
+            head_dim=hf.get("head_dim") or hidden // heads,
+            max_model_len=(
+                max_model_len or hf.get("max_position_embeddings", 2048)
+            ),
+            rope_theta=float(hf.get("rope_theta", 10000.0)),
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-5),
+            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            dtype=resolve_dtype(dtype),
+            eos_token_id=eos,
+            bos_token_id=hf.get("bos_token_id", 1) or 1,
+            embedding_multiplier=hf.get("embedding_multiplier", 1.0),
+            hidden_act=ModelConfig._validated_hidden_act(
+                hf.get("hidden_act") or "silu", "falcon_h1"
+            ),
+            mixer=MixerConfig(
+                d_ssm=d_ssm,
+                num_heads=m_heads,
+                head_dim=m_head_dim,
+                d_state=hf["mamba_d_state"],
+                n_groups=groups,
+                d_conv=hf.get("mamba_d_conv", 4),
+                chunk_size=hf.get("mamba_chunk_size", 128),
+                rms_norm=hf.get("mamba_rms_norm", True),
+                attention_in_multiplier=hf.get("attention_in_multiplier", 1.0),
+                attention_out_multiplier=hf.get(
+                    "attention_out_multiplier", 1.0),
+                key_multiplier=hf.get("key_multiplier", 1.0),
+                ssm_in_multiplier=hf.get("ssm_in_multiplier", 1.0),
+                ssm_out_multiplier=hf.get("ssm_out_multiplier", 1.0),
+                ssm_multipliers=tuple(
+                    hf.get("ssm_multipliers") or (1.0,) * 5),
+                mlp_multipliers=tuple(
+                    hf.get("mlp_multipliers") or (1.0, 1.0)),
+                lm_head_multiplier=hf.get("lm_head_multiplier", 1.0),
+            ),
         )
 
     @staticmethod
@@ -1141,6 +1304,7 @@ class EngineConfig:
                 "shared-device-tolerant); set exactly one of them > 1"
             )
         self._validate_replica_roles()
+        self._validate_recurrent_state()
         if self.kv_disk_cache_gb > 0 and self.kv_host_cache_gb <= 0:
             raise ValueError(
                 "--kv-disk-cache-gb requires the host KV tier "
@@ -1247,6 +1411,59 @@ class EngineConfig:
         # dp × pp composes: the async fleet builds one PIPELINE per dp
         # replica over a disjoint pp×tp device slice
         # (engine/async_llm.py from_config)
+
+    def _validate_recurrent_state(self) -> None:
+        """Truthful flags for a model with recurrent state (a Mamba-2
+        mixer: ``ModelConfig.mixer``, docs/STATE.md): a sequence's state
+        lives in ONE slot of the slab and is a function of every token
+        before it, so whatever moves, shares, shards or skips KV pages
+        without carrying that state is refused at boot, with what is
+        missing.  The host KV tier (and decode resume, which rests on
+        it) turns itself off in engine/core.py with a warning, as it
+        does for rolling-window eviction."""
+        if not self.model_config.has_recurrent_state:
+            return
+        model = f"model_type {self.model_config.model_type!r}"
+        pcfg = self.parallel_config
+        refused = [
+            (self.cache_config.enable_prefix_caching,
+             "--enable-prefix-caching",
+             "a cached page run would need the scan state at its end "
+             "snapshotted beside it (engine/kv_cache.py keeps none)"),
+            (self.swap_space_gib > 0, "--swap-space",
+             "the swap copy holds KV pages only; a preempted row is "
+             "recomputed from position 0 instead"),
+            (self.speculative is not None, "--speculative-model",
+             "a rejected draft token cannot be rolled back out of the "
+             "scan state (the verify span keeps no per-token states)"),
+            (bool(self.kvnet_listen or self.kvnet_peers),
+             "--kvnet-listen / --kvnet-peers",
+             "the kvnet wire format and DecodeCheckpoint carry KV pages "
+             "and no state slot (kvnet/, engine/kv_tier.py)"),
+            (self.roles_active(), "--dp-replica-roles",
+             "a prefill-to-decode handoff carries KV pages and no state "
+             "slot (engine/kv_tier.py DecodeCheckpoint)"),
+            (pcfg.tensor_parallel_size > 1, "--tensor-parallel-size > 1",
+             "the mixer's projections, its convolution and the state "
+             "slab have no shard rules (parallel/)"),
+            (pcfg.pipeline_parallel_size > 1, "--pipeline-parallel-size > 1",
+             "the staged runner allocates no state slab "
+             "(engine/pipeline.py)"),
+            (pcfg.sequence_parallel_size > 1, "--sequence-parallel-size > 1",
+             "the legacy solo-prefill planner sp engines use does not "
+             "carry state between chunks"),
+            (pcfg.data_parallel_size > 1 or pcfg.dp_replicas > 1,
+             "--data-parallel-size / --dp-replicas > 1",
+             "a request that moves between replicas (resume, handoff) "
+             "would leave its state behind"),
+        ]
+        for on, flag, missing in refused:
+            if on:
+                raise ValueError(
+                    f"{flag} does not compose with {model} yet: the "
+                    f"model keeps recurrent state beside its KV pages, "
+                    f"and {missing}; drop the flag (docs/STATE.md)"
+                )
 
     def resolved_replica_roles(self) -> tuple[str, ...]:
         """One role per replica: ``dp_replica_roles`` when given, else

@@ -219,6 +219,7 @@ class LLMEngine:
             config.kv_host_cache_gb > 0
             and pcfg.pipeline_parallel_size == 1
             and self.scheduler.rolling_window == 0
+            and not mcfg.has_recurrent_state
         ):
             from vllm_tgis_adapter_tpu.engine.kv_tier import HostKVTier
 
@@ -243,8 +244,11 @@ class LLMEngine:
             self._wire_kv_tier()
         elif config.kv_host_cache_gb > 0:
             logger.warning(
-                "--kv-host-cache-gb has no effect with pp > 1 or "
-                "rolling-window KV eviction; host KV tier disabled"
+                "--kv-host-cache-gb has no effect with pp > 1, "
+                "rolling-window KV eviction or a model with recurrent "
+                "state (a demoted page carries no scan state, "
+                "docs/STATE.md); host KV tier disabled, and with it "
+                "decode resume"
             )
         # black-box lifecycle recorder (flight_recorder.py): every
         # admission/dispatch/preemption/finish appends one bounded ring
@@ -572,6 +576,17 @@ class LLMEngine:
             raise ValueError(
                 f"prompt length {len(prompt_token_ids)} exceeds "
                 f"max_model_len {max_len}"
+            )
+        if (
+            params.prompt_logprobs is not None
+            and self.config.model_config.has_recurrent_state
+        ):
+            raise ValueError(
+                "prompt logprobs are not served for model_type "
+                f"{self.config.model_config.model_type!r} yet: they ride "
+                "the legacy solo-prefill program, which does not carry "
+                "recurrent state between chunks (docs/STATE.md); ask for "
+                "generated-token logprobs only"
             )
         seq = Sequence(
             request_id,
@@ -1841,12 +1856,37 @@ class LLMEngine:
                 batch_bucket=plan.batch_bucket, num_steps=plan.num_steps,
             )
 
+    def state_slot_usage(self) -> tuple[int, int]:
+        """``(held, total)`` slots of the recurrent-state slab; a
+        sequence's batch row IS its slot, held from its first chunk to
+        finish or preemption.  ``(0, 0)`` for a model without state."""
+        total = getattr(self.runner, "state_slots", 0)
+        if not total:
+            return 0, 0
+        return total - self.scheduler.free_slot_count, total
+
+    def _state_row_step_bytes(self) -> int:
+        """Scan-state bytes one row's one-token update reads and writes
+        over all layers (the float32 state alone, in and out)."""
+        m = self.config.model_config
+        return 2 * m.num_layers * m.mixer.scan_state_bytes
+
     def _observe_plan(self, plan, prepared) -> None:
         """Step-level telemetry (metrics.py): batch occupancy / padding
         waste gauges for this dispatch's shape, plus the plan→commit
         timestamp the commit phase turns into a step-duration sample."""
         try:
             if isinstance(plan, RaggedPlan):
+                if getattr(self.runner, "has_state", False):
+                    spans = [len(i.token_ids) for i in plan.items]
+                    ones = sum(1 for n in spans if n == 1)
+                    metrics.observe_state_plan(
+                        update_rows=ones, update_row_steps=ones,
+                        update_calls=self.config.model_config.num_layers,
+                        scan_tokens=sum(n for n in spans if n > 1),
+                        wave=False,
+                        row_step_bytes=self._state_row_step_bytes(),
+                    )
                 metrics.observe_ragged_plan(
                     real_tokens=plan.total_tokens,
                     bucket=plan.token_bucket,
@@ -1871,6 +1911,16 @@ class LLMEngine:
                     pages_per_item,
                 )
 
+                if getattr(self.runner, "has_state", False):
+                    metrics.observe_state_plan(
+                        update_rows=len(plan.seqs),
+                        update_row_steps=sum(plan.steps_per_seq),
+                        # the wave's loop ends at its last live step
+                        update_calls=self.config.model_config.num_layers
+                        * max(plan.steps_per_seq, default=0),
+                        scan_tokens=0, wave=True,
+                        row_step_bytes=self._state_row_step_bytes(),
+                    )
                 ctx = prepared.context_lens[: len(plan.seqs)]
                 pages = (ctx - 1) // self.runner.block_size + 1
                 run = pages_per_item(self.runner.block_size)

@@ -11,7 +11,10 @@ from __future__ import annotations
 
 from typing import TYPE_CHECKING
 
-from vllm_tgis_adapter_tpu.engine.kv_cache import device_memory_stats
+from vllm_tgis_adapter_tpu.engine.kv_cache import (
+    device_memory_stats,
+    state_slab_bytes,
+)
 from vllm_tgis_adapter_tpu.ops.attention import (
     _pallas_interpret,
     _use_pallas,
@@ -34,6 +37,7 @@ def device_report(engine: "LLMEngine") -> dict:
     )
     pallas = _use_pallas()
     ccfg = engine.config.cache_config
+    state_used, state_slots = engine.state_slot_usage()
     memory = []
     for d in devices:
         stats = device_memory_stats(d)
@@ -57,6 +61,13 @@ def device_report(engine: "LLMEngine") -> dict:
             "num_blocks": ccfg.num_blocks,
             "block_size": ccfg.block_size,
         },
+        # the recurrent-state slab beside the pool (docs/STATE.md);
+        # zeros for a model whose only per-sequence state is KV pages
+        "state_slab": {
+            "slots": state_slots,
+            "slots_used": state_used,
+            "bytes": state_slab_bytes(engine.config),
+        },
         "weights_load_s": engine.weights_load_s,
         "memory": memory,
     }
@@ -74,4 +85,9 @@ def format_report(report: dict) -> str:
         + f"; caches_donated={report['caches_donated']}; "
         f"kv_pool={pool['num_blocks']}x{pool['block_size']} "
         f"sized_from={pool['sized_from']}"
+        + (
+            f"; state_slab={report['state_slab']['slots']} slots "
+            f"{report['state_slab']['bytes'] / 1e9:.2f} GB"
+            if report["state_slab"]["slots"] else ""
+        )
     )

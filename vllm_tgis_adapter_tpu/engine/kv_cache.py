@@ -47,6 +47,23 @@ def _lora_stack_bytes(config: "EngineConfig") -> int:
     return elems * 4
 
 
+def state_slab_bytes(config: "EngineConfig") -> int:
+    """Device bytes of the recurrent-state slab (docs/STATE.md): one slot
+    a batch row, ``max_num_seqs`` of them, each the float32 scan state
+    and the convolution's tail of every layer
+    (``MixerConfig.state_bytes_per_row``; models/falcon_h1.py
+    ``make_state`` allocates exactly this).  0 for a model whose only
+    per-sequence state is KV pages."""
+    import jax.numpy as jnp
+
+    m = config.model_config
+    if not m.has_recurrent_state:
+        return 0
+    return config.scheduler_config.max_num_seqs * m.mixer.state_bytes_per_row(
+        m.num_layers, jnp.dtype(m.dtype).itemsize
+    )
+
+
 def per_block_bytes(config: "EngineConfig") -> int:
     """Per-device bytes ONE page costs (both caches, target + draft).
 
@@ -159,6 +176,15 @@ def resolve_num_blocks(
         budget -= lora_bytes
         logger.info(
             "reserving %.2f GB for LoRA adapter stacks", lora_bytes / 1e9
+        )
+    state_bytes = state_slab_bytes(config)
+    if state_bytes:
+        # the runner allocates the state slab AFTER the pool is sized
+        # (engine/runner.py): price it before the pages are counted
+        budget -= state_bytes
+        logger.info(
+            "reserving %.2f GB for the recurrent-state slab (%d slots)",
+            state_bytes / 1e9, config.scheduler_config.max_num_seqs,
         )
     num_blocks = budget // block_bytes
     if num_blocks < blocks_per_seq:

@@ -158,6 +158,7 @@ class PreparedRagged:
     block_tables: "np.ndarray"  # [S_max, max_blocks]
     logits_indices: "np.ndarray"  # [S_max] last-row per item (pad 0)
     row_slots: "np.ndarray"  # [S_max] batch row per SAMPLING item (-1)
+    state_slots: "np.ndarray"  # [S_max] batch row of EVERY item (-1 pads)
     seed_slots: "np.ndarray"  # [S_max] rows to (re)seed seen (-1 skip)
     seed_tokens: "np.ndarray"  # [S_max, P] prompt ids for seeding
     tensors: SamplingTensors  # S_max rows
@@ -286,6 +287,15 @@ class ModelRunner:
 
             mesh = mesh_from_parallel_config(pcfg)
         self.mesh = mesh
+        self.has_state = getattr(model, "has_recurrent_state", False)
+        if self.has_state and mesh is not None:
+            # boot refuses tp > 1 for such a model (engine/config.py); a
+            # mesh handed over directly is refused here
+            raise ValueError(
+                f"model_type {mcfg.model_type!r} keeps recurrent state, "
+                "and neither its mixer nor the state slab has shard rules "
+                "yet (parallel/): it cannot run under a device mesh"
+            )
         if mesh is not None:
             from vllm_tgis_adapter_tpu.parallel import (
                 cache_sharding,
@@ -357,6 +367,14 @@ class ModelRunner:
                 kv_scale_floors=kv_scale_floors,
             )
             self._data_sharding = None
+        # a model with recurrent state (models/falcon_h1.py) gets its
+        # state slab here, one slot per batch row (``Sequence.slot``): it
+        # travels as a third member of ``caches`` and is donated through
+        # the step programs like the pool (docs/STATE.md)
+        self.state_slots = 0
+        if self.has_state:
+            self.state_slots = config.scheduler_config.max_num_seqs
+            caches = (*caches, model.make_state(self.state_slots))
         self.params = params
         self.caches = caches
         # pallas kernels must be shard_map-wrapped under a TP mesh; the
@@ -555,6 +573,7 @@ class ModelRunner:
         block_size = self.block_size
         max_steps = self.config.scheduler_config.num_decode_steps
         vocab_size = self.config.model_config.vocab_size
+        has_state = self.has_state
         # the fused wave runs the SAME unified ragged kernel as mixed
         # steps (each row a one-token span) — the bucketed decode
         # variant ladder (folded → perhead → xla) is retired; the
@@ -622,6 +641,8 @@ class ModelRunner:
                 logits, caches = model.decode(
                     params, caches, tokens, pos, slot, block_tables,
                     context_lens0 + k, block_size, lora, lora_idx,
+                    # a row's batch row is its state slot
+                    **({"state_slots": row_slots} if has_state else {}),
                 )
                 t_k = dataclasses.replace(
                     tensors, gen_len=tensors.gen_len + k
@@ -1106,6 +1127,7 @@ class ModelRunner:
         block_tables = np.zeros((s_max, self.max_blocks_per_seq), np.int32)
         logits_indices = np.zeros(s_max, np.int32)
         row_slots = np.full(s_max, -1, np.int32)
+        state_slots = np.full(s_max, -1, np.int32)
         seed_slots = np.full(s_max, -1, np.int32)
         seeds = np.zeros(s_max, np.uint32)
         lora_idx = None
@@ -1137,6 +1159,7 @@ class ModelRunner:
             slot_mapping[off : off + t] = it.slots
             seq_starts[i] = off
             pos_base[i] = it.start_pos
+            state_slots[i] = it.seq.slot
             blocks = it.seq.blocks.blocks
             block_tables[i, : len(blocks)] = blocks
             if lora_idx is not None:
@@ -1229,6 +1252,7 @@ class ModelRunner:
             block_tables=block_tables,
             logits_indices=logits_indices,
             row_slots=row_slots,
+            state_slots=state_slots,
             seed_slots=seed_slots,
             seed_tokens=seed_tokens,
             tensors=tensors,
@@ -1483,6 +1507,14 @@ class ModelRunner:
             "n_items": self._put(np.asarray(prep.work_items, np.int32)),
         }
 
+    def _state_args(self, prep: "PreparedRagged") -> dict:
+        """For a model with recurrent state, the slab slot of every
+        sequence of the stream; nothing otherwise (the llama lineage's
+        programs take no such operand)."""
+        if not self.has_state:
+            return {}
+        return {"state_slots": self._put(prep.state_slots)}
+
     def dispatch_ragged(self, prep: "PreparedRagged"):
         """Enqueue ONE forward over the mixed ragged stream plus the
         batched sampler over every emitting row; no blocking transfers
@@ -1506,6 +1538,7 @@ class ModelRunner:
             self._put(prep.logits_indices),
             *lora_args,
             **self._work_args(prep),
+            **self._state_args(prep),
         )
         return self._sample_rows(
             logits,

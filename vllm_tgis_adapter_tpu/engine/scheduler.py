@@ -285,6 +285,12 @@ class Scheduler:
                 seq.prompt_token_ids, seq.blocks.blocks, seq.lora_name
             )
 
+    @property
+    def free_slot_count(self) -> int:
+        """Batch rows no sequence holds (a row is also the sequence's
+        slot of the recurrent-state slab, docs/STATE.md)."""
+        return len(self._free_slots)
+
     def finish(self, seq: Sequence) -> None:
         """Release a sequence's device resources (idempotent)."""
         ticket = getattr(seq, "kv_promotion", None)

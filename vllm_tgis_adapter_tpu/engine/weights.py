@@ -599,12 +599,94 @@ def load_phi3_params(
     return params
 
 
+#: Hugging Face ``falcon_h1`` tensor names of one layer (after
+#: ``model.layers.<i>.``) -> (pytree key, transpose to [in, out])
+FALCON_H1_LAYER_TENSORS = {
+    "input_layernorm.weight": ("input_norm", False),
+    "pre_ff_layernorm.weight": ("pre_ff_norm", False),
+    "self_attn.q_proj.weight": ("wq", True),
+    "self_attn.k_proj.weight": ("wk", True),
+    "self_attn.v_proj.weight": ("wv", True),
+    "self_attn.o_proj.weight": ("wo", True),
+    "mamba.in_proj.weight": ("in_proj", True),
+    "mamba.conv1d.weight": ("conv_w", False),  # [C, 1, taps], reshaped
+    "mamba.conv1d.bias": ("conv_b", False),
+    "mamba.dt_bias": ("dt_bias", False),
+    "mamba.A_log": ("a_log", False),
+    "mamba.D": ("d", False),
+    "mamba.norm.weight": ("mixer_norm", False),
+    "mamba.out_proj.weight": ("out_proj", True),
+    "feed_forward.gate_proj.weight": ("w_gate", True),
+    "feed_forward.up_proj.weight": ("w_up", True),
+    "feed_forward.down_proj.weight": ("w_down", True),
+}
+
+
+def falcon_h1_tensor_names(config: "ModelConfig") -> list[str]:
+    """Every checkpoint tensor ``load_falcon_h1_params`` asks for."""
+    names = ["model.embed_tokens.weight", "model.final_layernorm.weight"]
+    if not config.tie_word_embeddings:
+        names.append("lm_head.weight")
+    for i in range(config.num_layers):
+        names += [
+            f"model.layers.{i}.{name}"
+            for name in FALCON_H1_LAYER_TENSORS
+            if config.mixer.rms_norm or name != "mamba.norm.weight"
+        ]
+    return names
+
+
+def load_falcon_h1_params(
+    config: "ModelConfig",
+    model_path: str,
+    place: Optional[PlaceFn] = None,
+) -> dict:
+    """Build the FalconH1ForCausalLM param pytree (models/falcon_h1.py)
+    from a Hugging Face ``falcon_h1`` checkpoint: projections transposed
+    to ``[in, out]``, the depthwise convolution's ``[channels, 1, taps]``
+    weight to ``[taps, channels]``."""
+    raw = open_checkpoint_index(config, model_path)
+    place = place or (lambda _n, x: x)
+    take = _make_take(raw, config.dtype, place, ("",))
+    # the scan's per-head scalars stay as precise as the checkpoint has
+    # them: A_log and dt_bias pass through an exp
+    take_f32 = _make_take(raw, jnp.float32, place, ("",))
+    embed = take("model.embed_tokens.weight")
+    params: dict = {
+        "embed": embed,
+        "final_norm": take("model.final_layernorm.weight"),
+        "lm_head": (
+            embed.T if config.tie_word_embeddings
+            else take("lm_head.weight", transpose=True)
+        ),
+        "layers": [],
+    }
+    for i in range(config.num_layers):
+        layer = {}
+        for name, (key, transpose) in FALCON_H1_LAYER_TENSORS.items():
+            if key == "mixer_norm" and not config.mixer.rms_norm:
+                continue
+            get = take_f32 if key in ("a_log", "dt_bias", "d") else take
+            x = get(f"model.layers.{i}.{name}", transpose=transpose)
+            if key == "conv_w":
+                x = x[:, 0, :].T
+            layer[key] = x
+        params["layers"].append(layer)
+    ignored = raw.remaining()
+    if ignored:
+        logger.warning("ignored %d unexpected checkpoint tensors: %s",
+                       len(ignored), ignored[:5])
+    return params
+
+
 def load_model_params(
     config: "ModelConfig",
     model_path: str,
     place: Optional[PlaceFn] = None,
 ) -> dict:
     """Dispatch to the checkpoint layout for ``config.model_type``."""
+    if config.model_type == "falcon_h1":
+        return load_falcon_h1_params(config, model_path, place)
     if config.model_type == "opt":
         return load_opt_params(config, model_path, place)
     if config.model_type == "gpt_neox":

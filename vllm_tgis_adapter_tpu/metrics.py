@@ -335,6 +335,50 @@ attn_item_pages = _get_or_create(
     "from 1 to 128 / block_size",
     buckets=(1, 2, 3, 4, 5, 6, 7, 8, 16, 32),
 )
+# ---- recurrent state beside the KV pool (docs/STATE.md): a model with a
+# Mamba-2 mixer keeps one slot of a state slab per running sequence
+ssm_state_slots_used = _get_or_create(
+    Gauge,
+    f"{_PREFIX}_ssm_state_slots_used",
+    "Slots of the recurrent-state slab held by sequences (a slot is "
+    "held from a sequence's first chunk to its finish or preemption); "
+    "0 for a model without recurrent state",
+)
+ssm_state_slots_total = _get_or_create(
+    Gauge,
+    f"{_PREFIX}_ssm_state_slots_total",
+    "Slots of the recurrent-state slab (max_num_seqs per replica); 0 "
+    "for a model without recurrent state",
+)
+ssm_live_rows = _get_or_create(
+    Histogram,
+    f"{_PREFIX}_ssm_live_rows",
+    "Rows whose recurrent state a dispatched decode wave's first step "
+    "updates (the live extent of the ssm_decode_update kernel's grid)",
+    buckets=(1, 2, 4, 8, 16, 24, 32, 48, 64, 96, 128, 256),
+)
+ssm_state_bytes_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_ssm_state_bytes_total",
+    "Scan-state bytes the one-token update kernel read and wrote: live "
+    "rows x layers x steps x 2 x the float32 state of a row's layer, "
+    "over decode waves and the one-token spans of mixed steps alike "
+    "(the state alone, not x, B, C or dt)",
+)
+ssm_update_calls_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_ssm_update_calls_total",
+    "Calls of the one-token update kernel dispatched: layers x the steps "
+    "of a decode wave's loop, and layers x 1 for a mixed step (which "
+    "calls it whether or not it holds a one-token span); with "
+    "ssm_state_bytes_total, the state bytes of a call",
+)
+ssm_scan_tokens_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_ssm_scan_tokens_total",
+    "Prompt tokens through the chunked scan (the multi-token spans of "
+    "mixed steps)",
+)
 prefill_padding_waste = _get_or_create(
     Gauge,
     f"{_PREFIX}_prefill_padding_waste",
@@ -765,6 +809,23 @@ def observe_decode_plan(*, num_seqs: int, batch_bucket: int,
     step_snapshot.decode_steps += 1
 
 
+def observe_state_plan(*, update_rows: int, update_row_steps: int,
+                       update_calls: int, scan_tokens: int, wave: bool,
+                       row_step_bytes: int) -> None:
+    """Recurrent-state work of one dispatched plan: ``update_rows`` rows
+    go through the one-token update, ``update_row_steps`` times in all
+    (a wave's row runs its own number of steps) in ``update_calls``
+    calls of the kernel, ``scan_tokens`` tokens through the chunked
+    scan."""
+    if wave:
+        ssm_live_rows.observe(update_rows)
+    ssm_update_calls_total.inc(update_calls)
+    if update_row_steps:
+        ssm_state_bytes_total.inc(update_row_steps * row_step_bytes)
+    if scan_tokens:
+        ssm_scan_tokens_total.inc(scan_tokens)
+
+
 def observe_prefill_plan(*, real_tokens: int, bucket: int,
                          num_prompts: int) -> None:
     waste = (bucket - real_tokens) / bucket if bucket else 0.0
@@ -812,6 +873,8 @@ def update_engine_gauges(
     kv_used: int,
     kv_total: int,
     prefix_hits: int,
+    state_used: int = 0,
+    state_total: int = 0,
 ) -> None:
     # num_requests_running is NOT set here: the serving layer inc/decs it
     # per request (tgis_utils/logs.py) and a periodic .set() from a
@@ -821,6 +884,8 @@ def update_engine_gauges(
     kv_pages_total.set(kv_total)
     kv_cache_usage.set(kv_used / kv_total if kv_total else 0.0)
     prefix_cache_hit_tokens.set(prefix_hits)
+    ssm_state_slots_used.set(state_used)
+    ssm_state_slots_total.set(state_total)
 
 
 def record_response(

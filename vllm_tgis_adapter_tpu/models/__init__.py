@@ -9,6 +9,7 @@ data in ModelConfig, not code forks.
 
 from __future__ import annotations
 
+from .falcon_h1 import FalconH1ForCausalLM
 from .llama import LlamaForCausalLM
 
 _REGISTRY = {
@@ -43,6 +44,10 @@ _REGISTRY = {
     # Qwen3: qwen2 lineage plus per-head-dim q/k RMSNorms applied before
     # rotary (config.py qk_norm; llama.py _qkv)
     "qwen3": LlamaForCausalLM,
+    # Falcon-H1: a Mamba-2 mixer beside attention in every layer; a
+    # class of its own, with per-sequence state that is not KV pages
+    # (models/falcon_h1.py, docs/STATE.md)
+    "falcon_h1": FalconH1ForCausalLM,
 }
 
 
