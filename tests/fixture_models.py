@@ -979,3 +979,126 @@ def build_tiny_falcon_h1(path: str, seed: int = 0, **overrides) -> str:
         }
     save_file(tensors, out / "model.safetensors")
     return str(out)
+
+
+# a tiny DeepSeek-V2: one dense layer, then three layers of 16 routed
+# experts in 4 groups (2 groups and 3 experts a token) beside 2 shared
+# ones; latent attention with YaRN in every layer
+TINY_DEEPSEEK_V2_CONFIG = {
+    "architectures": ["DeepseekV2ForCausalLM"],
+    "model_type": "deepseek_v2",
+    "vocab_size": 512,
+    "hidden_size": 64,
+    "intermediate_size": 128,
+    "moe_intermediate_size": 32,
+    "num_hidden_layers": 4,
+    "first_k_dense_replace": 1,
+    "moe_layer_freq": 1,
+    "num_attention_heads": 4,
+    "num_key_value_heads": 4,
+    "q_lora_rank": 24,
+    "kv_lora_rank": 32,
+    "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8,
+    "v_head_dim": 16,
+    "n_routed_experts": 16,
+    "n_shared_experts": 2,
+    "n_group": 4,
+    "topk_group": 2,
+    "num_experts_per_tok": 3,
+    "routed_scaling_factor": 4.0,
+    "norm_topk_prob": False,
+    "scoring_func": "softmax",
+    "topk_method": "group_limited_greedy",
+    "attention_bias": False,
+    "hidden_act": "silu",
+    "max_position_embeddings": 512,
+    "rope_theta": 10000.0,
+    "rope_scaling": {
+        "type": "yarn", "factor": 4, "original_max_position_embeddings": 64,
+        "beta_fast": 32, "beta_slow": 1, "mscale": 0.707,
+        "mscale_all_dim": 0.707,
+    },
+    "rms_norm_eps": 1e-6,
+    "tie_word_embeddings": False,
+    "bos_token_id": 1,
+    "eos_token_id": 2,
+    "torch_dtype": "float32",
+}
+
+
+def build_tiny_deepseek_v2(path: str, seed: int = 0, held=None,
+                           **overrides) -> str:
+    """Tiny DeepSeek-V2 checkpoint in Hugging Face's ``deepseek_v2``
+    tensor names.  ``held = (first, last)`` writes only those experts of
+    each expert layer, under their published indices, and counts them in
+    ``n_routed_experts`` (the router keeps all its rows): one chip's
+    share of the same model, since every tensor is drawn as in the whole
+    checkpoint of that seed.  The norms' weights are away from 1 and
+    the router's rows at std 1, so that routing is decided by more than
+    rounding."""
+    import numpy as np
+    from safetensors.numpy import save_file
+
+    out = Path(path)
+    out.mkdir(parents=True, exist_ok=True)
+    build_tokenizer(path)
+    cfg = dict(TINY_DEEPSEEK_V2_CONFIG, **overrides)
+    total = cfg["n_routed_experts"]
+    lo, hi = held or (0, total)
+    with open(out / "config.json", "w") as f:
+        json.dump(dict(cfg, n_routed_experts=hi - lo), f, indent=2)
+
+    rng = np.random.default_rng(seed)
+    d, vocab = cfg["hidden_size"], cfg["vocab_size"]
+    h = cfg["num_attention_heads"]
+    nope, rope, v = (cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
+                     cfg["v_head_dim"])
+    q_rank, kv_rank = cfg["q_lora_rank"], cfg["kv_lora_rank"]
+    f_moe = cfg["moe_intermediate_size"]
+
+    def w(shape, std=None):
+        std = std or shape[-1] ** -0.5
+        return (rng.standard_normal(shape) * std).astype(np.float32)
+
+    def norm(n):
+        return (1.0 + 0.1 * rng.standard_normal(n)).astype(np.float32)
+
+    def swiglu(prefix, width):
+        return {
+            f"{prefix}.gate_proj.weight": w((width, d)),
+            f"{prefix}.up_proj.weight": w((width, d)),
+            f"{prefix}.down_proj.weight": w((d, width)),
+        }
+
+    tensors = {
+        "model.embed_tokens.weight": w((vocab, d), 1.0),
+        "model.norm.weight": norm(d),
+        "lm_head.weight": w((vocab, d)),
+    }
+    for i in range(cfg["num_hidden_layers"]):
+        p = f"model.layers.{i}"
+        tensors |= {
+            f"{p}.input_layernorm.weight": norm(d),
+            f"{p}.post_attention_layernorm.weight": norm(d),
+            f"{p}.self_attn.q_a_proj.weight": w((q_rank, d)),
+            f"{p}.self_attn.q_a_layernorm.weight": norm(q_rank),
+            f"{p}.self_attn.q_b_proj.weight": w((h * (nope + rope), q_rank)),
+            f"{p}.self_attn.kv_a_proj_with_mqa.weight": w(
+                (kv_rank + rope, d)),
+            f"{p}.self_attn.kv_a_layernorm.weight": norm(kv_rank),
+            f"{p}.self_attn.kv_b_proj.weight": w((h * (nope + v), kv_rank)),
+            f"{p}.self_attn.o_proj.weight": w((d, h * v)),
+        }
+        if i < cfg["first_k_dense_replace"]:
+            tensors |= swiglu(f"{p}.mlp", cfg["intermediate_size"])
+            continue
+        tensors[f"{p}.mlp.gate.weight"] = w((total, d), 1.0 / 8)
+        tensors |= swiglu(f"{p}.mlp.shared_experts",
+                          cfg["n_shared_experts"] * f_moe)
+        for e in range(total):  # every expert drawn, the held ones kept
+            expert = swiglu(f"{p}.mlp.experts.{e}", f_moe)
+            if lo <= e < hi:
+                tensors |= expert
+    save_file(tensors, out / "model.safetensors")
+    return str(out)

@@ -742,3 +742,155 @@ def test_falcon_h1_decode_wave_compiles_and_fits(topo, one_chip, on_chip):
     ).compile(compiler_options=TPU_STEP_COMPILER_OPTIONS)
     mem = _assert_state_in_place_and_fits(compiled, held)
     assert mem.temp_size_in_bytes < 512 << 20
+
+
+# ---- DeepSeek-V2 at its published widths, one dense and seven expert
+# layers, one group of 20 experts held (models/deepseek_v2.py)
+
+
+def _deepseek_v2_ep8_l8():
+    """``benchmark/configs/deepseek-v2-ep8-l8.json``: the router's 160
+    outputs are read from the checkpoint, which this file has none of."""
+    import dataclasses
+    import json
+    from pathlib import Path
+
+    from vllm_tgis_adapter_tpu.engine.config import ModelConfig
+
+    path = (Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+            / "deepseek-v2-ep8-l8.json")
+    cfg = ModelConfig.from_hf_config(
+        "deepseek-v2-ep8-l8", json.loads(path.read_text()),
+        max_model_len=4096, dtype="bfloat16",
+    )
+    assert (cfg.num_layers, cfg.experts.num_held) == (8, 20)
+    return dataclasses.replace(cfg, experts=dataclasses.replace(
+        cfg.experts, num_routed=160))
+
+
+def _deepseek_on_chip(one_chip, cfg):
+    """The model, its parameters' shapes and its latent pool as
+    ``resolve_num_blocks`` would size it after the weights: 640 lanes a
+    token a layer, capped at 64 rows x 4,096 tokens."""
+    from vllm_tgis_adapter_tpu.models.deepseek_v2 import (
+        DeepseekV2ForCausalLM,
+    )
+
+    model = DeepseekV2ForCausalLM(cfg)
+
+    def on_chip_shapes(tree):
+        return jax.tree.map(
+            lambda leaf: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = on_chip_shapes(
+        jax.eval_shape(model.init_params, jax.random.PRNGKey(0)))
+    weight_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(params))
+    # 12.14 GB: embedding and head 2.10, the dense layer 0.68, seven
+    # expert layers of 1.338 (ISSUE 43's arithmetic)
+    assert 12.1e9 < weight_bytes < 12.2e9, weight_bytes
+    assert cfg.mla.row_width == 640
+    token_bytes = cfg.num_layers * cfg.mla.row_width * 2
+    tokens = min((int(HBM_BYTES * 0.9) - weight_bytes) // token_bytes,
+                 64 * 4096) // BLOCK * BLOCK
+    assert tokens >= 64 * 2048, tokens
+    caches = on_chip_shapes(jax.eval_shape(
+        lambda: model.make_kv_caches(tokens, cfg.dtype)))
+    assert caches[0].shape == (8, tokens, 640)
+    # the two running counts take one 512-byte allocation
+    return model, params, caches, caches[0].size * 2 + 512
+
+
+def _assert_latent_in_place_and_fits(compiled, held, temp_limit):
+    """The pool is donated and updated in place, the latent kernel is in
+    the program under its own name beside the grouped matmul, and
+    weights + pool + temporaries fit one 16 GB chip."""
+    import re
+
+    text = compiled.as_text()
+    assert re.search(
+        r"%mla_ragged_attention[.\w]* = [^\n]*custom-call\([^\n]*"
+        r'custom_call_target="tpu_custom_call"', text)
+    # the grouped matmul, by the name the trace's readers match
+    assert re.search(
+        r"%gmm[.\w]* = [^\n]*custom-call\([^\n]*"
+        r'custom_call_target="tpu_custom_call"', text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == held
+    total = (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    )
+    assert total < HBM_BYTES, (total, mem.temp_size_in_bytes)
+    # read here (PR 43): 7.6 MB at 64 tokens, 91 MB at 512, 344 MB at
+    # 1,024, 9.6 MB for the wave
+    assert mem.temp_size_in_bytes < temp_limit, mem.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("t,work_w", [(64, 2048), (512, 4096),
+                                      (1024, 8192)])
+def test_deepseek_v2_ragged_step_compiles_and_fits(topo, one_chip, on_chip,
+                                                   t, work_w):
+    """The mixed step of DeepSeek-V2 at its published widths, 8 layers
+    and 20 held experts: 128 heads over rows of 640 lanes at a query
+    block of 4 tokens, the grouped matmul over the held experts' stacks,
+    in one program that fits beside the pool.  1,024 tokens is the
+    planner's widest step for this model (512 of a prompt and every
+    row's one) and 8,192 items the widest schedule the kernel's scalar
+    memory takes (``ops/mla_attention.py`` ``MAX_WORK_WIDTH``: the chip
+    refused 16,384)."""
+    from vllm_tgis_adapter_tpu.ops import mla_attention as mla
+
+    cfg = _deepseek_v2_ep8_l8()
+    assert mla.MAX_WORK_WIDTH == 8192
+    assert mla.max_step_prefill_tokens(4096, 64, BLOCK) == 512
+    model, params, caches, held = _deepseek_on_chip(one_chip, cfg)
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    seqs = 64
+    step = jax.jit(
+        functools.partial(model.ragged_forward, block_size=BLOCK),
+        donate_argnums=(1,),
+    )
+    compiled = step.lower(
+        params, caches, spec((t,), jnp.int32), spec((t,), jnp.int32),
+        spec((t,), jnp.int32), spec((seqs + 1,), jnp.int32),
+        spec((seqs,), jnp.int32), spec((), jnp.int32),
+        spec((seqs, 4096 // BLOCK), jnp.int32), spec((seqs,), jnp.int32),
+        work=spec((ra.work_rows(BLOCK), work_w), jnp.int32),
+        n_items=spec((), jnp.int32),
+    ).compile(compiler_options=TPU_STEP_COMPILER_OPTIONS)
+    _assert_latent_in_place_and_fits(compiled, held, 512 << 20)
+
+
+def test_deepseek_v2_decode_wave_compiles_and_fits(topo, one_chip, on_chip):
+    """The fused wave: the loop of traced length carries the pool in
+    place; each step one latent attention call a layer over the
+    in-trace schedule at a query block of one token, and three grouped
+    matmuls an expert layer."""
+    cfg = _deepseek_v2_ep8_l8()
+    model, params, caches, held = _deepseek_on_chip(one_chip, cfg)
+    b, max_blocks = 64, 4096 // BLOCK
+
+    def wave(params, caches, tokens, pos0, limits, tables, num_steps):
+        def step(k, carry):
+            caches, tokens = carry
+            pos = pos0 + k
+            blk = jnp.take_along_axis(
+                tables, (pos // BLOCK)[:, None], axis=1)[:, 0]
+            slot = jnp.where(pos <= limits, blk * BLOCK + pos % BLOCK, -1)
+            logits, caches = model.decode(
+                params, caches, tokens, pos, slot, tables, pos + 1, BLOCK)
+            return caches, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        return jax.lax.fori_loop(0, num_steps, step, (caches, tokens))
+
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(wave, donate_argnums=(1,)).lower(
+        params, caches, spec((b,), jnp.int32), spec((b,), jnp.int32),
+        spec((b,), jnp.int32), spec((b, max_blocks), jnp.int32),
+        spec((), jnp.int32),
+    ).compile(compiler_options=TPU_STEP_COMPILER_OPTIONS)
+    _assert_latent_in_place_and_fits(compiled, held, 512 << 20)

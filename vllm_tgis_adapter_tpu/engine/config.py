@@ -86,6 +86,117 @@ class MixerConfig:
         return num_layers * (self.scan_state_bytes + conv)
 
 
+def held_experts_of_checkpoint(
+    model_path: str, num_held: int
+) -> tuple[int, Optional[int]]:
+    """``(first held expert, experts the router scores)`` of a
+    ``deepseek_v2`` checkpoint: the indices ``e`` of the
+    ``mlp.experts.{e}.*`` tensors it holds, which are the experts'
+    published indices and must be ``num_held`` contiguous ones, and the
+    rows of its router ``mlp.gate.weight``.  A directory with no
+    safetensors (a config read on its own) holds experts 0 on, all the
+    router scores."""
+    import re
+
+    from safetensors import safe_open
+
+    held: set[int] = set()
+    routed = None
+    pattern = re.compile(r"model\.layers\.(\d+)\.mlp\.experts\.(\d+)\.")
+    for file in sorted(Path(model_path).glob("*.safetensors")):
+        with safe_open(file, framework="np") as f:
+            for name in f.keys():  # noqa: SIM118
+                m = pattern.match(name)
+                if m:
+                    held.add(int(m.group(2)))
+                elif routed is None and name.endswith(".mlp.gate.weight"):
+                    routed = f.get_slice(name).get_shape()[0]
+    if not held or routed is None:
+        return 0, None
+    first = min(held)
+    if sorted(held) != list(range(first, first + num_held)):
+        raise ValueError(
+            f"deepseek_v2 checkpoint holds experts {sorted(held)[:4]}... "
+            f"({len(held)} of them), but config.json's n_routed_experts "
+            f"says {num_held} are held: a server's share is that many "
+            "contiguous experts under their published indices"
+        )
+    if first + num_held > routed:
+        raise ValueError(
+            f"deepseek_v2 checkpoint holds experts up to "
+            f"{first + num_held - 1}, but its router scores only {routed}"
+        )
+    return first, routed
+
+
+#: lanes of one row of the latent pool: the 512-value latent, then the
+#: 64 rotary key columns padded to a lane tile (docs/LATENT_ATTENTION.md)
+LATENT_LANE = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttentionConfig:
+    """Multi-head latent attention (``deepseek_v2``; models/deepseek_v2.py):
+    keys and values of every head are projections of ONE cached row a
+    token a layer, ``[c_kv after its norm | k_pe after RoPE]``.  Its
+    presence on a ModelConfig is what "a latent cache" means to the
+    engine: a pool whose row is that latent and not per-head keys and
+    values (engine/kv_cache.py, docs/LATENT_ATTENTION.md)."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    # (nope + rope)^-0.5, times the YaRN attention factor squared
+    softmax_scale: float
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a cached row holds: the latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def rope_offset(self) -> int:
+        """First lane of the rotary key within a row: the latent, padded
+        to a lane tile."""
+        return -(-self.kv_lora_rank // LATENT_LANE) * LATENT_LANE
+
+    @property
+    def row_width(self) -> int:
+        """Lanes of a row of the pool: each half padded to a lane tile,
+        which is what the device's tiled memory stores anyway."""
+        return self.rope_offset + (
+            -(-self.qk_rope_head_dim // LATENT_LANE) * LATENT_LANE)
+
+
+@dataclasses.dataclass(frozen=True)
+class HeldExpertsConfig:
+    """Group-routed sparse experts of which this server holds a
+    contiguous share (``deepseek_v2``): the router scores all
+    ``num_routed`` experts, the layer computes the pairs that fall on the
+    ``num_held`` experts from ``first_held`` on, and what the absent
+    experts would add is left out (docs/LATENT_ATTENTION.md).  Which
+    experts are held is read from the checkpoint's tensor names, the
+    router's width from its tensor (``from_pretrained``)."""
+
+    num_routed: int  # the router's outputs (all experts of a layer)
+    first_held: int
+    num_held: int
+    n_group: int
+    topk_group: int
+    top_k: int
+    routed_scaling_factor: float
+    num_shared: int
+    intermediate_size: int  # one expert's width
+    first_dense_layers: int  # leading layers with a dense MLP instead
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """``[first, last)`` of the held experts' published indices."""
+        return self.first_held, self.first_held + self.num_held
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     """Architecture hyperparameters, read from a HF-style ``config.json``."""
@@ -177,6 +288,10 @@ class ModelConfig:
     # falcon_h1: a Mamba-2 mixer beside attention in every layer, and
     # with it per-sequence state that is not KV pages
     mixer: Optional[MixerConfig] = None
+    # deepseek_v2: latent attention over a pool of latent rows, and
+    # group-routed experts of which a share is held
+    mla: Optional[LatentAttentionConfig] = None
+    experts: Optional[HeldExpertsConfig] = None
 
     @property
     def q_per_kv(self) -> int:
@@ -185,6 +300,10 @@ class ModelConfig:
     @property
     def has_recurrent_state(self) -> bool:
         return self.mixer is not None
+
+    @property
+    def has_latent_cache(self) -> bool:
+        return self.mla is not None
 
     @staticmethod
     def _rope_scaling_factors(
@@ -399,6 +518,10 @@ class ModelConfig:
             return ModelConfig._from_falcon_h1_config(
                 model, hf, max_model_len=max_model_len, dtype=dtype
             )
+        if model_type == "deepseek_v2":
+            return ModelConfig._from_deepseek_v2_config(
+                model, hf, max_model_len=max_model_len, dtype=dtype
+            )
         hidden = hf["hidden_size"]
         heads = hf["num_attention_heads"]
         derived_len = hf.get("max_position_embeddings", 2048)
@@ -581,6 +704,120 @@ class ModelConfig:
                 mlp_multipliers=tuple(
                     hf.get("mlp_multipliers") or (1.0, 1.0)),
                 lm_head_multiplier=hf.get("lm_head_multiplier", 1.0),
+            ),
+        )
+
+    @staticmethod
+    def _from_deepseek_v2_config(
+        model: str,
+        hf: dict,
+        *,
+        max_model_len: int | None = None,
+        dtype: str = "auto",
+    ) -> "ModelConfig":
+        """DeepSeek-V2: latent attention in every layer, then a dense
+        SwiGLU MLP in the first ``first_k_dense_replace`` layers and
+        group-routed experts beside shared ones in the rest
+        (models/deepseek_v2.py)."""
+        import math
+
+        unsupported = (
+            ("attention_bias", hf.get("attention_bias"),
+             "the latent projections are loaded without biases"),
+            ("scoring_func", hf.get("scoring_func", "softmax") != "softmax",
+             "the router's scores are a softmax"),
+            ("topk_method",
+             hf.get("topk_method", "group_limited_greedy")
+             != "group_limited_greedy",
+             "routing is group-limited greedy"),
+            ("norm_topk_prob", hf.get("norm_topk_prob"),
+             "the chosen experts' weights are not renormalised"),
+            ("moe_layer_freq", hf.get("moe_layer_freq", 1) != 1,
+             "every layer after the dense ones is an expert layer"),
+            ("q_lora_rank", not hf.get("q_lora_rank"),
+             "the query goes through its low-rank projection"),
+            ("n_routed_experts", not hf.get("n_routed_experts"),
+             "the layers after the dense ones are expert layers"),
+        )
+        for key, bad, why in unsupported:
+            if bad:
+                raise ValueError(
+                    f"deepseek_v2 config sets {key}={hf.get(key)!r}, which "
+                    f"is not supported yet: {why}"
+                )
+        nope, rope_dim = hf["qk_nope_head_dim"], hf["qk_rope_head_dim"]
+        theta = float(hf.get("rope_theta", 10000.0))
+        scaling = hf.get("rope_scaling")
+        divisors, rope_mscale, score_mscale = None, 1.0, 1.0
+        if scaling:
+            divisors, rope_mscale = ModelConfig._rope_scaling_factors(
+                scaling, theta=theta, dim=rope_dim,
+                max_len=max_model_len or 0, hf=hf,
+            )
+            m_all, factor = scaling.get("mscale_all_dim", 0), scaling["factor"]
+            if m_all and factor > 1:
+                score_mscale = 0.1 * m_all * math.log(factor) + 1.0
+        num_held = hf["n_routed_experts"]
+        first_held, num_routed = held_experts_of_checkpoint(model, num_held)
+        groups = hf.get("n_group") or 1
+        if num_routed is None:
+            # no checkpoint beside the config: nothing can run from it,
+            # and the router's width is the tensor's to say
+            num_routed = num_held
+        elif num_routed % groups:
+            raise ValueError(
+                f"deepseek_v2 config: n_group {groups} does not divide the "
+                f"router's {num_routed} experts"
+            )
+        eos = hf.get("eos_token_id", 100001)
+        if isinstance(eos, list):
+            eos = eos[0]
+        heads = hf["num_attention_heads"]
+        return ModelConfig(
+            model=model,
+            model_type="deepseek_v2",
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf["intermediate_size"],
+            num_layers=hf["num_hidden_layers"],
+            num_heads=heads,
+            # one cached row serves every head
+            num_kv_heads=1,
+            head_dim=nope + rope_dim,
+            max_model_len=(
+                max_model_len or hf.get("max_position_embeddings", 4096)
+            ),
+            rope_theta=theta,
+            rms_norm_eps=hf.get("rms_norm_eps", 1e-6),
+            tie_word_embeddings=hf.get("tie_word_embeddings", False),
+            dtype=resolve_dtype(dtype),
+            eos_token_id=eos,
+            bos_token_id=hf.get("bos_token_id", 100000) or 1,
+            rope_inv_freq_divisors=divisors,
+            rope_mscale=rope_mscale,
+            hidden_act=ModelConfig._validated_hidden_act(
+                hf.get("hidden_act") or "silu", "deepseek_v2"
+            ),
+            mla=LatentAttentionConfig(
+                q_lora_rank=hf["q_lora_rank"],
+                kv_lora_rank=hf["kv_lora_rank"],
+                qk_nope_head_dim=nope,
+                qk_rope_head_dim=rope_dim,
+                v_head_dim=hf["v_head_dim"],
+                softmax_scale=(nope + rope_dim) ** -0.5 * score_mscale ** 2,
+            ),
+            experts=HeldExpertsConfig(
+                num_routed=num_routed,
+                first_held=first_held,
+                num_held=num_held,
+                n_group=groups,
+                topk_group=hf.get("topk_group") or groups,
+                top_k=hf["num_experts_per_tok"],
+                routed_scaling_factor=float(
+                    hf.get("routed_scaling_factor", 1.0)),
+                num_shared=hf.get("n_shared_experts") or 0,
+                intermediate_size=hf["moe_intermediate_size"],
+                first_dense_layers=hf.get("first_k_dense_replace", 0),
             ),
         )
 
@@ -1305,6 +1542,7 @@ class EngineConfig:
             )
         self._validate_replica_roles()
         self._validate_recurrent_state()
+        self._validate_latent_cache()
         if self.kv_disk_cache_gb > 0 and self.kv_host_cache_gb <= 0:
             raise ValueError(
                 "--kv-disk-cache-gb requires the host KV tier "
@@ -1463,6 +1701,72 @@ class EngineConfig:
                     f"{flag} does not compose with {model} yet: the "
                     f"model keeps recurrent state beside its KV pages, "
                     f"and {missing}; drop the flag (docs/STATE.md)"
+                )
+
+    def _validate_latent_cache(self) -> None:
+        """Truthful flags for a model with a latent cache and held
+        experts (``ModelConfig.mla`` / ``.experts``,
+        docs/LATENT_ATTENTION.md): the pool's row is one latent a token
+        a layer, so whatever quantizes, shares, moves or shards per-head
+        KV pages is refused at boot, with what is missing.  The host KV
+        tier (and decode resume, which rests on it) turns itself off in
+        engine/core.py with a warning, as it does for a model with
+        recurrent state: it is on by default at the command line."""
+        if not self.model_config.has_latent_cache:
+            return
+        model = f"model_type {self.model_config.model_type!r}"
+        pcfg = self.parallel_config
+        refused = [
+            (self.cache_config.kv_quantization != "none",
+             "--kv-quantization",
+             "the page-scale sidecar is laid out per KV head and the "
+             "latent kernel reads unscaled rows (ops/kv_quant.py, "
+             "ops/mla_attention.py)"),
+            (self.cache_config.enable_prefix_caching,
+             "--enable-prefix-caching",
+             "page sharing and copy-on-write move per-head K and V "
+             "pages (engine/runner.py copy programs know no latent "
+             "pool)"),
+            (self.swap_space_gib > 0, "--swap-space",
+             "the swap copy gathers per-head K and V pages; a preempted "
+             "row is recomputed from position 0 instead"),
+            (self.speculative is not None, "--speculative-model",
+             "the draft model's pages share the pool's budget and the "
+             "verify program assumes per-head K and V caches "
+             "(engine/speculative.py)"),
+            (bool(self.kvnet_listen or self.kvnet_peers),
+             "--kvnet-listen / --kvnet-peers",
+             "the kvnet wire format and DecodeCheckpoint carry per-head "
+             "K and V pages (kvnet/, engine/kv_tier.py)"),
+            (self.roles_active(), "--dp-replica-roles",
+             "a prefill-to-decode handoff carries per-head K and V "
+             "pages (engine/kv_tier.py DecodeCheckpoint)"),
+            (self.lora_config.enabled, "--enable-lora",
+             "the adapter stacks target q/k/v/o and the dense MLP's "
+             "projections; the latent projections and the expert "
+             "stacks have no LoRA targets (engine/lora.py)"),
+            (pcfg.tensor_parallel_size > 1, "--tensor-parallel-size > 1",
+             "the latent projections, the expert stacks and the latent "
+             "pool have no shard rules, and an expert layer has no "
+             "exchange across chips (parallel/)"),
+            (pcfg.pipeline_parallel_size > 1, "--pipeline-parallel-size > 1",
+             "the staged runner allocates per-head K and V pools "
+             "(engine/pipeline.py)"),
+            (pcfg.sequence_parallel_size > 1, "--sequence-parallel-size > 1",
+             "the legacy solo-prefill planner sp engines use knows no "
+             "latent pool"),
+            (pcfg.data_parallel_size > 1 or pcfg.dp_replicas > 1,
+             "--data-parallel-size / --dp-replicas > 1",
+             "each replica would need a device slice of its own, and "
+             "slices are cut for tensor-parallel meshes (parallel/)"),
+        ]
+        for on, flag, missing in refused:
+            if on:
+                raise ValueError(
+                    f"{flag} does not compose with {model} yet: the "
+                    f"model keeps a latent cache and a share of its "
+                    f"experts, and {missing}; drop the flag "
+                    f"(docs/LATENT_ATTENTION.md)"
                 )
 
     def resolved_replica_roles(self) -> tuple[str, ...]:

@@ -150,8 +150,25 @@ class LLMEngine:
                                          devices=pp_devices)
         else:
             self.runner = ModelRunner(config, model, params, mesh=mesh)
+        scheduler_config = config.scheduler_config
+        if hasattr(model, "max_step_prefill_tokens"):
+            # a model whose attention kernel bounds a step's work
+            # schedule (models/deepseek_v2.py) says how many prompt
+            # tokens a mixed step may hold; the planner's chunk budget
+            # follows it, and with it the flat-bucket ladder
+            import dataclasses as _dc
+
+            scheduler_config = _dc.replace(
+                scheduler_config,
+                max_num_batched_tokens=min(
+                    scheduler_config.max_num_batched_tokens,
+                    model.max_step_prefill_tokens(
+                        scheduler_config.max_num_seqs,
+                        config.cache_config.block_size),
+                ),
+            )
         self.scheduler = Scheduler(
-            config.scheduler_config,
+            scheduler_config,
             config.cache_config,
             config.cache_config.num_blocks,
             max_model_len=config.max_model_len,
@@ -220,6 +237,7 @@ class LLMEngine:
             and pcfg.pipeline_parallel_size == 1
             and self.scheduler.rolling_window == 0
             and not mcfg.has_recurrent_state
+            and not mcfg.has_latent_cache
         ):
             from vllm_tgis_adapter_tpu.engine.kv_tier import HostKVTier
 
@@ -245,10 +263,12 @@ class LLMEngine:
         elif config.kv_host_cache_gb > 0:
             logger.warning(
                 "--kv-host-cache-gb has no effect with pp > 1, "
-                "rolling-window KV eviction or a model with recurrent "
+                "rolling-window KV eviction, a model with recurrent "
                 "state (a demoted page carries no scan state, "
-                "docs/STATE.md); host KV tier disabled, and with it "
-                "decode resume"
+                "docs/STATE.md) or a model with a latent cache (the "
+                "tier's gather and scatter move per-head K and V pages, "
+                "docs/LATENT_ATTENTION.md); host KV tier disabled, and "
+                "with it decode resume"
             )
         # black-box lifecycle recorder (flight_recorder.py): every
         # admission/dispatch/preemption/finish appends one bounded ring
@@ -587,6 +607,17 @@ class LLMEngine:
                 "the legacy solo-prefill program, which does not carry "
                 "recurrent state between chunks (docs/STATE.md); ask for "
                 "generated-token logprobs only"
+            )
+        if (
+            params.prompt_logprobs is not None
+            and self.config.model_config.has_latent_cache
+        ):
+            raise ValueError(
+                "prompt logprobs are not served for model_type "
+                f"{self.config.model_config.model_type!r} yet: they ride "
+                "the legacy solo-prefill program, which knows no latent "
+                "pool (docs/LATENT_ATTENTION.md); ask for generated-token "
+                "logprobs only"
             )
         seq = Sequence(
             request_id,
@@ -1871,12 +1902,61 @@ class LLMEngine:
         m = self.config.model_config
         return 2 * m.num_layers * m.mixer.scan_state_bytes
 
+    def _observe_latent_attention(self, spans, steps: int) -> None:
+        """Latent-attention work of a dispatched plan, from the host's
+        copy of its shape: ``spans`` are ``(rows, first position)`` of
+        each sequence's tokens, ``steps`` the kernel calls a layer."""
+        from vllm_tgis_adapter_tpu.ops.mla_attention import (
+            attended_pairs,
+            attention_flops,
+        )
+
+        m = self.config.model_config
+        pairs = sum(attended_pairs(n, pos0) for n, pos0 in spans)
+        metrics.observe_latent_attention(
+            flops=m.num_layers * attention_flops(
+                pairs, m.num_heads, m.mla.latent_dim, m.mla.kv_lora_rank),
+            calls=m.num_layers * steps,
+        )
+
+    def _observe_result(self, plan, prepared) -> None:
+        """Telemetry that waits for a step's results: the expert counts
+        its program handed back (engine/runner.py)."""
+        counts = getattr(prepared, "expert_counts", None)
+        if counts is None:
+            return
+        try:
+            import jax.numpy as jnp
+
+            m = self.config.model_config
+            ex = m.experts
+            if isinstance(plan, RaggedPlan):
+                tokens, steps = plan.total_tokens, 1
+            else:
+                tokens = sum(plan.steps_per_seq)
+                steps = max(plan.steps_per_seq, default=0)
+            layers = m.num_layers - ex.first_dense_layers
+            item = jnp.dtype(m.dtype).itemsize
+            metrics.observe_expert_step(
+                routed_pairs=tokens * ex.top_k * layers,
+                local_pairs=counts[0], experts_hit=counts[1],
+                layer_steps=layers * steps, num_held=ex.num_held,
+                expert_bytes=3 * m.hidden_size * ex.intermediate_size * item,
+                pair_bytes=3 * (m.hidden_size + ex.intermediate_size) * item,
+            )
+        except Exception:  # pragma: no cover — metrics are best-effort
+            logger.debug("expert metric observation failed", exc_info=True)
+
     def _observe_plan(self, plan, prepared) -> None:
         """Step-level telemetry (metrics.py): batch occupancy / padding
         waste gauges for this dispatch's shape, plus the plan→commit
         timestamp the commit phase turns into a step-duration sample."""
         try:
             if isinstance(plan, RaggedPlan):
+                if self.config.model_config.has_latent_cache:
+                    self._observe_latent_attention(
+                        [(len(i.token_ids), i.start_pos)
+                         for i in plan.items], steps=1)
                 if getattr(self.runner, "has_state", False):
                     spans = [len(i.token_ids) for i in plan.items]
                     ones = sum(1 for n in spans if n == 1)
@@ -1911,6 +1991,13 @@ class LLMEngine:
                     pages_per_item,
                 )
 
+                if self.config.model_config.has_latent_cache:
+                    # a row's steps are consecutive tokens from its
+                    # position; the loop ends at the last live step
+                    self._observe_latent_attention(
+                        zip(plan.steps_per_seq,
+                            prepared.positions[: len(plan.seqs)].tolist()),
+                        steps=max(plan.steps_per_seq, default=0))
                 if getattr(self.runner, "has_state", False):
                     metrics.observe_state_plan(
                         update_rows=len(plan.seqs),
@@ -1948,6 +2035,7 @@ class LLMEngine:
         else:
             result = self.runner.execute_decode(prepared)
         self.steptime.end_wait(prepared)
+        self._observe_result(plan, prepared)
         return result
 
     def _stamp_dispatched(self, prepared, handle) -> None:  # noqa: ANN001
@@ -1991,6 +2079,7 @@ class LLMEngine:
         else:
             result = self.runner.wait_decode(prepared, handle)
         self.steptime.end_wait(prepared)
+        self._observe_result(plan, prepared)
         return result
 
     # --------------------------------------------------- chained decode waves

@@ -68,8 +68,39 @@ def device_report(engine: "LLMEngine") -> dict:
             "slots_used": state_used,
             "bytes": state_slab_bytes(engine.config),
         },
+        # a model with a latent cache (docs/LATENT_ATTENTION.md): what a
+        # row of the pool holds and what a token costs; the share of its
+        # experts this server holds
+        **_latent_report(engine),
         "weights_load_s": engine.weights_load_s,
         "memory": memory,
+    }
+
+
+def _latent_report(engine: "LLMEngine") -> dict:
+    m = engine.config.model_config
+    if not m.has_latent_cache:
+        return {}
+    import jax.numpy as jnp
+
+    mla, ex, ccfg = m.mla, m.experts, engine.config.cache_config
+    rope_end = mla.rope_offset + mla.qk_rope_head_dim
+    return {
+        "latent_pool": {
+            "row": f"c_kv[0:{mla.kv_lora_rank}] | "
+                   f"k_pe[{mla.rope_offset}:{rope_end}] | "
+                   f"zeros to {mla.row_width} lanes",
+            "row_values": mla.latent_dim,
+            "row_lanes": mla.row_width,
+            "bytes_per_token": m.num_layers * mla.row_width
+            * jnp.dtype(ccfg.cache_dtype).itemsize,
+            "tokens": ccfg.num_blocks * ccfg.block_size,
+        },
+        "held_experts": {
+            "first": ex.first_held, "last": ex.held[1] - 1,
+            "held": ex.num_held, "routed": ex.num_routed,
+            "expert_layers": m.num_layers - ex.first_dense_layers,
+        },
     }
 
 
@@ -89,5 +120,13 @@ def format_report(report: dict) -> str:
             f"; state_slab={report['state_slab']['slots']} slots "
             f"{report['state_slab']['bytes'] / 1e9:.2f} GB"
             if report["state_slab"]["slots"] else ""
+        )
+        + (
+            f"; latent_pool={report['latent_pool']['tokens']} tokens x "
+            f"{report['latent_pool']['bytes_per_token']} B; experts "
+            f"{report['held_experts']['first']}-"
+            f"{report['held_experts']['last']} of "
+            f"{report['held_experts']['routed']} held"
+            if "latent_pool" in report else ""
         )
     )

@@ -86,6 +86,12 @@ def per_block_bytes(config: "EngineConfig") -> int:
     ).itemsize
 
     def one_model(m) -> int:  # noqa: ANN001
+        if m.has_latent_cache:
+            # one latent row a token a layer, not per-head keys and
+            # values (models/deepseek_v2.py make_kv_caches;
+            # docs/LATENT_ATTENTION.md)
+            return (m.num_layers * ccfg.block_size * m.mla.row_width
+                    * itemsize)
         kv_heads_per_dev = max(1, m.num_kv_heads // tp)
         payload = (
             2 * m.num_layers * ccfg.block_size

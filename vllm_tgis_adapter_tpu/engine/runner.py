@@ -13,7 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import TYPE_CHECKING, Optional
+from typing import TYPE_CHECKING, Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -189,6 +189,10 @@ class PreparedRagged:
     # set by dispatch when the verify path actually ran (commit then
     # advances each verify row's draft_pos)
     spec_ran: bool = False
+    # a model with held experts (models/deepseek_v2.py): the running
+    # counts its step handed back (device), then, after wait, this
+    # dispatch's own ``(local pairs, held experts hit)``
+    expert_counts: Any = None
 
 
 @dataclasses.dataclass
@@ -211,6 +215,8 @@ class PreparedDecode:
     # sampler variant with no per-step lax.top_k and zero-width topn
     # outputs (the common serving case)
     want_topn: bool = True
+    # as PreparedRagged.expert_counts
+    expert_counts: Any = None
     # chained wave (async scheduling): which step row of the PREVIOUS
     # wave's device outputs feeds each row's input token
     chain_idx: "Optional[np.ndarray]" = None
@@ -288,6 +294,17 @@ class ModelRunner:
             mesh = mesh_from_parallel_config(pcfg)
         self.mesh = mesh
         self.has_state = getattr(model, "has_recurrent_state", False)
+        # a model that holds a share of its experts carries two running
+        # counts with its pool; every step program hands them back
+        self.has_expert_counts = getattr(model, "has_expert_counts", False)
+        self._expert_counts_seen = np.zeros(2, np.int64)
+        if mcfg.has_latent_cache and mesh is not None:
+            raise ValueError(
+                f"model_type {mcfg.model_type!r} keeps a latent cache, "
+                "and neither its projections, its experts nor the latent "
+                "pool has shard rules yet (parallel/): it cannot run "
+                "under a device mesh"
+            )
         if self.has_state and mesh is not None:
             # boot refuses tp > 1 for such a model (engine/config.py); a
             # mesh handed over directly is refused here
@@ -469,7 +486,8 @@ class ModelRunner:
             "ragged_step",
             jax.jit(
                 functools.partial(
-                    model.ragged_forward, block_size=self.block_size
+                    self._also_counts(model.ragged_forward, caches_at=1),
+                    block_size=self.block_size,
                 ),
                 donate_argnums=donate,
                 compiler_options=step_compiler_options(),
@@ -673,6 +691,7 @@ class ModelRunner:
             )
             return caches, seen, outs
 
+        decode_steps = self._also_counts(decode_steps, caches_at=0)
         donate = (1, 2)
 
         def chained_decode_steps(
@@ -712,6 +731,32 @@ class ModelRunner:
             # ints is arg 3 ([11, B])
             label=lambda args, kwargs: f"batch={args[3].shape[1]}",
         )
+
+    def _also_counts(self, step, *, caches_at: int):
+        """``step`` itself, or, for a model with held experts, ``step``
+        with the running expert counts of its output caches as one more
+        output: they come home with the step's tokens, at no extra
+        sync."""
+        if not self.has_expert_counts:
+            return step
+
+        def with_counts(*args, **kwargs):
+            out = step(*args, **kwargs)
+            return (*out, out[caches_at][-1])
+
+        return with_counts
+
+    def _take_expert_counts(self, prep) -> None:
+        """Blocking half: turn the running counts a dispatch handed back
+        into the dispatch's own (they wrap at 2**32; waits come in
+        dispatch order)."""
+        if prep.expert_counts is None:
+            return
+        # tpulint: disable=TPL202(sanctioned sync: a second, 8-byte fetch of a finished program's output, in the blocking wait_* half only)
+        now = np.asarray(prep.expert_counts).astype(np.int64)
+        delta = (now - self._expert_counts_seen) % (1 << 32)
+        self._expert_counts_seen = now
+        prep.expert_counts = (int(delta[0]), int(delta[1]))
 
     def _put(self, x) -> jax.Array:
         """Host array → device; replicated over the mesh when distributed
@@ -1214,7 +1259,8 @@ class ModelRunner:
 
             # same clamp + cdiv padding the kernel applies, so the
             # schedule covers exactly the kernel's query-block grid
-            block_q = min(128, bucket)
+            model_block_q = getattr(self.model, "ragged_block_q", 128)
+            block_q = min(model_block_q, bucket)
             work, work_items = build_work_schedule(
                 spans, block_tables,
                 block_size=self.block_size, block_q=block_q,
@@ -1228,7 +1274,9 @@ class ModelRunner:
             width = max(
                 work.shape[1],
                 self._ragged_work_hwm.get(bucket, 0),
-                _RAGGED_WORK_FLOOR,
+                # the floor is in query blocks of 128 tokens: a model
+                # with a shorter block has that many more items
+                _RAGGED_WORK_FLOOR * 128 // model_block_q,
             )
             self._ragged_work_hwm[bucket] = width
             if width > work.shape[1]:
@@ -1525,7 +1573,7 @@ class ModelRunner:
         lora_args = ()
         if self.lora_stacks is not None:
             lora_args = (self.lora_stacks, self._put(prep.lora_idx))
-        logits, self.caches = self._ragged_fn(
+        logits, self.caches, *counts = self._ragged_fn(
             self.params,
             self.caches,
             self._put(prep.token_ids),
@@ -1540,6 +1588,7 @@ class ModelRunner:
             **self._work_args(prep),
             **self._state_args(prep),
         )
+        prep.expert_counts = counts[0] if counts else None
         return self._sample_rows(
             logits,
             prep.row_slots,
@@ -1561,6 +1610,7 @@ class ModelRunner:
         if isinstance(handle, dict):
             return self._wait_ragged_verify(prep, handle)
         host = _HostSamplerOutput.from_packed(handle[None])
+        self._take_expert_counts(prep)
         return [
             [host.token(0, i)] if prep.samples[i] else None
             for i in range(prep.num_items)
@@ -1770,7 +1820,8 @@ class ModelRunner:
                 prep.want_topn,
             )
 
-        self.caches, self.seen, packed_out = call()
+        self.caches, self.seen, packed_out, *counts = call()
+        prep.expert_counts = counts[0] if counts else None
         return packed_out
 
     def _pack_decode_inputs(self, prep: "PreparedDecode"):
@@ -1816,7 +1867,8 @@ class ModelRunner:
                 prep.want_topn,
             )
 
-        self.caches, self.seen, packed_out = call()
+        self.caches, self.seen, packed_out, *counts = call()
+        prep.expert_counts = counts[0] if counts else None
         return packed_out
 
     def wait_decode(
@@ -1827,6 +1879,7 @@ class ModelRunner:
         list at EOS/stop-string)."""
         # [K, B, 3+2W] — one fetch per wave
         host = _HostSamplerOutput.from_packed(handle)
+        self._take_expert_counts(prep)
         return [
             [host.token(k, i) for k in range(prep.steps_per_seq[i])]
             for i in range(prep.num_seqs)

@@ -679,12 +679,123 @@ def load_falcon_h1_params(
     return params
 
 
+#: Hugging Face ``deepseek_v2`` tensor names of one layer's attention and
+#: norms (after ``model.layers.<i>.``) -> (pytree key, transpose to
+#: [in, out]); ``kv_b_proj`` is split a head into the absorbed form's
+#: ``w_kc`` and ``w_vc`` (models/deepseek_v2.py)
+DEEPSEEK_V2_LAYER_TENSORS = {
+    "input_layernorm.weight": ("input_norm", False),
+    "post_attention_layernorm.weight": ("post_norm", False),
+    "self_attn.q_a_proj.weight": ("q_a", True),
+    "self_attn.q_a_layernorm.weight": ("q_a_norm", False),
+    "self_attn.q_b_proj.weight": ("q_b", True),
+    "self_attn.kv_a_proj_with_mqa.weight": ("kv_a", True),
+    "self_attn.kv_a_layernorm.weight": ("kv_a_norm", False),
+    "self_attn.kv_b_proj.weight": ("kv_b", False),
+    "self_attn.o_proj.weight": ("wo", True),
+}
+_SWIGLU = (("gate_proj", "w_gate"), ("up_proj", "w_up"),
+           ("down_proj", "w_down"))
+
+
+def deepseek_v2_tensor_names(config: "ModelConfig") -> list[str]:
+    """Every checkpoint tensor ``load_deepseek_v2_params`` asks for: of
+    an expert layer's routed experts, the held ones under their
+    published indices."""
+    ex = config.experts
+    names = ["model.embed_tokens.weight", "model.norm.weight"]
+    if not config.tie_word_embeddings:
+        names.append("lm_head.weight")
+    for i in range(config.num_layers):
+        p = f"model.layers.{i}"
+        names += [f"{p}.{name}" for name in DEEPSEEK_V2_LAYER_TENSORS]
+        if i < ex.first_dense_layers:
+            names += [f"{p}.mlp.{hf}.weight" for hf, _ in _SWIGLU]
+            continue
+        names.append(f"{p}.mlp.gate.weight")
+        names += [f"{p}.mlp.shared_experts.{hf}.weight" for hf, _ in _SWIGLU]
+        names += [f"{p}.mlp.experts.{e}.{hf}.weight"
+                  for e in range(*ex.held) for hf, _ in _SWIGLU]
+    return names
+
+
+def load_deepseek_v2_params(
+    config: "ModelConfig",
+    model_path: str,
+    place: Optional[PlaceFn] = None,
+) -> dict:
+    """Build the DeepseekV2ForCausalLM param pytree
+    (models/deepseek_v2.py) from a Hugging Face ``deepseek_v2``
+    checkpoint: projections transposed to ``[in, out]``; ``kv_b_proj``
+    split a head into ``w_kc [H, nope, rank]`` and ``w_vc [H, rank, v]``
+    (the absorbed form); the rotary columns of ``q_b_proj`` and
+    ``kv_a_proj_with_mqa`` regrouped from interleaved pairs to halves
+    here, once, as the published code regroups them at every call; the
+    held experts' matrices stacked by expert."""
+    raw = open_checkpoint_index(config, model_path)
+    place = place or (lambda _n, x: x)
+    take = _make_take(raw, config.dtype, place, ("",))
+    mla, ex = config.mla, config.experts
+    h, nope, rope_dim = (config.num_heads, mla.qk_nope_head_dim,
+                         mla.qk_rope_head_dim)
+    halves = jnp.concatenate([jnp.arange(0, rope_dim, 2),
+                              jnp.arange(1, rope_dim, 2)])
+    embed = take("model.embed_tokens.weight")
+    params: dict = {
+        "embed": embed,
+        "final_norm": take("model.norm.weight"),
+        "lm_head": (
+            embed.T if config.tie_word_embeddings
+            else take("lm_head.weight", transpose=True)
+        ),
+        "layers": [],
+    }
+    for i in range(config.num_layers):
+        p = f"model.layers.{i}"
+        layer = {
+            key: take(f"{p}.{name}", transpose=transpose)
+            for name, (key, transpose) in DEEPSEEK_V2_LAYER_TENSORS.items()
+        }
+        q_b = layer["q_b"].reshape(-1, h, nope + rope_dim)
+        layer["q_b"] = jnp.concatenate(
+            [q_b[..., :nope], q_b[..., nope:][..., halves]], axis=-1
+        ).reshape(layer["q_b"].shape)
+        kv_a = layer["kv_a"]
+        layer["kv_a"] = jnp.concatenate(
+            [kv_a[:, :mla.kv_lora_rank],
+             kv_a[:, mla.kv_lora_rank:][:, halves]], axis=-1)
+        kv_b = layer.pop("kv_b").reshape(
+            h, nope + mla.v_head_dim, mla.kv_lora_rank)
+        layer["w_kc"] = kv_b[:, :nope]
+        layer["w_vc"] = jnp.swapaxes(kv_b[:, nope:], 1, 2)
+        if i < ex.first_dense_layers:
+            mlp = f"{p}.mlp"
+        else:
+            mlp = f"{p}.mlp.shared_experts"
+            layer["router"] = take(f"{p}.mlp.gate.weight", transpose=True)
+            for hf, key in _SWIGLU:
+                layer[f"experts_{key[2:]}"] = jnp.stack([
+                    take(f"{p}.mlp.experts.{e}.{hf}.weight", transpose=True)
+                    for e in range(*ex.held)
+                ])
+        for hf, key in _SWIGLU:
+            layer[key] = take(f"{mlp}.{hf}.weight", transpose=True)
+        params["layers"].append(layer)
+    ignored = raw.remaining()
+    if ignored:
+        logger.warning("ignored %d unexpected checkpoint tensors: %s",
+                       len(ignored), ignored[:5])
+    return params
+
+
 def load_model_params(
     config: "ModelConfig",
     model_path: str,
     place: Optional[PlaceFn] = None,
 ) -> dict:
     """Dispatch to the checkpoint layout for ``config.model_type``."""
+    if config.model_type == "deepseek_v2":
+        return load_deepseek_v2_params(config, model_path, place)
     if config.model_type == "falcon_h1":
         return load_falcon_h1_params(config, model_path, place)
     if config.model_type == "opt":

@@ -379,6 +379,59 @@ ssm_scan_tokens_total = _get_or_create(
     "Prompt tokens through the chunked scan (the multi-token spans of "
     "mixed steps)",
 )
+# ---- latent attention and held experts (docs/LATENT_ATTENTION.md).  Each
+# pair of counters is raised at ONE place, once for every call of the
+# kernel it speaks of, whatever program made the call (wave step,
+# chained wave, mixed step): work a call and seconds a call are then
+# over the same calls
+mla_attn_flops_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_mla_attn_flops_total",
+    "Floating-point operations of the latent attention kernel: real "
+    "(query row, cached token) pairs under the causal mask x heads x 2 x "
+    "(latent + rotary + latent), from the dispatched plan on the host; "
+    "the two absorbing matmuls outside the kernel are left out",
+)
+mla_attn_calls_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_mla_attn_calls_total",
+    "Calls of the latent attention kernel dispatched: layers x the steps "
+    "of a decode wave's loop, layers x 1 for a mixed step",
+)
+moe_routed_pairs_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_moe_routed_pairs_total",
+    "(token, expert) pairs the routers chose over all the experts they "
+    "score: real tokens x experts a token x expert layers",
+)
+moe_local_pairs_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_moe_local_pairs_total",
+    "Of those, the pairs that fell on experts this server holds and "
+    "were computed (counted on the device, returned with the step's "
+    "tokens); none is dropped",
+)
+moe_expert_bytes_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_moe_expert_bytes_total",
+    "Bytes the grouped matmuls over the held experts had to move: held "
+    "experts hit x their three matrices, plus each local pair's rows in "
+    "and out of the three matmuls",
+)
+moe_expert_calls_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_moe_expert_calls_total",
+    "Grouped matmuls over the held experts dispatched: 3 (gate, up, "
+    "down) x expert layers x the steps of a decode wave's loop, or x 1 "
+    "for a mixed step",
+)
+moe_expert_tokens = _get_or_create(
+    Histogram,
+    f"{_PREFIX}_moe_expert_tokens",
+    "Tokens a held expert computes in one step of one layer: a "
+    "dispatch's local pairs over held experts x expert layers x steps",
+    buckets=(0.25, 0.5, 1, 1.5, 2, 3, 4, 6, 8, 16, 32, 64, 128, 512),
+)
 prefill_padding_waste = _get_or_create(
     Gauge,
     f"{_PREFIX}_prefill_padding_waste",
@@ -824,6 +877,28 @@ def observe_state_plan(*, update_rows: int, update_row_steps: int,
         ssm_state_bytes_total.inc(update_row_steps * row_step_bytes)
     if scan_tokens:
         ssm_scan_tokens_total.inc(scan_tokens)
+
+
+def observe_latent_attention(*, flops: int, calls: int) -> None:
+    """Latent-attention work of one dispatched plan (mixed step, wave or
+    chained wave): the one place both counters are raised."""
+    mla_attn_flops_total.inc(flops)
+    mla_attn_calls_total.inc(calls)
+
+
+def observe_expert_step(*, routed_pairs: int, local_pairs: int,
+                        experts_hit: int, layer_steps: int, num_held: int,
+                        expert_bytes: int, pair_bytes: int) -> None:
+    """Expert-layer work of one finished dispatch, from the counts its
+    program returned: ``layer_steps`` is expert layers x steps of the
+    dispatch.  The one place these counters are raised."""
+    moe_routed_pairs_total.inc(routed_pairs)
+    moe_local_pairs_total.inc(local_pairs)
+    moe_expert_bytes_total.inc(
+        experts_hit * expert_bytes + local_pairs * pair_bytes)
+    moe_expert_calls_total.inc(3 * layer_steps)
+    if layer_steps and num_held:
+        moe_expert_tokens.observe(local_pairs / (layer_steps * num_held))
 
 
 def observe_prefill_plan(*, real_tokens: int, bucket: int,

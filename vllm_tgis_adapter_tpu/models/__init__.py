@@ -9,6 +9,7 @@ data in ModelConfig, not code forks.
 
 from __future__ import annotations
 
+from .deepseek_v2 import DeepseekV2ForCausalLM
 from .falcon_h1 import FalconH1ForCausalLM
 from .llama import LlamaForCausalLM
 
@@ -48,6 +49,10 @@ _REGISTRY = {
     # class of its own, with per-sequence state that is not KV pages
     # (models/falcon_h1.py, docs/STATE.md)
     "falcon_h1": FalconH1ForCausalLM,
+    # DeepSeek-V2: latent attention over a pool of latent rows, and
+    # group-routed experts of which the server holds a share; a class
+    # of its own (models/deepseek_v2.py, docs/LATENT_ATTENTION.md)
+    "deepseek_v2": DeepseekV2ForCausalLM,
 }
 
 

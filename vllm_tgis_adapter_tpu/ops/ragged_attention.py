@@ -206,11 +206,13 @@ def decode_work_schedule(
     block_tables: jax.Array,  # [S, max_blocks] i32
     *,
     block_size: int,
+    block_q: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """In-trace schedule for the fused decode wave, where every span is
     exactly ONE row (``seq_starts = arange(S+1)``): sequence *s* IS flat
     row *s*, so its items live only in query block
-    ``s // decode_block_q(S)``.
+    ``s // decode_block_q(S)`` (or ``s // block_q`` for a kernel with a
+    query block of its own, ops/mla_attention.py).
 
     Returns ``(work, n_items)``.  ``work`` keeps the static shape
     ``[WORK_FIELDS + P, S * cdiv(max_blocks, P)]`` (every row at full
@@ -226,7 +228,7 @@ def decode_work_schedule(
     s_count, max_blocks = block_tables.shape
     run = pages_per_item(block_size)
     max_runs = -(-max_blocks // run)
-    block_q = decode_block_q(s_count)
+    block_q = block_q or decode_block_q(s_count)
     nq = -(-s_count // block_q)
     rows = jnp.arange(s_count, dtype=jnp.int32)
     pages = jnp.where(
