@@ -48,6 +48,7 @@ import checkpoint  # noqa: E402
 import e2e  # noqa: E402
 import loadgen  # noqa: E402
 import prom  # noqa: E402
+import trace_reduce  # noqa: E402
 import traffic as traffic_mod  # noqa: E402
 from server import Server, check_device  # noqa: E402
 
@@ -58,6 +59,13 @@ NOT_HF = {"source", "assumed", "assumed_why", "reduced", "reduced_why",
 REFERENCE_PROMPTS = (48, 145)  # one inside a page run, one across pages
 REFERENCE_TOKENS = 17  # 1 from prefill + 16 from decode through the cache
 PROFILE_AFTER_S = 3.0
+#: a traced run's scrapes around its capture: how often, and for how long
+#: past the seconds the profiler client asks for
+FINE_EVERY_S = 0.25
+FINE_AFTER_S = 8.0
+#: a capture begins about this long after its client is started (3.3-4.2 s
+#: seen); one that could not end inside the window is not begun
+CLIENT_UP_S = 3.5
 SCRIPT_SEED = 1  # the warm-up script's prompts: the same in every run
 WARMUP_CAP_S = 600  # a warm-up still compiling after this fails the run
 #: logged with every run, judged or not (the last line holds only what
@@ -320,7 +328,19 @@ async def observe(session: Session, start: float, end: float,
     """Scrape /metrics at both ends of the window; in a traced run also
     sample it once a second and capture a few seconds of profile: a child
     pinned to the CPU asks the server's ``--jax-profiler-port`` for a
-    device and host trace with Python tracing off."""
+    device and host trace with Python tracing off.  From the moment that
+    child is started until ``FINE_AFTER_S`` past the seconds it asks for,
+    which is where the capture lies (it begins once the child has come up
+    and reached the server), a second list holds a scrape every
+    ``FINE_EVERY_S`` with its ``time.time()``: the counters' values at the
+    capture's own two ends are read from it (``readers/roofline.py``).
+
+    A capture whose client fails, or whose file names no device plane (the
+    engine stood still in those seconds: such a file comes back within
+    6 s where a whole one takes 20-60), is made again at once, with fine
+    scrapes of its own, as long as it can still end inside the window;
+    ``attempts`` says what each one met.  The once-a-second samples and
+    the scrape at the window's end do not wait for any of that."""
     server = session.server
 
     async def at(instant: float) -> None:
@@ -329,51 +349,88 @@ async def observe(session: Session, start: float, end: float,
     async def scrape() -> dict:
         return prom.parse(await asyncio.to_thread(server.get_text, "/metrics"))
 
+    async def profile() -> str | None:
+        """One capture; what went wrong with it, or None."""
+        shutil.rmtree(session.profile_dir, ignore_errors=True)
+        begun, began_wall = time.monotonic(), time.time()
+        child = await asyncio.create_subprocess_exec(
+            sys.executable, "-m", "jax.collect_profile",
+            str(server.profiler_port), str(int(trace_s * 1000)),
+            "--log_dir", str(session.profile_dir), "--no_perfetto_link",
+            "--python_tracer_level=0",
+            env=dict(os.environ, JAX_PLATFORMS="cpu"),
+            stdout=asyncio.subprocess.PIPE,
+            stderr=asyncio.subprocess.STDOUT)
+        text, _ = await child.communicate()
+        out["profile_collect_s"] = time.monotonic() - begun
+        out["profile_began_wall"] = began_wall
+        if child.returncode != 0:
+            return f"jax.collect_profile failed: {text.decode()[-600:]}"
+        # the profiler client gives request + capture + hand-over 60 s:
+        # how far from it this capture stayed (PERF.md section 4)
+        files = sorted(session.profile_dir.rglob("*.xplane.pb"))
+        if not files:
+            return "the profiler client wrote no xplane.pb"
+        stat = files[-1].stat()
+        out["xplane"] = {"bytes": stat.st_size,
+                         "written_after_s": stat.st_mtime - began_wall}
+        if not await asyncio.to_thread(
+                trace_reduce.names_device_plane, files[-1],
+                trace_reduce.plane_pattern(session.on_cpu)):
+            return f"no device plane in {stat.st_size} bytes of xplane.pb"
+        return None
+
+    async def fine(into: list) -> None:
+        tick = time.monotonic()
+        until = tick + trace_s + FINE_AFTER_S
+        while tick < until:
+            await at(tick)
+            asked = time.time()
+            seen = await scrape()
+            into.append(((asked + time.time()) / 2, seen))
+            tick += FINE_EVERY_S
+
+    async def capture() -> None:
+        await at(start + PROFILE_AFTER_S)
+        out["attempts"] = []
+        while True:
+            began = time.monotonic() - start
+            out["fine_samples"] = []
+            sampling = asyncio.create_task(fine(out["fine_samples"]))
+            wrong = await profile()
+            out["attempts"].append({
+                "after_s": round(began, 1), "wrong": wrong,
+                "took_s": round(out["profile_collect_s"], 1)})
+            if wrong is None:
+                await sampling
+                return
+            sampling.cancel()
+            if time.monotonic() + CLIENT_UP_S + trace_s > end:
+                raise RuntimeError(
+                    f"no capture of the window holds a device operation: "
+                    f"{out['attempts']}")
+
     await at(start)
     out["scrape0"] = await scrape()
     out["samples"] = []
+    capturing = asyncio.create_task(capture()) if trace else None
     if trace:
-        async def profile() -> None:
-            await at(start + PROFILE_AFTER_S)
-            begun, began_wall = time.monotonic(), time.time()
-            child = await asyncio.create_subprocess_exec(
-                sys.executable, "-m", "jax.collect_profile",
-                str(server.profiler_port), str(int(trace_s * 1000)),
-                "--log_dir", str(session.profile_dir), "--no_perfetto_link",
-                "--python_tracer_level=0",
-                env=dict(os.environ, JAX_PLATFORMS="cpu"),
-                stdout=asyncio.subprocess.PIPE,
-                stderr=asyncio.subprocess.STDOUT)
-            text, _ = await child.communicate()
-            if child.returncode != 0:
-                raise RuntimeError(
-                    f"jax.collect_profile failed:\n{text.decode()[-1500:]}")
-            out["trace_span"] = (begun, begun + trace_s)
-            out["profile_collect_s"] = time.monotonic() - begun
-            # the profiler client gives request + capture + hand-over 60 s:
-            # how far from it this capture stayed (PERF.md section 4)
-            for file in session.profile_dir.rglob("*.xplane.pb"):
-                stat = file.stat()
-                out["xplane"] = {
-                    "bytes": stat.st_size,
-                    "written_after_s": stat.st_mtime - began_wall}
-
-        profiling = asyncio.create_task(profile())
         tick = start + 1.0
         while tick < end:
             await at(tick)
             out["samples"].append(await scrape())
             tick += 1.0
-        await profiling
     await at(end)
     out["scrape1"] = await scrape()
+    if capturing:
+        await capturing
 
 
 def reduce_trace(profile_dir: Path, on_cpu: bool, keep: Path | None) -> dict:
     """In a child pinned to the CPU, after the server has exited."""
-    cmd = [sys.executable, str(HERE / "trace_reduce.py"), str(profile_dir)]
-    if on_cpu:
-        cmd.append(r"^/host:CPU$")  # rehearsal: exercises the reducer only
+    # a rehearsal's pattern exercises the reducer only
+    cmd = [sys.executable, str(HERE / "trace_reduce.py"), str(profile_dir),
+           trace_reduce.plane_pattern(on_cpu)]
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     done = subprocess.run(cmd, env=env, capture_output=True, text=True,
                           timeout=900)
@@ -386,6 +443,20 @@ def reduce_trace(profile_dir: Path, on_cpu: bool, keep: Path | None) -> dict:
     if done.returncode != 0:
         raise RuntimeError(f"trace reduction failed:\n{done.stderr[-2000:]}")
     return json.loads(done.stdout.strip().splitlines()[-1])
+
+
+def placed(seen: dict, summary: dict) -> dict:
+    """Where the capture and the scrapes around it lay, in seconds after
+    the profiler client was started: for the log."""
+    began = seen["profile_began_wall"]
+    fine = [t - began for t, _ in seen["fine_samples"]]
+    return {
+        "capture_after_client_s": [
+            t - began for t in summary["capture_wall"] or []],
+        "fine_after_client_s": fine[:1] + fine[-1:],
+        "fine": len(fine), "samples": len(seen["samples"]),
+        "attempts": seen["attempts"],
+    }
 
 
 def layer_metrics(cell_name: str, ctx: dict, on_cpu: bool) -> dict:
@@ -510,12 +581,14 @@ def main() -> None:
             "records": records,
             "window_s": args.seconds, "scrape0": seen.get("scrape0"),
             "scrape1": seen.get("scrape1"), "samples": seen.get("samples"),
-            "trace": summary, "trace_span": seen.get("trace_span"),
+            "fine_samples": seen.get("fine_samples"), "trace": summary,
             "hf_config": session.hf_config,
             "device_kind": device["device_kind"],
         }
         metrics = layer_metrics(args.workload, ctx, args.rehearse_on_cpu)
         if summary["busy_s"] <= 0 and not args.rehearse_on_cpu:
+            say(phase="trace_empty", lines=summary["lines"],
+                planes=summary["all_planes"], capture=placed(seen, summary))
             raise RuntimeError("the trace holds no device operation")
         if not args.rehearse_on_cpu:
             out_device |= {"busy_s": summary["busy_s"],
@@ -525,7 +598,7 @@ def main() -> None:
         say(phase="trace", lines=summary["lines"],
             planes=summary["all_planes"],
             profile_collect_s=seen.get("profile_collect_s"),
-            xplane=seen.get("xplane"),
+            xplane=seen.get("xplane"), capture=placed(seen, summary),
             also={n: e2e.compute(n, records, args.seconds)
                   for n in CLIENT_SEES})
     say(phase="detail", reference=got["reference"], warmup=got["warmup"],

@@ -67,5 +67,14 @@ def test_a_rehearsal_cell_still_prints_a_line(cell, trace, tmp_path):
     if trace:
         assert "gen_late_p99_ms" in line["metrics"]
         assert "device_idle_pct" not in line["metrics"]  # no device here
+        # the once-a-second samples are what they were (1-9 s into a window
+        # of 10), and the fine scrapes lie around the capture and hold it
+        said = [json.loads(text) for text in done.stdout.splitlines()
+                if text.startswith('{"phase": "trace"')][-1]["capture"]
+        assert said["samples"] == 9 and 36 <= said["fine"] <= 40
+        first, last = said["fine_after_client_s"]
+        begins, ends = said["capture_after_client_s"]
+        assert 0 <= first <= begins < ends <= last <= 10.5
+        assert [a["wrong"] for a in said["attempts"]] == [None]
     else:
         assert set(line["metrics"]) == {"gap_p99_ms", "setup_s"}

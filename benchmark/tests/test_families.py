@@ -114,8 +114,8 @@ def test_every_configuration_names_a_family_that_fits_its_widths(config):
     """Holds for a configuration a later PR adds, whatever its family:
     the module is there, its table has a layer for every layer and a
     tensor with the vocabulary's rows, and every matrix has the residual
-    width on one side.  Written to be lifted into the tier-1 tests as it
-    is (it imports no JAX): PERF.md section 7."""
+    width (or a low rank the configuration names) on one side.  Its copy
+    in the tier-1 tests is ``tests/test_benchmark_families.py``."""
     full, hf = served_config(config)
     assert "family" in full and "family" not in hf
     top, layers = run.load_family(full["family"]).tensors(hf)
@@ -123,9 +123,13 @@ def test_every_configuration_names_a_family_that_fits_its_widths(config):
     shapes = [shape for table in (top, *layers)
               for shape, _ in table.values()]
     assert any(shape[0] == hf["vocab_size"] for shape in shapes)
+    # a low-rank projection's second factor (deepseek_v2's q_b_proj and
+    # kv_b_proj) has its rank where other matrices have the residual width
+    widths = {hf["hidden_size"], hf.get("q_lora_rank"),
+              hf.get("kv_lora_rank")} - {None}
     for shape in shapes:
         if len(shape) == 2:
-            assert hf["hidden_size"] in shape, shape
+            assert widths & set(shape), shape
 
 
 def test_an_unknown_family_ends_the_run_naming_the_missing_file():

@@ -185,15 +185,19 @@ def test_the_roofline_reader_takes_the_flop_counters():
     seconds_a_call = flops_a_call / 197e12 * 10  # a tenth of the peak
     name = "mla_ragged_attention custom-call:tpu_custom_call"
     ctx = {
-        "samples": [scrape(100 * k) for k in range(20)],
-        "trace_span": (1000.0, 1002.0), "device_kind": "TPU v5 lite",
+        # a scrape every quarter of a second around a capture of 2 s
+        "fine_samples": [(1000.0 + k / 4, scrape(75 * k))
+                         for k in range(28)],
+        "device_kind": "TPU v5 lite",
         "trace": {"device_planes": ["/device:TPU:0"], "busy_s": 1.0,
+                  "capture_wall": [1002.6, 1004.6],
                   "op_seconds": {name: 600 * seconds_a_call, "fusion": 0.5},
                   "op_calls": {name: 600, "fusion": 9}},
     }
     assert roofline.read(params, ctx) == pytest.approx(10.0)
     # a parent without the counters: silent
-    assert roofline.read(params, {**ctx, "samples": [{}, {}] * 10}) is None
+    empty = [(t, {}) for t, _ in ctx["fine_samples"]]
+    assert roofline.read(params, {**ctx, "fine_samples": empty}) is None
 
 
 def test_no_branch_is_flattened_at_the_published_widths():

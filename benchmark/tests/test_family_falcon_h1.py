@@ -144,9 +144,12 @@ def test_the_roofline_reader_divides_bytes_a_call_by_seconds_a_call():
     # 30 live rows a call at half the published bandwidth
     seconds_a_call = 30 * per_row / 819e9 * 2
     ctx = {
-        "samples": [scrape(100 * k, 30) for k in range(20)],
-        "trace_span": (1000.0, 1002.0), "device_kind": "TPU v5 lite",
+        # a scrape every quarter of a second around a capture of 2 s
+        "fine_samples": [(1000.0 + k / 4, scrape(75 * k, 30))
+                         for k in range(28)],
+        "device_kind": "TPU v5 lite",
         "trace": {"device_planes": ["/device:TPU:0"], "busy_s": 1.0,
+                  "capture_wall": [1002.6, 1004.6],
                   "op_seconds": {"ssm_decode_update custom-call:x":
                                  600 * seconds_a_call, "fusion": 0.5},
                   "op_calls": {"ssm_decode_update custom-call:x": 600,
@@ -154,7 +157,8 @@ def test_the_roofline_reader_divides_bytes_a_call_by_seconds_a_call():
     }
     assert roofline.read(params, ctx) == pytest.approx(50.0)
     # a parent without the counters, or a trace without the kernel: silent
-    assert roofline.read(params, {**ctx, "samples": [{}, {}] * 10}) is None
+    empty = [(t, {}) for t, _ in ctx["fine_samples"]]
+    assert roofline.read(params, {**ctx, "fine_samples": empty}) is None
     bare = {**ctx["trace"], "op_seconds": {"fusion": 0.5},
             "op_calls": {"fusion": 9}}
     assert roofline.read(params, {**ctx, "trace": bare}) is None

@@ -71,9 +71,44 @@ def test_a_recorded_trace_goes_through_the_real_parser():
     """``data/cpu_small.xplane.pb``: three runs of one jitted function,
     recorded on the CPU with Python tracing off (8 KB); it has no TPU
     plane, so the host plane stands in for the device here."""
-    raw = trace_reduce.read_planes(HERE / "data" / "cpu_small.xplane.pb")
+    raw, session_start_ns = trace_reduce.read_planes(
+        HERE / "data" / "cpu_small.xplane.pb")
     assert "/host:CPU" in [name for name, _ in raw]
     assert trace_reduce.reduce_planes(raw)["device_planes"] == []
-    got = trace_reduce.reduce_planes(raw, r"^/host:CPU$")
+    got = trace_reduce.reduce_planes(raw, r"^/host:CPU$", session_start_ns)
     assert 0 < got["busy_s"] <= got["window_s"] < 1.0
     assert any("PjitFunction" in name for name in got["op_seconds"])
+    # the file's Task Environment plane says when its session began, in
+    # nanoseconds since the epoch, and the events count from there
+    assert session_start_ns == 1790487859575495314
+    first, last = got["capture_wall"]
+    assert 0 < first - session_start_ns / 1e9 < 0.001
+    assert last - first == pytest.approx(got["window_s"], abs=1e-6)
+
+
+def test_the_capture_is_placed_on_the_wall_clock_or_not_at_all():
+    """The device plane's events run from 0 to 8 s after the session's
+    start; the host plane's 100 s do not count."""
+    begun = 1_790_000_000 * 10**9
+    got = trace_reduce.reduce_planes(planes(), session_start_ns=begun)
+    assert got["capture_wall"] == pytest.approx([1.79e9, 1.79e9 + 8.0])
+    assert trace_reduce.reduce_planes(planes())["capture_wall"] is None
+    no_device = trace_reduce.reduce_planes(planes()[:1], session_start_ns=begun)
+    assert no_device["capture_wall"] is None
+
+
+def test_a_file_names_its_device_plane_in_plain_bytes(tmp_path):
+    """What the load generator looks at while its window runs: a capture
+    of seconds in which the engine stood still names the host's planes
+    and the chip's other ones, and no ``/device:TPU:<n>``."""
+    whole, empty = tmp_path / "whole.pb", tmp_path / "empty.pb"
+    whole.write_bytes(b"\x0a\x15#Chip0 Host Interface\x0a\x0d/device:TPU:0"
+                      b"\x12\x07XLA Ops\x0a\x09/host:CPU")
+    empty.write_bytes(b"\x0a\x15#Chip0 Host Interface\x0a\x09/host:CPU"
+                      b"\x0a\x1f/device:CUSTOM:Megascale Trace")
+    assert trace_reduce.names_device_plane(whole)
+    assert not trace_reduce.names_device_plane(empty)
+    on_cpu = trace_reduce.plane_pattern(True)
+    assert trace_reduce.names_device_plane(empty, on_cpu)
+    assert trace_reduce.plane_pattern(False) == trace_reduce.DEVICE_PLANE
+

@@ -13,6 +13,12 @@ intervals, named by the operation that ended each gap (no host span can
 name them yet).  The traced window is the span from the first to the last
 event of the device planes: the host's planes also hold the seconds the
 profiler itself takes to start and to stop, in which no step is traced.
+``capture_wall`` places that span on the wall clock (seconds since the
+epoch, as ``time.time()`` reads them on the same machine): an event's
+``start_ns`` counts from the profiler session's start, which the file's
+``Task Environment`` plane states as ``profile_start_time``, nanoseconds
+since the epoch (PERF.md section 6, PR 44).  A file without that plane
+or without a device event places nothing: None.
 An operation is named by its HLO instruction without the number, and its
 kind, so the forty layers' calls of one kernel add up under one name.
 """
@@ -25,6 +31,9 @@ import sys
 from pathlib import Path
 
 DEVICE_PLANE = r"^/device:TPU:\d+$"
+#: the plane whose stats say when the profiler session began and ended
+SESSION_PLANE = "Task Environment"
+SESSION_START = "profile_start_time"
 #: the line of a device plane that holds one event per operation
 OPS_LINE = "XLA Ops"
 TOP = 10
@@ -63,9 +72,27 @@ def union_and_gaps(intervals: list[tuple[float, float, str]]):
     return busy, gaps
 
 
-def reduce_planes(planes, device_plane: str = DEVICE_PLANE) -> dict:
+def plane_pattern(on_cpu: bool) -> str:
+    """The planes that count as the device's: the chip's, or in a
+    rehearsal on the CPU the host's own, which exercises the code only."""
+    return r"^/host:CPU$" if on_cpu else DEVICE_PLANE
+
+
+def names_device_plane(path: Path, device_plane: str = DEVICE_PLANE) -> bool:
+    """Whether the file names a plane that matches, without parsing it (a
+    plane's name stands in the file as plain bytes): what the load
+    generator can afford to look at while its window runs.  A capture of
+    seconds in which the engine stood still has no device plane at all."""
+    name = device_plane.strip("^$").encode()
+    return re.search(rb"(?<![\w/:])" + name + rb"(?![\w/:])",
+                     path.read_bytes()) is not None
+
+
+def reduce_planes(planes, device_plane: str = DEVICE_PLANE,
+                  session_start_ns: int | None = None) -> dict:
     """``planes``: [(plane name, [(line name, [(name, start_ns,
-    duration_ns)])])], as ``ProfileData`` gives them."""
+    duration_ns)])])], as ``ProfileData`` gives them; ``session_start_ns``:
+    the wall-clock instant their ``start_ns`` count from."""
     lo, hi = None, None
     devices = []
     for plane_name, lines in planes:
@@ -100,6 +127,7 @@ def reduce_planes(planes, device_plane: str = DEVICE_PLANE) -> dict:
             "lines": [(name, len(events)) for name, events in lines],
         })
     window_s = 0.0 if lo is None else (hi - lo) / 1e9
+    placed = lo is not None and session_start_ns is not None
     n = max(1, len(devices))
 
     def mean_top(key: str) -> list:
@@ -111,6 +139,8 @@ def reduce_planes(planes, device_plane: str = DEVICE_PLANE) -> dict:
 
     return {
         "window_s": window_s,
+        "capture_wall": [(session_start_ns + lo) / 1e9,
+                         (session_start_ns + hi) / 1e9] if placed else None,
         "busy_s": sum(d["busy_s"] for d in devices) / n,
         "device_planes": [d["plane"] for d in devices],
         "all_planes": [name for name, _ in planes],
@@ -126,19 +156,23 @@ def reduce_planes(planes, device_plane: str = DEVICE_PLANE) -> dict:
 
 
 def read_planes(path: Path):
+    """(the planes as ``reduce_planes`` takes them, the session's start in
+    nanoseconds since the epoch or None)."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(str(path))
-    return [
-        (plane.name, [
+    planes, session_start_ns = [], None
+    for plane in data.planes:
+        if plane.name == SESSION_PLANE:
+            session_start_ns = dict(plane.stats).get(SESSION_START)
+        planes.append((plane.name, [
             (line.name, [
                 (e.name, float(e.start_ns), float(e.duration_ns))
                 for e in line.events
             ])
             for line in plane.lines
-        ])
-        for plane in data.planes
-    ]
+        ]))
+    return planes, session_start_ns
 
 
 def main() -> None:
@@ -147,7 +181,8 @@ def main() -> None:
     found = sorted(root.rglob("*.xplane.pb"))
     if not found:
         raise SystemExit(f"no *.xplane.pb under {root}")
-    print(json.dumps(reduce_planes(read_planes(found[-1]), pattern)))
+    planes, session_start_ns = read_planes(found[-1])
+    print(json.dumps(reduce_planes(planes, pattern, session_start_ns)))
 
 
 if __name__ == "__main__":
