@@ -60,8 +60,7 @@ def test_every_configuration_names_a_family_that_fits_its_widths(config):
     assert any(shape[0] == hf["vocab_size"] for shape in shapes)
     # a low-rank projection's second factor (deepseek_v2's q_b_proj and
     # kv_b_proj) has its rank where other matrices have the residual
-    # width; benchmark/tests/test_families.py's copy of this guard does
-    # not know that yet (PERF.md section 7)
+    # width
     widths = {hf["hidden_size"], hf.get("q_lora_rank"),
               hf.get("kv_lora_rank")} - {None}
     for shape in shapes:

@@ -1,8 +1,9 @@
-"""Chained decode waves (vLLM-style async scheduling).
+"""Decode waves planned ahead (vLLM-style async scheduling).
 
 While one fused decode wave executes on device, its successor is planned
-from host projections and dispatched with token feedback read from the
-in-flight wave's device outputs (engine/runner.py chained_decode_steps).
+from host projections and dispatched with token feedback read on the
+device (runner.last_tokens, the newest sampled token of every batch row;
+the general rule, any dispatch behind any, is tests/test_plan_ahead.py).
 Pinned here:
 
 * greedy output parity with the synchronous engine;
@@ -447,12 +448,15 @@ def test_one_wave_program_for_every_step_count(tiny_model_dir):
 
 
 def test_chained_wave_after_short_wave(tiny_model_dir):
-    """A chained wave behind a SHORT one (a tight pool halves a row's
-    fused run, scheduler._schedule_decode; here the cap is patched in)
-    reads its input tokens from step ``prev_k - 1`` of the one fixed
-    ``[K, B, ...]`` result: tokens equal the sync engine's, and both
-    wave programs compile once."""
+    """A wave planned ahead behind a SHORT one (a tight pool halves a
+    row's fused run, scheduler._schedule_decode; here the cap is patched
+    in for a wave that follows a mixed step) projects each row
+    ``prev_k`` tokens on and reads its input token from the row's newest
+    sample on the device: tokens equal the sync engine's, and the ONE
+    wave program compiles once (there is no chained twin)."""
+    from vllm_tgis_adapter_tpu.engine.runner import ModelRunner
     from vllm_tgis_adapter_tpu.engine.scheduler import Scheduler
+    from vllm_tgis_adapter_tpu.telemetry.steptime import StepTimeline
 
     requests = [
         ("a", list(range(3, 10)),
@@ -464,23 +468,27 @@ def test_chained_wave_after_short_wave(tiny_model_dir):
 
     inner = Scheduler._allowed_steps
     short_prev = []
-    plain, chained = (
-        _wave_compiles("ragged_decode"),
-        _wave_compiles("ragged_chained_decode"),
-    )
-    from vllm_tgis_adapter_tpu.engine.runner import ModelRunner
+    plain = _wave_compiles("ragged_decode")
+    prepare = ModelRunner.prepare_decode
 
-    prepare = ModelRunner.prepare_chained_decode
-
-    def spy(self, plan, prev_prep):
-        short_prev.append((prev_prep.num_steps, plan.num_steps))
-        return prepare(self, plan, prev_prep)
+    def spy(self, plan):
+        if plan.inflight:
+            short_prev.append((max(plan.inflight), plan.num_steps))
+        return prepare(self, plan)
 
     mp = pytest.MonkeyPatch()
     try:
-        mp.setattr(Scheduler, "_allowed_steps",
-                   lambda self, seq: min(inner(self, seq), 3))
-        mp.setattr(ModelRunner, "prepare_chained_decode", spy)
+        mp.setattr(
+            Scheduler, "_allowed_steps",
+            lambda self, seq: inner(self, seq)
+            if self._inflight.get(id(seq), 0) > 1
+            else min(inner(self, seq), 3),
+        )
+        # the depth rule would follow this machine's clock: hold the
+        # ceiling so the successor's depth is known
+        mp.setattr(StepTimeline, "wave_depth",
+                   lambda self, ceiling, window=16: ceiling)
+        mp.setattr(ModelRunner, "prepare_decode", spy)
         got = _async_run(_config(tiny_model_dir, num_decode_steps=8),
                          requests)
     finally:
@@ -489,4 +497,4 @@ def test_chained_wave_after_short_wave(tiny_model_dir):
     # a plain wave of 3 steps, then its successor at the full 8
     assert (3, 8) in short_prev, short_prev
     assert _wave_compiles("ragged_decode") - plain == 1
-    assert _wave_compiles("ragged_chained_decode") - chained == 1
+    assert _wave_compiles("ragged_chained_decode") == 0

@@ -833,7 +833,10 @@ def test_dp_replica_death_replays_cross_replica_with_bounded_ttft(
         # targeted equivalent of the core.wait_step hang+raise combo
         orig_wait = victim.engine.wait_step
 
+        parked = threading.Event()
+
         def blocking_wait(plan, prepared, handle):
+            parked.set()
             if not gate.wait(timeout=60):
                 return orig_wait(plan, prepared, handle)
             raise failpoints.FailpointError(
@@ -841,6 +844,9 @@ def test_dp_replica_death_replays_cross_replica_with_bounded_ttft(
             )
 
         victim.engine.wait_step = blocking_wait
+        # the loop plans a successor BEFORE it pulls a result, so it may
+        # still admit what arrives until it is parked: send w only then
+        await _wait_for(parked.is_set, what="the victim's loop to park")
         # w lands on the frozen victim (tenant stickiness) and stays a
         # zero-token waiting request — the replay-safe class
         w_task = asyncio.create_task(_collect(

@@ -1226,6 +1226,9 @@ class AsyncLLMEngine:
                 "replicas": [
                     {
                         "replica": rep.index,
+                        # bails of the overlapped loop by reason (the
+                        # counter: tgis_tpu_step_ahead_bails_total)
+                        "ahead_bails": dict(rep.engine.ahead_bails),
                         **rep.engine.steptime.debug_state(),
                     }
                     for rep in self._replicas
@@ -1605,27 +1608,48 @@ class AsyncLLMEngine:
     # ------------------------------------------------------------- step loop
 
     async def _run_loop(self, rep: _Replica) -> None:
-        """Depth-1 pipelined step loop (host/device overlap).
+        """The step loop: every successor is planned while its
+        predecessor runs.
 
         The lock covers only the fast host phases (plan/commit); device
         work runs WITHOUT it so aborts and new requests land mid-dispatch
-        instead of queueing behind a full fused-step program.
+        instead of queueing behind a device program.
 
         Overlap: ``dispatch_step`` only ENQUEUES device work (JAX async
-        dispatch); while one dispatch executes, the loop plans and
-        enqueues the next admission (``plan_step(prefill_only=True)`` —
-        admissions are independent of the pending commit) and only then
-        blocks on the in-flight results.  The device therefore runs
-        back-to-back programs across prefill waves instead of idling
-        through each step's host prep — the async-scheduling behavior
-        the reference consumes from vLLM
-        (/root/reference/src/vllm_tgis_adapter/grpc/grpc_server.py:205).
+        dispatch).  While dispatch *n* executes, the loop plans, prepares
+        and enqueues dispatch *n+1* (``plan_step(ahead_of=n)``), whatever the
+        two are — wave after wave, mixed step after wave, wave after
+        mixed step, mixed step after mixed step — and only then blocks
+        on *n*'s results.  *n+1* is a projection: every row *n* samples
+        is taken to consume its whole budget there, its input token is
+        read on the device from *n*'s sample, admissions and prompt
+        chunks come from the host as always.  A row that ends inside *n*
+        has its *n+1* tokens discarded at commit; what it frees — pages
+        and its batch row — is quarantined until *n+1* has retired
+        (free epochs).  The device therefore runs back-to-back programs
+        and a wave needs to be no deeper than hides the host's turn
+        (``LLMEngine._set_wave_depth``), so the gap a client sees
+        between frames is one token step and not one fused wave.  A
+        committed dispatch's frames go to their streams once the next
+        dispatch is enqueued (``commit_in_flight`` / ``deliver``).
+
+        Where a projection cannot be right or safe the loop commits *n*
+        first and plans in the serial order (``plan_step``); each such
+        bail is counted by its reason (``plan_ahead_step``).  With
+        ``enable_chained_decode`` off the loop is strictly serial — only
+        a cold-start admission may follow a dispatch in flight — and is
+        the control the tests compare against.  The staged runner
+        answers ``SYNC_DISPATCH``: its work happens inside the wait, so
+        nothing can be enqueued behind it.
         """
         from vllm_tgis_adapter_tpu.engine.runner import SYNC_DISPATCH
 
         engine = rep.engine
-        # (plan, prepared, handle, chained) — chained waves hold a free
-        # quarantine epoch open until they retire
+        engine.overlapped = (
+            engine.config.scheduler_config.enable_chained_decode
+        )
+        # (plan, prepared, handle, ahead) — a dispatch planned ahead
+        # holds a free quarantine epoch open until it retires
         in_flight: Optional[tuple] = None
 
         async def emit(outputs) -> None:
@@ -1638,28 +1662,47 @@ class AsyncLLMEngine:
                     async with rep.lock:
                         engine.abort_request(out.request_id)
 
-        async def commit_in_flight() -> None:
-            nonlocal in_flight
-            plan, prepared, handle, chained = in_flight
+        async def commit_in_flight(then_dispatching: bool = False) -> None:
+            """Wait for the dispatch in flight, commit it and hand its
+            outputs to their streams — at once, or, ``then_dispatching``,
+            once the loop has enqueued the dispatch it plans next
+            (``deliver``): the stream consumers run on this event loop
+            and the dispatch runs in a worker thread under the same
+            interpreter lock, so consumers woken before the enqueue
+            delay it, and the device waits."""
+            nonlocal in_flight, undelivered
+            plan, prepared, handle, ahead = in_flight
             result = await asyncio.to_thread(
                 engine.wait_step, plan, prepared, handle
             )
             async with rep.lock:
-                if chained:
-                    # this wave has retired: the frees quarantined when
-                    # it was dispatched can no longer be stale-written
+                if ahead:
+                    # this dispatch has retired: the frees quarantined
+                    # when it was planned can no longer be stale-written
                     engine.flush_free_epoch()
                 outs = engine.commit_step(plan, result, prepared)
             in_flight = None
             rep.in_flight_desc = None
             rep.last_beat = time.monotonic()
-            await emit(outs)
+            await deliver()  # streams get their frames in commit order
+            undelivered = (outs, self._plan_tokens(plan))
+            if not then_dispatching or engine.pending_handoffs:
+                await deliver()
             if engine.pending_handoffs:
                 # prefill-role commit staged finished prompts: move
                 # them onto decode-capable replicas NOW, before the
-                # next prefill wave (docs/SCALING.md)
+                # next prefill wave (docs/SCALING.md); their first
+                # tokens were delivered just above
                 await self._drain_handoffs(rep)
-            committed = self._plan_tokens(plan)
+
+        async def deliver() -> None:
+            """Hand the committed dispatch's outputs to their streams
+            and feed the per-commit telemetry."""
+            nonlocal undelivered
+            if undelivered is None:
+                return
+            (outs, committed), undelivered = undelivered, None
+            await emit(outs)
             # per-replica committed-token attribution: the placement
             # router's load tiebreak
             self.router.note_committed(rep.index, committed)
@@ -1672,33 +1715,21 @@ class AsyncLLMEngine:
                 # throughput EWMA
                 self.frontdoor.note_progress(committed, replica=rep.index)
 
-        async def try_chain() -> Optional[tuple]:
-            """Dispatch the in-flight decode's successor wave from
-            device-resident token feedback (async scheduling).  Returns
-            the successor's in_flight tuple, or None when chaining is
-            not possible."""
-            plan, prepared, handle, _ = in_flight
-            if handle is SYNC_DISPATCH:
-                return None
-            async with rep.lock:
-                chained = engine.plan_chained_step(plan, prepared)
-                if chained is None:
-                    return None
-                # the quarantine epoch opens in the SAME critical section
-                # that planned the successor: from this point any free —
-                # an abort sneaking in during the dispatch await, or the
-                # predecessor's commit reaping finished rows — buffers
-                # until the successor (whose block tables reference those
-                # pages) has retired
-                engine.begin_free_epoch()
-            c_plan, c_prep = chained
-            c_handle = await asyncio.to_thread(
-                engine.dispatch_chained_step, c_plan, c_prep, handle
-            )
-            chained_desc = {**(describe_plan(c_plan) or {}), "chained": True}
-            await commit_in_flight()
-            rep.in_flight_desc = chained_desc
-            return (c_plan, c_prep, c_handle, True)
+        # outputs of a committed dispatch that wait for ``deliver``
+        undelivered: Optional[tuple] = None
+
+        def hand_over_undelivered() -> None:
+            """The loop is ending (death, cancel): what was committed is
+            in the sequences' state, so it must reach the streams too —
+            a checkpointed request resumes after its last COMMITTED
+            token."""
+            nonlocal undelivered
+            if undelivered is not None:
+                for out in undelivered[0]:
+                    queue = self._queues.get(out.request_id)
+                    if queue is not None:
+                        queue.put_nowait(out)
+                undelivered = None
 
         try:
             while not self._stopped:
@@ -1707,21 +1738,36 @@ class AsyncLLMEngine:
                     rep.new_work.clear()
                     await rep.new_work.wait()
                     continue
+                ahead = in_flight is not None and engine.overlapped
                 async with rep.lock:
-                    outputs, plan, prepared = engine.plan_step(
-                        prefill_only=in_flight is not None
-                    )
+                    if ahead:
+                        outputs, plan, prepared = engine.plan_step(
+                            ahead_of=in_flight[0]
+                        )
+                        if plan is not None:
+                            # the quarantine epoch opens in the SAME
+                            # critical section that planned the
+                            # successor: from this point any free — an
+                            # abort sneaking in during the dispatch
+                            # await, or the predecessor's commit reaping
+                            # finished rows — buffers until the successor
+                            # (whose block tables and batch rows
+                            # reference them) has retired
+                            engine.begin_free_epoch()
+                    else:
+                        outputs, plan, prepared = engine.plan_step(
+                            prefill_only=in_flight is not None
+                        )
                 await emit(outputs)
                 if self.frontdoor is not None:
                     # planning admits waiting rows (and sheds expired
                     # ones): admission-window room may have opened
                     self.frontdoor.kick()
                 if plan is None:
+                    await deliver()
                     if in_flight is not None:
-                        chained = await try_chain()
-                        if chained is not None:
-                            in_flight = chained
-                            continue
+                        # nothing to enqueue behind it (or a bail):
+                        # commit, then plan in the serial order
                         await commit_in_flight()
                     elif engine.has_unfinished_requests():
                         # nothing plannable right now — e.g. every
@@ -1731,34 +1777,53 @@ class AsyncLLMEngine:
                         # the background transfer completes.
                         await asyncio.sleep(0.001)
                     continue
-                handle = await asyncio.to_thread(
-                    engine.dispatch_step, plan, prepared
-                )
+                if ahead:
+                    handle = await asyncio.to_thread(
+                        engine.dispatch_chained_step, plan, prepared,
+                        in_flight[2],
+                    )
+                else:
+                    handle = await asyncio.to_thread(
+                        engine.dispatch_step, plan, prepared
+                    )
+                # the device has its next work: now the streams
+                await deliver()
                 new_desc = describe_plan(plan)
+                if ahead:
+                    new_desc = {**(new_desc or {}), "chained": True}
                 if in_flight is not None:
                     # commits stay in dispatch order: drain the older
-                    # dispatch (its device work overlapped our planning)
-                    await commit_in_flight()
+                    # dispatch (its device work overlapped our planning);
+                    # in the overlapped loop the next iteration enqueues
+                    # its successor before the streams get their turn
+                    await commit_in_flight(
+                        then_dispatching=engine.overlapped
+                    )
                 # set AFTER the older commit (which clears the field):
                 # the watchdog dump should describe the newest dispatch
                 rep.in_flight_desc = new_desc
+                in_flight = (plan, prepared, handle, ahead)
                 if handle is SYNC_DISPATCH:
                     # not enqueue-only (the staged pipeline runner —
                     # speculative verify is enqueue-only since it moved
                     # onto the ragged span path): the device work
                     # happens inside wait_step, so it must NOT sit in
-                    # flight — a later eagerly-dispatched prefill would
+                    # flight — a later eagerly-dispatched step would
                     # then execute BEFORE it on device, breaking the
                     # plan-order invariant (stale K/V writes onto
                     # re-allocated pages).  Execute and commit
-                    # synchronously instead.
-                    in_flight = (plan, prepared, handle, False)
+                    # synchronously instead: nothing is ever planned
+                    # ahead of such a runner, and its waves keep the
+                    # configured depth.
+                    if engine.overlapped:
+                        engine.count_ahead_bail("sync_dispatch")
+                        engine.overlapped = False
                     await commit_in_flight()
-                else:
-                    in_flight = (plan, prepared, handle, False)
         except asyncio.CancelledError:
+            hand_over_undelivered()
             raise
         except BaseException as e:  # noqa: BLE001 — engine death boundary
+            hand_over_undelivered()
             logger.exception("engine step loop %d died", rep.index)
             engine.recorder.record(
                 "error", step=engine.step_counter, replica=rep.index,
@@ -1803,8 +1868,10 @@ class AsyncLLMEngine:
             self.dead_event.set()
             raise
         finally:
-            # epochs left open by a death between a chained dispatch and
-            # its commit would quarantine their pages forever
+            hand_over_undelivered()  # whichever way the loop ended
+            # epochs left open by a death between a planned-ahead
+            # dispatch and its commit would quarantine pages and batch
+            # rows forever
             engine.flush_all_free_epochs()
 
     # ----------------------------------------------------- death & recovery

@@ -1147,21 +1147,28 @@ class SchedulerConfig:
     # prompt lengths are padded up to one of these buckets to bound the
     # number of distinct compiled prefill shapes
     prefill_buckets: tuple[int, ...] = (32, 64, 128, 256, 512, 1024, 2048, 4096)
-    # decode steps fused into one device dispatch (lax.scan over the step
-    # axis): each dispatch samples up to this many tokens per sequence
-    # before control returns to the host, amortising dispatch latency and
-    # host work across K tokens.  Stop/EOS detection happens on the host
-    # afterwards, so up to K-1 speculatively decoded tokens per finished
-    # sequence are discarded — cheap next to the dispatch savings.
+    # the CEILING of the decode steps fused into one device dispatch (a
+    # device loop over the step axis): a wave samples up to this many
+    # tokens per sequence before control returns to the host.  In the
+    # overlapped step loop a wave fuses only as many steps as hide the
+    # host's turn, ceil(host turn / token step) from what the loop
+    # measures (telemetry/steptime.py wave_depth), so that a client sees
+    # a token a step and not eight at once; in the serial order
+    # (enable_chained_decode off, LLMEngine.step) every fused step saves
+    # a turn the device would wait for and the ceiling is the depth.
+    # Stop/EOS detection happens on the host afterwards, so up to K-1
+    # speculatively decoded tokens per finished sequence are discarded.
     num_decode_steps: int = 8
-    # chained-decode overlap (async scheduling): while one decode wave
-    # runs on device, its successor is planned and enqueued from
-    # device-resident token feedback.  False serializes the step loop —
-    # plan / dispatch / wait / commit strictly in sequence — a
-    # diagnostic kill-switch for bisecting overlap bugs and the
-    # deliberately host-bound configuration the bottleneck doctor's
-    # host_bound regime is validated against (docs/OBSERVABILITY.md
-    # "Validating the doctor"): with sync dispatch and
+    # plan-ahead overlap (async scheduling): while one dispatch runs on
+    # the device, its successor — wave or mixed step — is planned from a
+    # projection of it and enqueued, its input tokens read on the device
+    # (engine/async_llm.py _run_loop).  False is the strictly serial
+    # loop — plan / dispatch / wait / commit in sequence, waves at the
+    # full num_decode_steps — the control the tests compare the
+    # overlapped loop against, a kill-switch for bisecting overlap bugs,
+    # and the deliberately host-bound configuration the bottleneck
+    # doctor's host_bound regime is validated against
+    # (docs/OBSERVABILITY.md "Validating the doctor"): with
     # num_decode_steps=1 every token pays the full un-overlapped host
     # round-trip.
     enable_chained_decode: bool = True

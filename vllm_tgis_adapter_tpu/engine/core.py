@@ -287,6 +287,13 @@ class LLMEngine:
         self.steptime = StepTimeline(
             dispatch_blocks=backend_dispatch_blocks()
         )
+        # set by the async step loop while it plans every successor
+        # beside its predecessor (engine/async_llm.py _run_loop): the
+        # fused depth of a wave then follows the measured host turn
+        # (_set_wave_depth).  Bails of that loop by reason, for
+        # /debug/state (the counter is tgis_tpu_step_ahead_bails_total)
+        self.overlapped = False
+        self.ahead_bails: dict[str, int] = {}
         # monotonically increasing dispatch counter; stamps recorder
         # events so "which wave was in flight" is answerable post-hoc
         self.step_counter = 0
@@ -1493,10 +1500,8 @@ class LLMEngine:
         # "all" also compiles the want_topn=True decode variant (static
         # argnum: flipping it at serving time is a fresh full compile)
         topn_variants = [False, True] if batch_widths == "all" else [False]
-        # two full fused waves: the first compiles the wave program
-        # (one for every step count), the second is dispatched CHAINED
-        # so the async loop's separately-jitted _chained_decode_fn
-        # compiles at the same width
+        # two full fused waves: the first compiles the wave program (one
+        # for every step count, planned ahead or not)
         steps = sched.config.num_decode_steps
         total = 0
         # solo-prefill buckets whose program ACTUALLY compiled: recorded
@@ -1586,12 +1591,13 @@ class LLMEngine:
     def _precompile_drain(
         self, width: int, covered: Optional[set[int]] = None
     ) -> None:
-        """Run the warmup batch to completion, dispatching the FIRST
-        full-batch decode wave CHAINED (mirroring the async loop's
-        plan_chained_step -> dispatch_chained_step -> commit order,
-        free-epoch discipline included) so the chained program compiles
-        at the production width rather than on the first live chained
-        wave.
+        """Run the warmup batch to completion, planning the successor
+        of the FIRST full-batch decode wave ahead (mirroring the async
+        loop's plan_ahead_step -> dispatch_chained_step -> commit
+        order, free-epoch discipline included): it runs the same
+        programs as the serial order, so this warms no twin, but the
+        path the server lives on has then run once at the production
+        width before traffic does.
 
         ``covered`` (when given) collects the SOLO prefill buckets this
         drain actually dispatched — the ground truth precompile() needs
@@ -1600,9 +1606,8 @@ class LLMEngine:
 
         All prefills drain first (``prefill_only=True`` planning):
         organic interleaving would let early rows burn their max_tokens
-        budget before the batch fills, making schedule_chained bail on
-        the full-width wave (the projection needs >= 1 step of headroom
-        on every row)."""
+        budget before the batch fills, and the wave would not run at
+        its full width."""
 
         def note_plan(plan) -> None:  # noqa: ANN001
             if covered is None:
@@ -1634,7 +1639,7 @@ class LLMEngine:
                 ),
                 prepared,
             )
-        chained_done = False
+        ahead_done = False
         guard = 0
         while self.has_unfinished_requests():
             guard += 1
@@ -1645,15 +1650,15 @@ class LLMEngine:
                 continue
             note_plan(plan)
             handle = self.dispatch_step(plan, prepared)
-            chained = None
-            if not chained_done:
-                chained = self.plan_chained_step(plan, prepared)
-            if chained is None:
+            c_plan = None
+            if not ahead_done and isinstance(plan, DecodePlan):
+                _, c_plan, c_prep = self.plan_ahead_step(plan)
+            if c_plan is None:
                 self.commit_step(
                     plan, self.wait_step(plan, prepared, handle), prepared
                 )
                 continue
-            c_plan, c_prep = chained
+            note_plan(c_plan)
             self.begin_free_epoch()
             try:
                 c_handle = self.dispatch_chained_step(
@@ -1664,13 +1669,13 @@ class LLMEngine:
                 )
                 c_result = self.wait_step(c_plan, c_prep, c_handle)
             finally:
-                # chained wave retired — or died with the warmup: a
+                # the successor retired — or died with the warmup: a
                 # supervised re-warm failure is retried, and an epoch
                 # left open here would quarantine every later free on
                 # the retrying engine (tpulint TPL501)
                 self.flush_free_epoch()
             self.commit_step(c_plan, c_result, c_prep)
-            chained_done = True
+            ahead_done = True
 
     def _precompile_ragged_tail(self, covered: set[int]) -> int:
         """Warm the flat-length buckets only DECODE-HEAVY mixed steps
@@ -1784,29 +1789,25 @@ class LLMEngine:
         result = self.execute_step(plan, prepared)
         return outputs + self.commit_step(plan, result, prepared)
 
-    def plan_step(self, prefill_only: bool = False):
+    def plan_step(self, prefill_only: bool = False, ahead_of=None):  # noqa: ANN001
         """Phase 1 (host, engine lock held): drain scheduler-finished
         requests, pick the next plan, snapshot its dispatch inputs.
 
-        ``prefill_only``: the async loop sets this while a dispatch is
-        still in flight — admissions are independent of in-flight results
-        and may be enqueued behind them, whereas a decode plan depends on
-        the pending commit (tokens, page frees) and must wait.
+        ``ahead_of``: the plan of the dispatch still in flight — the
+        overlapped loop's rule: the successor is planned from a
+        projection of it (``plan_ahead_step``).  Without it, the serial
+        order: every row is planned from committed state.
+        ``prefill_only``: the serial loop (``enable_chained_decode``
+        off) sets this while a dispatch is still in flight — admissions
+        are independent of in-flight results and may be enqueued behind
+        them, whereas a decode plan depends on the pending commit
+        (tokens, page frees) and must wait.
         """
         failpoints.fire("core.plan_step")
+        if ahead_of is not None:
+            return self.plan_ahead_step(ahead_of)
         _st_enter = time.perf_counter()  # steptime: plan-phase origin
-        outputs: list[RequestOutput] = []
-        for seq in self.scheduler.newly_finished:
-            self._seqs.pop(seq.request_id, None)
-            self.lora_manager.unpin(seq.lora_name)
-            seq.metrics.finished_time = time.time()
-            self.recorder.record(
-                "finish", seq.request_id, step=self.step_counter,
-                trace_id=seq.trace_id, reason=seq.finish_reason,
-                rejected=True,
-            )
-            outputs.append(seq.to_request_output())
-        self.scheduler.newly_finished.clear()
+        outputs = self._drain_rejected()
 
         _drain_s = 0.0
         if not prefill_only and self.scheduler.swap_out_fn is not None:
@@ -1822,7 +1823,45 @@ class LLMEngine:
             self._drain_promotions()
             _drain_s += time.perf_counter() - _t
         self.runner.sync_lora(self.lora_manager)
+        self._set_wave_depth()
         plan = self.scheduler.schedule(prefill_only=prefill_only)
+        return self._prepare_plan(
+            outputs, plan, t_enter=_st_enter, drain_s=_drain_s,
+        )
+
+    def _drain_rejected(self) -> list[RequestOutput]:
+        """Final outputs of the requests the scheduler itself finished
+        (rejected prompts, queue-TTL sheds)."""
+        outputs: list[RequestOutput] = []
+        for seq in self.scheduler.newly_finished:
+            self._seqs.pop(seq.request_id, None)
+            self.lora_manager.unpin(seq.lora_name)
+            seq.metrics.finished_time = time.time()
+            self.recorder.record(
+                "finish", seq.request_id, step=self.step_counter,
+                trace_id=seq.trace_id, reason=seq.finish_reason,
+                rejected=True,
+            )
+            outputs.append(seq.to_request_output())
+        self.scheduler.newly_finished.clear()
+        return outputs
+
+    def _set_wave_depth(self) -> None:
+        """A wave fuses no more token steps than hide the host's turn
+        (telemetry/steptime.py ``wave_depth``) while the step loop
+        overlaps the two; in the serial order every fused step saves a
+        turn the device would wait for, so the ceiling stands."""
+        sched = self.scheduler
+        sched.wave_depth = (
+            self.steptime.wave_depth(sched.config.num_decode_steps)
+            if self.overlapped
+            else None
+        )
+
+    def _prepare_plan(self, outputs, plan, *, t_enter: float,  # noqa: ANN001
+                      drain_s: float = 0.0, ahead: bool = False):
+        """Second half of both planning phases: snapshot ``plan``'s
+        dispatch inputs, raise its telemetry, stamp its anatomy."""
         _st_sched = time.perf_counter()
         if plan is None:
             return outputs, None, None
@@ -1845,20 +1884,25 @@ class LLMEngine:
         else:
             prepared = self.runner.prepare_decode(plan)
         self._observe_plan(plan, prepared)
-        self._record_dispatch(plan)
+        self._record_dispatch(plan, ahead=ahead)
         self.steptime.stamp_plan(
-            prepared, t_enter=_st_enter, t_sched=_st_sched,
-            drain_s=_drain_s,
+            prepared, t_enter=t_enter, t_sched=_st_sched,
+            drain_s=drain_s, chained=ahead,
         )
         return outputs, plan, prepared
 
-    def _record_dispatch(self, plan) -> None:  # noqa: ANN001
+    def _record_dispatch(self, plan, ahead: bool = False) -> None:  # noqa: ANN001
         """One recorder entry per dispatch (per prompt for prefills, so
         ``events_for(request_id)`` sees every wave that touched it;
         batch-level for decode — per-request decode cadence is the
-        ``decode_progress`` marker in ``_process_sampled``)."""
+        ``decode_progress`` marker in ``_process_sampled``), and the
+        count of dispatches by whether they were planned ahead."""
         self.step_counter += 1
         step = self.step_counter
+        metrics.step_dispatches_total.labels(
+            ahead="true" if ahead else "false",
+            replica=str(self.replica_index),
+        ).inc()
         if isinstance(plan, RaggedPlan):
             for item in plan.items:
                 self.recorder.record(
@@ -2082,50 +2126,87 @@ class LLMEngine:
         self._observe_result(plan, prepared)
         return result
 
-    # --------------------------------------------------- chained decode waves
+    # ------------------------------------------------------- planning ahead
 
-    def plan_chained_step(self, prev_plan, prev_prepared):
-        """Phase 1' (host, engine lock held): plan the SUCCESSOR decode
-        wave of an in-flight plain decode dispatch — projections assume
-        full step consumption; token feedback stays on device
-        (scheduler.schedule_chained / runner.prepare_chained_decode).
-        Returns (plan, prepared) or None when chaining is not safe."""
-        if not self.config.scheduler_config.enable_chained_decode:
-            return None
-        if not isinstance(prev_plan, DecodePlan):
-            return None
+    def plan_ahead_step(self, prev_plan):  # noqa: ANN001
+        """Phase 1' (host, engine lock held): plan the SUCCESSOR of the
+        dispatch in flight, whatever the two are — the rule of the
+        overlapped step loop (engine/async_llm.py ``_run_loop``).  Every
+        row ``prev_plan`` samples is projected as having consumed its
+        whole budget there; its input token is read on the device
+        (scheduler.schedule_ahead, runner.last_tokens).
+
+        Returns ``(outputs, plan, prepared)`` as ``plan_step`` does.
+        ``plan`` is None where there is nothing to plan or where the
+        projection cannot be right or safe; each such bail is counted by
+        its reason (``tgis_tpu_step_ahead_bails_total``) and the caller
+        commits the predecessor and plans in the serial order."""
         _st_enter = time.perf_counter()
-        plan = self.scheduler.schedule_chained(prev_plan)
-        if plan is None:
-            return None
-        _st_sched = time.perf_counter()
-        prepared = self.runner.prepare_chained_decode(plan, prev_prepared)
-        self._observe_plan(plan, prepared)
-        self._record_dispatch(plan)
-        self.steptime.stamp_plan(
-            prepared, t_enter=_st_enter, t_sched=_st_sched, chained=True,
+        reason = self._ahead_refusal(prev_plan)
+        if reason is not None:
+            self.count_ahead_bail(reason)
+            return [], None, None
+        if isinstance(prev_plan, RaggedPlan):
+            inflight = {
+                id(item.seq): 1 for item in prev_plan.items if item.is_final
+            }
+        else:
+            inflight = {
+                id(seq): k
+                for seq, k in zip(prev_plan.seqs, prev_plan.steps_per_seq)
+            }
+        outputs = self._drain_rejected()
+        self.runner.sync_lora(self.lora_manager)
+        self._set_wave_depth()
+        plan = self.scheduler.schedule_ahead(inflight)
+        if plan is None and self.scheduler.ahead_bail is not None:
+            self.count_ahead_bail(self.scheduler.ahead_bail)
+        return self._prepare_plan(
+            outputs, plan, t_enter=_st_enter, ahead=True,
         )
-        return plan, prepared
+
+    def _ahead_refusal(self, prev_plan) -> Optional[str]:  # noqa: ANN001
+        """The engine's own reasons against planning ahead (the
+        scheduler's are in ``Scheduler.schedule_ahead``)."""
+        if isinstance(prev_plan, PrefillPlan):
+            # the solo-prefill path of prompt-logprob heads and of the
+            # engines without a ragged planner
+            return "legacy_planner"
+        if self.replica_role == "prefill" and self.scheduler.running:
+            # a prompt that finished in ``prev_plan`` leaves for a decode
+            # replica at its commit (_stage_handoffs): it decodes nothing
+            # here
+            return "handoff"
+        if any(t.ready or t.cancelled for _, t in self._promotions):
+            # a host-tier promotion is ready to land (or to be dropped):
+            # the scatter rebinds the caches, so it gets a clean
+            # boundary (_drain_promotions)
+            return "kv_tier"
+        return None
+
+    def count_ahead_bail(self, reason: str) -> None:
+        metrics.step_ahead_bails_total.labels(
+            reason=reason, replica=str(self.replica_index)
+        ).inc()
+        self.ahead_bails[reason] = self.ahead_bails.get(reason, 0) + 1
 
     def dispatch_chained_step(self, plan, prepared, prev_handle):  # noqa: ARG002
-        """Phase 2a' (lock-free): enqueue the successor wave behind the
-        in-flight one."""
-        self.steptime.begin_dispatch(prepared)
-        handle = self.runner.dispatch_chained_decode(prepared, prev_handle)
-        self._stamp_dispatched(prepared, handle)
-        return handle
+        """Phase 2a' (lock-free): enqueue a dispatch planned ahead behind
+        the one in flight.  The device orders the two; the successor's
+        input tokens are on it already."""
+        return self.dispatch_step(plan, prepared)
 
     def begin_free_epoch(self) -> None:
-        self.scheduler.allocator.begin_free_epoch()
+        self.scheduler.begin_free_epoch()
 
     def flush_free_epoch(self) -> None:
-        self.scheduler.allocator.flush_free_epoch()
+        self.scheduler.flush_free_epoch()
 
     def flush_all_free_epochs(self) -> None:
         """Step-loop teardown: nothing can be in flight any more, so any
-        epochs left open (loop died between a chained dispatch and its
-        commit) release their quarantined pages."""
-        self.scheduler.allocator.flush_all_free_epochs()
+        epochs left open (loop died between a planned-ahead dispatch and
+        its commit) release their quarantined pages and batch rows."""
+        self.scheduler.flush_all_free_epochs()
 
     def commit_step(self, plan, result, prepared=None) -> list[RequestOutput]:
         """Phase 3 (host, engine lock held): fold sampled tokens back into
@@ -2149,6 +2230,7 @@ class LLMEngine:
         (telemetry/steptime.py) with the plan's shape facts."""
         if prepared is None or plan is None:
             return
+        steps = 1
         if isinstance(plan, RaggedPlan):
             kind = "ragged"
             tokens = plan.total_tokens
@@ -2160,6 +2242,7 @@ class LLMEngine:
             fill = tokens / plan.bucket_len if plan.bucket_len else 0.0
         else:
             kind = "decode-wave"
+            steps = plan.num_steps
             tokens = len(plan.seqs) * plan.num_steps
             fill = (
                 len(plan.seqs) / plan.batch_bucket
@@ -2169,7 +2252,7 @@ class LLMEngine:
         self.steptime.finish(
             prepared, step=self.step_counter,
             replica=self.replica_index, kind=kind, tokens=tokens,
-            fill_ratio=fill,
+            fill_ratio=fill, steps=steps,
         )
 
     def _commit_inner(self, plan, result, prepared=None) -> list[RequestOutput]:

@@ -164,6 +164,10 @@ class PreparedRagged:
     tensors: SamplingTensors  # S_max rows
     allowed_mask: "Optional[np.ndarray]"  # [S_max, V] FSM rows or None
     lora_idx: "Optional[np.ndarray]"  # [bucket] adapter slot per ROW
+    # [bucket] plan-ahead: the batch row whose newest sampled token
+    # (runner.last_tokens, on the device) is this stream row's input,
+    # -1 = ``token_ids`` holds it.  All -1 in the serial order
+    feed_idx: "np.ndarray"
     samples: list[bool]  # per item: does it emit a token this step
     work: "Optional[np.ndarray]"  # Pallas work schedule (TPU only)
     # real items at the head of ``work``: the attention grid's extent
@@ -217,9 +221,10 @@ class PreparedDecode:
     want_topn: bool = True
     # as PreparedRagged.expert_counts
     expert_counts: Any = None
-    # chained wave (async scheduling): which step row of the PREVIOUS
-    # wave's device outputs feeds each row's input token
-    chain_idx: "Optional[np.ndarray]" = None
+    # [B] plan-ahead: 1 = the row's input token is its newest sampled
+    # token on the device (runner.last_tokens), 0 = ``token_ids`` holds
+    # it.  All 0 in the serial order
+    feed: "Optional[np.ndarray]" = None
 
 
 @dataclasses.dataclass
@@ -435,6 +440,13 @@ class ModelRunner:
 
         max_seqs = config.scheduler_config.max_num_seqs
         self.seen = self._put(jnp.zeros((max_seqs, mcfg.vocab_size), bool))
+        # the newest sampled token of every batch row, kept on the device
+        # by the two serving programs' sampler tails: a dispatch planned
+        # while its predecessor runs reads a row's input token here
+        # (PreparedRagged.feed_idx / PreparedDecode.feed), so token
+        # feedback needs no host round trip whatever the two dispatches
+        # are.  Read only for rows the predecessor sampled
+        self.last_tokens = self._put(jnp.zeros((max_seqs,), jnp.int32))
         self._rng = np.random.default_rng(config.seed)
         self.lora_stacks = None
         self._lora_version = 0  # manager starts at 0 = nothing loaded
@@ -486,7 +498,10 @@ class ModelRunner:
             "ragged_step",
             jax.jit(
                 functools.partial(
-                    self._also_counts(model.ragged_forward, caches_at=1),
+                    self._also_counts(
+                        self._fed_from_device(model.ragged_forward),
+                        caches_at=1,
+                    ),
                     block_size=self.block_size,
                 ),
                 donate_argnums=donate,
@@ -580,8 +595,8 @@ class ModelRunner:
         one fixed ``[num_decode_steps, B, 3+2W]`` buffer: a step no row
         can use would be a whole pass over the weights.
 
-        Transfer packing: the eleven per-row int32 inputs travel as ONE
-        ``[11, B]`` array and the five float32 sampling knobs as one
+        Transfer packing: the twelve per-row int32 inputs travel as ONE
+        ``[12, B]`` array and the five float32 sampling knobs as one
         ``[5, B]`` array; results come back as one int and one float
         array.  Each host↔device buffer is its own transfer at the
         runtime layer, so per-dispatch overhead scales with the
@@ -602,9 +617,11 @@ class ModelRunner:
             params,
             caches,
             seen,  # [max_seqs, V] full seen-token matrix (carried)
-            ints,  # [11, B] i32: tokens, positions0, limits, ctx_lens0,
+            last_tokens,  # [max_seqs] newest sampled token a row (carried)
+            ints,  # [12, B] i32: tokens, positions0, limits, ctx_lens0,
             #      row_slots, top_k, len_penalty_start, min_tokens,
-            #      eos_token_id, gen_len, base_key (uint32 bitcast)
+            #      eos_token_id, gen_len, base_key (uint32 bitcast),
+            #      feed (1 = the row's input token is last_tokens[row])
             floats,  # [5, B] f32: temperature, top_p, typical_p,
             #        repetition_penalty, len_penalty_decay
             block_tables,  # [B, max_blocks]
@@ -613,11 +630,17 @@ class ModelRunner:
             lora_idx,  # [B] adapter slot per row or None
             want_topn: bool = True,  # static: any row wants top-N logprobs
         ):
-            tokens0 = ints[0]
             positions0 = ints[1]
             limits = ints[2]
             context_lens0 = ints[3]
             row_slots = ints[4]
+            rows = jnp.clip(row_slots, 0, None)
+            # a wave planned while its predecessor runs takes each row's
+            # input token from that predecessor's sample on the device —
+            # no host round trip between dispatches, and no twin program
+            tokens0 = jnp.where(
+                ints[11] > 0, jnp.take(last_tokens, rows, axis=0), ints[0]
+            )
             tensors = SamplingTensors(
                 temperature=floats[0],
                 top_k=ints[5],
@@ -633,7 +656,6 @@ class ModelRunner:
                     ints[10], jnp.uint32
                 ),
             )
-            rows = jnp.clip(row_slots, 0, None)
             max_blocks = block_tables.shape[1]
             # steps some live row can use (row i is active at step k
             # while positions0[i] + k <= limits[i])
@@ -689,48 +711,48 @@ class ModelRunner:
             caches, seen, _, outs = jax.lax.fori_loop(
                 0, num_steps, step, (caches, seen, tokens0, outs)
             )
-            return caches, seen, outs
+            # each live row's last step is its newest sampled token
+            live = (row_slots >= 0) & (limits >= positions0)
+            final = jnp.take_along_axis(
+                outs[..., 0],
+                jnp.clip(limits - positions0, 0, max_steps - 1)[None, :],
+                axis=0,
+            )[0]
+            last_tokens = last_tokens.at[
+                jnp.where(live, row_slots, last_tokens.shape[0])
+            ].set(final, mode="drop")
+            return caches, seen, last_tokens, outs
 
         decode_steps = self._also_counts(decode_steps, caches_at=0)
-        donate = (1, 2)
+        donate = (1, 2, 3)
 
-        def chained_decode_steps(
-            params, caches, seen,
-            prev_ints_out,  # [K, B, 3+2W] the in-flight wave's packed
-            #     outputs (column 0 = sampled tokens; see packed_out)
-            chain_idx,  # [B] i32: last live step per row in prev wave
-            ints, floats, block_tables, allowed_mask, lora, lora_idx,
-            want_topn: bool = True,
-        ):
-            # chained wave (async scheduling): the input token of each row
-            # is the PREVIOUS wave's final sampled token, read directly
-            # from its device-resident outputs — no host round trip
-            # between decode waves (packed layout: column 0 is tokens)
-            tokens0 = jnp.take_along_axis(
-                prev_ints_out[..., 0], chain_idx[None, :], axis=0
-            )[0]
-            ints = ints.at[0].set(tokens0)
-            return decode_steps(
-                params, caches, seen, ints, floats, block_tables,
-                allowed_mask, lora, lora_idx, want_topn,
-            )
-
-        self._chained_decode_fn = track_jit(
-            "ragged_chained_decode",
-            jax.jit(chained_decode_steps, static_argnums=(11,),
-                    donate_argnums=donate,
-                    compiler_options=step_compiler_options()),
-            # ints is arg 5 ([11, B])
-            label=lambda args, kwargs: f"batch={args[5].shape[1]}",
-        )
         return track_jit(
             "ragged_decode",
-            jax.jit(decode_steps, static_argnums=(9,),
+            jax.jit(decode_steps, static_argnums=(10,),
                     donate_argnums=donate,
                     compiler_options=step_compiler_options()),
-            # ints is arg 3 ([11, B])
-            label=lambda args, kwargs: f"batch={args[3].shape[1]}",
+            # ints is arg 4 ([12, B])
+            label=lambda args, kwargs: f"batch={args[4].shape[1]}",
         )
+
+    @staticmethod
+    def _fed_from_device(forward):  # noqa: ANN001, ANN205
+        """``forward`` with its stream's tokens taken, where ``feed_idx``
+        names a batch row, from that row's newest sampled token on the
+        device: ONE mixed-step program a bucket serves a step planned in
+        the serial order (``feed_idx`` all -1: the gather is an identity)
+        and one planned while its predecessor runs."""
+
+        def fed(params, caches, token_ids, *args, last_tokens, feed_idx,
+                **kwargs):
+            token_ids = jnp.where(
+                feed_idx >= 0,
+                jnp.take(last_tokens, jnp.clip(feed_idx, 0, None), axis=0),
+                token_ids,
+            )
+            return forward(params, caches, token_ids, *args, **kwargs)
+
+        return fed
 
     def _also_counts(self, step, *, caches_at: int):
         """``step`` itself, or, for a model with held experts, ``step``
@@ -1150,8 +1172,8 @@ class ModelRunner:
             ),
             want_topn=want_topn,
         )
-        self.seen = sampler_mod.update_seen(
-            self.seen, self._put(row_slots), out.tokens
+        self.seen, self.last_tokens = sampler_mod.note_sampled(
+            self.seen, self.last_tokens, self._put(row_slots), out.tokens
         )
         return sampler_mod.pack_output(out)
 
@@ -1175,6 +1197,7 @@ class ModelRunner:
         state_slots = np.full(s_max, -1, np.int32)
         seed_slots = np.full(s_max, -1, np.int32)
         seeds = np.zeros(s_max, np.uint32)
+        feed_idx = np.full(bucket, -1, np.int32)
         lora_idx = None
         if self.lora_stacks is not None:
             lora_idx = np.zeros(bucket, np.int32)
@@ -1205,6 +1228,10 @@ class ModelRunner:
             seq_starts[i] = off
             pos_base[i] = it.start_pos
             state_slots[i] = it.seq.slot
+            if it.inflight:
+                # planned ahead: the span's first token is still being
+                # sampled; the program reads it from the row's entry
+                feed_idx[off] = it.seq.slot
             blocks = it.seq.blocks.blocks
             block_tables[i, : len(blocks)] = blocks
             if lora_idx is not None:
@@ -1228,7 +1255,8 @@ class ModelRunner:
             it.seq.params if it.is_final else None for it in items
         ] + [None] * (s_max - len(items))
         gen_lens = [
-            it.seq.num_output_tokens if it.is_final else 0 for it in items
+            it.seq.num_output_tokens + it.inflight if it.is_final else 0
+            for it in items
         ] + [0] * (s_max - len(items))
         tensors = SamplingTensors.from_params(
             params_list,
@@ -1306,6 +1334,7 @@ class ModelRunner:
             tensors=tensors,
             allowed_mask=allowed_mask,
             lora_idx=lora_idx,
+            feed_idx=feed_idx,
             samples=samples,
             work=work,
             work_items=work_items,
@@ -1585,6 +1614,8 @@ class ModelRunner:
             self._put(prep.block_tables),
             self._put(prep.logits_indices),
             *lora_args,
+            last_tokens=self.last_tokens,
+            feed_idx=self._put(prep.feed_idx),
             **self._work_args(prep),
             **self._state_args(prep),
         )
@@ -1670,19 +1701,28 @@ class ModelRunner:
         block_tables = np.zeros((b, self.max_blocks_per_seq), np.int32)
         slots = np.full(b, -1, np.int32)
         seeds = np.zeros(b, np.uint32)
+        # planned ahead (scheduler.schedule_ahead): a row's tokens still
+        # in flight are counted into its position, length and sampling
+        # counter, and its input token is read on the device
+        inflight = plan.inflight or [0] * len(seqs)
+        feed = np.zeros(b, np.int32)
         for i, seq in enumerate(seqs):
-            pos = seq.num_tokens - 1  # the last sampled token runs first
+            # the last sampled token runs first
+            pos = seq.num_tokens - 1 + inflight[i]
             token_ids[i] = seq.all_token_ids[-1]
+            feed[i] = inflight[i] > 0
             positions[i] = pos
             limits[i] = pos + plan.steps_per_seq[i] - 1
-            context_lens[i] = seq.num_tokens
+            context_lens[i] = pos + 1
             blocks = seq.blocks.blocks
             block_tables[i, : len(blocks)] = blocks
             slots[i] = seq.slot
             seeds[i] = seq.fallback_seed
 
         params_list = [s.params for s in seqs] + [None] * (b - len(seqs))
-        gen_lens = [s.num_output_tokens for s in seqs] + [0] * (b - len(seqs))
+        gen_lens = [
+            s.num_output_tokens + a for s, a in zip(seqs, inflight)
+        ] + [0] * (b - len(seqs))
         tensors = SamplingTensors.from_params(
             params_list,
             eos_token_id=self.config.model_config.eos_token_id,
@@ -1728,101 +1768,8 @@ class ModelRunner:
             tensors=tensors,
             allowed_mask=allowed_mask,
             lora_idx=lora_idx,
+            feed=feed,
         )
-
-    def prepare_chained_decode(
-        self, plan: "DecodePlan", prev_prep: "PreparedDecode"
-    ) -> "PreparedDecode":
-        """Host inputs for the SUCCESSOR wave of ``prev_prep``, planned
-        while that wave still executes (scheduler.schedule_chained):
-        every per-row position/length/PRNG projection assumes the row
-        consumes its full previous step budget; the input tokens stay on
-        device (dispatch_chained_decode reads them from the in-flight
-        wave's outputs)."""
-        seqs = plan.seqs
-        b = plan.batch_bucket
-        prev_k = prev_prep.steps_per_seq
-
-        token_ids = np.zeros(b, np.int32)  # overridden on device
-        positions = np.zeros(b, np.int32)
-        limits = np.full(b, -1, np.int32)
-        context_lens = np.ones(b, np.int32)
-        block_tables = np.zeros((b, self.max_blocks_per_seq), np.int32)
-        slots = np.full(b, -1, np.int32)
-        seeds = np.zeros(b, np.uint32)
-        chain_idx = np.zeros(b, np.int32)
-        gen_lens = []
-        for i, seq in enumerate(seqs):
-            pos = seq.num_tokens - 1 + prev_k[i]
-            positions[i] = pos
-            limits[i] = pos + plan.steps_per_seq[i] - 1
-            context_lens[i] = seq.num_tokens + prev_k[i]
-            blocks = seq.blocks.blocks
-            block_tables[i, : len(blocks)] = blocks
-            slots[i] = seq.slot
-            seeds[i] = seq.fallback_seed
-            chain_idx[i] = prev_k[i] - 1
-            gen_lens.append(seq.num_output_tokens + prev_k[i])
-
-        params_list = [s.params for s in seqs] + [None] * (b - len(seqs))
-        tensors = SamplingTensors.from_params(
-            params_list,
-            eos_token_id=self.config.model_config.eos_token_id,
-            gen_lens=gen_lens + [0] * (b - len(seqs)),
-            fallback_seeds=seeds,
-        )
-        lora_idx = None
-        if self.lora_stacks is not None:
-            lora_idx = np.zeros(b, np.int32)
-            for i, seq in enumerate(seqs):
-                lora_idx[i] = seq.lora_slot
-
-        return PreparedDecode(
-            num_seqs=len(seqs),
-            num_steps=plan.num_steps,
-            steps_per_seq=list(plan.steps_per_seq),
-            token_ids=token_ids,
-            positions=positions,
-            limits=limits,
-            context_lens=context_lens,
-            block_tables=block_tables,
-            slots=slots,
-            tensors=tensors,
-            allowed_mask=None,  # FSM rows never chain (scheduler bail)
-            lora_idx=lora_idx,
-            chain_idx=chain_idx,
-            want_topn=any(
-                seq.params.logprobs not in (None, 0) for seq in seqs
-            ),
-        )
-
-    def dispatch_chained_decode(self, prep: "PreparedDecode", prev_handle):
-        """Enqueue the successor wave behind the in-flight one, feeding
-        input tokens from its device-resident outputs."""
-        lora = self.lora_stacks if prep.lora_idx is not None else None
-        ints, floats = self._pack_decode_inputs(prep)
-
-        def call():  # noqa: ANN202
-            return self._chained_decode_fn(
-                self.params,
-                self.caches,
-                self.seen,
-                prev_handle,
-                self._put(prep.chain_idx),
-                self._put(ints),
-                self._put(floats),
-                self._put(prep.block_tables),
-                None,
-                lora,
-                self._put(prep.lora_idx)
-                if prep.lora_idx is not None
-                else None,
-                prep.want_topn,
-            )
-
-        self.caches, self.seen, packed_out, *counts = call()
-        prep.expert_counts = counts[0] if counts else None
-        return packed_out
 
     def _pack_decode_inputs(self, prep: "PreparedDecode"):
         """Two transfer-packed arrays (see _build_decode_fn docstring)."""
@@ -1836,6 +1783,7 @@ class ModelRunner:
             np.asarray(t.eos_token_id, np.int32),
             np.asarray(t.gen_len, np.int32),
             np.asarray(t.base_key, np.uint32).view(np.int32),
+            prep.feed,
         ]).astype(np.int32)
         floats = np.stack([
             t.temperature, t.top_p, t.typical_p,
@@ -1854,6 +1802,7 @@ class ModelRunner:
                 self.params,
                 self.caches,
                 self.seen,
+                self.last_tokens,
                 self._put(ints),
                 self._put(floats),
                 self._put(prep.block_tables),
@@ -1867,7 +1816,7 @@ class ModelRunner:
                 prep.want_topn,
             )
 
-        self.caches, self.seen, packed_out, *counts = call()
+        self.caches, self.seen, self.last_tokens, packed_out, *counts = call()
         prep.expert_counts = counts[0] if counts else None
         return packed_out
 

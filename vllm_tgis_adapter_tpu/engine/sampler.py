@@ -351,6 +351,23 @@ def update_seen(seen: jax.Array, rows: jax.Array, tokens: jax.Array) -> jax.Arra
 
 
 @jax.jit
+def note_sampled(
+    seen: jax.Array,  # [max_seqs, V]
+    last_tokens: jax.Array,  # [max_seqs] newest sampled token a row
+    rows: jax.Array,  # [K] batch rows; -1 entries are dropped
+    tokens: jax.Array,  # [K]
+) -> tuple[jax.Array, jax.Array]:
+    """``update_seen`` and, in the same dispatch, each row's newest
+    sampled token (runner.last_tokens: what a step planned while this
+    one runs reads its input tokens from)."""
+    safe_rows = jnp.where(rows < 0, seen.shape[0], rows)
+    return (
+        seen.at[safe_rows, tokens].set(True, mode="drop"),
+        last_tokens.at[safe_rows].set(tokens, mode="drop"),
+    )
+
+
+@jax.jit
 def set_seen_row(seen: jax.Array, row: jax.Array, token_ids: jax.Array) -> jax.Array:
     """Reset one batch row of the seen matrix from (padded) prompt tokens."""
     v = seen.shape[1]

@@ -65,6 +65,11 @@ class RaggedItem:
     # placeholders the runner scatters in AFTER the draft proposes.
     # Acceptance emits up to ``spec_width`` tokens for the row.
     spec_width: int = 0
+    # plan-ahead (schedule_ahead): tokens of this row that were still in
+    # flight when the span was planned.  ``start_pos`` and ``slots``
+    # already count them; ``token_ids[0]`` is a placeholder, the device
+    # reads the row's input token from its predecessor's sample
+    inflight: int = 0
 
 
 @dataclasses.dataclass
@@ -91,6 +96,18 @@ class DecodePlan:
     # max_tokens remainder and the model-length headroom), masked after
     num_steps: int = 1
     steps_per_seq: list[int] = dataclasses.field(default_factory=list)
+    # plan-ahead (schedule_ahead): per row, the tokens still in flight
+    # when the wave was planned (see RaggedItem.inflight); empty = none
+    inflight: list[int] = dataclasses.field(default_factory=list)
+
+
+class _AheadBail(Exception):
+    """Raised inside planning when a projection cannot be right or
+    safe; ``schedule_ahead`` turns it into a counted bail."""
+
+    def __init__(self, reason: str):
+        super().__init__(reason)
+        self.reason = reason
 
 
 class Scheduler:
@@ -117,6 +134,25 @@ class Scheduler:
         # engine core drains this each step to emit their final outputs
         self.newly_finished: list[Sequence] = []
         self._free_slots = list(range(scheduler_config.max_num_seqs - 1, -1, -1))
+        # batch rows freed while a planned-ahead dispatch may still touch
+        # them (a row is also its slot of the recurrent-state slab and of
+        # the seen matrix): one list per open free epoch, FIFO like the
+        # allocator's page epochs (begin_free_epoch / flush_free_epoch)
+        self._slot_epochs: deque[list[int]] = deque()
+        # plan-ahead projection (schedule_ahead): id(seq) -> tokens of the
+        # row still in flight.  Empty outside schedule_ahead, so every
+        # planner below reads a row's committed state unless told
+        # otherwise — the serial order is the projection with nothing
+        # in flight
+        self._inflight: dict[int, int] = {}
+        self._ahead = False
+        # why the last schedule_ahead refused (None = it planned, or
+        # there was nothing to plan)
+        self.ahead_bail: Optional[str] = None
+        # fused steps a wave may hold (engine/core.py sets it from the
+        # measured host turn and token step while the loop overlaps
+        # them); None = the configured ceiling num_decode_steps
+        self.wave_depth: Optional[int] = None
         # prefill token budget per device step: prompts longer than this
         # are admitted in chunks, with decode steps interleaved between
         # chunks so long prompts cannot starve running sequences
@@ -291,6 +327,26 @@ class Scheduler:
         slot of the recurrent-state slab, docs/STATE.md)."""
         return len(self._free_slots)
 
+    # ------------------------------------------------------ free epochs
+
+    def begin_free_epoch(self) -> None:
+        """Open a quarantine epoch for pages (allocator) and batch rows:
+        what is freed from now on is released when the dispatch that was
+        just planned ahead has retired (flush_free_epoch)."""
+        self.allocator.begin_free_epoch()
+        self._slot_epochs.append([])
+
+    def flush_free_epoch(self) -> None:
+        """Release the OLDEST epoch's pages and rows."""
+        self.allocator.flush_free_epoch()
+        if self._slot_epochs:
+            self._free_slots.extend(self._slot_epochs.popleft())
+
+    def flush_all_free_epochs(self) -> None:
+        self.allocator.flush_all_free_epochs()
+        while self._slot_epochs:
+            self._free_slots.extend(self._slot_epochs.popleft())
+
     def finish(self, seq: Sequence) -> None:
         """Release a sequence's device resources (idempotent)."""
         ticket = getattr(seq, "kv_promotion", None)
@@ -302,7 +358,13 @@ class Scheduler:
         if seq in self.running:
             self.running.remove(seq)
         if seq.slot >= 0:
-            self._free_slots.append(seq.slot)
+            # inside an open free epoch the row is quarantined with its
+            # pages: a dispatch planned before this release may still
+            # write the slot's state
+            (
+                self._slot_epochs[-1] if self._slot_epochs
+                else self._free_slots
+            ).append(seq.slot)
             seq.slot = -1
         if seq.blocks is not None:
             seq.blocks.release()
@@ -589,32 +651,88 @@ class Scheduler:
             # constrained rows take one step per dispatch: the host must
             # advance the FSM and rebuild the token mask between tokens
             return 1
+        a = self._inflight.get(id(seq), 0)
         k = self.config.num_decode_steps
+        if self.wave_depth is not None:
+            k = min(k, self.wave_depth)
         if seq.params.max_tokens is not None:
-            k = min(k, seq.params.max_tokens - seq.num_output_tokens)
-        k = min(k, self.max_model_len - seq.num_tokens)
+            k = min(k, seq.params.max_tokens - seq.num_output_tokens - a)
+        k = min(k, self.max_model_len - seq.num_tokens - a)
         return max(1, k)
 
+    def _ends_in_flight(self, seq: Sequence) -> bool:
+        """Plan-ahead: will ``seq`` certainly end by length inside the
+        dispatch in flight?  Such a row gets no successor span (an end by
+        EOS or a stop string cannot be known: that row runs on and its
+        tokens are discarded at commit)."""
+        a = self._inflight.get(id(seq), 0)
+        if not a:
+            return False
+        mt = seq.params.max_tokens
+        return (
+            mt is not None and mt - seq.num_output_tokens - a < 1
+        ) or self.max_model_len - seq.num_tokens - a < 1
+
+    def _live_rows(self) -> list[Sequence]:
+        """Running rows the next dispatch decodes: all of them, less
+        those a planned-ahead dispatch knows will have ended."""
+        if not self._inflight:
+            return self.running
+        return [s for s in self.running if not self._ends_in_flight(s)]
+
+    def _still_running(self, live: list[Sequence]) -> list[Sequence]:
+        """``live`` less what a preemption of this planning pass took
+        out of ``running``, in batch-row order."""
+        running = {id(s) for s in self.running}
+        return sorted(
+            (s for s in live if id(s) in running), key=lambda s: s.slot
+        )
+
+    def _check_growth(self, rows: list[Sequence], tokens) -> None:  # noqa: ANN001
+        """Plan-ahead never preempts on a projection: refuse up front
+        when the pages the rows' growth needs are not free."""
+        if not self._ahead:
+            return
+        needed = sum(
+            max(
+                0,
+                self.allocator.blocks_needed(tokens(seq))
+                - len(seq.blocks.blocks),
+            )
+            for seq in rows
+        )
+        if needed and not self.allocator.can_allocate(needed):
+            raise _AheadBail("kv_growth")
+
     def _schedule_decode(self) -> Optional[DecodePlan]:
-        if not self.running:
+        live = self._live_rows()
+        if not live:
             return None
+        inflight = self._inflight
         # rolling-window eviction runs BEFORE capacity/preemption: the
         # pages it reclaims must be visible to this pass's ensure_capacity,
         # or a tight pool preempts work that eviction could have fed
-        self._roll_window(self.running)
+        self._roll_window(live)
+        self._check_growth(
+            live,
+            lambda s: s.num_tokens - 1 + inflight.get(id(s), 0)
+            + self._allowed_steps(s),
+        )
         # grow each sequence's page list for every token this dispatch may
         # write (positions num_tokens-1 … num_tokens-2+allowed); preempt
         # youngest sequences if the pool runs dry.  Iterate over a snapshot
         # but re-check membership: a preemption earlier in this loop may
         # have evicted a later element (blocks == None).
         planned: dict[int, int] = {}
-        for seq in sorted(self.running, key=lambda s: s.metrics.arrival_time):
+        for seq in sorted(live, key=lambda s: s.metrics.arrival_time):
             if seq not in self.running:
                 continue  # preempted earlier in this same pass
             k = self._allowed_steps(seq)
             while True:
                 try:
-                    seq.blocks.ensure_capacity(seq.num_tokens - 1 + k)
+                    seq.blocks.ensure_capacity(
+                        seq.num_tokens - 1 + inflight.get(id(seq), 0) + k
+                    )
                     break
                 except RuntimeError:
                     if k > 1:
@@ -631,12 +749,15 @@ class Scheduler:
                             "KV cache too small for a single sequence"
                         ) from None
             planned[id(seq)] = k
-        if not self.running:
+        seqs = self._still_running(live)
+        if not seqs:
             return None
-        seqs = sorted(self.running, key=lambda s: s.slot)
         steps_per_seq = [planned[id(s)] for s in seqs]
         return DecodePlan(
             seqs=seqs,
+            inflight=(
+                [inflight.get(id(s), 0) for s in seqs] if inflight else []
+            ),
             # ONE decode width (max_num_seqs) — the per-width bucket
             # ladder retired with the bucketed backend; dead rows are
             # masked on device (slot -1), exactly like bucket padding
@@ -705,6 +826,9 @@ class Scheduler:
         """
         head = self.waiting[0] if self.waiting else None
         if head is not None and head.params.prompt_logprobs is not None:
+            if self._ahead:
+                # the solo-prefill path alternates on committed state
+                raise _AheadBail("prompt_logprobs")
             # legacy fallback: solo prefill for the lp head, with the
             # usual prefill/decode anti-starvation alternation
             if self._last_was_prefill and self.running:
@@ -724,6 +848,10 @@ class Scheduler:
             return self._schedule_decode()
         if prefill_only and self.running:
             return None
+        if not self.waiting and self.spec_gamma <= 0:
+            # nothing to admit and nothing to verify: the fused wave,
+            # without walking the rows here first
+            return None if prefill_only else self._schedule_decode()
 
         # mandatory decode spans: one token per running row (γ+1 for a
         # spec-eligible verify span), youngest preempted when the pool
@@ -731,17 +859,23 @@ class Scheduler:
         # shrinks the verify span before resorting to preemption)
         decode_seqs: list[Sequence] = []
         spec_extra: dict[int, int] = {}
-        if self.running:
-            self._roll_window(self.running)
-            for seq in sorted(
-                self.running, key=lambda s: s.metrics.arrival_time
-            ):
+        inflight = self._inflight
+        live = self._live_rows()
+        if live:
+            self._roll_window(live)
+            self._check_growth(
+                live, lambda s: s.num_tokens + inflight.get(id(s), 0)
+            )
+            for seq in sorted(live, key=lambda s: s.metrics.arrival_time):
                 if seq not in self.running:
                     continue  # preempted earlier in this pass
                 extra = self._spec_extra(seq)
                 while True:
                     try:
-                        seq.blocks.ensure_capacity(seq.num_tokens + extra)
+                        seq.blocks.ensure_capacity(
+                            seq.num_tokens + inflight.get(id(seq), 0)
+                            + extra
+                        )
                         break
                     except RuntimeError:
                         if extra > 0:
@@ -756,7 +890,7 @@ class Scheduler:
                                 "KV cache too small for a single sequence"
                             ) from None
                 spec_extra[id(seq)] = extra
-            decode_seqs = sorted(self.running, key=lambda s: s.slot)
+            decode_seqs = self._still_running(live)
         base = sum(1 + spec_extra.get(id(s), 0) for s in decode_seqs)
         has_spec = any(
             spec_extra.get(id(s), 0) > 0 for s in decode_seqs
@@ -797,6 +931,15 @@ class Scheduler:
                     matched = self.allocator.peek_prefix(
                         seq.all_token_ids, seq.lora_name
                     )
+                if self._ahead and not self.allocator.can_allocate(
+                    self.allocator.blocks_needed(len(seq.all_token_ids))
+                    - self.allocator.blocks_needed(matched)
+                ):
+                    # inside an open free epoch an admission that adopts
+                    # cached pages and is then rolled back would pin them
+                    # until the epoch flushes (its decref is quarantined,
+                    # _waiting_head_admissible): look before adopting
+                    continue
             remaining = len(seq.all_token_ids) - max(
                 seq.prefill_pos, matched
             )
@@ -837,7 +980,8 @@ class Scheduler:
         items: list[RaggedItem] = []
         for seq in decode_seqs:
             extra = spec_extra.get(id(seq), 0)
-            pos0 = seq.num_tokens - 1
+            ahead = inflight.get(id(seq), 0)
+            pos0 = seq.num_tokens - 1 + ahead
             items.append(
                 RaggedItem(
                     seq=seq,
@@ -849,6 +993,7 @@ class Scheduler:
                     is_final=True,
                     is_decode=True,
                     spec_width=(1 + extra) if extra > 0 else 0,
+                    inflight=ahead,
                 )
             )
         total = base
@@ -956,91 +1101,76 @@ class Scheduler:
             if lo > 0 and seq.blocks is not None:
                 seq.blocks.evict_below(lo)
 
-    def schedule_chained(
-        self, prev: DecodePlan
-    ) -> Optional[DecodePlan]:
-        """Plan the NEXT decode wave while ``prev`` is still executing
-        (vLLM-style async scheduling): token feedback stays on device, so
-        the only host inputs are projections — each row is ASSUMED to
-        consume its full ``prev`` step budget.  Rows that finish early
-        simply discard the successor wave's tokens at commit (the
-        standard fused-decode over-run path).
+    def schedule_ahead(
+        self, inflight: dict[int, int]
+    ) -> Optional[RaggedPlan | DecodePlan]:
+        """Plan the NEXT dispatch while its predecessor still executes
+        (the rule of the overlapped step loop, engine/async_llm.py).
 
-        Bails (returns None) whenever the projection could be wrong or
-        unsafe: waiting work exists (admissions/chunks take priority and
-        change the batch), any row is FSM-constrained (host must rebuild
-        its mask between tokens), the batch composition changed, or page
-        growth would need preemption (never preempt on a projection).
+        ``inflight`` maps ``id(seq)`` to the tokens the predecessor
+        samples for that row: every running row is ASSUMED to consume
+        its whole budget there, so its position, its page growth, its
+        sampling counters and its remaining budget are projected from
+        committed state plus those tokens, and its input token is read
+        on the device from the predecessor's sample (RaggedItem.inflight
+        / DecodePlan.inflight).  Admissions and prompt chunks come from
+        the host as in the serial order.  A row that will certainly end
+        by length inside the predecessor gets no span; one that ends by
+        EOS or a stop string runs on and has its tokens discarded at
+        commit (the fused-decode over-run path), its pages and its batch
+        row quarantined until this dispatch has retired (free epochs).
+
+        Returns None with ``ahead_bail`` set wherever the projection
+        could be wrong or unsafe — the caller then commits the
+        predecessor and plans in the serial order: an FSM-constrained
+        row (the host rebuilds its mask between tokens), a verify span
+        of speculative decoding (its length is the acceptance), a
+        prompt-logprob head (the solo-prefill path), a swap-in that is
+        due (it rebinds the caches on a clean boundary), a running row
+        the predecessor did not sample (resumed or restored behind its
+        back), page growth that would need preemption, the legacy
+        planner.  None with ``ahead_bail`` None means nothing to plan.
         """
-        if not self.running:
+        failpoints.fire("scheduler.schedule")
+        self.ahead_bail = self._ahead_refusal(inflight)
+        if self.ahead_bail is not None:
             return None
-        if self._waiting_head_admissible():
-            # waiting work that can actually make progress takes
-            # priority over a projected wave; a head that is BLOCKED
-            # (no batch slot / no KV pages) costs nothing to chain past
-            # and is re-checked before every chained wave, so a slot
-            # freed by a finishing row stops the chain within one wave
+        self._shed_expired()
+        self._inflight, self._ahead = inflight, True
+        try:
+            return self._schedule_ragged()
+        except _AheadBail as bail:
+            self.ahead_bail = bail.reason
             return None
-        if len(self.running) != len(prev.seqs) or {
-            id(s) for s in self.running
-        } != {id(s) for s in prev.seqs}:
-            # a row finished/aborted since prev was planned: the device
-            # wave still runs it, but projections are stale — fall back
-            return None
-        # eviction first (see _schedule_decode): reclaimed pages must
-        # count toward this projection's capacity check.  The in-flight
-        # wave's deepest read is num_tokens - window, and reallocation is
-        # safe because any new owner's writes are dispatched (and
-        # therefore execute) after that wave retires.
-        self._roll_window(prev.seqs)
-        # two passes: validate EVERY row before allocating a single page,
-        # so a bail on a later row cannot leave earlier rows holding
-        # speculative capacity for a wave that never dispatches
-        planned: list[int] = []
-        total_needed = 0
-        for seq, prev_k in zip(prev.seqs, prev.steps_per_seq):
+        finally:
+            self._inflight, self._ahead = {}, False
+
+    def _ahead_refusal(self, inflight: dict[int, int]) -> Optional[str]:
+        """The reasons against planning ahead that need no planning."""
+        if not self.ragged:
+            return "legacy_planner"
+        for seq in self.running:
+            if id(seq) not in inflight:
+                return "composition"
             if seq.fsm is not None:
-                return None
-            projected = seq.num_tokens + prev_k  # after prev commits
-            k = self.config.num_decode_steps
-            if seq.params.max_tokens is not None:
-                k = min(
-                    k,
-                    seq.params.max_tokens
-                    - (seq.num_output_tokens + prev_k),
-                )
-            k = min(k, self.max_model_len - projected)
-            if k < 1:
-                return None  # row exhausts its budget inside prev
-            total_needed += max(
-                0,
-                self.allocator.blocks_needed(projected - 1 + k)
-                - len(seq.blocks.blocks),
-            )
-            planned.append(k)
-        if total_needed > 0 and not self.allocator.can_allocate(
-            total_needed
+                return "fsm"
+            if self.spec_gamma > 0 and seq.spec_eligible:
+                return "spec_verify"
+        head = self.waiting[0] if self.waiting else None
+        if (
+            head is not None
+            and head.swapped is not None
+            and self.swap_out_fn is not None
+            and self._waiting_head_admissible()
         ):
-            return None
-        for seq, prev_k, k in zip(
-            prev.seqs, prev.steps_per_seq, planned
-        ):
-            seq.blocks.ensure_capacity(seq.num_tokens + prev_k - 1 + k)
-        return DecodePlan(
-            seqs=list(prev.seqs),
-            batch_bucket=self.config.max_num_seqs,
-            num_steps=max(planned),
-            steps_per_seq=planned,
-        )
+            return "swap_in"
+        return None
 
     def _waiting_head_admissible(self) -> bool:
         """Could the waiting head make progress if plan_step ran now?
 
-        Used by ``schedule_chained``: chaining past an ADMISSIBLE head
-        would delay its admission by a full fused wave, but chaining
-        while the head is blocked on resources is free throughput —
-        the saturated-server steady state (queue deep, batch full) is
-        exactly where on-device token feedback matters most.  Mirrors
+        Used by ``schedule_ahead`` for a swapped head (is its swap-in
+        due?) and by tests of the admission rules.  Mirrors
         the resource checks of ``_try_schedule_prefill`` /
         ``try_swap_in``.  The prefix probe is ``peek_prefix`` — a pure
         hash-walk (ADVICE r5): the old match_prefix+free round-trip

@@ -811,6 +811,36 @@ host_gap_frac = _get_or_create(
     "doctor's host_bound input",
     labelnames=("replica",),
 )
+# ---- the overlapped step loop (engine/async_llm.py _run_loop): how
+# often a dispatch was planned while its predecessor ran, why not, and
+# how deep the waves are that hide the host's turn
+step_dispatches_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_step_dispatches_total",
+    "Dispatched plans by whether they were planned and enqueued while "
+    "their predecessor was still in flight (ahead=\"true\") or only "
+    "after it had committed (ahead=\"false\": the first dispatch after "
+    "idle, a bail, the serial loop), per dp replica",
+    labelnames=("ahead", "replica"),
+)
+step_ahead_bails_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_step_ahead_bails_total",
+    "Times the step loop fell back to the serial order because a "
+    "successor could not be projected from its predecessor, by reason: "
+    "fsm, spec_verify, prompt_logprobs, kv_growth, swap_in, kv_tier, "
+    "handoff, composition, legacy_planner, sync_dispatch",
+    labelnames=("reason", "replica"),
+)
+wave_depth_steps = _get_or_create(
+    Histogram,
+    f"{_PREFIX}_wave_depth_steps",
+    "Token steps fused into each dispatched decode wave: in the "
+    "overlapped loop ceil(host turn / token step) of recent dispatches, "
+    "at most num_decode_steps, less where every row's budget ends "
+    "sooner",
+    buckets=(1, 2, 3, 4, 6, 8, 12, 16, 32),
+)
 doctor_episodes_total = _get_or_create(
     Counter,
     f"{_PREFIX}_doctor_episodes_total",
@@ -852,6 +882,7 @@ def observe_decode_plan(*, num_seqs: int, batch_bucket: int,
                         live_pages: int, replica: int = 0) -> None:
     occupancy = num_seqs / batch_bucket if batch_bucket else 0.0
     decode_batch_occupancy.labels(replica=str(replica)).set(occupancy)
+    wave_depth_steps.observe(num_steps)
     decode_live_items.observe(live_items)
     if live_items:
         attn_item_pages.observe(live_pages / live_items)

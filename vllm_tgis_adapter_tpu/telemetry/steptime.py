@@ -55,6 +55,8 @@ blamed replica), and the chrome-trace exporter (telemetry/timeline.py).
 
 from __future__ import annotations
 
+import math
+import statistics
 import time
 from collections import deque
 from typing import Any, Optional
@@ -78,6 +80,15 @@ IDLE_CUTOFF_S = 1.0
 #: sliding window the host_gap_frac gauge / doctor read.
 DEFAULT_CAPACITY = 256
 DEFAULT_WINDOW = 32
+#: Dispatches the fused-depth rule (StepTimeline.wave_depth) takes its
+#: two medians over: enough that one compile or one stalled turn does
+#: not move them, few enough that a change of load shows within a second.
+DEPTH_WINDOW = 16
+#: A wave's device seconds are known from the host's clock only where
+#: the host had to wait for them: a wait that returns at once says the
+#: device had finished earlier and idled since — the distance between
+#: two such results is the host's turn, not the device's work.
+WAITED_MIN_S = 0.001
 
 
 def backend_dispatch_blocks() -> bool:
@@ -129,13 +140,15 @@ class StepRecord:
         "step", "replica", "kind", "tokens", "fill_ratio", "chained",
         "sync", "t_enter", "t_sched", "t_prep", "t_disp1", "t_wait0",
         "t_wait1", "t_end", "wall_end", "host_gap_s", "drain_s",
-        "compile_fn",
+        "compile_fn", "steps", "device_s", "turn_s",
     )
 
     def __init__(self, *, step: int, replica: int, kind: str,
                  tokens: int, fill_ratio: float, stamps: _Stamps,
                  t_end: float, wall_end: float,
-                 host_gap_s: float) -> None:
+                 host_gap_s: float, steps: int = 1,
+                 device_s: float = 0.0,
+                 turn_s: Optional[float] = None) -> None:
         self.step = step
         self.replica = replica
         self.kind = kind
@@ -154,6 +167,23 @@ class StepRecord:
         self.host_gap_s = host_gap_s
         self.drain_s = stamps.drain_s
         self.compile_fn = stamps.compile_fn
+        # token steps the dispatch ran (a wave's fused depth; 1 for a
+        # mixed step) and the seconds the device spent on it alone: from
+        # its start, or its predecessor's end where it was enqueued
+        # behind one, to its own end
+        self.steps = steps
+        self.device_s = device_s
+        # the host's turn that this dispatch's device time has to hide.
+        # Planned ahead: everything the loop did between its two waits
+        # for the device, from the predecessor's results to the start of
+        # the wait for these — the predecessor's commit, the successor's
+        # plan, prepare and dispatch, and whatever else ran on the event
+        # loop between them (the streams).  Else the four host phases
+        self.turn_s = (
+            turn_s if turn_s is not None
+            else self.plan_s + self.prepare_s + self.dispatch_s
+            + self.commit_s
+        )
 
     # ------------------------------------------------ derived durations
 
@@ -207,6 +237,9 @@ class StepRecord:
             "ts": round(self.wall_end - (self.t_end - self.t_enter), 6),
             "wall_s": round(self.wall_s, 6),
             "drain_s": round(self.drain_s, 6),
+            "steps": self.steps,
+            "device_s": round(self.device_s, 6),
+            "turn_s": round(self.turn_s, 6),
             "compile_fn": self.compile_fn,
             "phases": {
                 name: round(value, 6)
@@ -235,6 +268,11 @@ class StepTimeline:
         # previous step's device_end (finish order == commit order ==
         # dispatch order), feeding the host_gap computation
         self._last_device_end: Optional[float] = None
+        # when the previous step's results reached the host
+        self._last_wait_end: Optional[float] = None
+        # seconds a token step of the recent waves the host had to wait
+        # for (WAITED_MIN_S): the depth rule's denominator
+        self._token_steps: deque[float] = deque(maxlen=DEPTH_WINDOW)
 
     # ------------------------------------------------------ stamp helpers
 
@@ -282,7 +320,8 @@ class StepTimeline:
     # ----------------------------------------------------------- finalize
 
     def finish(self, prepared, *, step: int, replica: int, kind: str,  # noqa: ANN001
-               tokens: int, fill_ratio: float) -> Optional[StepRecord]:
+               tokens: int, fill_ratio: float,
+               steps: int = 1) -> Optional[StepRecord]:
         """Commit boundary: close the record, feed the metrics, append
         to the ring.  Returns the record (tests) or None when stamps
         are missing/incomplete."""
@@ -305,16 +344,30 @@ class StepTimeline:
         else:
             device_start, device_end = st.t_disp1, st.t_wait1
         gap = 0.0
+        busy_from = device_start
         if self._last_device_end is not None:
             raw = device_start - self._last_device_end
             if 0.0 < raw <= IDLE_CUTOFF_S:
                 gap = min(raw, GAP_CAP_S)
+            # enqueued behind its predecessor: the device took it up
+            # when that one ended
+            busy_from = max(device_start, self._last_device_end)
+        turn = None
+        if st.chained and self._last_wait_end is not None:
+            turn = st.t_wait0 - self._last_wait_end
         record = StepRecord(
             step=step, replica=replica, kind=kind, tokens=tokens,
             fill_ratio=fill_ratio, stamps=st, t_end=t_end,
-            wall_end=time.time(), host_gap_s=gap,
+            wall_end=time.time(), host_gap_s=gap, steps=max(1, steps),
+            device_s=max(0.0, device_end - busy_from), turn_s=turn,
         )
+        if kind == "decode-wave" and (
+            st.sync or self.dispatch_blocks
+            or st.t_wait1 - st.t_wait0 >= WAITED_MIN_S
+        ):
+            self._token_steps.append(record.device_s / record.steps)
         self._last_device_end = device_end
+        self._last_wait_end = st.t_wait1
         self._ring.append(record)
         self._observe(record)
         return record
@@ -351,6 +404,29 @@ class StepTimeline:
         if wall <= 0:
             return 0.0
         return sum(r.host_gap_s for r in records) / wall
+
+    def wave_depth(self, ceiling: int,
+                   window: int = DEPTH_WINDOW) -> int:
+        """Token steps a decode wave should fuse so that the device is
+        still busy when the host's turn ends: ``ceil(host turn / token
+        step)``, at least 1 and at most ``ceiling``.  Both are medians
+        (one compile, one stalled turn or one deep wave moves neither):
+        the turn (``turn_s``) over the last ``window`` dispatches, the
+        token step (``device_s / steps``) over the last ``window`` waves
+        whose results the host had to wait for — while the host is the
+        slower of the two, the host's clock shows its own turn between
+        two results and not the device's work, so those waves say
+        nothing about the token step and the last ones that did stand.
+        With nothing measured yet the ceiling stands, as in the serial
+        order (and its deep waves are the first to be measured)."""
+        records = self.last_records(window)
+        if not records or not self._token_steps:
+            return ceiling
+        turn = statistics.median(r.turn_s for r in records)
+        token_step = statistics.median(self._token_steps)
+        if token_step <= 0.0:
+            return ceiling
+        return max(1, min(ceiling, math.ceil(turn / token_step)))
 
     def records(self, last_n: Optional[int] = None) -> list[dict]:
         items = list(self._ring)
