@@ -8,7 +8,8 @@ held experts) at their small test sizes:
 
 * a mixed stream of arrivals and decoding rows gives the tokens and
   logprobs of the strictly serial loop (``enable_chained_decode=False``),
-  greedy and seeded, with a row that ends inside a projected step;
+  greedy and seeded, with a row that ends inside a projected step, and
+  with the stall rule's bucket pinned under a prompt of three caps;
 * an abort between a plan and its commit;
 * a batch row reused right after its owner ended gives what a fresh
   server gives;
@@ -185,6 +186,41 @@ def test_mixed_stream_equals_the_serial_loop(model_dirs, family, sampling):
     assert_same(ahead, serial)
     assert serial["e"][2] == "stop" and len(serial["e"][0]) <= 6
     assert len(serial["long"][0]) == 40
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_mixed_stream_under_a_pinned_stall_bucket(model_dirs, family,
+                                                  monkeypatch):
+    """The stall rule cuts a prompt of three caps into capped steps over
+    the rows that run: the overlapped loop still says what the serial
+    loop says, token for token.  The cap is pinned, not read from this
+    machine's clock."""
+    from vllm_tgis_adapter_tpu import metrics
+    from vllm_tgis_adapter_tpu.telemetry.steptime import StepTimeline
+
+    monkeypatch.setattr(StepTimeline, "stall_bucket",
+                        lambda self, ladder: 32)
+    model_dir = model_dirs(family)
+    free = dict(temperature=0.0, ignore_eos=True)
+    requests = [
+        ("a", prompt(9, 1), dict(max_tokens=48, **free), 0.0),
+        ("b", prompt(21, 2), dict(max_tokens=40, **free), 0.0),
+        ("long", prompt(96, 3), dict(max_tokens=12, **free), 0.06),
+        ("late", prompt(40, 4), dict(max_tokens=9, **free), 0.1),
+    ]
+    capped = metrics.mixed_steps_total.labels(capped="true")
+    before = capped._value.get()
+    serial, core = run_stream(make_config(model_dir, ahead=False), requests)
+    assert not core.ahead_bails
+    assert core.scheduler.stall_bucket == 32
+    # 96 tokens beside two rows in a bucket of 32: at least three steps
+    assert capped._value.get() - before >= 3
+    before, planned = capped._value.get(), ahead_dispatches()
+    ahead, core = run_stream(make_config(model_dir, ahead=True), requests)
+    assert ahead_dispatches() - planned > 10
+    assert capped._value.get() - before >= 3
+    assert_same(ahead, serial)
+    assert len(serial["long"][0]) == 12
 
 
 @pytest.mark.parametrize("family", FAMILIES)

@@ -471,3 +471,175 @@ def test_spec_gamma_ignored_without_eligible_rows():
     plan = sched.schedule()
     assert isinstance(plan, DecodePlan)
     assert plan.batch_bucket == sched.config.max_num_seqs
+
+
+# ------------------------------------------------------ the stall rule
+
+
+def _stall_scheduler(rows, *, chunk=1024, gamma=0, max_num_seqs=64):
+    """A ragged scheduler with ``rows`` rows running (each one token
+    past its prompt), planned without the stall rule so far."""
+    from vllm_tgis_adapter_tpu.engine.config import CacheConfig, SchedulerConfig
+    from vllm_tgis_adapter_tpu.engine.scheduler import Scheduler
+
+    sched = Scheduler(
+        SchedulerConfig(max_num_seqs=max_num_seqs,
+                        prefill_buckets=(16, chunk), num_decode_steps=1),
+        CacheConfig(block_size=16, num_blocks=2048),
+        2048,
+    )
+    sched.ragged = True
+    if gamma:
+        sched.set_spec_gamma(gamma)
+    for i in range(rows):
+        seq = make_seq(f"row{i}", 5, arrival=float(i), max_tokens=10_000)
+        seq.spec_eligible = bool(gamma)
+        sched.add(seq)
+        while sched.waiting:
+            for item in sched.schedule().items:
+                if item.is_final:
+                    item.seq.output_token_ids.append(1)
+    return sched
+
+
+def _prefill_to_the_end(sched, prompts):
+    """Plan until every prompt of ``prompts`` runs, giving every
+    running row its token after each plan.  Returns, per plan,
+    ``(token_bucket, base, waiting tokens before it, capped,
+    {request_id: chunk tokens})`` and checks that each prompt is
+    prefilled completely, in order, with one final chunk."""
+    from vllm_tgis_adapter_tpu.engine.scheduler import RaggedPlan
+
+    seen = {p.request_id: [] for p in prompts}
+    finals = {p.request_id: 0 for p in prompts}
+    plans = []
+    for _ in range(10_000):
+        if not sched.waiting:
+            break
+        waiting = sum(
+            len(s.all_token_ids) - s.prefill_pos for s in sched.waiting
+        )
+        plan = sched.schedule()
+        assert isinstance(plan, RaggedPlan)
+        base = sum(len(i.token_ids) for i in plan.items if i.is_decode)
+        chunks = {}
+        for item in plan.items:
+            if item.is_decode:
+                continue
+            rid = item.seq.request_id
+            assert item.start_pos == len(seen[rid])
+            seen[rid] += item.token_ids
+            finals[rid] += item.is_final
+            chunks[rid] = list(item.token_ids)
+        plans.append((plan.token_bucket, base, waiting, plan.capped, chunks))
+        for item in plan.items:
+            if item.is_final:
+                item.seq.output_token_ids.append(1)
+    assert not sched.waiting
+    for p in prompts:
+        assert seen[p.request_id] == p.prompt_token_ids
+        assert finals[p.request_id] == 1
+    return plans
+
+
+@pytest.mark.parametrize(
+    ("rows", "cap", "prompt_lens"),
+    [
+        (3, 64, [300]),
+        (3, 16, [300]),
+        (35, 128, [300]),
+        (35, 128, [1024]),  # a lone prompt of more than four budgets
+        (23, 64, [90, 700, 40]),
+        (40, 16, [500]),  # the rows alone need a larger bucket than the cap
+        (3, None, [300]),
+        (0, 32, [300, 40]),
+    ],
+    ids=["few-rows", "smallest-bucket", "many-rows", "over-four-budgets",
+         "three-prompts", "cap-under-the-rows", "no-cap", "no-running-row"],
+)
+def test_stall_rule_bounds_a_mixed_step_and_loses_no_token(
+        rows, cap, prompt_lens):
+    """With rows running and ``stall_bucket`` set, a plan's flat bucket
+    stays within ``max(stall_bucket, bucket of base + 1)`` unless more
+    than four such budgets of prompt tokens wait, and then within the
+    bucket a quarter of them needs; whatever the cap, every prompt is
+    prefilled completely and in order."""
+    sched = _stall_scheduler(rows)
+    sched.stall_bucket = cap
+    prompts = [make_seq(f"p{i}", n, arrival=100.0 + i, max_tokens=10_000)
+               for i, n in enumerate(prompt_lens)]
+    for p in prompts:
+        sched.add(p)
+    plans = _prefill_to_the_end(sched, prompts)
+    if cap is None or not rows:
+        assert not any(capped for *_, capped, _ in plans)
+        return
+    for bucket, base, waiting, capped, _ in plans:
+        allowed = max(cap, sched._ragged_bucket(base + 1))
+        if waiting <= 4 * (allowed - base):
+            assert bucket <= allowed
+        else:
+            assert bucket <= sched._ragged_bucket(base - (-waiting // 4))
+            assert not capped
+    assert any(capped for *_, capped, _ in plans)
+
+
+def test_stall_rule_off_or_no_running_row_plans_as_before():
+    """``stall_bucket=None`` gives the floor-bucket walk the planner
+    always made (written out here), and a cap changes nothing for a
+    step with no running row."""
+    sched = _stall_scheduler(3)
+    prompt = make_seq("p", 300, arrival=100.0, max_tokens=10_000)
+    sched.add(prompt)
+    plans = _prefill_to_the_end(sched, [prompt])
+    assert [(b, len(c["p"])) for b, _, _, _, c in plans] == [
+        (256, 253), (32, 29), (16, 13), (16, 5),
+    ]
+
+    walks = []
+    for cap in (None, 16):
+        cold = _stall_scheduler(0, chunk=256)
+        cold.stall_bucket = cap
+        prompts = [make_seq("a", 700, arrival=1.0, max_tokens=10_000),
+                   make_seq("b", 90, arrival=2.0, max_tokens=10_000)]
+        # both arrive at an idle engine: the first plan has no running row
+        for p in prompts:
+            cold.add(p)
+        plan = cold.schedule()
+        walks.append((plan.token_bucket, plan.capped,
+                      [(i.seq.request_id, i.token_ids) for i in plan.items]))
+    assert walks[0] == walks[1]
+    assert walks[0][0] == 256
+
+
+def test_stall_rule_yields_to_a_backlog():
+    """Sixteen caps' worth of prompt tokens waiting: the step is the one
+    the planner makes without the rule, at its top bucket."""
+    planned = []
+    for cap in (None, 64):
+        sched = _stall_scheduler(3, chunk=256)
+        sched.stall_bucket = cap
+        for i in range(4):
+            sched.add(make_seq(f"p{i}", 16 * 64 // 4, arrival=100.0 + i,
+                               max_tokens=10_000))
+        plan = sched.schedule()
+        planned.append((plan.token_bucket, plan.total_tokens, plan.capped,
+                        [(i.seq.request_id, i.token_ids)
+                         for i in plan.items]))
+    assert planned[0] == planned[1]
+    assert planned[0][0] == 256 and not planned[0][2]
+
+
+def test_stall_rule_keeps_a_full_verify_batch_in_one_plan():
+    """64 rows of spec_gamma 3 are 256 tokens of verify spans: the
+    smallest cap still leaves them one plan, with a prompt chunk beside
+    them in the bucket they need."""
+    sched = _stall_scheduler(64, gamma=3)
+    sched.stall_bucket = sched.ragged_buckets[0]
+    prompt = make_seq("p", 400, arrival=100.0, max_tokens=10_000)
+    # the batch is full: the prompt waits for a row, the spans ride alone
+    sched.add(prompt)
+    plan = sched.schedule()
+    spans = [i for i in plan.items if i.is_decode]
+    assert len(spans) == 64 and all(i.spec_width == 4 for i in spans)
+    assert plan.token_bucket == 256 and plan.total_tokens == 256

@@ -19,6 +19,8 @@ import re
 import time
 from types import SimpleNamespace
 
+import pytest
+
 from vllm_tgis_adapter_tpu.telemetry.steptime import (
     GAP_CAP_S,
     PHASES,
@@ -287,7 +289,7 @@ def test_record_serializer_and_debug_state_shape():
     assert set(as_dict["phases"]) == set(PHASES)
     for key in ("step", "replica", "kind", "tokens", "fill_ratio",
                 "chained", "sync", "ts", "wall_s", "drain_s",
-                "compile_fn"):
+                "compile_fn", "bucket", "running", "compiled"):
         assert key in as_dict
     state = tl.debug_state()
     assert state["steps"] == 1
@@ -309,6 +311,225 @@ def test_anatomy_metrics_observed():
     )
     assert after - before == 1
     assert "tgis_tpu_host_gap_frac" in _scrape()
+
+
+# ------------------------------------------------ the stall rule's bucket
+
+LADDER = [16, 32, 64, 128, 256, 512]
+
+
+@pytest.fixture
+def multiple(monkeypatch):
+    """Set ``STALL_MULTIPLE``, the one place the rule's constant lives."""
+    from vllm_tgis_adapter_tpu.telemetry import steptime
+
+    def set_to(value: float) -> None:
+        monkeypatch.setattr(steptime, "STALL_MULTIPLE", value)
+
+    set_to(2.0)
+    return set_to
+
+
+def _fed(tl: StepTimeline, kind: str, device_ms: float, *,
+         bucket: int | None = None, waited_ms: float | None = None,
+         n: int = 1, steps: int = 1, running: int | None = None,
+         compiles: int | None = None) -> None:
+    """``n`` dispatches of ``device_ms`` each, back to back on the
+    device; the host waits ``waited_ms`` for each result (all of the
+    device time unless said).  A mixed step carries two running rows
+    unless said; ``compiles`` is compile_tracker's count at its plan."""
+    if running is None:
+        running = 2 if bucket is not None else 0
+    for _ in range(n):
+        behind = tl._last_device_end is not None
+        start = tl._last_device_end if behind else 50.0
+        end = start + device_ms / 1000.0
+        waited = device_ms if waited_ms is None else waited_ms
+        st = _Stamps()
+        st.t_enter, st.t_sched = start - 0.03, start - 0.02
+        st.t_prep = st.t_disp0 = start - 0.01
+        # enqueued while its predecessor ran, where there is one
+        st.t_disp1 = start - 0.005 if behind else start
+        st.t_wait0, st.t_wait1 = end - waited / 1000.0, end
+        st.compiles = compiles
+        record = tl.finish(
+            SimpleNamespace(_steptime=st), step=0, replica=0, kind=kind,
+            tokens=bucket or steps, fill_ratio=1.0, steps=steps,
+            bucket=bucket, running=running,
+        )
+        assert abs(record.device_s - device_ms / 1000.0) < 1e-9
+        assert (record.bucket, record.running) == (bucket, running)
+
+
+def test_stall_bucket_is_the_last_within_the_multiple_of_a_token_step(
+        multiple):
+    tl = StepTimeline()
+    _fed(tl, "decode-wave", 20.0, n=16)
+    for bucket, ms in ((16, 21.0), (32, 22.0), (64, 26.0), (128, 36.0),
+                       (256, 55.0), (512, 95.0)):
+        _fed(tl, "ragged", ms, bucket=bucket, n=5)
+    assert tl.stall_bucket(LADDER) == 128
+    # a deeper wave says the same of the token step
+    _fed(tl, "decode-wave", 80.0, n=16, steps=4)
+    assert tl.stall_bucket(LADDER) == 128
+    # the rows' token step grows: so does what a mixed step may cost
+    _fed(tl, "decode-wave", 35.0, n=16)
+    assert tl.stall_bucket(LADDER) == 256
+
+
+@pytest.mark.parametrize("value,bucket", [(1.5, 64), (3.0, 256), (1.0, 16)])
+def test_stall_bucket_follows_the_constant(multiple, value, bucket):
+    """The engine passes no multiple: the one constant in the code
+    rules, for every model.  Never under the ladder's first bucket."""
+    multiple(value)
+    tl = StepTimeline()
+    _fed(tl, "decode-wave", 20.0, n=16)
+    for b, ms in ((16, 21.0), (32, 22.0), (64, 26.0), (128, 36.0),
+                  (256, 55.0), (512, 95.0)):
+        _fed(tl, "ragged", ms, bucket=b, n=5)
+    assert tl.stall_bucket(LADDER) == bucket
+
+
+def test_stall_bucket_reads_medians_of_each_bucket(multiple):
+    """One stalled step among a bucket's last sixteen moves nothing; a
+    bucket that stays slow does, and only its own reading."""
+    tl = StepTimeline()
+    _fed(tl, "decode-wave", 20.0, n=16)
+    _fed(tl, "ragged", 30.0, bucket=64, n=15)
+    _fed(tl, "ragged", 900.0, bucket=64)
+    _fed(tl, "ragged", 35.0, bucket=128, n=3)
+    assert tl.stall_bucket(LADDER) == 512
+    _fed(tl, "ragged", 45.0, bucket=128, n=4)
+    assert tl.stall_bucket(LADDER) == 64
+    # only the last sixteen of a bucket count, and one that was over
+    # comes back only well under the limit
+    _fed(tl, "ragged", 37.0, bucket=128, n=16)
+    assert tl.stall_bucket(LADDER) == 64
+    _fed(tl, "ragged", 33.0, bucket=128, n=16)
+    assert tl.stall_bucket(LADDER) == 512
+
+
+def test_stall_bucket_leaves_out_what_the_host_did_not_wait_for(multiple):
+    """A result that was there when the host came for it shows the
+    host's clock, not the device's work (``WAITED_MIN_S``): neither such
+    a mixed step nor such a wave enters a median."""
+    tl = StepTimeline()
+    _fed(tl, "decode-wave", 20.0, n=16)
+    _fed(tl, "ragged", 90.0, bucket=64, waited_ms=0.2, n=16)
+    assert not tl._bucket_steps
+    assert tl.stall_bucket(LADDER) == 512
+    _fed(tl, "ragged", 30.0, bucket=64, n=3)
+    _fed(tl, "decode-wave", 60.0, waited_ms=0.2, n=16)  # host-bound waves
+    assert tl.stall_bucket(LADDER) == 512
+    _fed(tl, "ragged", 50.0, bucket=64, n=4)
+    assert tl.stall_bucket(LADDER) == 32
+
+
+def test_stall_bucket_leaves_out_a_step_that_compiled(multiple):
+    """Where dispatch blocks, a step's device window holds its program's
+    compile: such a step enters no median, mixed or wave."""
+    from vllm_tgis_adapter_tpu import compile_tracker
+
+    tl = StepTimeline()
+    now = compile_tracker.total_recompiles()
+    _fed(tl, "decode-wave", 4000.0, n=3, compiles=now - 1)
+    assert tl.last_records(1)[0].compiled
+    assert not tl._token_steps
+    _fed(tl, "decode-wave", 20.0, n=3, compiles=now)
+    assert not tl.last_records(1)[0].compiled
+    _fed(tl, "ragged", 5000.0, bucket=32, n=8, compiles=now - 1)
+    assert not tl._bucket_steps
+    assert tl.stall_bucket(LADDER) == 512
+    _fed(tl, "ragged", 5000.0, bucket=32, n=3, compiles=now)
+    assert tl.stall_bucket(LADDER) == 16
+
+
+def test_stall_bucket_reads_only_steps_that_carried_running_rows(multiple):
+    """A cold admission or a fill of the idle engine makes no row wait:
+    it says nothing of what a running row pays."""
+    tl = StepTimeline()
+    _fed(tl, "decode-wave", 20.0, n=16)
+    _fed(tl, "ragged", 90.0, bucket=64, running=0, n=16)
+    assert not tl._bucket_steps
+    assert tl.last_records(1)[0].bucket == 64
+    assert tl.stall_bucket(LADDER) == 512
+    _fed(tl, "ragged", 90.0, bucket=64, running=1, n=3)
+    assert tl.stall_bucket(LADDER) == 32
+
+
+def test_stall_bucket_takes_an_unmeasured_bucket_as_within(multiple):
+    """A bucket's first uses measure it, and fewer than
+    ``STALL_MIN_SAMPLES`` of them shut nothing; one measured over the
+    limit stops the walk whatever lies above it."""
+    tl = StepTimeline()
+    _fed(tl, "decode-wave", 20.0, n=4)
+    _fed(tl, "ragged", 25.0, bucket=32, n=2)
+    assert tl.stall_bucket(LADDER) == 512
+    _fed(tl, "ragged", 70.0, bucket=256, n=2)
+    assert tl.stall_bucket(LADDER) == 512  # two late results, not three
+    _fed(tl, "ragged", 70.0, bucket=256)
+    assert tl.stall_bucket(LADDER) == 128  # 64 and 128 never measured
+    assert not {64, 128, 512} & set(tl._bucket_steps)
+
+
+def test_stall_bucket_holds_its_choice_against_a_drifting_ratio(multiple):
+    """The ratio moves a few per cent with the rows in flight: a bucket
+    found over stays out until it reads ``STALL_HYSTERESIS`` under the
+    limit, and one within stays in up to the limit itself."""
+    tl = StepTimeline()
+    _fed(tl, "decode-wave", 20.0, n=16)
+    _fed(tl, "ragged", 30.0, bucket=64, n=5)
+    _fed(tl, "ragged", 39.0, bucket=128, n=5)  # 1.95: within
+    assert tl.stall_bucket(LADDER) == 512
+    _fed(tl, "decode-wave", 19.0, n=16)  # 2.05: over
+    assert tl.stall_bucket(LADDER) == 64
+    _fed(tl, "decode-wave", 20.0, n=16)  # 1.95 again: still out
+    assert tl.stall_bucket(LADDER) == 64
+    _fed(tl, "decode-wave", 21.0, n=16)  # 1.86: not 8 % under yet
+    assert tl.stall_bucket(LADDER) == 64
+    _fed(tl, "decode-wave", 22.0, n=16)  # 1.77: back
+    assert tl.stall_bucket(LADDER) == 512
+    _fed(tl, "decode-wave", 20.0, n=16)  # 1.95: and it stays
+    assert tl.stall_bucket(LADDER) == 512
+
+
+def test_stall_bucket_gives_a_shut_bucket_one_step_to_be_read_again(
+        multiple):
+    """No step with running rows takes a bucket over the limit, so no
+    step measures it: after ``STALL_REPROBE_S`` without a reading it is
+    returned itself, once, and its reading joins the median."""
+    from vllm_tgis_adapter_tpu.telemetry import steptime
+
+    tl = StepTimeline()
+    _fed(tl, "decode-wave", 20.0, n=16)
+    _fed(tl, "ragged", 55.0, bucket=128, n=16)
+    _fed(tl, "ragged", 95.0, bucket=256, n=5)
+    assert tl.stall_bucket(LADDER) == 64
+    tl._bucket_seen[128] -= steptime.STALL_REPROBE_S
+    tl._bucket_seen[256] -= steptime.STALL_REPROBE_S
+    assert tl.stall_bucket(LADDER) == 128  # one bucket up, not the ladder
+    _fed(tl, "ragged", 30.0, bucket=128)  # the step that read it
+    assert tl.stall_bucket(LADDER) == 64
+    # the load has changed for good: nine such readings move the median
+    for _ in range(8):
+        tl._bucket_seen[128] -= steptime.STALL_REPROBE_S
+        assert tl.stall_bucket(LADDER) == 128
+        _fed(tl, "ragged", 30.0, bucket=128)
+    assert tl.stall_bucket(LADDER) == 256  # 256's turn to be read again
+    _fed(tl, "ragged", 95.0, bucket=256)
+    assert tl.stall_bucket(LADDER) == 128
+
+
+def test_stall_bucket_is_the_whole_ladder_with_nothing_measured(multiple):
+    tl = StepTimeline()
+    assert tl.stall_bucket(LADDER) == 512
+    # mixed steps alone say nothing of a token step
+    _fed(tl, "ragged", 400.0, bucket=16, n=16)
+    assert tl.stall_bucket(LADDER) == 512
+    _fed(tl, "decode-wave", 20.0, n=2)
+    assert tl.stall_bucket(LADDER) == 512  # nor do two waves
+    _fed(tl, "decode-wave", 20.0)
+    assert tl.stall_bucket(LADDER) == 16
 
 
 # ---------------------------------------------------------- real engine

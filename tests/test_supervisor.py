@@ -1366,9 +1366,12 @@ def test_ledger_shed_closes_exactly_one_record(tiny_model_dir, tmp_path):
 
     async def scenario():
         a_task = asyncio.create_task(_collect(
-            engine, "a", prompt_ids=list(range(3, 15)), max_tokens=24,
+            engine, "a", prompt_ids=list(range(3, 15)), max_tokens=240,
             tenant_id="acme",
         ))
+        # long enough that "a" is still decoding when a 10 ms poll looks
+        # (24 tokens are over in ~12 ms of the overlapped loop, and a
+        # request that has ended reads -1 here for ever)
         await _wait_for(lambda: _output_tokens(engine, "a") >= 1,
                         what="request a decoding")
         # freeze the step loop: b parks deterministically behind the
@@ -1410,7 +1413,7 @@ def test_ledger_shed_closes_exactly_one_record(tiny_model_dir, tmp_path):
     assert by_rid["c"]["shed_reason"] == "queue_full"
     assert by_rid["c"]["tokens_out"] == 0
     assert by_rid["a"]["outcome"] == "finish"
-    assert by_rid["a"]["tokens_out"] == 24
+    assert by_rid["a"]["tokens_out"] == 240
     assert by_rid["b"]["outcome"] == "finish"
     assert engine.ledger.by_outcome["shed"] == 1
     assert engine.ledger.open_count == 0

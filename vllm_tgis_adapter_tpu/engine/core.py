@@ -1850,13 +1850,17 @@ class LLMEngine:
         """A wave fuses no more token steps than hide the host's turn
         (telemetry/steptime.py ``wave_depth``) while the step loop
         overlaps the two; in the serial order every fused step saves a
-        turn the device would wait for, so the ceiling stands."""
+        turn the device would wait for, so the ceiling stands.  In both
+        orders a mixed step that carries running rows takes no larger a
+        bucket than the measured step times keep within ``STALL_MULTIPLE``
+        token steps (``stall_bucket``)."""
         sched = self.scheduler
         sched.wave_depth = (
             self.steptime.wave_depth(sched.config.num_decode_steps)
             if self.overlapped
             else None
         )
+        sched.stall_bucket = self.steptime.stall_bucket(sched.ragged_buckets)
 
     def _prepare_plan(self, outputs, plan, *, t_enter: float,  # noqa: ANN001
                       drain_s: float = 0.0, ahead: bool = False):
@@ -2018,6 +2022,7 @@ class LLMEngine:
                         1 for i in plan.items if not i.is_decode
                     ),
                     num_decode=sum(1 for i in plan.items if i.is_decode),
+                    capped=plan.capped,
                     live_items=prepared.work_items,
                     live_pages=prepared.work_pages,
                 )
@@ -2231,10 +2236,13 @@ class LLMEngine:
         if prepared is None or plan is None:
             return
         steps = 1
+        bucket = None
+        running = 0
         if isinstance(plan, RaggedPlan):
             kind = "ragged"
             tokens = plan.total_tokens
             bucket = plan.token_bucket
+            running = sum(1 for i in plan.items if i.is_decode)
             fill = tokens / bucket if bucket else 0.0
         elif isinstance(plan, PrefillPlan):
             kind = "solo"
@@ -2252,7 +2260,7 @@ class LLMEngine:
         self.steptime.finish(
             prepared, step=self.step_counter,
             replica=self.replica_index, kind=kind, tokens=tokens,
-            fill_ratio=fill, steps=steps,
+            fill_ratio=fill, steps=steps, bucket=bucket, running=running,
         )
 
     def _commit_inner(self, plan, result, prepared=None) -> list[RequestOutput]:

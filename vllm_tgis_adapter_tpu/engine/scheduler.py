@@ -85,6 +85,9 @@ class RaggedPlan:
     items: list[RaggedItem]  # stream order: decode rows, then prefill
     token_bucket: int  # single flat-length compile bucket
     total_tokens: int  # real tokens across all spans
+    # the stall rule (Scheduler.stall_bucket), not the backlog and not
+    # the chunk budget, cut this step's prompt share
+    capped: bool = False
 
 
 @dataclasses.dataclass
@@ -99,6 +102,12 @@ class DecodePlan:
     # plan-ahead (schedule_ahead): per row, the tokens still in flight
     # when the wave was planned (see RaggedItem.inflight); empty = none
     inflight: list[int] = dataclasses.field(default_factory=list)
+
+
+#: The stall rule yields to backlog: a step's prompt budget is never
+#: less than one part in this many of the prompt tokens waiting, so an
+#: overloaded server plans the full steps it would plan without the rule
+BACKLOG_SHARE = 4
 
 
 class _AheadBail(Exception):
@@ -153,6 +162,11 @@ class Scheduler:
         # measured host turn and token step while the loop overlaps
         # them); None = the configured ceiling num_decode_steps
         self.wave_depth: Optional[int] = None
+        # largest flat-token bucket a mixed step may take while rows are
+        # running (engine/core.py sets it from the measured step times,
+        # telemetry/steptime.py stall_bucket): every running row waits
+        # through the step for its one token.  None = the whole ladder
+        self.stall_bucket: Optional[int] = None
         # prefill token budget per device step: prompts longer than this
         # are admitted in chunks, with decode steps interleaved between
         # chunks so long prompts cannot starve running sequences
@@ -796,6 +810,30 @@ class Scheduler:
         extra = min(extra, self.max_model_len - seq.num_tokens)
         return max(0, extra)
 
+    def _stall_budget(self, base: int, budget: int) -> tuple[int, bool]:
+        """Prompt tokens a step that carries ``base`` tokens of running
+        rows may hold, and whether the stall rule set that number: what
+        fits the bucket ``stall_bucket`` allows (never under the bucket
+        the rows themselves need), but no less than one part in
+        ``BACKLOG_SHARE`` of the prompt tokens waiting (queued and
+        continuing; prompt-logprob heads and swapped rows are other
+        paths') and no more than ``budget``."""
+        room = max(self.stall_bucket, self._ragged_bucket(base + 1)) - base
+        if room >= budget:
+            return budget, False
+        backlog = 0
+        for seq in self.waiting:
+            if (
+                seq.params.prompt_logprobs is not None
+                or seq.swapped is not None
+            ):
+                continue
+            backlog += max(1, len(seq.all_token_ids) - seq.prefill_pos)
+            if backlog >= BACKLOG_SHARE * budget:
+                return budget, False
+        share = -(-backlog // BACKLOG_SHARE)
+        return max(room, share), room >= share
+
     def _schedule_ragged(
         self, prefill_only: bool = False
     ) -> Optional[RaggedPlan | PrefillPlan | DecodePlan]:
@@ -900,8 +938,14 @@ class Scheduler:
         # ride this dispatch — continuing chunks and new prompts, in
         # queue order, later entries jumping blocked ones
         budget = min(self.chunk_budget, self.ragged_buckets[-1] - base)
+        # running rows wait through this step for their one token: it
+        # holds no more prompt tokens than keep it near a token step
+        ruled = False
+        if decode_seqs and self.stall_bucket is not None:
+            budget, ruled = self._stall_budget(base, budget)
         tokens_left = budget
         cands: list[tuple[Sequence, int]] = []
+        cut = False  # a prompt got fewer tokens than it has left
         slots_left = len(self._free_slots)
         for seq in list(self.waiting):
             if tokens_left <= 0:
@@ -948,6 +992,8 @@ class Scheduler:
             take = min(remaining, tokens_left)
             cands.append((seq, take))
             tokens_left -= take
+            if take < remaining:
+                cut = True
             if first:
                 slots_left -= 1
 
@@ -1087,6 +1133,7 @@ class Scheduler:
             items=items,
             token_bucket=self._ragged_bucket(total),
             total_tokens=total,
+            capped=ruled and cut,
         )
 
     def _roll_window(self, seqs: list[Sequence]) -> None:

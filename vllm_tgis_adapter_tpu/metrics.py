@@ -841,6 +841,27 @@ wave_depth_steps = _get_or_create(
     "sooner",
     buckets=(1, 2, 3, 4, 6, 8, 12, 16, 32),
 )
+# ---- the stall rule (engine/scheduler.py _stall_budget): how large the
+# mixed steps are that running rows wait through, and how often the
+# rule, not the backlog or the chunk budget, set their prompt share
+mixed_step_tokens = _get_or_create(
+    Histogram,
+    f"{_PREFIX}_mixed_step_tokens",
+    "Flat-token bucket of each dispatched mixed step that carried at "
+    "least one running row: every such row waits through the step for "
+    "its one token",
+    buckets=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192),
+)
+mixed_steps_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_mixed_steps_total",
+    "Dispatched mixed steps that carried at least one running row, by "
+    "whether the stall rule (a bucket within a stated multiple of the "
+    "measured token step) cut their prompt share (capped=\"true\") or "
+    "the prompts waiting, a backlog or the chunk budget did "
+    "(capped=\"false\")",
+    labelnames=("capped",),
+)
 doctor_episodes_total = _get_or_create(
     Counter,
     f"{_PREFIX}_doctor_episodes_total",
@@ -946,13 +967,15 @@ def observe_prefill_plan(*, real_tokens: int, bucket: int,
 def observe_ragged_plan(*, real_tokens: int, bucket: int,
                         num_prefill: int, num_decode: int,
                         live_items: int = 0,
-                        live_pages: int = 0) -> None:
+                        live_pages: int = 0,
+                        capped: bool = False) -> None:
     """Shape stats for one unified ragged dispatch
     (--attention-backend=ragged).  The padding-waste gauge reads from
     the RAGGED plan here — the bucketed gauges must not report stale
     bucket math when the ragged path is serving.  ``live_items`` is the
     real-item count of the step's work schedule (0: none was built),
-    ``live_pages`` the KV pages those items read."""
+    ``live_pages`` the KV pages those items read, ``capped`` whether
+    the stall rule cut the step's prompt share (RaggedPlan.capped)."""
     fill = real_tokens / bucket if bucket else 0.0
     ragged_batch_fill_ratio.set(fill)
     if live_items:
@@ -963,6 +986,11 @@ def observe_ragged_plan(*, real_tokens: int, bucket: int,
         padded_tokens_total.labels(phase="ragged").inc(bucket - real_tokens)
     if num_prefill:
         packed_prefill_prompts.observe(num_prefill)
+    if num_decode:
+        mixed_step_tokens.observe(bucket)
+        mixed_steps_total.labels(
+            capped="true" if capped else "false"
+        ).inc()
     step_snapshot.prefill_padding_waste = 1.0 - fill
     step_snapshot.prefill_steps += 1
 

@@ -83,12 +83,35 @@ DEFAULT_WINDOW = 32
 #: Dispatches the fused-depth rule (StepTimeline.wave_depth) takes its
 #: two medians over: enough that one compile or one stalled turn does
 #: not move them, few enough that a change of load shows within a second.
+#: The mixed steps of one flat-token bucket (StepTimeline.stall_bucket)
+#: are kept as deep.
 DEPTH_WINDOW = 16
 #: A wave's device seconds are known from the host's clock only where
 #: the host had to wait for them: a wait that returns at once says the
 #: device had finished earlier and idled since — the distance between
 #: two such results is the host's turn, not the device's work.
 WAITED_MIN_S = 0.001
+#: Token steps a mixed step may cost the rows that ride it
+#: (StepTimeline.stall_bucket): one number for every model, because the
+#: step times it is held against are the model's own.  The smallest
+#: mixed step costs 1.4-1.8 token steps on a v5e whatever it holds and
+#: one of 128 tokens 1.7-2.2 (PERF.md section 5): at 2.0 the rule cut
+#: prompts into 64-token steps and paid three steps for one.
+STALL_MULTIPLE = 2.5
+#: Readings the rule needs before it judges: of the token step, and of
+#: a bucket before that bucket may stop the walk (one late result among
+#: a bucket's first does not shut it).
+STALL_MIN_SAMPLES = 3
+#: A bucket found over the limit comes back only once it reads this
+#: share under it: a ratio that drifts a few per cent with the rows in
+#: flight (2.5-4 % between runs of one cell, PERF.md section 5) does
+#: not flip the cap between two sizes of step.  No wider: a bucket shut
+#: while few rows ran (a cheap token step) has to come back when many do.
+STALL_HYSTERESIS = 0.08
+#: A bucket over the limit is used by no step with running rows, so
+#: nothing measures it again: after this many seconds without a reading
+#: it is given one step, whose reading joins its median.
+STALL_REPROBE_S = 30.0
 
 
 def backend_dispatch_blocks() -> bool:
@@ -115,7 +138,7 @@ class _Stamps:
     __slots__ = (
         "t_enter", "t_sched", "t_prep", "t_disp0", "t_disp1",
         "t_wait0", "t_wait1", "drain_s", "chained", "sync",
-        "compile_fn",
+        "compile_fn", "compiles",
     )
 
     def __init__(self) -> None:
@@ -130,6 +153,17 @@ class _Stamps:
         self.chained = False
         self.sync = False
         self.compile_fn: Optional[str] = None
+        # compile_tracker's count of compiles when the step was planned
+        self.compiles: Optional[int] = None
+
+
+def _compiles() -> Optional[int]:
+    try:
+        from vllm_tgis_adapter_tpu import compile_tracker
+
+        return compile_tracker.total_recompiles()
+    except Exception:  # noqa: BLE001 — anatomy must never break serving
+        return None
 
 
 class StepRecord:
@@ -140,7 +174,8 @@ class StepRecord:
         "step", "replica", "kind", "tokens", "fill_ratio", "chained",
         "sync", "t_enter", "t_sched", "t_prep", "t_disp1", "t_wait0",
         "t_wait1", "t_end", "wall_end", "host_gap_s", "drain_s",
-        "compile_fn", "steps", "device_s", "turn_s",
+        "compile_fn", "steps", "device_s", "turn_s", "bucket",
+        "running", "compiled",
     )
 
     def __init__(self, *, step: int, replica: int, kind: str,
@@ -148,7 +183,9 @@ class StepRecord:
                  t_end: float, wall_end: float,
                  host_gap_s: float, steps: int = 1,
                  device_s: float = 0.0,
-                 turn_s: Optional[float] = None) -> None:
+                 turn_s: Optional[float] = None,
+                 bucket: Optional[int] = None,
+                 running: int = 0, compiled: bool = False) -> None:
         self.step = step
         self.replica = replica
         self.kind = kind
@@ -173,6 +210,14 @@ class StepRecord:
         # behind one, to its own end
         self.steps = steps
         self.device_s = device_s
+        # a mixed step's flat-token bucket (its compile shape), else
+        # None, and the running rows that rode it for their one token
+        self.bucket = bucket
+        self.running = running
+        # a program compiled between this step's plan and its commit:
+        # where dispatch blocks or the work runs in the wait, its
+        # device window holds the compile
+        self.compiled = compiled
         # the host's turn that this dispatch's device time has to hide.
         # Planned ahead: everything the loop did between its two waits
         # for the device, from the predecessor's results to the start of
@@ -231,6 +276,8 @@ class StepRecord:
             "replica": self.replica,
             "kind": self.kind,
             "tokens": self.tokens,
+            "bucket": self.bucket,
+            "running": self.running,
             "fill_ratio": round(self.fill_ratio, 4),
             "chained": self.chained,
             "sync": self.sync,
@@ -241,6 +288,7 @@ class StepRecord:
             "device_s": round(self.device_s, 6),
             "turn_s": round(self.turn_s, 6),
             "compile_fn": self.compile_fn,
+            "compiled": self.compiled,
             "phases": {
                 name: round(value, 6)
                 for name, value in self.phases().items()
@@ -273,6 +321,14 @@ class StepTimeline:
         # seconds a token step of the recent waves the host had to wait
         # for (WAITED_MIN_S): the depth rule's denominator
         self._token_steps: deque[float] = deque(maxlen=DEPTH_WINDOW)
+        # device seconds of the recent mixed steps that carried running
+        # rows and that the host had to wait for, by flat-token bucket:
+        # what a running row pays for riding a step of that size
+        # (stall_bucket); when each bucket was last read; and the
+        # buckets last found over the limit
+        self._bucket_steps: dict[int, deque[float]] = {}
+        self._bucket_seen: dict[int, float] = {}
+        self._over: set[int] = set()
 
     # ------------------------------------------------------ stamp helpers
 
@@ -291,6 +347,7 @@ class StepTimeline:
         st.t_prep = time.perf_counter()
         st.drain_s = drain_s
         st.chained = chained
+        st.compiles = _compiles()
         prepared._steptime = st  # noqa: SLF001 — same carrier as _obs_plan_t0
         return
 
@@ -320,11 +377,13 @@ class StepTimeline:
     # ----------------------------------------------------------- finalize
 
     def finish(self, prepared, *, step: int, replica: int, kind: str,  # noqa: ANN001
-               tokens: int, fill_ratio: float,
-               steps: int = 1) -> Optional[StepRecord]:
+               tokens: int, fill_ratio: float, steps: int = 1,
+               bucket: Optional[int] = None,
+               running: int = 0) -> Optional[StepRecord]:
         """Commit boundary: close the record, feed the metrics, append
-        to the ring.  Returns the record (tests) or None when stamps
-        are missing/incomplete."""
+        to the ring.  ``bucket`` is a mixed step's flat-token bucket,
+        ``running`` the rows that rode it for one token.  Returns the
+        record (tests) or None when stamps are missing/incomplete."""
         st = self._stamps(prepared)
         if st is None:
             return None
@@ -360,12 +419,21 @@ class StepTimeline:
             fill_ratio=fill_ratio, stamps=st, t_end=t_end,
             wall_end=time.time(), host_gap_s=gap, steps=max(1, steps),
             device_s=max(0.0, device_end - busy_from), turn_s=turn,
+            bucket=bucket, running=running,
+            compiled=st.compiles is not None
+            and (_compiles() or 0) > st.compiles,
         )
-        if kind == "decode-wave" and (
+        if not record.compiled and (
             st.sync or self.dispatch_blocks
             or st.t_wait1 - st.t_wait0 >= WAITED_MIN_S
         ):
-            self._token_steps.append(record.device_s / record.steps)
+            if kind == "decode-wave":
+                self._token_steps.append(record.device_s / record.steps)
+            elif bucket is not None and running:
+                self._bucket_steps.setdefault(
+                    bucket, deque(maxlen=DEPTH_WINDOW)
+                ).append(record.device_s)
+                self._bucket_seen[bucket] = t_end
         self._last_device_end = device_end
         self._last_wait_end = st.t_wait1
         self._ring.append(record)
@@ -413,10 +481,11 @@ class StepTimeline:
         (one compile, one stalled turn or one deep wave moves neither):
         the turn (``turn_s``) over the last ``window`` dispatches, the
         token step (``device_s / steps``) over the last ``window`` waves
-        whose results the host had to wait for — while the host is the
-        slower of the two, the host's clock shows its own turn between
-        two results and not the device's work, so those waves say
-        nothing about the token step and the last ones that did stand.
+        that compiled nothing and whose results the host had to wait
+        for — while the host is the slower of the two, the host's clock
+        shows its own turn between two results and not the device's
+        work, so those waves say nothing about the token step and the
+        last ones that did stand.
         With nothing measured yet the ceiling stands, as in the serial
         order (and its deep waves are the first to be measured)."""
         records = self.last_records(window)
@@ -427,6 +496,45 @@ class StepTimeline:
         if token_step <= 0.0:
             return ceiling
         return max(1, min(ceiling, math.ceil(turn / token_step)))
+
+    def stall_bucket(self, ladder: list[int]) -> int:
+        """The largest flat-token bucket of ``ladder`` (ascending) a
+        mixed step may take while rows are running: the last one before
+        the first whose median device time passes ``STALL_MULTIPLE``
+        token steps.  Medians over the last ``DEPTH_WINDOW`` mixed steps
+        of a bucket that carried running rows and token steps of a wave,
+        each only where the host had to wait for the results and no
+        program compiled (as for ``wave_depth``).  A bucket with fewer
+        than ``STALL_MIN_SAMPLES`` readings counts as within — its first
+        uses measure it — and so does everything while the token step
+        has as few.  A bucket once over comes back at
+        ``STALL_HYSTERESIS`` under the limit, and one that no step has
+        read for ``STALL_REPROBE_S`` is returned itself, for the one
+        step that reads it again."""
+        if len(self._token_steps) < STALL_MIN_SAMPLES:
+            return ladder[-1]
+        limit = STALL_MULTIPLE * statistics.median(self._token_steps)
+        within = ladder[0]
+        for bucket in ladder:
+            seen = self._bucket_steps.get(bucket)
+            if seen is not None and len(seen) >= STALL_MIN_SAMPLES:
+                was = bucket in self._over
+                cost = statistics.median(seen)
+                over = cost > (
+                    limit * (1.0 - STALL_HYSTERESIS) if was else limit)
+                if over != was:
+                    self._over.symmetric_difference_update((bucket,))
+                    logger.info(
+                        "stall rule: a mixed step of %d tokens costs %.2f "
+                        "token steps: %s rows running", bucket,
+                        cost * STALL_MULTIPLE / limit,
+                        "shut to steps with" if over else "open again to",
+                    )
+                if over:
+                    idle = time.perf_counter() - self._bucket_seen[bucket]
+                    return bucket if idle >= STALL_REPROBE_S else within
+            within = bucket
+        return within
 
     def records(self, last_n: Optional[int] = None) -> list[dict]:
         items = list(self._ring)
