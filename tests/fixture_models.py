@@ -1102,3 +1102,118 @@ def build_tiny_deepseek_v2(path: str, seed: int = 0, held=None,
                 tensors |= expert
     save_file(tensors, out / "model.safetensors")
     return str(out)
+
+
+# Command A+ (``cohere2_moe``) in miniature: one period of three window
+# layers and one full one, 8 query heads over 2 KV heads (4 a KV head),
+# window 8, 8 sigmoid-routed experts (2 a token) beside 2 averaged shared
+# ones, a tied head (tests/test_cohere2_moe.py).
+TINY_COHERE2_MOE_CONFIG = {
+    "architectures": ["Cohere2MoeForCausalLM"],
+    "model_type": "cohere2_moe",
+    "attention_bias": False,
+    "expert_selection_fn": "sigmoid",
+    "first_k_dense_replace": 0,
+    "head_dim": 16,
+    "hidden_act": "silu",
+    "hidden_size": 64,
+    "intermediate_size": 32,
+    "layer_norm_eps": 1e-5,
+    "layer_switch": 4,
+    "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+    "logit_scale": 1,
+    "max_position_embeddings": 256,
+    "norm_topk_prob": True,
+    "num_attention_heads": 8,
+    "num_experts": 8,
+    "num_experts_per_tok": 2,
+    "num_hidden_layers": 4,
+    "num_key_value_heads": 2,
+    "num_shared_experts": 2,
+    "position_embedding_type": "rope_gptj",
+    "rope_theta": 50000,
+    "rotary_pct": 1,
+    "shared_expert_combination_strategy": "average",
+    "sliding_window": 8,
+    "tie_word_embeddings": True,
+    "use_parallel_block": True,
+    "use_qk_norm": False,
+    "vocab_size": 512,
+    "bos_token_id": 1,
+    "eos_token_id": 2,
+    "torch_dtype": "float32",
+}
+
+
+def cohere2_moe_tensors(cfg: dict, seed: int = 0) -> dict:
+    """Every tensor of the WHOLE tiny checkpoint (all the router's
+    experts), by name, float32: norms' weights away from 1, the router's
+    rows at std 1 so that routing is decided by more than rounding."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    d, vocab = cfg["hidden_size"], cfg["vocab_size"]
+    h, kv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                 cfg["head_dim"])
+    f = cfg["intermediate_size"]
+
+    def w(shape, std=None):
+        std = std or shape[-1] ** -0.5
+        return (rng.standard_normal(shape) * std).astype(np.float32)
+
+    def norm(n):
+        return (1.0 + 0.1 * rng.standard_normal(n)).astype(np.float32)
+
+    def swiglu(prefix):
+        return {
+            f"{prefix}.gate_proj.weight": w((f, d)),
+            f"{prefix}.up_proj.weight": w((f, d)),
+            f"{prefix}.down_proj.weight": w((d, f)),
+        }
+
+    tensors = {
+        "model.embed_tokens.weight": w((vocab, d), 1.0),
+        "model.norm.weight": norm(d),
+    }
+    for i in range(cfg["num_hidden_layers"]):
+        p = f"model.layers.{i}"
+        tensors |= {
+            f"{p}.input_layernorm.weight": norm(d),
+            f"{p}.self_attn.q_proj.weight": w((h * dh, d)),
+            f"{p}.self_attn.k_proj.weight": w((kv * dh, d)),
+            f"{p}.self_attn.v_proj.weight": w((kv * dh, d)),
+            f"{p}.self_attn.o_proj.weight": w((d, h * dh)),
+            f"{p}.mlp.gate.weight": w((cfg["num_experts"], d), 1.0 / 8),
+        }
+        for j in range(cfg["num_shared_experts"]):
+            tensors |= swiglu(f"{p}.mlp.shared_experts.{j}")
+        for e in range(cfg["num_experts"]):
+            tensors |= swiglu(f"{p}.mlp.experts.{e}")
+    return tensors
+
+
+def build_tiny_cohere2_moe(path: str, seed: int = 0, held=None,
+                           **overrides) -> str:
+    """Tiny ``cohere2_moe`` checkpoint.  ``held = (first, last)`` writes
+    only those experts of each layer, under their published indices, and
+    counts them in ``num_experts`` (the router keeps all its rows): one
+    chip's share of the same model, since every tensor is drawn as in
+    the whole checkpoint of that seed."""
+    import re
+
+    from safetensors.numpy import save_file
+
+    out = Path(path)
+    out.mkdir(parents=True, exist_ok=True)
+    build_tokenizer(path)
+    cfg = dict(TINY_COHERE2_MOE_CONFIG, **overrides)
+    lo, hi = held or (0, cfg["num_experts"])
+    with open(out / "config.json", "w") as f:
+        json.dump(dict(cfg, num_experts=hi - lo), f, indent=2)
+    tensors = {
+        name: t for name, t in cohere2_moe_tensors(cfg, seed).items()
+        if not (m := re.search(r"\.mlp\.experts\.(\d+)\.", name))
+        or lo <= int(m.group(1)) < hi
+    }
+    save_file(tensors, out / "model.safetensors")
+    return str(out)

@@ -41,9 +41,10 @@ def served_config(name: str) -> tuple[dict, dict]:
 
 
 def test_every_family_is_here():
-    assert {"llama", "falcon_h1", "deepseek_v2"} <= set(FAMILIES)
+    assert {"llama", "falcon_h1", "deepseek_v2",
+            "cohere2_moe"} <= set(FAMILIES)
     assert {run.load("configs", c)["family"] for c in CONFIGS} == {
-        "llama", "falcon_h1", "deepseek_v2"}
+        "llama", "falcon_h1", "deepseek_v2", "cohere2_moe"}
 
 
 @pytest.mark.parametrize("config", CONFIGS)
@@ -76,7 +77,8 @@ def test_a_family_module_holds_the_three_things(family):
 
 
 @pytest.fixture(scope="module",
-                params=["rehearsal", "falcon-h1-tiny", "deepseek-v2-tiny"])
+                params=["rehearsal", "falcon-h1-tiny", "deepseek-v2-tiny",
+                        "cohere2-moe-tiny"])
 def written(request, tmp_path_factory):
     """A checkpoint of each family, written by ``ensure_checkpoint``."""
     if request.param == "rehearsal":
@@ -88,6 +90,12 @@ def written(request, tmp_path_factory):
         # router's 4 x 4 experts the first group's four are held
         full = {"family": "deepseek_v2", "dtype": "float32"}
         hf = dict(TINY_DEEPSEEK_V2_CONFIG, n_routed_experts=4)
+    elif request.param == "cohere2-moe-tiny":
+        from tests.fixture_models import TINY_COHERE2_MOE_CONFIG
+
+        # one chip's share: of the router's 128 experts the first two
+        full = {"family": "cohere2_moe", "dtype": "float32"}
+        hf = dict(TINY_COHERE2_MOE_CONFIG, num_experts=2)
     else:
         from tests.fixture_models import TINY_FALCON_H1_CONFIG
 

@@ -1409,6 +1409,8 @@ class AsyncLLMEngine:
         num_blocks = sum(a.num_blocks for a in allocators)
         used = num_blocks - sum(a.num_free for a in allocators)
         state = [e.state_slot_usage() for e in engines]
+        windows = [w for e in engines
+                   if (w := e.scheduler.window_allocator) is not None]
         # requests parked in the front-door fair queue are "waiting" in
         # every operational sense (they count against the bound and the
         # autoscaler should see them), they just haven't reached a
@@ -1428,6 +1430,8 @@ class AsyncLLMEngine:
                 prefix_hits=sum(a.prefix_hits for a in allocators),
                 state_used=sum(u for u, _ in state),
                 state_total=sum(t for _, t in state),
+                window_used=sum(w.num_blocks - w.num_free for w in windows),
+                window_total=sum(w.num_blocks for w in windows),
             )
             # per-tier prefix hit rates (docs/KV_TIERING.md): tokens
             # served from each tier over prompt tokens that consulted

@@ -285,6 +285,13 @@ class ModelConfig:
     # qwen2 semantics: the first ``max_window_layers`` layers use FULL
     # attention, the band applies from that layer on (0 = all layers)
     max_window_layers: int = 0
+    # per-layer attention kind as data (cohere2_moe's ``layer_types``):
+    # each layer's window (0 = full attention) and whether it rotates
+    # its queries and keys.  None = the llama lineage's two patterns,
+    # which ``window_of_layer`` fills from the two integers above: every
+    # layer banded, or the first ``max_window_layers`` full
+    layer_windows: Optional[tuple] = None  # int a layer
+    layer_rotary: Optional[tuple] = None  # bool a layer
     # falcon_h1: a Mamba-2 mixer beside attention in every layer, and
     # with it per-sequence state that is not KV pages
     mixer: Optional[MixerConfig] = None
@@ -304,6 +311,61 @@ class ModelConfig:
     @property
     def has_latent_cache(self) -> bool:
         return self.mla is not None
+
+    def window_of_layer(self, i: int) -> int:
+        """Layer ``i``'s attention window, 0 = full (``i`` is the
+        layer's index in the whole model, whatever slice a stage
+        holds)."""
+        if self.layer_windows is not None:
+            return self.layer_windows[i]
+        if self.sliding_window and i < self.max_window_layers:
+            return 0
+        return self.sliding_window
+
+    def layer_rotates(self, i: int) -> bool:
+        """Whether layer ``i`` applies rotary embeddings to q and k."""
+        return self.layer_rotary is None or bool(self.layer_rotary[i])
+
+    @property
+    def window_layers(self) -> tuple:
+        """Indices of the layers with a window, in order."""
+        return tuple(i for i in range(self.num_layers)
+                     if self.window_of_layer(i) > 0)
+
+    @property
+    def full_layers(self) -> tuple:
+        """Indices of the full-attention layers, in order."""
+        return tuple(i for i in range(self.num_layers)
+                     if self.window_of_layer(i) == 0)
+
+    @property
+    def has_window_group(self) -> bool:
+        """Window layers AND full layers in one stack: the cache keeps
+        the two kinds' pages in two groups, each with its own pool and
+        block table (engine/kv_cache.py, docs/ATTENTION.md).  A model
+        with one kind of layer has one group."""
+        return (self.layer_windows is not None
+                and bool(self.window_layers) and bool(self.full_layers))
+
+    @property
+    def default_block_size(self) -> int:
+        """The page size where ``--block-size`` names none: 16 tokens,
+        the granule of the prefix cache and of a pool's last-page waste;
+        for a model with a window group one run of the attention
+        kernel's work item (ops/ragged_attention.py ``KV_TILE``).  Such
+        a model has no prefix cache to share pages with (boot refuses
+        it), its rows are long (a context past the window is why it has
+        two groups), and a run of 128 tokens is then one DMA a pool
+        where pages of 16 make it eight: on a v5e the median time per
+        token at 10-15 rows was 163 ms at pages of 16 and 84 ms at 128
+        (PERF.md section 4, PR 49)."""
+        return 128 if self.has_window_group else 16
+
+    @property
+    def group_window(self) -> int:
+        """The window group's band: its layers' widest window."""
+        return max((self.window_of_layer(i) for i in self.window_layers),
+                   default=0)
 
     @staticmethod
     def _rope_scaling_factors(
@@ -520,6 +582,10 @@ class ModelConfig:
             )
         if model_type == "deepseek_v2":
             return ModelConfig._from_deepseek_v2_config(
+                model, hf, max_model_len=max_model_len, dtype=dtype
+            )
+        if model_type == "cohere2_moe":
+            return ModelConfig._from_cohere2_moe_config(
                 model, hf, max_model_len=max_model_len, dtype=dtype
             )
         hidden = hf["hidden_size"]
@@ -822,6 +888,120 @@ class ModelConfig:
         )
 
     @staticmethod
+    def _from_cohere2_moe_config(
+        model: str,
+        hf: dict,
+        *,
+        max_model_len: int | None = None,
+        dtype: str = "auto",
+    ) -> "ModelConfig":
+        """Command A+ (``cohere2_moe``): window and full attention layers
+        in one stack (``layer_types``), a parallel block under one
+        weight-only LayerNorm, sigmoid-routed experts of which a share is
+        held beside averaged shared ones, a tied head
+        (models/cohere2_moe.py)."""
+        kinds = hf.get("layer_types")
+        layers = hf["num_hidden_layers"]
+        if not kinds:
+            # the published pattern: layer_switch - 1 window layers,
+            # then one full
+            switch = hf.get("layer_switch") or 4
+            kinds = ["full_attention" if (i + 1) % switch == 0
+                     else "sliding_attention" for i in range(layers)]
+        kinds = list(kinds)[:layers]
+        window = hf.get("sliding_window") or 0
+        unsupported = (
+            ("attention_bias", hf.get("attention_bias"),
+             "the projections are loaded without biases"),
+            ("use_qk_norm", hf.get("use_qk_norm"),
+             "queries and keys are not normed"),
+            ("use_parallel_block", not hf.get("use_parallel_block", True),
+             "one norm feeds attention and the experts"),
+            ("first_k_dense_replace", hf.get("first_k_dense_replace"),
+             "every layer is an expert layer"),
+            ("expert_selection_fn",
+             hf.get("expert_selection_fn", "sigmoid") != "sigmoid",
+             "the router's scores are sigmoids"),
+            ("norm_topk_prob", not hf.get("norm_topk_prob", True),
+             "the chosen experts' weights are renormalised"),
+            ("shared_expert_combination_strategy",
+             hf.get("shared_expert_combination_strategy", "average")
+             != "average", "the shared experts are averaged"),
+            ("position_embedding_type",
+             hf.get("position_embedding_type", "rope_gptj") != "rope_gptj",
+             "window layers rotate interleaved pairs"),
+            ("rotary_pct", hf.get("rotary_pct", 1) != 1,
+             "window layers rotate the whole head"),
+            ("tie_word_embeddings", not hf.get("tie_word_embeddings", True),
+             "the head is the embedding"),
+            ("layer_types", len(kinds) != layers or any(
+                k not in ("sliding_attention", "full_attention")
+                for k in kinds),
+             "a layer is sliding_attention or full_attention"),
+            ("sliding_window", "sliding_attention" in kinds and window <= 0,
+             "a sliding layer needs its window"),
+        )
+        for key, bad, why in unsupported:
+            if bad:
+                raise ValueError(
+                    f"cohere2_moe config sets {key}={hf.get(key)!r}, which "
+                    f"is not supported yet: {why}"
+                )
+        num_held = hf["num_experts"]
+        first_held, num_routed = held_experts_of_checkpoint(model, num_held)
+        if num_routed is None:
+            num_routed = num_held
+        eos = hf.get("eos_token_id", 255001)
+        if isinstance(eos, list):
+            eos = eos[0]
+        theta = float(
+            (hf.get("rope_parameters") or {}).get("rope_theta")
+            or hf.get("rope_theta", 10000.0))
+        return ModelConfig(
+            model=model,
+            model_type="cohere2_moe",
+            vocab_size=hf["vocab_size"],
+            hidden_size=hf["hidden_size"],
+            intermediate_size=hf["intermediate_size"],
+            num_layers=layers,
+            num_heads=hf["num_attention_heads"],
+            num_kv_heads=hf["num_key_value_heads"],
+            head_dim=hf.get("head_dim")
+            or hf["hidden_size"] // hf["num_attention_heads"],
+            max_model_len=(
+                max_model_len or hf.get("max_position_embeddings", 8192)
+            ),
+            rope_theta=theta,
+            rms_norm_eps=hf.get("layer_norm_eps", 1e-5),
+            tie_word_embeddings=True,
+            dtype=resolve_dtype(dtype),
+            eos_token_id=eos,
+            bos_token_id=hf.get("bos_token_id", 5) or 1,
+            logits_scaling=1.0 / float(hf.get("logit_scale", 1.0) or 1.0),
+            hidden_act=ModelConfig._validated_hidden_act(
+                hf.get("hidden_act") or "silu", "cohere2_moe"
+            ),
+            norm_type="layernorm",
+            parallel_residual=True,
+            sliding_window=window,
+            layer_windows=tuple(
+                window if k == "sliding_attention" else 0 for k in kinds),
+            layer_rotary=tuple(k == "sliding_attention" for k in kinds),
+            experts=HeldExpertsConfig(
+                num_routed=num_routed,
+                first_held=first_held,
+                num_held=num_held,
+                n_group=1,
+                topk_group=1,
+                top_k=hf["num_experts_per_tok"],
+                routed_scaling_factor=1.0,
+                num_shared=hf.get("num_shared_experts") or 0,
+                intermediate_size=hf["intermediate_size"],
+                first_dense_layers=0,
+            ),
+        )
+
+    @staticmethod
     def _from_opt_config(
         model: str,
         hf: dict,
@@ -1116,6 +1296,11 @@ class CacheConfig:
     # <= 0 requests auto-sizing against the HBM budget at engine boot
     # (kv_cache.resolve_num_blocks); a positive value is used as-is
     num_blocks: int = 512
+    # pages of the WINDOW group's pool, for a model whose window layers
+    # and full layers keep their pages in two groups (num_blocks is then
+    # the full group's); <= 0 derives it from num_blocks at engine boot
+    # (kv_cache.window_blocks_for).  Unused by a model with one group
+    num_window_blocks: int = 0
     cache_dtype: Any = jnp.bfloat16
     # content-addressed reuse of full prompt pages across requests
     # (engine/kv_cache.py BlockAllocator prefix caching)
@@ -1550,6 +1735,7 @@ class EngineConfig:
         self._validate_replica_roles()
         self._validate_recurrent_state()
         self._validate_latent_cache()
+        self._validate_window_group()
         if self.kv_disk_cache_gb > 0 and self.kv_host_cache_gb <= 0:
             raise ValueError(
                 "--kv-disk-cache-gb requires the host KV tier "
@@ -1776,6 +1962,68 @@ class EngineConfig:
                     f"(docs/LATENT_ATTENTION.md)"
                 )
 
+    def _validate_window_group(self) -> None:
+        """Truthful flags for a model whose window layers and full
+        layers keep their pages in two groups
+        (``ModelConfig.has_window_group``, docs/ATTENTION.md): a
+        sequence has a block table in each, and the window group's pages
+        behind the band are freed as it advances, so whatever shares,
+        moves, shards or quantizes ONE table's pages is refused at boot,
+        with what is missing; none falls back to keeping every page.
+        The host KV tier (and decode resume) turns itself off in
+        engine/core.py with a warning, as for rolling-window eviction."""
+        if not self.model_config.has_window_group:
+            return
+        model = f"model_type {self.model_config.model_type!r}"
+        pcfg = self.parallel_config
+        refused = [
+            (self.cache_config.kv_quantization != "none",
+             "--kv-quantization",
+             "the page-scale sidecars are made for one pool, not one a "
+             "group (ops/kv_quant.py)"),
+            (self.cache_config.enable_prefix_caching,
+             "--enable-prefix-caching",
+             "a cached page run would have to be kept and matched in "
+             "both groups, and the window group frees its pages behind "
+             "the band (engine/kv_cache.py registers one table)"),
+            (self.swap_space_gib > 0, "--swap-space",
+             "the swap copy gathers one table's slots; a preempted row "
+             "is recomputed from position 0 instead"),
+            (self.speculative is not None, "--speculative-model",
+             "the draft model's pages share one table's slot geometry "
+             "(engine/speculative.py)"),
+            (bool(self.kvnet_listen or self.kvnet_peers),
+             "--kvnet-listen / --kvnet-peers",
+             "the kvnet wire format and DecodeCheckpoint carry one "
+             "table's pages (kvnet/, engine/kv_tier.py)"),
+            (self.roles_active(), "--dp-replica-roles",
+             "a prefill-to-decode handoff carries one table's pages "
+             "(engine/kv_tier.py DecodeCheckpoint)"),
+            (self.lora_config.enabled, "--enable-lora",
+             "the expert stacks have no LoRA targets (engine/lora.py)"),
+            (pcfg.tensor_parallel_size > 1, "--tensor-parallel-size > 1",
+             "the expert stacks and the second pool have no shard rules "
+             "(parallel/)"),
+            (pcfg.pipeline_parallel_size > 1, "--pipeline-parallel-size > 1",
+             "the staged runner allocates one pool a stage and knows no "
+             "window group (engine/pipeline.py)"),
+            (pcfg.sequence_parallel_size > 1, "--sequence-parallel-size > 1",
+             "the legacy solo-prefill planner sp engines use knows one "
+             "table"),
+            (pcfg.data_parallel_size > 1 or pcfg.dp_replicas > 1,
+             "--data-parallel-size / --dp-replicas > 1",
+             "a request that moves between replicas (resume, handoff) "
+             "carries one table's pages"),
+        ]
+        for on, flag, missing in refused:
+            if on:
+                raise ValueError(
+                    f"{flag} does not compose with {model} yet: the "
+                    f"model keeps its window layers' and its full "
+                    f"layers' pages in two groups, and {missing}; drop "
+                    f"the flag (docs/ATTENTION.md)"
+                )
+
     def resolved_replica_roles(self) -> tuple[str, ...]:
         """One role per replica: ``dp_replica_roles`` when given, else
         ``replica_role`` repeated over the replica count."""
@@ -1983,7 +2231,10 @@ class EngineConfig:
         return EngineConfig(
             model_config=model_config,
             cache_config=CacheConfig(
-                block_size=args.block_size,
+                block_size=(
+                    getattr(args, "block_size", None)
+                    or model_config.default_block_size
+                ),
                 num_blocks=0,  # auto-size from HBM at engine boot
                 enable_prefix_caching=getattr(
                     args, "enable_prefix_caching", False

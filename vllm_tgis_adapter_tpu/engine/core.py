@@ -90,13 +90,31 @@ class LLMEngine:
                  mesh=None, memory_device=None, pp_devices=None):
         # device_report.py: how the pool came to its size, and how long
         # from_config spent loading weights (None for direct builds)
+        import dataclasses as _dc
+
         self.kv_pool_sized_from = "config"
         self.weights_load_s: float | None = None
+        if hasattr(model, "max_step_prefill_tokens"):
+            # a model whose attention kernel bounds a step's work
+            # schedule (models/deepseek_v2.py, models/cohere2_moe.py)
+            # says how many prompt tokens a mixed step may hold; the
+            # planner's chunk budget follows it, and with it the
+            # flat-bucket ladder and the window group's band
+            config = _dc.replace(
+                config,
+                scheduler_config=_dc.replace(
+                    config.scheduler_config,
+                    max_num_batched_tokens=min(
+                        config.scheduler_config.max_num_batched_tokens,
+                        model.max_step_prefill_tokens(
+                            config.scheduler_config.max_num_seqs,
+                            config.cache_config.block_size),
+                    ),
+                ),
+            )
         if config.cache_config.num_blocks <= 0:
             # auto-size the KV pool from free HBM now that the weights are
             # resident (reference behavior: vLLM's gpu_memory_utilization)
-            import dataclasses as _dc
-
             import jax
 
             from vllm_tgis_adapter_tpu.engine.kv_cache import (
@@ -141,6 +159,22 @@ class LLMEngine:
                     num_blocks=resolve_num_blocks(size_cfg, memory_device),
                 ),
             )
+        if (
+            config.model_config.has_window_group
+            and config.cache_config.num_window_blocks <= 0
+        ):
+            from vllm_tgis_adapter_tpu.engine.kv_cache import (
+                window_blocks_for,
+            )
+
+            config = _dc.replace(
+                config,
+                cache_config=_dc.replace(
+                    config.cache_config,
+                    num_window_blocks=window_blocks_for(
+                        config, config.cache_config.num_blocks),
+                ),
+            )
         self.config = config
         self.tokenizer = tokenizer
         if config.parallel_config.pipeline_parallel_size > 1:
@@ -151,27 +185,15 @@ class LLMEngine:
         else:
             self.runner = ModelRunner(config, model, params, mesh=mesh)
         scheduler_config = config.scheduler_config
-        if hasattr(model, "max_step_prefill_tokens"):
-            # a model whose attention kernel bounds a step's work
-            # schedule (models/deepseek_v2.py) says how many prompt
-            # tokens a mixed step may hold; the planner's chunk budget
-            # follows it, and with it the flat-bucket ladder
-            import dataclasses as _dc
-
-            scheduler_config = _dc.replace(
-                scheduler_config,
-                max_num_batched_tokens=min(
-                    scheduler_config.max_num_batched_tokens,
-                    model.max_step_prefill_tokens(
-                        scheduler_config.max_num_seqs,
-                        config.cache_config.block_size),
-                ),
-            )
         self.scheduler = Scheduler(
             scheduler_config,
             config.cache_config,
             config.cache_config.num_blocks,
             max_model_len=config.max_model_len,
+            num_window_blocks=(
+                config.cache_config.num_window_blocks
+                if config.model_config.has_window_group else 0
+            ),
         )
         # ragged unified data path — THE serving planner
         # (docs/ATTENTION.md): the scheduler plans token-budgeted
@@ -194,14 +216,16 @@ class LLMEngine:
             and pcfg.sequence_parallel_size == 1
             and mesh_sp == 1
         )
-        # rolling-window KV eviction (scheduler docstring for the gates)
+        # rolling-window KV eviction (scheduler docstring for the gates):
+        # every layer banded, or the window layers' own group of pages
+        # (which boot refuses prefix caching and speculation for)
         if (
             mcfg.sliding_window > 0
-            and mcfg.max_window_layers == 0
+            and (not mcfg.full_layers or mcfg.has_window_group)
             and not config.cache_config.enable_prefix_caching
             and config.speculative is None
         ):
-            self.scheduler.rolling_window = mcfg.sliding_window
+            self.scheduler.rolling_window = mcfg.group_window
         # --swap-space host KV swap for preemption victims.  Gates: the
         # flat ModelRunner cache only (pp stages split the layer axis),
         # and no rolling-window eviction (evicted low pages make the
@@ -624,6 +648,17 @@ class LLMEngine:
                 f"{self.config.model_config.model_type!r} yet: they ride "
                 "the legacy solo-prefill program, which knows no latent "
                 "pool (docs/LATENT_ATTENTION.md); ask for generated-token "
+                "logprobs only"
+            )
+        if (
+            params.prompt_logprobs is not None
+            and self.config.model_config.has_window_group
+        ):
+            raise ValueError(
+                "prompt logprobs are not served for model_type "
+                f"{self.config.model_config.model_type!r} yet: they ride "
+                "the legacy solo-prefill program, which knows one block "
+                "table (docs/ATTENTION.md); ask for generated-token "
                 "logprobs only"
             )
         seq = Sequence(
@@ -1967,6 +2002,37 @@ class LLMEngine:
             calls=m.num_layers * steps,
         )
 
+    def _observe_window_attention(self, spans, steps: int,
+                                  items: tuple[int, int]) -> None:
+        """Attention work of a dispatched plan of a model with window
+        and full layers, from the host's copy of its shape: ``spans``
+        are ``(rows, first position)`` of each sequence's tokens,
+        ``steps`` the kernel calls a layer, ``items`` the work items of
+        the causal and of the window layers' schedule."""
+        from vllm_tgis_adapter_tpu.ops.mla_attention import attended_pairs
+
+        m = self.config.model_config
+        window = m.group_window
+        causal = banded = 0
+        for n, pos0 in spans:
+            pairs = attended_pairs(n, pos0)
+            causal += pairs
+            # rows past the band's first `window` positions see `window`
+            # tokens, not position + 1: take off what lies under the band
+            under = n + pos0 - window  # the last row's hidden tokens
+            first = max(under - n + 1, 1)  # the first row that hides any
+            if under > 0:
+                pairs -= (under + first) * (under - first + 1) // 2
+            banded += pairs
+        per_pair = m.num_heads * 2 * 2 * m.head_dim
+        metrics.observe_window_attention(
+            window_flops=len(m.window_layers) * banded * per_pair,
+            window_calls=len(m.window_layers) * steps,
+            full_flops=len(m.full_layers) * causal * per_pair,
+            full_calls=len(m.full_layers) * steps,
+            causal_items=items[0], window_items=items[1],
+        )
+
     def _observe_result(self, plan, prepared) -> None:
         """Telemetry that waits for a step's results: the expert counts
         its program handed back (engine/runner.py)."""
@@ -2005,6 +2071,16 @@ class LLMEngine:
                     self._observe_latent_attention(
                         [(len(i.token_ids), i.start_pos)
                          for i in plan.items], steps=1)
+                if self.config.model_config.has_window_group:
+                    # the running rows' spans are a kernel call of
+                    # their own where the schedules leave them out
+                    units = prepared.unit_items
+                    self._observe_window_attention(
+                        [(len(i.token_ids), i.start_pos)
+                         for i in plan.items],
+                        steps=1 if prepared.unit_rows is None else 2,
+                        items=(prepared.work_items + units[0],
+                               prepared.window_work_items + units[1]))
                 if getattr(self.runner, "has_state", False):
                     spans = [len(i.token_ids) for i in plan.items]
                     ones = sum(1 for n in spans if n == 1)
@@ -2060,6 +2136,18 @@ class LLMEngine:
                 ctx = prepared.context_lens[: len(plan.seqs)]
                 pages = (ctx - 1) // self.runner.block_size + 1
                 run = pages_per_item(self.runner.block_size)
+                if self.config.model_config.has_window_group:
+                    # the wave's first step: a row's band starts at the
+                    # page of the oldest token it sees
+                    first = (
+                        ctx - self.config.model_config.group_window
+                    ).clip(min=0) // self.runner.block_size
+                    self._observe_window_attention(
+                        zip(plan.steps_per_seq,
+                            prepared.positions[: len(plan.seqs)].tolist()),
+                        steps=max(plan.steps_per_seq, default=0),
+                        items=(int((-(-pages // run)).sum()),
+                               int((-(-(pages - first) // run)).sum())))
                 metrics.observe_decode_plan(
                     num_seqs=len(plan.seqs),
                     batch_bucket=plan.batch_bucket,

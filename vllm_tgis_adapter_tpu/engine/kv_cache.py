@@ -93,8 +93,11 @@ def per_block_bytes(config: "EngineConfig") -> int:
             return (m.num_layers * ccfg.block_size * m.mla.row_width
                     * itemsize)
         kv_heads_per_dev = max(1, m.num_kv_heads // tp)
+        # with window and full layers in two groups, the FULL group's
+        # page: its layers alone (the other's is window_block_bytes)
+        layers = len(m.full_layers) if m.has_window_group else m.num_layers
         payload = (
-            2 * m.num_layers * ccfg.block_size
+            2 * layers * ccfg.block_size
             * kv_heads_per_dev * kv_quant.cache_head_dim(m.head_dim)
             * itemsize
         )
@@ -110,6 +113,52 @@ def per_block_bytes(config: "EngineConfig") -> int:
         # geometry (engine/speculative.py) — its pages share the budget
         block_bytes += one_model(config.speculative.draft_model_config)
     return block_bytes
+
+
+def window_block_bytes(config: "EngineConfig") -> int:
+    """Per-device bytes ONE page of the window group costs: keys and
+    values of the window layers alone.  0 for a model with one group."""
+    import jax.numpy as jnp
+
+    from vllm_tgis_adapter_tpu.ops import kv_quant
+
+    m, ccfg = config.model_config, config.cache_config
+    if not m.has_window_group:
+        return 0
+    return (2 * len(m.window_layers) * ccfg.block_size * m.num_kv_heads
+            * kv_quant.cache_head_dim(m.head_dim)
+            * jnp.dtype(ccfg.cache_dtype).itemsize)
+
+
+def window_band_blocks(config: "EngineConfig") -> int:
+    """Pages of the window group a sequence at ``max_model_len`` holds
+    at most: its band, the prompt tokens one step may add to it
+    (``max_num_batched_tokens``: they are written before the pages
+    behind them are freed), and a run of pages and one more for the
+    band's ragged ends — or the whole sequence where that is shorter."""
+    from vllm_tgis_adapter_tpu.ops.ragged_attention import pages_per_item
+
+    m, bs = config.model_config, config.cache_config.block_size
+    band = (
+        -(-(m.group_window
+            + config.scheduler_config.max_num_batched_tokens) // bs)
+        + pages_per_item(bs) + 1
+    )
+    return min(band, -(-m.max_model_len // bs))
+
+
+def window_blocks_for(config: "EngineConfig", num_blocks: int) -> int:
+    """The window group's pages beside ``num_blocks`` of the full
+    group.  The rule: both groups hold the SAME number of sequences at
+    ``max_model_len``, of which the full group keeps every page
+    (``max_model_len / block_size``) and the window group its band
+    (``window_band_blocks``); so the window group gets ``band /
+    (max_model_len / block_size)`` pages for each page of the full
+    group, and never less than one band."""
+    full_seq = -(-config.model_config.max_model_len
+                 // config.cache_config.block_size)
+    band = window_band_blocks(config)
+    return max(band, -(-num_blocks * band // full_seq))
 
 
 def device_memory_stats(device) -> dict:
@@ -148,6 +197,12 @@ def resolve_num_blocks(
 
     block_bytes = per_block_bytes(config)
     blocks_per_seq = -(-mcfg.max_model_len // ccfg.block_size)
+    if mcfg.has_window_group:
+        # a page of the full group comes with its share of the window
+        # group's pages (window_blocks_for): priced together, so the
+        # budget is split by that one rule
+        block_bytes += -(-window_block_bytes(config)
+                         * window_band_blocks(config) // blocks_per_seq)
     # beyond full occupancy (every batch row at max_model_len) extra pages
     # can never be touched
     full_occupancy = config.scheduler_config.max_num_seqs * blocks_per_seq
@@ -498,23 +553,44 @@ class BlockAllocator:
 
 
 class SequenceBlocks:
-    """Per-sequence page list + slot computation."""
+    """Per-sequence page list + slot computation.
 
-    def __init__(self, allocator: BlockAllocator):
+    A model whose window layers and full layers keep their pages in two
+    groups (``ModelConfig.has_window_group``) gives a sequence a table
+    in each: this object's own ``blocks`` is the full group's, and
+    ``window`` is a second ``SequenceBlocks`` over the window group's
+    allocator, position-aligned with it.  ``evict_below`` then frees
+    the window group's pages behind the band and leaves the full
+    group's alone.  A model with one kind of layer has ``window`` None
+    and one table, as ever."""
+
+    def __init__(self, allocator: BlockAllocator,
+                 window_allocator: Optional[BlockAllocator] = None):
         self._allocator = allocator
         self.blocks: list[int] = []
         self.num_tokens = 0
         self._evicted_upto = 0  # rolling-window cursor (evict_below)
+        self.window: Optional[SequenceBlocks] = (
+            None if window_allocator is None
+            else SequenceBlocks(window_allocator)
+        )
 
     def adopt(self, blocks: list[int]) -> None:
         """Prepend already-refcounted pages (prefix-cache hits)."""
         self.blocks.extend(blocks)
 
-    def ensure_capacity(self, num_tokens: int) -> None:
-        """Grow the page list to hold ``num_tokens`` total tokens."""
+    def ensure_capacity(self, num_tokens: int, *,
+                        window_group: bool = True) -> None:
+        """Grow the page list to hold ``num_tokens`` total tokens, in
+        both groups where there are two.  ``window_group=False`` grows
+        the full group alone: a prompt is admitted with every page of
+        its full group, and its window group grows chunk by chunk
+        (``window.ensure_capacity``) as the band moves over it."""
         needed = self._allocator.blocks_needed(num_tokens) - len(self.blocks)
         if needed > 0:
             self.blocks.extend(self._allocator.allocate(needed))
+        if self.window is not None and window_group:
+            self.window.ensure_capacity(num_tokens)
 
     def slot_for(self, position: int) -> int:
         """Flat cache slot for the token at ``position``."""
@@ -528,12 +604,15 @@ class SequenceBlocks:
 
     def evict_below(self, position: int) -> int:
         """Rolling-window eviction: free every page that lies ENTIRELY
-        below ``position`` (sliding-window models never read below the
-        band again).  Freed entries become -1 — the list keeps its
+        below ``position`` (window layers never read below the band
+        again) — of the window group where the sequence has one, else
+        of its one table.  Freed entries become -1 — the list keeps its
         position-aligned indexing, device-side lookups clamp negative
         ids and the band mask discards whatever those pages now hold.
         A cursor makes each call O(pages newly freed), not O(history).
         Returns the number of pages freed."""
+        if self.window is not None:
+            return self.window.evict_below(position)
         bs = self._allocator.block_size
         last_dead = min(position // bs, len(self.blocks))
         if last_dead <= self._evicted_upto:
@@ -552,3 +631,5 @@ class SequenceBlocks:
         self.blocks = []
         self.num_tokens = 0
         self._evicted_upto = 0
+        if self.window is not None:
+            self.window.release()

@@ -225,6 +225,9 @@ class PipelineRunner(ModelRunner):
         self.max_blocks_per_seq = -(-mcfg.max_model_len // self.block_size)
         self._rng = np.random.default_rng(config.seed)
         self.lora_stacks = None
+        # one pool a stage: boot refuses pp for a model with window and
+        # full layers in two groups (engine/config.py)
+        self.has_window_group = False
         self._stage_lora = None
         self._lora_version = 0
         self._seen_pad_lens = sorted(

@@ -67,6 +67,15 @@ def step_compiler_options() -> Optional[dict]:
 _RAGGED_WORK_FLOOR = 64
 
 
+def _live_table(blocks: list[int]) -> "np.ndarray":
+    """A window group's table for the device: a page freed behind the
+    band (-1) is replaced by the sequence's newest page, so that no
+    entry names a page another sequence may hold by now; the band mask
+    discards those columns (engine/kv_cache.py ``evict_below``)."""
+    table = np.asarray(blocks, np.int32)
+    return np.where(table < 0, table[-1], table)
+
+
 @dataclasses.dataclass
 class SampledToken:
     """Host-side result for one sequence after one step."""
@@ -197,6 +206,18 @@ class PreparedRagged:
     # counts its step handed back (device), then, after wait, this
     # dispatch's own ``(local pairs, held experts hit)``
     expert_counts: Any = None
+    # a model with window and full layers in two groups of pages
+    # (models/cohere2_moe.py): the window group's table and slots beside
+    # the full group's above, and the window layers' own schedule
+    window_tables: "Optional[np.ndarray]" = None  # [S_max, max_blocks]
+    window_slots: "Optional[np.ndarray]" = None  # [bucket] (-1 pads)
+    window_work: "Optional[np.ndarray]" = None
+    window_work_items: int = 0
+    # the stream's leading one-token spans, which the two schedules
+    # above leave out (None: they hold every span), and the items the
+    # program's own two schedules of those rows hold (causal, window)
+    unit_rows: Optional[int] = None
+    unit_items: tuple[int, int] = (0, 0)
 
 
 @dataclasses.dataclass
@@ -225,6 +246,8 @@ class PreparedDecode:
     # token on the device (runner.last_tokens), 0 = ``token_ids`` holds
     # it.  All 0 in the serial order
     feed: "Optional[np.ndarray]" = None
+    # as PreparedRagged.window_tables
+    window_tables: "Optional[np.ndarray]" = None
 
 
 @dataclasses.dataclass
@@ -303,6 +326,20 @@ class ModelRunner:
         # counts with its pool; every step program hands them back
         self.has_expert_counts = getattr(model, "has_expert_counts", False)
         self._expert_counts_seen = np.zeros(2, np.int64)
+        # a model with window and full layers keeps their pages in two
+        # pools, each with its own tables, slots and schedule
+        self.has_window_group = mcfg.has_window_group
+        self.window_slots = (
+            cache_cfg.num_window_blocks * cache_cfg.block_size
+            if self.has_window_group else 0
+        )
+        if self.has_window_group and mesh is not None:
+            raise ValueError(
+                f"model_type {mcfg.model_type!r} keeps its pages in two "
+                "groups, and neither its experts nor the second pool has "
+                "shard rules yet (parallel/): it cannot run under a "
+                "device mesh"
+            )
         if mcfg.has_latent_cache and mesh is not None:
             raise ValueError(
                 f"model_type {mcfg.model_type!r} keeps a latent cache, "
@@ -387,6 +424,8 @@ class ModelRunner:
                 quantization=cache_cfg.kv_quantization,
                 block_size=cache_cfg.block_size,
                 kv_scale_floors=kv_scale_floors,
+                **({"window_slots": self.window_slots}
+                   if self.has_window_group else {}),
             )
             self._data_sharding = None
         # a model with recurrent state (models/falcon_h1.py) gets its
@@ -607,6 +646,7 @@ class ModelRunner:
         max_steps = self.config.scheduler_config.num_decode_steps
         vocab_size = self.config.model_config.vocab_size
         has_state = self.has_state
+        has_window_group = self.has_window_group
         # the fused wave runs the SAME unified ragged kernel as mixed
         # steps (each row a one-token span) — the bucketed decode
         # variant ladder (folded → perhead → xla) is retired; the
@@ -629,6 +669,8 @@ class ModelRunner:
             lora,  # LoRAStacks or None
             lora_idx,  # [B] adapter slot per row or None
             want_topn: bool = True,  # static: any row wants top-N logprobs
+            window_tables=None,  # [B, max_blocks]: the window group's
+            #     table, for a model with two groups of pages
         ):
             positions0 = ints[1]
             limits = ints[2]
@@ -678,11 +720,26 @@ class ModelRunner:
                 slot = jnp.where(
                     active, blk * block_size + pos % block_size, -1
                 )
+                extra = {}
+                if has_state:
+                    # a row's batch row is its state slot
+                    extra["state_slots"] = row_slots
+                if has_window_group:
+                    # the same position's slot in the window group
+                    wblk = jnp.take_along_axis(
+                        window_tables,
+                        jnp.clip(pos // block_size, 0, max_blocks - 1)[
+                            :, None],
+                        axis=1,
+                    )[:, 0]
+                    extra["window_slots"] = jnp.where(
+                        active, wblk * block_size + pos % block_size, -1
+                    )
+                    extra["window_tables"] = window_tables
                 logits, caches = model.decode(
                     params, caches, tokens, pos, slot, block_tables,
                     context_lens0 + k, block_size, lora, lora_idx,
-                    # a row's batch row is its state slot
-                    **({"state_slots": row_slots} if has_state else {}),
+                    **extra,
                 )
                 t_k = dataclasses.replace(
                     tensors, gen_len=tensors.gen_len + k
@@ -1198,6 +1255,10 @@ class ModelRunner:
         seed_slots = np.full(s_max, -1, np.int32)
         seeds = np.zeros(s_max, np.uint32)
         feed_idx = np.full(bucket, -1, np.int32)
+        window_tables = window_slots = None
+        if self.has_window_group:
+            window_tables = np.zeros_like(block_tables)
+            window_slots = np.full(bucket, -1, np.int32)
         lora_idx = None
         if self.lora_stacks is not None:
             lora_idx = np.zeros(bucket, np.int32)
@@ -1234,6 +1295,12 @@ class ModelRunner:
                 feed_idx[off] = it.seq.slot
             blocks = it.seq.blocks.blocks
             block_tables[i, : len(blocks)] = blocks
+            if window_tables is not None:
+                window = it.seq.blocks.window
+                window_tables[i, : len(window.blocks)] = _live_table(
+                    window.blocks)
+                window_slots[off : off + t] = window.slots_for_range(
+                    it.start_pos, it.start_pos + t)
             if lora_idx is not None:
                 lora_idx[off : off + t] = it.seq.lora_slot
             spans.append((off, t, it.start_pos))
@@ -1278,43 +1345,32 @@ class ModelRunner:
                     allowed_mask[i, len(row):] = False
 
         work, work_items = None, 0
+        window_work, window_work_items = None, 0
         from vllm_tgis_adapter_tpu.ops import attention as attn_ops
 
+        unit_rows, unit_items = None, (0, 0)
         if attn_ops._use_pallas():
-            from vllm_tgis_adapter_tpu.ops.ragged_attention import (
-                build_work_schedule,
-            )
-
-            # same clamp + cdiv padding the kernel applies, so the
-            # schedule covers exactly the kernel's query-block grid
-            model_block_q = getattr(self.model, "ragged_block_q", 128)
-            block_q = min(model_block_q, bucket)
-            work, work_items = build_work_schedule(
-                spans, block_tables,
-                block_size=self.block_size, block_q=block_q,
-                t_pad=-(-bucket // block_q) * block_q,
-            )
-            # the schedule width is a compile shape on the jitted
-            # ragged step: quantize it to a per-bucket high-water mark
-            # (pow2, floored) so width growth retraces log-many times
-            # and steady state keeps one program per flat bucket.  The
-            # grid ends at work_items, so the padding costs no step
-            width = max(
-                work.shape[1],
-                self._ragged_work_hwm.get(bucket, 0),
-                # the floor is in query blocks of 128 tokens: a model
-                # with a shorter block has that many more items
-                _RAGGED_WORK_FLOOR * 128 // model_block_q,
-            )
-            self._ragged_work_hwm[bucket] = width
-            if width > work.shape[1]:
-                tail = np.zeros(
-                    (work.shape[0], width - work.shape[1]), np.int32
-                )
-                # pads hold the final real block index (flags all zero
-                # = no-ops), same contract as build_work_schedule's own
-                tail[0, :] = work[0, -1]
-                work = np.concatenate([work, tail], axis=1)
+            if window_tables is not None:
+                # the running rows' one-token spans lead the stream and
+                # have a schedule of their own, built in the program as
+                # the wave's is (models/cohere2_moe.py): these two hold
+                # the prompt chunks alone
+                unit_rows = next(
+                    (i for i, (_, t, _) in enumerate(spans) if t != 1),
+                    len(spans))
+                unit_items = self._unit_items(spans[:unit_rows])
+                spans = [(off, 0, pos) for off, _, pos in spans[:unit_rows]
+                         ] + spans[unit_rows:]
+            work, work_items = self._work_schedule(
+                spans, block_tables, bucket)
+            if window_tables is not None:
+                # the window layers' own: the runs of pages below the
+                # band are left out (ops/ragged_attention.py).  Never
+                # more items than the causal schedule, and padded to its
+                # width: one compile shape for both, not a lattice
+                window_work, window_work_items = self._work_schedule(
+                    spans, window_tables, bucket, width=work.shape[1],
+                    window=self.config.model_config.group_window)
 
         prep = PreparedRagged(
             bucket=bucket,
@@ -1339,6 +1395,12 @@ class ModelRunner:
             work=work,
             work_items=work_items,
             work_pages=0 if work is None else int(work[2].sum()),
+            window_tables=window_tables,
+            window_slots=window_slots,
+            window_work=window_work,
+            window_work_items=window_work_items,
+            unit_rows=unit_rows,
+            unit_items=unit_items,
             want_topn=any(
                 it.is_final and it.seq.params.logprobs not in (None, 0)
                 for it in items
@@ -1572,6 +1634,68 @@ class ModelRunner:
         prep.spec_ran = True
         return {"std": packed_std, "spec": packed_spec}
 
+    def _unit_items(self, spans) -> tuple[int, int]:
+        """Work items of one-token spans in a causal and in the window
+        layers' schedule: a run of pages for every 128 tokens a row
+        reads (ops/ragged_attention.py ``decode_work_schedule``)."""
+        from vllm_tgis_adapter_tpu.ops.ragged_attention import (
+            band_pages,
+            pages_per_item,
+        )
+
+        run = pages_per_item(self.block_size)
+        counts = []
+        for window in (0, self.config.model_config.group_window):
+            pages = (band_pages(pos, pos, self.block_size, window)
+                     for _, _, pos in spans)
+            counts.append(sum(-(-(last + 1 - first) // run)
+                              for first, last in pages))
+        return counts[0], counts[1]
+
+    def _work_schedule(self, spans, block_tables, bucket: int,
+                       width: Optional[int] = None, window: int = 0):
+        """``(work, real items)`` of one mixed step for the layers of
+        one kind (``window`` 0: the causal span; else the window
+        layers' band), at its compile width: the bucket's high-water
+        mark, or ``width`` where the caller names one."""
+        from vllm_tgis_adapter_tpu.ops.ragged_attention import (
+            build_work_schedule,
+        )
+
+        # same clamp + cdiv padding the kernel applies, so the
+        # schedule covers exactly the kernel's query-block grid
+        model_block_q = getattr(self.model, "ragged_block_q", 128)
+        block_q = min(model_block_q, bucket)
+        work, work_items = build_work_schedule(
+            spans, block_tables,
+            block_size=self.block_size, block_q=block_q,
+            t_pad=-(-bucket // block_q) * block_q,
+            window=window,
+        )
+        # the schedule width is a compile shape on the jitted
+        # ragged step: quantize it to a per-bucket high-water mark
+        # (pow2, floored) so width growth retraces log-many times
+        # and steady state keeps one program per flat bucket.  The
+        # grid ends at work_items, so the padding costs no step
+        if width is None:
+            width = max(
+                work.shape[1],
+                self._ragged_work_hwm.get(bucket, 0),
+                # the floor is in query blocks of 128 tokens: a model
+                # with a shorter block has that many more items
+                _RAGGED_WORK_FLOOR * 128 // model_block_q,
+            )
+            self._ragged_work_hwm[bucket] = width
+        if width > work.shape[1]:
+            tail = np.zeros(
+                (work.shape[0], width - work.shape[1]), np.int32
+            )
+            # pads hold the final real block index (flags all zero
+            # = no-ops), same contract as build_work_schedule's own
+            tail[0, :] = work[0, -1]
+            work = np.concatenate([work, tail], axis=1)
+        return work, work_items
+
     def _work_args(self, prep: "PreparedRagged") -> dict:
         """The Pallas path's schedule operands of a ragged dispatch: the
         schedule at its compile width and, beside it, the count of real
@@ -1583,6 +1707,24 @@ class ModelRunner:
             "work": self._put(prep.work),
             "n_items": self._put(np.asarray(prep.work_items, np.int32)),
         }
+
+    def _window_args(self, prep: "PreparedRagged") -> dict:
+        """For a model with two groups of pages, the window group's
+        table and slots and (the Pallas path's) the window layers'
+        schedule; nothing otherwise."""
+        if prep.window_tables is None:
+            return {}
+        args = {
+            "window_tables": self._put(prep.window_tables),
+            "window_slots": self._put(prep.window_slots),
+        }
+        if prep.window_work is not None:
+            args["window_work"] = self._put(prep.window_work)
+            args["window_n_items"] = self._put(
+                np.asarray(prep.window_work_items, np.int32))
+            args["unit_rows"] = self._put(
+                np.asarray(prep.unit_rows, np.int32))
+        return args
 
     def _state_args(self, prep: "PreparedRagged") -> dict:
         """For a model with recurrent state, the slab slot of every
@@ -1618,6 +1760,7 @@ class ModelRunner:
             feed_idx=self._put(prep.feed_idx),
             **self._work_args(prep),
             **self._state_args(prep),
+            **self._window_args(prep),
         )
         prep.expert_counts = counts[0] if counts else None
         return self._sample_rows(
@@ -1701,6 +1844,9 @@ class ModelRunner:
         block_tables = np.zeros((b, self.max_blocks_per_seq), np.int32)
         slots = np.full(b, -1, np.int32)
         seeds = np.zeros(b, np.uint32)
+        window_tables = (
+            np.zeros_like(block_tables) if self.has_window_group else None
+        )
         # planned ahead (scheduler.schedule_ahead): a row's tokens still
         # in flight are counted into its position, length and sampling
         # counter, and its input token is read on the device
@@ -1716,6 +1862,9 @@ class ModelRunner:
             context_lens[i] = pos + 1
             blocks = seq.blocks.blocks
             block_tables[i, : len(blocks)] = blocks
+            if window_tables is not None:
+                window = seq.blocks.window.blocks
+                window_tables[i, : len(window)] = _live_table(window)
             slots[i] = seq.slot
             seeds[i] = seq.fallback_seed
 
@@ -1769,6 +1918,7 @@ class ModelRunner:
             allowed_mask=allowed_mask,
             lora_idx=lora_idx,
             feed=feed,
+            window_tables=window_tables,
         )
 
     def _pack_decode_inputs(self, prep: "PreparedDecode"):
@@ -1814,6 +1964,8 @@ class ModelRunner:
                 if prep.lora_idx is not None
                 else None,
                 prep.want_topn,
+                **({"window_tables": self._put(prep.window_tables)}
+                   if prep.window_tables is not None else {}),
             )
 
         self.caches, self.seen, self.last_tokens, packed_out, *counts = call()

@@ -126,6 +126,7 @@ class Scheduler:
         cache_config: CacheConfig,
         num_blocks: int,
         max_model_len: int = 1 << 30,
+        num_window_blocks: int = 0,
     ):
         self.config = scheduler_config
         self.block_size = cache_config.block_size
@@ -136,6 +137,15 @@ class Scheduler:
             enable_prefix_caching=getattr(
                 cache_config, "enable_prefix_caching", False
             ),
+        )
+        # a model whose window layers and full layers keep their pages
+        # in two groups (ModelConfig.has_window_group): ``allocator`` is
+        # then the full group's pool, this the window group's, whose
+        # pages behind a row's band are freed as the row advances
+        # (_roll_window; docs/ATTENTION.md).  None = one group
+        self.window_allocator: Optional[BlockAllocator] = (
+            BlockAllocator(num_window_blocks, cache_config.block_size)
+            if num_window_blocks > 0 else None
         )
         self.waiting: deque[Sequence] = deque()
         self.running: list[Sequence] = []
@@ -341,23 +351,62 @@ class Scheduler:
         slot of the recurrent-state slab, docs/STATE.md)."""
         return len(self._free_slots)
 
+    # ------------------------------------------------- groups of pages
+
+    def _pools(self) -> list[BlockAllocator]:
+        """The pool of every group of layers: one, or the full group's
+        and the window group's."""
+        if self.window_allocator is None:
+            return [self.allocator]
+        return [self.allocator, self.window_allocator]
+
+    def _new_blocks(self) -> SequenceBlocks:
+        """A sequence's tables: one a group."""
+        return SequenceBlocks(self.allocator, self.window_allocator)
+
+    def _band_pages(self, num_tokens: int) -> int:
+        """Pages of the window group a row of ``num_tokens`` tokens comes
+        to hold: its band (``rolling_window``) and one step's chunk over
+        it, or all of it where it is shorter."""
+        return self.allocator.blocks_needed(
+            min(num_tokens, self.rolling_window + self.chunk_budget)
+        ) + 1
+
+    def _window_growth(self, seq: Sequence, upto: int) -> int:
+        """Pages the window group must add before ``seq`` holds tokens
+        up to ``upto``; for a row not admitted yet, the band it will
+        come to hold (admission counts both groups).  0 with one
+        group."""
+        if self.window_allocator is None:
+            return 0
+        if seq.blocks is None:
+            return self._band_pages(len(seq.all_token_ids))
+        return max(
+            0,
+            self.allocator.blocks_needed(upto)
+            - len(seq.blocks.window.blocks),
+        )
+
     # ------------------------------------------------------ free epochs
 
     def begin_free_epoch(self) -> None:
         """Open a quarantine epoch for pages (allocator) and batch rows:
         what is freed from now on is released when the dispatch that was
         just planned ahead has retired (flush_free_epoch)."""
-        self.allocator.begin_free_epoch()
+        for pool in self._pools():
+            pool.begin_free_epoch()
         self._slot_epochs.append([])
 
     def flush_free_epoch(self) -> None:
         """Release the OLDEST epoch's pages and rows."""
-        self.allocator.flush_free_epoch()
+        for pool in self._pools():
+            pool.flush_free_epoch()
         if self._slot_epochs:
             self._free_slots.extend(self._slot_epochs.popleft())
 
     def flush_all_free_epochs(self) -> None:
-        self.allocator.flush_all_free_epochs()
+        for pool in self._pools():
+            pool.flush_all_free_epochs()
         while self._slot_epochs:
             self._free_slots.extend(self._slot_epochs.popleft())
 
@@ -582,7 +631,7 @@ class Scheduler:
             # the paged cache, exactly like a later chunk).  prompt-logprob
             # requests never adopt (their skipped span's table rows could
             # never be computed — see _adoptable)
-            seq.blocks = SequenceBlocks(self.allocator)
+            seq.blocks = self._new_blocks()
             if self._adoptable(seq):
                 hit_blocks, matched = self.allocator.match_prefix(
                     token_ids, seq.lora_name
@@ -715,7 +764,10 @@ class Scheduler:
             )
             for seq in rows
         )
-        if needed and not self.allocator.can_allocate(needed):
+        # a running row's tables are of one length in every group
+        if needed and not all(
+            pool.can_allocate(needed) for pool in self._pools()
+        ):
             raise _AheadBail("kv_growth")
 
     def _schedule_decode(self) -> Optional[DecodePlan]:
@@ -947,6 +999,12 @@ class Scheduler:
         cands: list[tuple[Sequence, int]] = []
         cut = False  # a prompt got fewer tokens than it has left
         slots_left = len(self._free_slots)
+        # pages of the window group still free for this step's chunks
+        window_left = (
+            self.window_allocator.num_free
+            if self.window_allocator is not None else 0
+        )
+        window_blocked: list[Sequence] = []
         for seq in list(self.waiting):
             if tokens_left <= 0:
                 break
@@ -990,6 +1048,14 @@ class Scheduler:
             if remaining <= 0:
                 remaining = 1  # defensive: the last row always runs
             take = min(remaining, tokens_left)
+            grow = self._window_growth(seq, seq.prefill_pos + take)
+            if grow > window_left:
+                # the window group cannot hold this chunk (or, for a
+                # new row, its band) yet: pages free behind other rows'
+                # bands and as rows end
+                window_blocked.append(seq)
+                continue
+            window_left -= grow
             cands.append((seq, take))
             tokens_left -= take
             if take < remaining:
@@ -998,6 +1064,15 @@ class Scheduler:
                 slots_left -= 1
 
         if not cands:
+            if (
+                window_blocked and not decode_seqs and not self._ahead
+                and not self._inflight
+            ):
+                # nothing runs and every prompt waits for pages of the
+                # window group that only other waiting prompts hold: the
+                # youngest of them gives its pages back and starts over
+                if self._preempt_youngest(exclude=window_blocked[0]):
+                    return self._schedule_ragged(prefill_only)
             if prefill_only or not decode_seqs:
                 return None
             if not has_spec:
@@ -1052,7 +1127,7 @@ class Scheduler:
             if first:
                 if not self._free_slots:
                     continue
-                seq.blocks = SequenceBlocks(self.allocator)
+                seq.blocks = self._new_blocks()
                 if self._adoptable(seq):
                     hit_blocks, matched = self.allocator.match_prefix(
                         token_ids, seq.lora_name
@@ -1086,7 +1161,7 @@ class Scheduler:
                     seq.blocks = None
                     seq.prefill_pos = 0
                     continue
-                seq.blocks.ensure_capacity(n_total)
+                seq.blocks.ensure_capacity(n_total, window_group=False)
                 seq.slot = self._free_slots.pop()
                 self.allocator.prefix_hits += seq.prefill_pos
                 self.allocator.prefix_lookup_tokens += n_total
@@ -1104,6 +1179,11 @@ class Scheduler:
             if chunk <= 0:
                 continue
             end = seq.prefill_pos + chunk
+            if seq.blocks.window is not None:
+                # the window group grows chunk by chunk behind a band
+                # that moves with it (phase 1 saw the pages free)
+                self._roll_window([seq], upto=seq.prefill_pos + 1)
+                seq.blocks.window.ensure_capacity(end)
             items.append(
                 RaggedItem(
                     seq=seq,
@@ -1136,17 +1216,26 @@ class Scheduler:
             capped=ruled and cut,
         )
 
-    def _roll_window(self, seqs: list[Sequence]) -> None:
+    def _roll_window(self, seqs: list[Sequence],
+                     upto: Optional[int] = None) -> None:
         """Free KV pages entirely below the attention band (see
-        ``rolling_window``).  No wave — in flight or planned — reads
-        positions under ``num_tokens - window``, and band masks discard
-        whatever a reallocated page later holds."""
+        ``rolling_window``) — of the window group where a row has two
+        groups, whatever its full group keeps.  The next row a sequence
+        runs sits at position ``upto - 1`` (its ``num_tokens``: the last
+        sampled token; a prompt chunk passes its first row), and no wave
+        — in flight or planned — reads positions under ``upto -
+        window``; band masks discard whatever a reallocated page later
+        holds, and the free epochs keep a freed page from being handed
+        out while a dispatch that reads it is still in flight."""
         if not self.rolling_window:
             return
+        freed = 0
         for seq in seqs:
-            lo = seq.num_tokens - self.rolling_window
+            lo = (upto or seq.num_tokens) - self.rolling_window
             if lo > 0 and seq.blocks is not None:
-                seq.blocks.evict_below(lo)
+                freed += seq.blocks.evict_below(lo)
+        if freed and self.window_allocator is not None:
+            metrics.kv_window_pages_freed_total.inc(freed)
 
     def schedule_ahead(
         self, inflight: dict[int, int]
@@ -1256,6 +1345,12 @@ class Scheduler:
         needed = self.allocator.blocks_needed(total) - (
             self.allocator.blocks_needed(matched) if matched else 0
         )
+        if (
+            self.window_allocator is not None
+            and not self.window_allocator.can_allocate(
+                self._band_pages(total))
+        ):
+            return False
         return self.allocator.can_allocate(max(0, needed))
 
     def try_swap_in(self) -> Optional[Sequence]:
@@ -1278,7 +1373,7 @@ class Scheduler:
         needed = self.allocator.blocks_needed(total)
         if not self.allocator.can_allocate(needed):
             return None
-        seq.blocks = SequenceBlocks(self.allocator)
+        seq.blocks = self._new_blocks()
         seq.blocks.ensure_capacity(total)
         seq.slot = self._free_slots.pop()
         self.waiting.popleft()

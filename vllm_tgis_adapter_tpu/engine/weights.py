@@ -788,6 +788,81 @@ def load_deepseek_v2_params(
     return params
 
 
+def cohere2_moe_tensor_names(config: "ModelConfig") -> list[str]:
+    """Every checkpoint tensor ``load_cohere2_moe_params`` asks for: of
+    a layer's routed experts, the held ones under their published
+    indices; the head is the embedding."""
+    ex = config.experts
+    names = ["model.embed_tokens.weight", "model.norm.weight"]
+    for i in range(config.num_layers):
+        p = f"model.layers.{i}"
+        names.append(f"{p}.input_layernorm.weight")
+        names += [f"{p}.self_attn.{n}_proj.weight" for n in "qkvo"]
+        names.append(f"{p}.mlp.gate.weight")
+        names += [f"{p}.mlp.shared_experts.{j}.{hf}.weight"
+                  for j in range(ex.num_shared) for hf, _ in _SWIGLU]
+        names += [f"{p}.mlp.experts.{e}.{hf}.weight"
+                  for e in range(*ex.held) for hf, _ in _SWIGLU]
+    return names
+
+
+def load_cohere2_moe_params(
+    config: "ModelConfig",
+    model_path: str,
+    place: Optional[PlaceFn] = None,
+) -> dict:
+    """Build the Cohere2MoeForCausalLM param pytree
+    (models/cohere2_moe.py) from a ``cohere2_moe`` checkpoint:
+    projections transposed to ``[in, out]``; in the layers that rotate,
+    each head's columns of ``q_proj`` and ``k_proj`` regrouped from
+    interleaved pairs to halves, once, so that the runtime rotates
+    halves; the shared experts laid side by side as ONE SwiGLU (gate and
+    up along the output axis, down along the input axis: its output is
+    their sum); the held experts' matrices stacked by expert."""
+    raw = open_checkpoint_index(config, model_path)
+    place = place or (lambda _n, x: x)
+    take = _make_take(raw, config.dtype, place, ("",))
+    ex, dh = config.experts, config.head_dim
+    halves = jnp.concatenate([jnp.arange(0, dh, 2), jnp.arange(1, dh, 2)])
+
+    def regrouped(w: jax.Array) -> jax.Array:  # [d, heads * dh]
+        return w.reshape(w.shape[0], -1, dh)[..., halves].reshape(w.shape)
+
+    params: dict = {
+        "embed": take("model.embed_tokens.weight"),
+        "final_norm": take("model.norm.weight"),
+        "layers": [],
+    }
+    for i in range(config.num_layers):
+        p = f"model.layers.{i}"
+        layer = {
+            "input_norm": take(f"{p}.input_layernorm.weight"),
+            "router": take(f"{p}.mlp.gate.weight", transpose=True),
+        }
+        for n in "qkvo":
+            layer[f"w{n}"] = take(f"{p}.self_attn.{n}_proj.weight",
+                                  transpose=True)
+        if config.layer_rotates(i):
+            layer["wq"] = regrouped(layer["wq"])
+            layer["wk"] = regrouped(layer["wk"])
+        for hf, key in _SWIGLU:
+            layer[f"experts_{key[2:]}"] = jnp.stack([
+                take(f"{p}.mlp.experts.{e}.{hf}.weight", transpose=True)
+                for e in range(*ex.held)
+            ])
+            layer[key] = jnp.concatenate([
+                take(f"{p}.mlp.shared_experts.{j}.{hf}.weight",
+                     transpose=True)
+                for j in range(ex.num_shared)
+            ], axis=0 if hf == "down_proj" else 1)
+        params["layers"].append(layer)
+    ignored = raw.remaining()
+    if ignored:
+        logger.warning("ignored %d unexpected checkpoint tensors: %s",
+                       len(ignored), ignored[:5])
+    return params
+
+
 def load_model_params(
     config: "ModelConfig",
     model_path: str,
@@ -796,6 +871,8 @@ def load_model_params(
     """Dispatch to the checkpoint layout for ``config.model_type``."""
     if config.model_type == "deepseek_v2":
         return load_deepseek_v2_params(config, model_path, place)
+    if config.model_type == "cohere2_moe":
+        return load_cohere2_moe_params(config, model_path, place)
     if config.model_type == "falcon_h1":
         return load_falcon_h1_params(config, model_path, place)
     if config.model_type == "opt":

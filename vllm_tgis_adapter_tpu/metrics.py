@@ -425,6 +425,65 @@ moe_expert_calls_total = _get_or_create(
     "down) x expert layers x the steps of a decode wave's loop, or x 1 "
     "for a mixed step",
 )
+# ---- window layers and full layers in one stack (docs/ATTENTION.md): a
+# model whose cache keeps the two kinds' pages in two groups runs one
+# kernel call a layer under the kind's own name, schedule and mask.  As
+# for the latent kernel, the counters rise when a plan is dispatched and
+# speak of the kernel whatever program made the call
+window_attn_flops_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_window_attn_flops_total",
+    "Floating-point operations of the window layers' attention kernel: "
+    "real (query row, cached token) pairs under the band mask x query "
+    "heads x 2 x 2 x head_dim, from the dispatched plan on the host",
+)
+window_attn_calls_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_window_attn_calls_total",
+    "Calls of the window layers' attention kernel dispatched: window "
+    "layers x the steps of a decode wave's loop, window layers x 1 for a "
+    "mixed step",
+)
+full_attn_flops_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_full_attn_flops_total",
+    "Floating-point operations of the full layers' attention kernel "
+    "beside window layers: real pairs under the causal mask x query "
+    "heads x 2 x 2 x head_dim, from the dispatched plan on the host",
+)
+full_attn_calls_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_full_attn_calls_total",
+    "Calls of the full layers' attention kernel dispatched beside window "
+    "layers: full layers x the steps of a decode wave's loop, full "
+    "layers x 1 for a mixed step",
+)
+kv_window_pages_freed_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_kv_window_pages_freed_total",
+    "Pages of the window group freed behind a running or prefilling "
+    "row's band (engine/scheduler.py _roll_window), a model with two "
+    "groups of pages only",
+)
+kv_window_pages_used = _get_or_create(
+    Gauge,
+    f"{_PREFIX}_kv_window_pages_used",
+    "Pages of the window group's pool held by sequences (kv_pages_used "
+    "is then the full group's); 0 for a model with one group",
+)
+kv_window_pages_total = _get_or_create(
+    Gauge,
+    f"{_PREFIX}_kv_window_pages_total",
+    "Pages of the window group's pool; 0 for a model with one group",
+)
+window_items_skipped = _get_or_create(
+    Histogram,
+    f"{_PREFIX}_window_items_skipped",
+    "Share (0-1) of a dispatched plan's causal work items (query block, "
+    "sequence, run of pages) that the window layers' schedule left out "
+    "because they lie wholly below the band",
+    buckets=(0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0),
+)
 moe_expert_tokens = _get_or_create(
     Histogram,
     f"{_PREFIX}_moe_expert_tokens",
@@ -938,6 +997,20 @@ def observe_latent_attention(*, flops: int, calls: int) -> None:
     mla_attn_calls_total.inc(calls)
 
 
+def observe_window_attention(*, window_flops: int, window_calls: int,
+                             full_flops: int, full_calls: int,
+                             causal_items: int, window_items: int) -> None:
+    """Attention work of one dispatched plan of a model with window and
+    full layers (mixed step, wave or chained wave): the one place these
+    counters are raised."""
+    window_attn_flops_total.inc(window_flops)
+    window_attn_calls_total.inc(window_calls)
+    full_attn_flops_total.inc(full_flops)
+    full_attn_calls_total.inc(full_calls)
+    if causal_items:
+        window_items_skipped.observe(1.0 - window_items / causal_items)
+
+
 def observe_expert_step(*, routed_pairs: int, local_pairs: int,
                         experts_hit: int, layer_steps: int, num_held: int,
                         expert_bytes: int, pair_bytes: int) -> None:
@@ -1009,6 +1082,8 @@ def update_engine_gauges(
     prefix_hits: int,
     state_used: int = 0,
     state_total: int = 0,
+    window_used: int = 0,
+    window_total: int = 0,
 ) -> None:
     # num_requests_running is NOT set here: the serving layer inc/decs it
     # per request (tgis_utils/logs.py) and a periodic .set() from a
@@ -1020,6 +1095,8 @@ def update_engine_gauges(
     prefix_cache_hit_tokens.set(prefix_hits)
     ssm_state_slots_used.set(state_used)
     ssm_state_slots_total.set(state_total)
+    kv_window_pages_used.set(window_used)
+    kv_window_pages_total.set(window_total)
 
 
 def record_response(

@@ -9,6 +9,7 @@ data in ModelConfig, not code forks.
 
 from __future__ import annotations
 
+from .cohere2_moe import Cohere2MoeForCausalLM
 from .deepseek_v2 import DeepseekV2ForCausalLM
 from .falcon_h1 import FalconH1ForCausalLM
 from .llama import LlamaForCausalLM
@@ -53,6 +54,11 @@ _REGISTRY = {
     # group-routed experts of which the server holds a share; a class
     # of its own (models/deepseek_v2.py, docs/LATENT_ATTENTION.md)
     "deepseek_v2": DeepseekV2ForCausalLM,
+    # Command A+: window and full attention layers in one stack over a
+    # cache in two groups, sigmoid-routed experts of which the server
+    # holds a share; a class of its own (models/cohere2_moe.py,
+    # docs/ATTENTION.md)
+    "cohere2_moe": Cohere2MoeForCausalLM,
 }
 
 

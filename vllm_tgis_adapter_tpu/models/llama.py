@@ -392,14 +392,12 @@ class LlamaForCausalLM:
         return rms_norm(x, container[name], cfg.rms_norm_eps)
 
     def _window_for_layer(self, i: int) -> int:
-        """Per-layer sliding window: qwen2 keeps the first
-        ``max_window_layers`` layers on full attention (HF semantics);
-        every other windowed model bands all layers.  ``i`` is local to
-        this stage's layer slice; layer_offset globalises it."""
-        cfg = self.config
-        if cfg.sliding_window and i + self.layer_offset < cfg.max_window_layers:
-            return 0
-        return cfg.sliding_window
+        """Layer ``i``'s sliding window, 0 = full attention, as the
+        config's per-layer data has it (``ModelConfig.window_of_layer``:
+        every layer banded, or qwen2's first ``max_window_layers`` full).
+        ``i`` is local to this stage's layer slice; layer_offset
+        globalises it."""
+        return self.config.window_of_layer(i + self.layer_offset)
 
     def _rope_tables(self, positions: jax.Array):
         """cos/sin for rotary models; None when positions enter at embed."""

@@ -39,6 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from vllm_tgis_adapter_tpu.ops import attention as attn_ops
+from vllm_tgis_adapter_tpu.ops import ragged_attention
 from vllm_tgis_adapter_tpu.ops.attention import NEG_INF
 from vllm_tgis_adapter_tpu.ops.ragged_attention import (
     WORK_FIELDS,
@@ -60,29 +61,17 @@ MIXED_BLOCK_Q = 4
 DECODE_BLOCK_Q = 1
 
 
-#: widest work schedule the kernel takes: the schedule rides scalar
-#: prefetch, 16 rows of 4 bytes an item in the chip's 1 MB of scalar
-#: memory, and 16,384 items do not fit beside the other prefetched
-#: operands (the chip's compiler refuses the program)
-MAX_WORK_WIDTH = 8192
+#: widest work schedule the kernel takes (the ragged kernel's own bound)
+MAX_WORK_WIDTH = ragged_attention.MAX_WORK_WIDTH
 
 
 def max_step_prefill_tokens(max_model_len: int, max_num_seqs: int,
                             block_size: int) -> int:
     """The most prompt tokens a mixed step may hold so that its schedule
-    fits ``MAX_WORK_WIDTH`` whatever the contexts: every running row's
-    one token and every query block of the chunk may each see
-    ``max_model_len`` tokens, an item a run of 128.  A power of two (the
-    planner's chunks are)."""
-    run_tokens = pages_per_item(block_size) * block_size
-    runs = -(-max_model_len // run_tokens)
-    blocks = MAX_WORK_WIDTH // runs - max_num_seqs
-    if blocks < 1:
-        raise ValueError(
-            f"max_model_len {max_model_len} with {max_num_seqs} rows: one "
-            f"step's work schedule would not fit {MAX_WORK_WIDTH} items")
-    tokens = blocks * MIXED_BLOCK_Q
-    return 1 << (tokens.bit_length() - 1)
+    fits ``MAX_WORK_WIDTH`` whatever the contexts, at this kernel's
+    query block (ops/ragged_attention.py has the rule)."""
+    return ragged_attention.max_step_prefill_tokens(
+        max_model_len, max_num_seqs, block_size, MIXED_BLOCK_Q)
 
 
 def attended_pairs(n_rows: int, pos0: int) -> int:

@@ -57,6 +57,22 @@ def group_limited_route(
     return experts.astype(jnp.int32), weights * scaling
 
 
+def sigmoid_route(
+    logits: jax.Array,  # [T, E] router outputs, any float dtype
+    *,
+    top_k: int,
+) -> tuple[jax.Array, jax.Array]:
+    """Sigmoid routing (``cohere2_moe``): every expert's score is the
+    sigmoid of its logit, in float32; the ``top_k`` largest are the
+    token's experts, each weighted by its score over the sum of the
+    chosen scores.  Ties break to the lower index.  Returns
+    ``(experts [T, k] i32, weights [T, k] f32)``."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    chosen, experts = jax.lax.top_k(scores, top_k)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), weights
+
+
 def grouped_matmul(
     lhs: jax.Array,  # [P, k] rows sorted by group
     rhs: jax.Array,  # [G, k, n]

@@ -107,6 +107,31 @@ def work_rows(block_size: int) -> int:
 SCALE_LANES = 128
 
 
+#: widest work schedule a kernel takes: the schedule rides scalar
+#: prefetch, 16 rows of 4 bytes an item in the chip's 1 MB of scalar
+#: memory, and 16,384 items do not fit beside the other prefetched
+#: operands (the chip's compiler refuses the program)
+MAX_WORK_WIDTH = 8192
+
+
+def max_step_prefill_tokens(max_model_len: int, max_num_seqs: int,
+                            block_size: int, block_q: int) -> int:
+    """The most prompt tokens a mixed step may hold so that its schedule
+    fits ``MAX_WORK_WIDTH`` whatever the contexts: every running row's
+    one token and every query block of the chunk may each see
+    ``max_model_len`` tokens, an item a run of 128.  A power of two (the
+    planner's chunks are)."""
+    run_tokens = pages_per_item(block_size) * block_size
+    runs = -(-max_model_len // run_tokens)
+    blocks = MAX_WORK_WIDTH // runs - max_num_seqs
+    if blocks < 1:
+        raise ValueError(
+            f"max_model_len {max_model_len} with {max_num_seqs} rows: one "
+            f"step's work schedule would not fit {MAX_WORK_WIDTH} items")
+    tokens = blocks * block_q
+    return 1 << (tokens.bit_length() - 1)
+
+
 def _pow2_ceil(n: int) -> int:
     b = 1
     while b < n:
@@ -125,6 +150,7 @@ def build_work_schedule(
     block_q: int,
     t_pad: int,
     w_bucket: int | None = None,
+    window: int = 0,
 ) -> tuple["np.ndarray", int]:
     """Host-side sparse schedule for a mixed ragged batch.
 
@@ -132,9 +158,16 @@ def build_work_schedule(
     triple: the logical pages that could be causally visible to some
     row of that sequence in that block — the exact page set, so the
     kernel never DMAs a page no row reads — cut into runs of
-    ``pages_per_item(block_size)``.  Windowed layers mask inside the
-    kernel (the schedule is shared across layers and some layers may be
-    full-attention, so it must cover the full causal span).  Every query
+    ``pages_per_item(block_size)``.  A schedule serves the layers of one
+    kind: with ``window`` 0 it covers the full causal span (the band of
+    a model whose layers all share this one schedule is cut by the
+    kernel's mask alone); with ``window`` > 0 it is the window layers'
+    own and leaves out every run of pages that lies wholly below the
+    band of the block's first row — the runs start at the band's first
+    page, and the kernel's band mask cuts into that first run.  A page
+    the cache no longer holds (table entry < 0: freed behind the band)
+    is never named: its place repeats a live page of the same sequence,
+    whose columns the band mask discards.  Every query
     block gets at least one item (a dead one if the block is all
     padding) so its output block is always initialised and finalised.
 
@@ -160,13 +193,19 @@ def build_work_schedule(
             # can see: its last row's own position
             row_hi = min(start + n_rows - 1, (qb + 1) * block_q - 1)
             max_pos = pos0 + (row_hi - start)
-            pages = block_tables[s, : max_pos // block_size + 1].tolist()
+            first, last = band_pages(
+                pos0 + (max(start, qb * block_q) - start), max_pos,
+                block_size, window)
+            pages = block_tables[s, first: last + 1].tolist()
+            live = pages[-1]  # the newest page is never freed
+            pages = [p if p >= 0 else live for p in pages]
             for j in range(0, len(pages), run):
                 ids = pages[j: j + run]
                 # a short run repeats its last real page: the causal
                 # mask already excludes those columns
                 per_block[qb].append((
-                    s, len(ids), j, 1, ids + ids[-1:] * (run - len(ids)),
+                    s, len(ids), first + j, 1,
+                    ids + ids[-1:] * (run - len(ids)),
                 ))
     items: list[list[int]] = []
     for qb in range(nq):
@@ -189,6 +228,16 @@ def build_work_schedule(
     return work, w
 
 
+def band_pages(pos_lo: int, pos_hi: int, block_size: int,
+               window: int) -> tuple[int, int]:
+    """``(first, last)`` logical pages some row at a position in
+    ``[pos_lo, pos_hi]`` reads: down from ``pos_hi``'s own page to page
+    0, or with a window to the page of the oldest token ``pos_lo``
+    sees."""
+    first = max(0, pos_lo - window + 1) // block_size if window > 0 else 0
+    return first, pos_hi // block_size
+
+
 #: query-block height of the fused decode wave's schedule: every span
 #: is one row, so a taller block only multiplies masked rows per item
 DECODE_BLOCK_Q = 8
@@ -207,6 +256,7 @@ def decode_work_schedule(
     *,
     block_size: int,
     block_q: int | None = None,
+    window: int = 0,
 ) -> tuple[jax.Array, jax.Array]:
     """In-trace schedule for the fused decode wave, where every span is
     exactly ONE row (``seq_starts = arange(S+1)``): sequence *s* IS flat
@@ -224,16 +274,28 @@ def decode_work_schedule(
     live, so every output block is still initialised and finalised.  The
     kernel's grid ends at ``n_items`` (a traced scalar): what is not
     live costs no grid step.
+
+    With ``window`` > 0 (the window layers' own schedule) a row owns
+    only the pages from its band's first one on (``band_pages``), its
+    runs start there, and the static width shrinks to the band's runs.
     """
     s_count, max_blocks = block_tables.shape
     run = pages_per_item(block_size)
-    max_runs = -(-max_blocks // run)
+    band = max_blocks
+    if window > 0:
+        # a band covers window tokens from anywhere in a page
+        band = min(max_blocks, -(-window // block_size) + 1)
+    max_runs = -(-band // run)
     block_q = block_q or decode_block_q(s_count)
     nq = -(-s_count // block_q)
     rows = jnp.arange(s_count, dtype=jnp.int32)
-    pages = jnp.where(
-        active, jnp.clip(pos_base // block_size + 1, 1, max_blocks), 0
+    last_page = jnp.clip(pos_base // block_size, 0, max_blocks - 1)
+    first_page = (
+        jnp.maximum(pos_base - window + 1, 0) // block_size
+        if window > 0 else jnp.zeros_like(last_page)
     ).astype(jnp.int32)
+    pages = jnp.where(active, last_page + 1 - first_page, 0).astype(
+        jnp.int32)
     runs = -(-pages // run)
     block_runs = jnp.sum(
         jnp.pad(runs, (0, nq * block_q - s_count)).reshape(nq, block_q),
@@ -255,16 +317,18 @@ def decode_work_schedule(
     r = w - (jnp.take(ends, s) - jnp.take(items, s))
     row_pages = jnp.take(pages, s)
     live = r < jnp.take(runs, s)
-    j = r * run  # the run's first logical page
+    row_first = jnp.take(first_page, s)
+    j = r * run  # the run's first page, counted from the row's first
     n_pages = jnp.where(live, jnp.minimum(row_pages - j, run), 0)
     # a short run repeats its last real page, and a dead item's DMA
     # indices clamp to a page of its row's table: the causal mask
     # already excludes those columns
-    j_eff = jnp.clip(
+    j_eff = row_first[None, :] + jnp.clip(
         j[None, :] + jnp.arange(run, dtype=jnp.int32)[:, None],
         0, jnp.maximum(row_pages - 1, 0)[None, :],
     )
     page_ids = jnp.clip(block_tables[s[None, :], j_eff], 0, None)
+    j = row_first + j  # the run's first LOGICAL page
     qb = s // block_q
     # first/last flags on the block TRANSITIONS: blocks hold ragged
     # item counts, and every block's accumulators must init exactly
@@ -448,6 +512,7 @@ def _ragged_attention_pallas(
     n_items: jax.Array | None = None,  # traced scalar: the grid visits
     #     only work[:, :n_items] (None = the whole schedule)
     layer: int | None = None,  # static: which layer of the pool to read
+    name: str | None = None,  # the call's name in a device trace
 ) -> jax.Array:
     t, num_heads, head_dim = q.shape
     k_cache, v_cache, kv_scales, layer = _pool_view(
@@ -545,6 +610,7 @@ def _ragged_attention_pallas(
             (num_kv, nq * g * block_q, head_dim), q.dtype
         ),
         interpret=interpret,
+        **({} if name is None else {"name": name}),
     )(work, seq_starts.astype(jnp.int32), pos_base.astype(jnp.int32),
       slopes, *operands)
     return jnp.transpose(
@@ -611,6 +677,8 @@ def ragged_paged_attention(
     kv_scales: tuple | None = None,  # f32 x2 quantized-KV sidecars:
     #     [L, Hkv, pages] with ``layer``, one layer's [Hkv, pages] without
     layer: int | None = None,  # static: the pool's layer this call reads
+    name: str | None = None,  # the kernel call's name in a device trace
+    #     (None = the kernel function's own, as every trace so far has it)
 ) -> jax.Array:
     """One causal paged-attention dispatch over a mixed ragged stream.
 
@@ -661,6 +729,7 @@ def ragged_paged_attention(
         window=window,
         interpret=_pallas_interpret(),
         layer=layer,
+        name=name,
     )
     # what only some calls carry
     extras = {

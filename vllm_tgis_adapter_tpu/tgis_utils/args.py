@@ -206,8 +206,12 @@ def add_engine_args(parser: argparse.ArgumentParser) -> argparse.ArgumentParser:
                    help="decode steps fused into one device dispatch "
                         "(tokens sampled per sequence between host "
                         "round-trips); 1 disables multi-step decode")
-    g.add_argument("--block-size", type=int, default=16,
-                   help="KV-cache page size in tokens")
+    g.add_argument("--block-size", type=int, default=None,
+                   help="KV-cache page size in tokens; unset, 16, and "
+                        "128 (one run of the attention kernel's work "
+                        "item) for a model that keeps its window layers' "
+                        "and its full layers' pages in two groups "
+                        "(docs/ATTENTION.md)")
     g.add_argument("--attention-backend", type=str, default="ragged",
                    choices=["bucketed", "ragged"],
                    help="serving data path (docs/ATTENTION.md): "
