@@ -147,7 +147,9 @@ class Driver:
     STREAM = 40  # every span is padded to this many rows
 
     def __init__(self, served_model, *, pages: int = 24,
-                 window_pages: int = 12, poison: bool = False):
+                 window_pages: int = 12, poison: bool = False,
+                 stream: int = STREAM):
+        self.STREAM = stream
         self.mcfg, self.model, self.params = served_model
         self.caches = self.model.make_kv_caches(
             pages * BLOCK, jnp.float32, window_slots=window_pages * BLOCK)
@@ -346,6 +348,25 @@ def test_window_layers_through_the_kernel_match_reference(share, ref_logits,
                 [(0, n, at)], block_size=BLOCK, block_q=block_q,
                 window=window)
         at += n
+
+
+def test_the_kernel_over_freed_pages_filled_with_nan(share, ref_logits,
+                                                     pallas):
+    """(b) through the kernel (interpreted): the window layers' schedule
+    never names a freed page, so NaN in one reaches no tile, with the
+    chunks' tiles of 256 rows (4 query heads a KV head x 64) keeping
+    their statistics on sublanes and the wave's of 16 on lanes."""
+    mcfg, model, _ = share
+    assert ragged.tile_stats(
+        mcfg.q_per_kv, ragged.query_block(model.ragged_block_q, 64)
+    ) == "sublanes"
+    assert ragged.tile_stats(
+        mcfg.q_per_kv, ragged.decode_block_q(Driver.ROWS)) == "lanes"
+    worst, drv = chunked_then_decoded(
+        share, ref_logits, (16, 8, 5, 3), 9, poison=True, window_pages=16,
+        stream=64)
+    assert drv.freed > 3
+    assert worst < TOLERANCE, worst
 
 
 def kernel_case(window: int, *, schedule_window: int, pos0: int = 520,

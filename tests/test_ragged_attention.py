@@ -212,6 +212,167 @@ def test_ragged_pallas_matches_xla(window, use_alibi, schedule, shape):
     )
 
 
+#: tiles by their rows (G query heads a KV head x block_q rows a query
+#: block) and the axis ``tile_stats`` puts their statistics on: the
+#: served chunk tiles (512 granite, 640 falcon, 1,024 Command A+), the
+#: 128-row wave tile of Command A+, and the waves' 32 and 40 rows
+_TILES = {
+    "128-rows": (16, 8, "sublanes"),
+    "512-rows": (4, 128, "sublanes"),
+    "640-rows": (5, 128, "sublanes"),
+    "1024-rows": (16, 64, "sublanes"),
+    "32-rows": (4, 8, "lanes"),
+    "40-rows": (5, 8, "lanes"),
+}
+
+
+def _quantized(kc, vc, bs, scheme):
+    """One layer's float ``[Hkv, slots, Dh]`` caches as quantized pages
+    and their scale sidecars, written as the step programs write them."""
+    import jax.numpy as jnp
+
+    from vllm_tgis_adapter_tpu.ops import kv_quant
+
+    slots = jnp.arange(kc.shape[1], dtype=jnp.int32)
+    pools = [
+        kv_quant.scatter_layer(
+            kv_quant.make_kv_cache(
+                (1, *cache.shape), jnp.float32, scheme, bs),
+            0, slots, jnp.swapaxes(cache, 0, 1))
+        for cache in (kc, vc)
+    ]
+    return (*(kv_quant.layer_data(pool, 0) for pool in pools),
+            kv_quant.layer_scales(*pools, 0))
+
+
+@pytest.mark.parametrize("variant", ["plain", "window", "alibi", "int8"])
+@pytest.mark.parametrize("tile", _TILES)
+def test_tile_layouts_match_xla(tile, variant):
+    """The kernel (interpret mode) is the XLA reference's to 1e-5 with
+    its statistics on either axis: a fresh prompt that crosses a query
+    block, decode rows on both sides of a run's end, a chunk whose later
+    rows read a second run, a row three runs deep; plain, under a band
+    that cuts into a run, with ALiBi, and over quantized pages."""
+    import jax.numpy as jnp
+
+    from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
+
+    g, block_q, stats = _TILES[tile]
+    assert ra.tile_stats(g, block_q) == stats
+    rng = np.random.default_rng(5)
+    case = _build_mixed_case(
+        rng, [(0, 70), (126, 1), (127, 1), (128, 1), (120, 12), (299, 1)],
+        hkv=1, g=g, bs=16, max_blocks=20, num_blocks=64, t_pad=128,
+        shuffle=True, stale=0.0 if variant == "int8" else 1e4,
+    )
+    window = 40 if variant == "window" else 0
+    slopes = (
+        jnp.asarray(rng.standard_normal(case["h"]).astype(np.float32) * 0.1)
+        if variant == "alibi" else None
+    )
+    kc, vc, scales = case["kc"], case["vc"], None
+    if variant == "int8":
+        kc, vc, scales = _quantized(kc, vc, case["bs"], "int8")
+    ref = ra.ragged_attention_xla(
+        jnp.asarray(case["q"]), kc, vc,
+        jnp.asarray(case["positions"]), jnp.asarray(case["seq_starts"]),
+        jnp.asarray(case["t"]), jnp.asarray(case["block_tables"]),
+        case["bs"], case["scale"], window=window, alibi_slopes=slopes,
+        kv_scales=scales,
+    )
+    work, n_items = ra.build_work_schedule(
+        case["spans"], case["block_tables"], block_size=case["bs"],
+        block_q=block_q, t_pad=case["t_pad"],
+    )
+    out = ra._ragged_attention_pallas(
+        jnp.asarray(case["q"]), kc, vc,
+        jnp.asarray(case["seq_starts"]), jnp.asarray(case["pos_base"]),
+        jnp.asarray(work), case["bs"], case["scale"], block_q=block_q,
+        window=window, alibi_slopes=slopes, interpret=True,
+        kv_scales=scales, n_items=jnp.asarray(n_items, jnp.int32),
+    )
+    np.testing.assert_allclose(
+        np.asarray(out[: case["t"]]), np.asarray(ref[: case["t"]]),
+        rtol=1e-5, atol=1e-5,
+    )
+
+
+@pytest.mark.parametrize("tile", ["1024-rows", "32-rows"])
+def test_masked_rows_and_padded_blocks_give_zeros_not_nan(tile):
+    """Rows past the stream's end share a query block with real rows
+    (every score of theirs masked: m stays -inf), and the stream's
+    padding has query blocks of its own (one dead item each): both come
+    out as zeros under either layout, never NaN."""
+    import jax.numpy as jnp
+
+    from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
+
+    g, block_q, _ = _TILES[tile]
+    rng = np.random.default_rng(6)
+    case = _build_mixed_case(
+        rng, [(130, 9), (0, 3)], hkv=1, g=g, bs=16, max_blocks=20,
+        num_blocks=64, t_pad=4 * block_q, stale=1e4,
+    )
+    work, n_items = ra.build_work_schedule(
+        case["spans"], case["block_tables"], block_size=case["bs"],
+        block_q=block_q, t_pad=case["t_pad"],
+    )
+    assert n_items > 3 and (work[6, :n_items] == 0).sum() >= 2
+    out = np.asarray(ra._ragged_attention_pallas(
+        jnp.asarray(case["q"]), case["kc"], case["vc"],
+        jnp.asarray(case["seq_starts"]), jnp.asarray(case["pos_base"]),
+        jnp.asarray(work), case["bs"], case["scale"], block_q=block_q,
+        window=0, interpret=True, n_items=jnp.asarray(n_items, jnp.int32),
+    ))
+    assert np.isfinite(out).all()
+    assert np.abs(out[: case["t"]]).max(axis=(1, 2)).min() > 0
+    assert not out[case["t"]:].any()
+
+
+@pytest.mark.parametrize("tile", _TILES)
+def test_counter_label_is_the_layout_the_kernel_call_takes(tile,
+                                                           monkeypatch):
+    """``tgis_tpu_ragged_items_total``'s label for a call and the layout
+    the kernel's wrapper hands its body are one predicate's answer: the
+    host asks ``tile_stats`` with the model's query heads a KV head and
+    the plan's query block, the wrapper with the operands' own."""
+    import types
+
+    import jax.numpy as jnp
+
+    from vllm_tgis_adapter_tpu.engine.core import LLMEngine
+    from vllm_tgis_adapter_tpu.ops import ragged_attention as ra
+
+    g, block_q, stats = _TILES[tile]
+    taken = []
+    kernel = ra._ragged_kernel
+
+    def spy(*refs, **static):
+        taken.append(static["stats"])
+        return kernel(*refs, **static)
+
+    monkeypatch.setattr(ra, "_ragged_kernel", spy)
+    case = _build_mixed_case(
+        np.random.default_rng(8), [(3, 5)], hkv=1, g=g, t_pad=block_q)
+    work, n_items = ra.build_work_schedule(
+        case["spans"], case["block_tables"], block_size=case["bs"],
+        block_q=block_q, t_pad=case["t_pad"])
+    ra._ragged_attention_pallas(
+        jnp.asarray(case["q"]), case["kc"], case["vc"],
+        jnp.asarray(case["seq_starts"]), jnp.asarray(case["pos_base"]),
+        jnp.asarray(work), case["bs"], case["scale"], block_q=block_q,
+        window=0, interpret=True, n_items=jnp.asarray(n_items, jnp.int32))
+    engine = types.SimpleNamespace(config=types.SimpleNamespace(
+        model_config=types.SimpleNamespace(
+            has_latent_cache=False, q_per_kv=g)))
+    raised = LLMEngine._items_by_stats(engine, [(block_q, n_items)])
+    assert set(taken) == set(raised) == {stats}
+    assert raised[stats] == n_items
+    # a model with another attention kernel raises neither label
+    engine.config.model_config.has_latent_cache = True
+    assert not LLMEngine._items_by_stats(engine, [(block_q, n_items)])
+
+
 def _decode_case(rng, ctx, *, bs=4, max_blocks=8, hkv=2, g=2, dh=16,
                  cache_dtype=None, page_gain=1.0):
     """A decode wave's attention inputs: row i one token at position
@@ -245,14 +406,7 @@ def _decode_case(rng, ctx, *, bs=4, max_blocks=8, hkv=2, g=2, dh=16,
         kc, vc = kc * gain, vc * gain
     scales = None
     if cache_dtype is not None:
-        qk = qv = kv_quant.make_kv_cache(
-            (1, *shape), jnp.float32, cache_dtype, bs
-        )
-        slots = jnp.arange(num_pages * bs, dtype=jnp.int32)
-        qk = kv_quant.scatter_layer(qk, 0, slots, jnp.swapaxes(kc, 0, 1))
-        qv = kv_quant.scatter_layer(qv, 0, slots, jnp.swapaxes(vc, 0, 1))
-        kc, vc = kv_quant.layer_data(qk, 0), kv_quant.layer_data(qv, 0)
-        scales = kv_quant.layer_scales(qk, qv, 0)
+        kc, vc, scales = _quantized(kc, vc, bs, cache_dtype)
     return dict(
         q=jnp.asarray(rng.standard_normal((b, hkv * g, dh)), jnp.float32),
         kc=kc, vc=vc, scales=scales, tables=jnp.asarray(tables),

@@ -168,6 +168,17 @@ def test_mixed_step_observes_its_real_item_count(tiny_model_dir,
     steps_0 = _sample(before, "tgis_tpu_ragged_live_items_count")
     items_0 = _sample(before, "tgis_tpu_ragged_live_items_sum")
 
+    def kernel_items(text: str) -> float:
+        """Every live item a ragged-kernel call of a plan visited: the
+        mixed steps' and the waves', under either layout's label."""
+        return sum(
+            _sample(text, "tgis_tpu_ragged_items_total", (f'stats="{s}"',))
+            for s in ("sublanes", "lanes"))
+
+    def plan_items(text: str) -> float:
+        return (_sample(text, "tgis_tpu_ragged_live_items_sum")
+                + _sample(text, "tgis_tpu_decode_live_items_sum"))
+
     alone: list[int] = []
 
     async def scenario() -> None:
@@ -203,6 +214,14 @@ def test_mixed_step_observes_its_real_item_count(tiny_model_dir,
         _sample(after, "tgis_tpu_ragged_live_items_sum") - items_0
         == sum(items for items, _ in seen)
     )
+    # one kernel call a plan in this family: the counter by layout holds
+    # what the two histograms hold, and this model's tiles (4 query
+    # heads over 2 KV heads, query blocks of at most 64) are all small
+    assert kernel_items(after) - kernel_items(before) == (
+        plan_items(after) - plan_items(before)) > 0
+    assert _sample(after, "tgis_tpu_ragged_items_total",
+                   ('stats="sublanes"',)) == _sample(
+        before, "tgis_tpu_ragged_items_total", ('stats="sublanes"',))
 
 
 def test_recompile_tracker_two_batch_shapes(tiny_model_dir):
@@ -258,6 +277,8 @@ def test_metrics_endpoint_serves_step_metrics(tiny_model_dir):
         "tgis_tpu_decode_batch_occupancy",
         "tgis_tpu_decode_live_items",
         "tgis_tpu_ragged_live_items",
+        'tgis_tpu_ragged_items_total{stats="sublanes"}',
+        'tgis_tpu_ragged_items_total{stats="lanes"}',
         "tgis_tpu_attn_item_pages",
         "tgis_tpu_prefill_padding_waste",
         "tgis_tpu_padded_tokens_total",

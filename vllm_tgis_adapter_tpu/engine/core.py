@@ -2061,20 +2061,52 @@ class LLMEngine:
         except Exception:  # pragma: no cover — metrics are best-effort
             logger.debug("expert metric observation failed", exc_info=True)
 
+    def _items_by_stats(self, calls) -> dict:
+        """The live items of a plan's ragged-kernel calls, each a
+        ``(query block, items a KV head)``, by the layout the call's
+        static shape selects (``ops/ragged_attention.py`` ``tile_stats``,
+        which the kernel's wrapper asks too).  Empty for a model whose
+        attention is another kernel."""
+        from vllm_tgis_adapter_tpu.ops.ragged_attention import tile_stats
+
+        mcfg = self.config.model_config
+        by_stats: dict = {}
+        if mcfg.has_latent_cache:
+            return by_stats
+        for block_q, items in calls:
+            stats = tile_stats(mcfg.q_per_kv, block_q)
+            by_stats[stats] = by_stats.get(stats, 0) + items
+        return by_stats
+
     def _observe_plan(self, plan, prepared) -> None:
         """Step-level telemetry (metrics.py): batch occupancy / padding
         waste gauges for this dispatch's shape, plus the plan→commit
         timestamp the commit phase turns into a step-duration sample."""
+        from vllm_tgis_adapter_tpu.ops.ragged_attention import (
+            decode_block_q,
+            pages_per_item,
+            query_block,
+        )
+
         try:
             if isinstance(plan, RaggedPlan):
                 if self.config.model_config.has_latent_cache:
                     self._observe_latent_attention(
                         [(len(i.token_ids), i.start_pos)
                          for i in plan.items], steps=1)
+                chunk_q = query_block(
+                    getattr(self.runner.model, "ragged_block_q", 128),
+                    prepared.bucket)
+                calls = [(chunk_q, prepared.work_items)]
                 if self.config.model_config.has_window_group:
                     # the running rows' spans are a kernel call of
                     # their own where the schedules leave them out
                     units = prepared.unit_items
+                    calls += [
+                        (chunk_q, prepared.window_work_items),
+                        (decode_block_q(min(len(prepared.seq_starts) - 1,
+                                            prepared.bucket)), sum(units)),
+                    ]
                     self._observe_window_attention(
                         [(len(i.token_ids), i.start_pos)
                          for i in plan.items],
@@ -2101,6 +2133,7 @@ class LLMEngine:
                     capped=plan.capped,
                     live_items=prepared.work_items,
                     live_pages=prepared.work_pages,
+                    items_by_stats=self._items_by_stats(calls),
                 )
             elif isinstance(plan, PrefillPlan):
                 metrics.observe_prefill_plan(
@@ -2112,10 +2145,6 @@ class LLMEngine:
                 # pages under the live rows' contexts and the runs the
                 # kernel reads them in, from the host's copy of the
                 # wave's inputs (no device read-back)
-                from vllm_tgis_adapter_tpu.ops.ragged_attention import (
-                    pages_per_item,
-                )
-
                 if self.config.model_config.has_latent_cache:
                     # a row's steps are consecutive tokens from its
                     # position; the loop ends at the last live step
@@ -2136,25 +2165,30 @@ class LLMEngine:
                 ctx = prepared.context_lens[: len(plan.seqs)]
                 pages = (ctx - 1) // self.runner.block_size + 1
                 run = pages_per_item(self.runner.block_size)
+                live_items = int((-(-pages // run)).sum())
+                wave_q = decode_block_q(plan.batch_bucket)
+                calls = [(wave_q, live_items)]
                 if self.config.model_config.has_window_group:
                     # the wave's first step: a row's band starts at the
                     # page of the oldest token it sees
                     first = (
                         ctx - self.config.model_config.group_window
                     ).clip(min=0) // self.runner.block_size
+                    window_items = int((-(-(pages - first) // run)).sum())
+                    calls.append((wave_q, window_items))
                     self._observe_window_attention(
                         zip(plan.steps_per_seq,
                             prepared.positions[: len(plan.seqs)].tolist()),
                         steps=max(plan.steps_per_seq, default=0),
-                        items=(int((-(-pages // run)).sum()),
-                               int((-(-(pages - first) // run)).sum())))
+                        items=(live_items, window_items))
                 metrics.observe_decode_plan(
                     num_seqs=len(plan.seqs),
                     batch_bucket=plan.batch_bucket,
                     num_steps=plan.num_steps,
-                    live_items=int((-(-pages // run)).sum()),
+                    live_items=live_items,
                     live_pages=int(pages.sum()),
                     replica=self.replica_index,
+                    items_by_stats=self._items_by_stats(calls),
                 )
         except Exception:  # pragma: no cover — metrics are best-effort
             logger.debug("step metric observation failed", exc_info=True)

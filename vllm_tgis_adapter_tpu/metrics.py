@@ -326,6 +326,22 @@ ragged_live_items = _get_or_create(
     "is built, i.e. on the Pallas path",
     buckets=(16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192, 16384),
 )
+ragged_items_total = _get_or_create(
+    Counter,
+    f"{_PREFIX}_ragged_items_total",
+    "Live work items of the ragged attention kernel's calls in "
+    "dispatched plans (a mixed step's schedules, its running rows' own, "
+    "a wave's first step), by the axis of the score tile the call's "
+    "online-softmax statistics run along: stats=\"sublanes\" where the "
+    "tile's rows (query heads a KV head x query-block rows) fill whole "
+    "lane widths and it is computed transposed, stats=\"lanes\" for the "
+    "small tiles that keep [rows, 128] "
+    "(ops/ragged_attention.py tile_stats); a model that does not run "
+    "this kernel raises neither",
+    labelnames=("stats",),
+)
+for _stats in ("sublanes", "lanes"):
+    ragged_items_total.labels(stats=_stats)
 attn_item_pages = _get_or_create(
     Histogram,
     f"{_PREFIX}_attn_item_pages",
@@ -959,11 +975,18 @@ step_snapshot = _StepSnapshot()
 
 def observe_decode_plan(*, num_seqs: int, batch_bucket: int,
                         num_steps: int, live_items: int,
-                        live_pages: int, replica: int = 0) -> None:
+                        live_pages: int, replica: int = 0,
+                        items_by_stats: dict[str, int] | None = None,
+                        ) -> None:
+    """``items_by_stats``: the live items of the wave's ragged-kernel
+    calls by the layout of their tile (empty: the model runs another
+    kernel)."""
     occupancy = num_seqs / batch_bucket if batch_bucket else 0.0
     decode_batch_occupancy.labels(replica=str(replica)).set(occupancy)
     wave_depth_steps.observe(num_steps)
     decode_live_items.observe(live_items)
+    for stats, items in (items_by_stats or {}).items():
+        ragged_items_total.labels(stats=stats).inc(items)
     if live_items:
         attn_item_pages.observe(live_pages / live_items)
     padded = (batch_bucket - num_seqs) * num_steps
@@ -1041,16 +1064,22 @@ def observe_ragged_plan(*, real_tokens: int, bucket: int,
                         num_prefill: int, num_decode: int,
                         live_items: int = 0,
                         live_pages: int = 0,
-                        capped: bool = False) -> None:
+                        capped: bool = False,
+                        items_by_stats: dict[str, int] | None = None,
+                        ) -> None:
     """Shape stats for one unified ragged dispatch
     (--attention-backend=ragged).  The padding-waste gauge reads from
     the RAGGED plan here — the bucketed gauges must not report stale
     bucket math when the ragged path is serving.  ``live_items`` is the
     real-item count of the step's work schedule (0: none was built),
     ``live_pages`` the KV pages those items read, ``capped`` whether
-    the stall rule cut the step's prompt share (RaggedPlan.capped)."""
+    the stall rule cut the step's prompt share (RaggedPlan.capped),
+    ``items_by_stats`` the live items of the step's ragged-kernel calls
+    by the layout of their tile."""
     fill = real_tokens / bucket if bucket else 0.0
     ragged_batch_fill_ratio.set(fill)
+    for stats, items in (items_by_stats or {}).items():
+        ragged_items_total.labels(stats=stats).inc(items)
     if live_items:
         ragged_live_items.observe(live_items)
         attn_item_pages.observe(live_pages / live_items)

@@ -56,7 +56,11 @@ builds its own in-trace, once per step (``decode_work_schedule``).
 Numerics: f32 online-softmax
 accumulation, masking identical to ``paged_decode_attention_xla`` — the
 XLA path below IS that function, so parity is pinned to the same
-reference the bucketed kernels are.
+reference the bucketed kernels are.  A tile whose rows fill whole lane
+widths is computed transposed, cached tokens on sublanes, so that the
+softmax's statistics are element-wise work and not reductions across
+lanes (``tile_stats``): the same float32 products and sums in another
+order.
 """
 
 from __future__ import annotations
@@ -100,6 +104,37 @@ def pages_per_item(block_size: int) -> int:
 def work_rows(block_size: int) -> int:
     """Rows of a work schedule: the fields, then a run's page ids."""
     return WORK_FIELDS + pages_per_item(block_size)
+
+
+def query_block(block_q: int, num_rows: int) -> int:
+    """The query block a call over a stream of ``num_rows`` rows runs
+    at: its ``block_q``, or the stream's padded length where that is
+    shorter."""
+    return min(block_q, _pow2_ceil(num_rows))
+
+
+def tile_stats(g: int, block_q: int) -> str:
+    """Which axis of a call's score tile the online softmax's statistics
+    run along, from the tile's static shape: ``g`` query heads a KV head
+    times ``block_q`` rows a query block (``query_block``) against
+    ``KV_TILE`` cached tokens.
+
+    ``"sublanes"``: a tile whose rows fill whole lane widths is computed
+    transposed, cached tokens on sublanes and query rows on lanes, so
+    the row maximum and the row sum are element-wise work over the
+    tile's sublane groups and one 8-to-1 reduce a lane tile.  Across
+    lanes they are one cross-lane reduction a vreg, twice, and their
+    results are broadcast back across lanes: 1.2 of a 1,024-row tile's
+    2.4 us (docs/ATTENTION.md "What a grid step costs").  A tile of
+    exactly 128 rows (16 query heads a KV head in a wave) holds as many
+    vregs either way and is a quarter faster so: the rule is a multiple
+    of 128, not 256 and up.
+
+    ``"lanes"``: the tile as ``[rows, KV_TILE]``.  The wave's tiles of
+    32 or 40 rows would pad their lanes to 128 when transposed, and
+    their cost is the pages' DMAs, not the reductions.
+    """
+    return "sublanes" if (g * block_q) % 128 == 0 else "lanes"
 
 
 #: lane width of the scale-sidecar block the quantized kernel DMAs (the
@@ -362,7 +397,8 @@ def _ragged_kernel(
     # P ks refs and P vs refs, each [Hkv, SCALE_LANES] f32 (the lane
     # tile of the layer's scale sidecar that holds that page's dequant
     # scale); then o_ref [1, G*bq, Dh] and the three f32 scratch
-    # accumulators (m [G*bq, 1], l [G*bq, 1], acc [G*bq, Dh])
+    # accumulators (m [G*bq, 1], l [G*bq, 1], acc [G*bq, Dh]; with the
+    # statistics on sublanes m [1, G*bq], l [1, G*bq], acc [Dh, G*bq])
     q_ref,
     *refs,
     scale: float,
@@ -373,7 +409,13 @@ def _ragged_kernel(
     use_alibi: bool,
     quantized: bool,
     run: int,
+    stats: str,
 ):
+    # the axes of the score tile that query rows and cached tokens lie
+    # on (``tile_stats``): the online softmax's maximum and sum run
+    # along the cached tokens' axis
+    transposed = stats == "sublanes"
+    q_axis, kv_axis = (1, 0) if transposed else (0, 1)
     k_refs, v_refs, refs = refs[:run], refs[run: 2 * run], refs[2 * run:]
     ks_refs = vs_refs = None
     if quantized:
@@ -421,13 +463,16 @@ def _ragged_kernel(
         q = q_ref[0].astype(jnp.float32)  # [G*bq, Dh]
         k = _tile(k_refs, ks_refs)  # [P*bs, Dh]
         v = _tile(v_refs, vs_refs)
+        # [G*bq, P*bs], or k @ q^T = [P*bs, G*bq] with the statistics on
+        # sublanes: the same NT product, operands swapped
         s_mat = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            *((k, q) if transposed else (q, k)), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # [G*bq, P*bs]
+        ) * scale
         # rows are (g, i) flattened row-major (chunked-kernel layout):
         # flat token index = qb*bq + row % bq
-        row = jax.lax.broadcasted_iota(jnp.int32, s_mat.shape, dimension=0)
+        row = jax.lax.broadcasted_iota(
+            jnp.int32, s_mat.shape, dimension=q_axis)
         tok = work_ref[0, w] * block_q + row % block_q
         # the item already names its sequence, and spans are contiguous
         # and sorted — membership and global position are two SMEM
@@ -436,7 +481,8 @@ def _ragged_kernel(
         # the span mask out, so their garbage pos_row never matters)
         start = starts_ref[seq]
         pos_row = base_ref[seq] + tok - start
-        col = jax.lax.broadcasted_iota(jnp.int32, s_mat.shape, dimension=1)
+        col = jax.lax.broadcasted_iota(
+            jnp.int32, s_mat.shape, dimension=kv_axis)
         # the run's pages are consecutive LOGICAL pages, so a column's
         # position is its offset from the run's first page; the places
         # of a short run lie past every row's own position
@@ -459,7 +505,8 @@ def _ragged_kernel(
         s_mat = jnp.where(keep, s_mat, NEG_INF)
 
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s_mat, axis=-1, keepdims=True))
+        m_new = jnp.maximum(
+            m_prev, jnp.max(s_mat, axis=kv_axis, keepdims=True))
         # fully masked rows keep m == -inf; pin the shift finite so exp
         # stays NaN-free (house convention, see _prefill_kernel)
         shift = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
@@ -467,17 +514,21 @@ def _ragged_kernel(
         alpha = jnp.exp(
             jnp.where(jnp.isfinite(m_prev), m_prev, shift) - shift
         )
-        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(
+            p, axis=kv_axis, keepdims=True)
+        # p @ v = [G*bq, Dh], or v^T @ p = [Dh, G*bq]
         acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            *((v, p) if transposed else (p, v)),
+            (((kv_axis,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_ref[...] = m_new
 
     @pl.when(work_ref[5, w] == 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+        # one transpose a (query block, sequence), not one an item
+        o_ref[0] = (out.T if transposed else out).astype(o_ref.dtype)
 
 
 def _pool_view(k_cache, v_cache, kv_scales, layer):
@@ -526,7 +577,7 @@ def _ragged_attention_pallas(
             f"work schedule of {work.shape[0]} rows: block size "
             f"{block_size} takes {WORK_FIELDS} fields and {run} page ids"
         )
-    block_q = min(block_q, _pow2_ceil(t))
+    block_q = query_block(block_q, t)
     nq = pl.cdiv(t, block_q)
     t_pad = nq * block_q
 
@@ -545,6 +596,12 @@ def _ragged_attention_pallas(
     )
     num_work = work.shape[1] if n_items is None else n_items
     quantized = kv_scales is not None
+    stats = tile_stats(g, block_q)
+    rows = g * block_q
+    stat_shape, acc_shape = {
+        "lanes": ((rows, 1), (rows, head_dim)),
+        "sublanes": ((1, rows), (head_dim, rows)),
+    }[stats]
     in_specs = [
         pl.BlockSpec(
             (1, g * block_q, head_dim),
@@ -593,9 +650,9 @@ def _ragged_attention_pallas(
             lambda h, w, wk, st, bs_, al: (h, wk[0, w], 0),
         ),
         scratch_shapes=[
-            pltpu.VMEM((g * block_q, 1), jnp.float32),
-            pltpu.VMEM((g * block_q, 1), jnp.float32),
-            pltpu.VMEM((g * block_q, head_dim), jnp.float32),
+            pltpu.VMEM(stat_shape, jnp.float32),
+            pltpu.VMEM(stat_shape, jnp.float32),
+            pltpu.VMEM(acc_shape, jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -603,7 +660,7 @@ def _ragged_attention_pallas(
             _ragged_kernel, scale=scale, block_size=block_size,
             block_q=block_q, g=g, window=window,
             use_alibi=alibi_slopes is not None, quantized=quantized,
-            run=run,
+            run=run, stats=stats,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
